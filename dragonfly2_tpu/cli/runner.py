@@ -18,6 +18,10 @@ from dragonfly2_tpu.utils import dflog
 
 logger = dflog.get("cli")
 
+# the binaries that own JAX device planes (fit, scoring service, topology
+# engine, forecaster); manager and daemon never import jax
+_DEVICE_SERVICES = ("scheduler", "trainer")
+
 
 def run(name: str, server) -> int:
     stop_event = threading.Event()
@@ -73,11 +77,10 @@ def main_with_config(name: str, build, argv=None) -> int:
     )
     args = p.parse_args(argv)
 
-    # test/e2e hook: force the JAX platform before any compute-plane
-    # import (see cli/config.apply_jax_platform_env)
-    from dragonfly2_tpu.cli.config import apply_jax_platform_env
+    if name in _DEVICE_SERVICES:
+        from dragonfly2_tpu.utils.jitcache import enable_compile_cache
 
-    apply_jax_platform_env()
+        enable_compile_cache()
 
     # multi-host slice/DCN job: bring up jax.distributed before any
     # device query (no-op without DF_JAX_COORDINATOR)

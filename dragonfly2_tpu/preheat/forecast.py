@@ -301,13 +301,15 @@ class DemandForecaster:
 
 
 def _device_usable() -> bool:
+    """jax when it is installed. A backend that imports but cannot
+    enumerate its devices raises here instead of quietly forecasting on
+    the numpy twin."""
     try:
         import jax
-
-        jax.devices()
-        return True
-    except Exception:
+    except ImportError:
         return False
+    jax.devices()
+    return True
 
 
 def _tree_map_np(params):

@@ -520,6 +520,16 @@ def make_handler(service: str, implementation: Any) -> grpc.GenericRpcHandler:
     return grpc.method_handlers_generic_handler(service, handlers)
 
 
+# both ends of every channel: the announcer's Train stream ships the
+# dataset in 128 MiB chunks (announcer.DEFAULT_UPLOAD_CHUNK), far past
+# grpc's 4 MiB default — a limit raised on the dialing side alone still
+# has the server refuse the first real upload with RESOURCE_EXHAUSTED
+_MESSAGE_LIMITS = (
+    ("grpc.max_send_message_length", 256 * 1024 * 1024),
+    ("grpc.max_receive_message_length", 256 * 1024 * 1024),
+)
+
+
 def serve(
     implementations: dict[str, Any],
     address: str = "127.0.0.1:0",
@@ -537,7 +547,9 @@ def serve(
     local-CLI path (reference pkg/rpc/mux.go serves tcp+unix+vsock from
     one grpc.Server); extras are plaintext, the filesystem is their
     access control."""
-    server = grpc.server(futures.ThreadPoolExecutor(max_workers=max_workers))
+    server = grpc.server(
+        futures.ThreadPoolExecutor(max_workers=max_workers), options=_MESSAGE_LIMITS
+    )
     for service, impl in implementations.items():
         server.add_generic_rpc_handlers((make_handler(service, impl),))
     if tls is not None:
@@ -574,10 +586,7 @@ def dial(
     against that root; ``tls_client`` adds the client pair for mTLS;
     ``tls_server_name`` overrides SNI/verification for certs issued to a
     different name."""
-    options = [
-        ("grpc.max_send_message_length", 256 * 1024 * 1024),
-        ("grpc.max_receive_message_length", 256 * 1024 * 1024),
-    ]
+    options = list(_MESSAGE_LIMITS)
     if tls_server_name:
         options.append(("grpc.ssl_target_name_override", tls_server_name))
     last: Exception | None = None

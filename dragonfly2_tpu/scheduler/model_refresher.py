@@ -21,10 +21,27 @@ from dragonfly2_tpu.rpc import gen  # noqa: F401
 import manager_pb2  # noqa: E402
 
 from dragonfly2_tpu.scheduler.evaluator import MLEvaluator
-from dragonfly2_tpu.trainer.serving import MLPScorer, deserialize_params_auto
+from dragonfly2_tpu.trainer.serving import (
+    BUCKET_LADDER,
+    MLPScorer,
+    bucket_rows,
+    deserialize_params_auto,
+)
 from dragonfly2_tpu.utils import dflog
 
 logger = dflog.get("scheduler.model_refresher")
+
+
+def _serving_rungs(serving) -> list[int]:
+    """Every bucket rung a packed batch can reach: the ladder, plus the
+    rung a batch lands on when its last request overshoots the service's
+    pack target. A scorer is compiled at ALL of them before it is
+    installed: a cold rung met on the serving thread stalls every queued
+    decision behind an XLA compile for longer than the service's grace,
+    and each of those decisions drops a rung on the ladder (seen on the
+    chip, where one such compile takes over a second)."""
+    top = serving.cfg.max_rows if serving is not None else BUCKET_LADDER[-1]
+    return sorted({bucket_rows(n) for n in (*BUCKET_LADDER, 2 * top)})
 
 
 class ModelRefresher:
@@ -114,12 +131,16 @@ class ModelRefresher:
             params = deserialize_params_auto(w.weights)
             scorer = MLPScorer(params)
             # compile + sanity-check before install: a scorer that cannot
-            # run must never reach the scheduling hot path
+            # run must never reach the scheduling hot path, and neither
+            # may its first compile at any rung (plain or wave-ranked)
             import numpy as np
 
             from dragonfly2_tpu.schema.features import MLP_FEATURE_NAMES
 
-            scorer.predict(np.zeros((1, len(MLP_FEATURE_NAMES)), np.float32))
+            for rows in _serving_rungs(self.serving):
+                x = np.zeros((rows, len(MLP_FEATURE_NAMES)), np.float32)
+                scorer.predict(x)
+                scorer.predict_ranked(x, np.zeros((rows,), np.int32))
         except Exception as e:
             logger.warning(
                 "loading model %s v%d failed (%s); keeping previous", m.model_id, m.version, e
@@ -209,7 +230,10 @@ class ModelRefresher:
             return None
         scorer = GNNScorer(params, graph)
         # compile + sanity-check at swap time, like the MLP install
-        scorer.predict_rtt_log_ms([graph.node_ids[0]], [graph.node_ids[1]])
+        for rows in _serving_rungs(self.serving):
+            scorer.predict_rtt_log_ms(
+                [graph.node_ids[0]] * rows, [graph.node_ids[1]] * rows
+            )
         return scorer
 
     def _refresh_gru(self, resp) -> bool:

@@ -14,7 +14,9 @@ so the fallback is a semantic spec for the native code.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from pathlib import Path
@@ -36,17 +38,45 @@ logger = dflog.get("schema.native")
 _REPO_ROOT = Path(__file__).resolve().parent.parent.parent
 _NATIVE_DIR = _REPO_ROOT / "native"
 _LIB_PATH = _NATIVE_DIR / "build" / "libdfnative.so"
+_STAMP_PATH = _LIB_PATH.with_suffix(".stamp")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_failed = False
 
 
-def _build() -> bool:
-    """make the shared library; True on success."""
+def _build_stamp() -> str:
+    """What a default build is valid for: these sources on this CPU. The
+    Makefile compiles with ``-march=native``, so an artefact copied from
+    another machine can carry instructions this one faults on, and file
+    mtimes say nothing about where it was built."""
+    h = hashlib.sha256()
+    for name in ("dfnative.cc", "Makefile"):
+        h.update((_NATIVE_DIR / name).read_bytes())
+    cpu = platform.machine()
     try:
+        with open("/proc/cpuinfo") as f:
+            cpu += next((ln for ln in f if ln.startswith(("flags", "Features"))), "")
+    except OSError:
+        pass
+    h.update(cpu.encode())
+    return h.hexdigest()
+
+
+def _stale(path: Path) -> bool:
+    try:
+        return not path.exists() or _STAMP_PATH.read_text() != _build_stamp()
+    except OSError:
+        return True  # no stamp (foreign artefact) or no sources to stamp
+
+
+def _build() -> bool:
+    """make the shared library and stamp it; True on success. ``-B``:
+    make's own mtime test would keep a foreign artefact."""
+    try:
+        stamp = _build_stamp()
         proc = subprocess.run(
-            ["make", "-C", str(_NATIVE_DIR)],
+            ["make", "-B", "-C", str(_NATIVE_DIR)],
             capture_output=True,
             text=True,
             timeout=120,
@@ -57,6 +87,7 @@ def _build() -> bool:
     if proc.returncode != 0:
         logger.warning("native build failed:\n%s", proc.stderr[-2000:])
         return False
+    _STAMP_PATH.write_text(stamp)
     return True
 
 
@@ -132,12 +163,7 @@ def load() -> ctypes.CDLL | None:
         if not override:
             # only the repo's default build is ours to (re)build; an
             # explicit override is loaded as-is
-            src = _NATIVE_DIR / "dfnative.cc"
-            stale = (
-                not path.exists()
-                or (src.exists() and src.stat().st_mtime > path.stat().st_mtime)
-            )
-            if stale and not _build():
+            if _stale(path) and not _build():
                 _load_failed = True
                 return None
         try:

@@ -1,11 +1,10 @@
 """Columnar train-stream wire format (v1) — the binary payload that
 replaces CSV on the announcer → trainer hot path.
 
-Why this exists (BENCH_r05 / VERDICT round 5): the single-threaded CSV
-decode rate (190k records/s) is *itself below* the 208k/s north-star
-rate, and `decode_wait_s` was 75-85% of every e2e wall — no consumer-side
-tuning can win while the payload must be re-parsed per byte on a 1-core
-trainer host. The structural fix is to move the per-record work to where
+Why this exists: with CSV on the stream the fit spent most of every
+end-to-end wall waiting on decode — no consumer-side tuning can win
+while the payload must be re-parsed per byte on the trainer host. The
+structural fix is to move the per-record work to where
 the records are born: the scheduler's sink extracts the training tensors
 **in batch at block-encode time**, and the trainer's ingest is
 ``mmap`` + ``np.frombuffer`` + an f16 cast — no parsing at all.

@@ -588,10 +588,9 @@ def run_gru_ab(cfg: GruABConfig | None = None, workdir: str | None = None) -> di
 
 
 def main() -> None:
-    # same platform hook as the service binaries
-    from dragonfly2_tpu.cli.config import apply_jax_platform_env
+    from dragonfly2_tpu.utils.jitcache import enable_compile_cache
 
-    apply_jax_platform_env()
+    enable_compile_cache()
     out = run_ab()
     out["gru"] = run_gru_ab()
     print(json.dumps(out))

@@ -145,6 +145,9 @@ def main(argv=None) -> int:
         from dragonfly2_tpu.tools.multichip_fit import ensure_devices
 
         ensure_devices(args.mesh)
+    from dragonfly2_tpu.utils.jitcache import enable_compile_cache
+
+    enable_compile_cache()
     stats = run(
         args.mb,
         args.passes,

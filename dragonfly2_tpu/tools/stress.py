@@ -835,47 +835,22 @@ def _serving_swarm(candidates: int, peers: int):
     return parents, children, task
 
 
-def _serving_scorer(backend: str):
-    """→ (scorer, backend_name): the jitted MLPScorer when XLA is usable
-    (per-call dispatch cost is what batching amortizes), the numpy
-    fallback otherwise — identical batched API either way."""
+def _serving_scorer():
+    """The jitted MLPScorer both soak arms share (per-call dispatch cost
+    is what batching amortizes), compiled once before any timing."""
     import jax
+    import numpy as np
 
+    from dragonfly2_tpu.models.mlp import init_mlp
     from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
     from dragonfly2_tpu.trainer import serving as tserving
 
-    if backend in ("auto", "jax"):
-        try:
-            from dragonfly2_tpu.models.mlp import init_mlp
-
-            params = init_mlp(jax.random.PRNGKey(0), [MLP_FEATURE_DIM, 64, 1])
-            scorer = tserving.MLPScorer(
-                tserving.deserialize_params_auto(
-                    tserving.serialize_params(params)
-                )
-            )
-            import numpy as np
-
-            scorer.predict(np.zeros((1, MLP_FEATURE_DIM), np.float32))
-            return scorer, "jax"
-        except Exception as e:
-            if backend == "jax":
-                raise
-            print(f"stress: jax scorer unavailable ({e}); numpy", file=sys.stderr)
-    import numpy as np
-
-    rng = np.random.default_rng(0)
-    params = {
-        "layers": [
-            {"w": rng.normal(0, 0.3, (MLP_FEATURE_DIM, 64)).astype(np.float32),
-             "b": np.zeros(64, np.float32)},
-            {"w": rng.normal(0, 0.3, (64, 1)).astype(np.float32),
-             "b": np.zeros(1, np.float32)},
-        ]
-    }
-    from dragonfly2_tpu.trainer.serving import NumpyMLPScorer
-
-    return NumpyMLPScorer(params), "numpy"
+    params = init_mlp(jax.random.PRNGKey(0), [MLP_FEATURE_DIM, 64, 1])
+    scorer = tserving.MLPScorer(
+        tserving.deserialize_params_auto(tserving.serialize_params(params))
+    )
+    scorer.predict(np.zeros((1, MLP_FEATURE_DIM), np.float32))
+    return scorer
 
 
 def serving_soak(
@@ -883,7 +858,6 @@ def serving_soak(
     decisions_per_peer: int = 20,
     candidates: int = 12,
     window_ms: float = 2.0,
-    backend: str = "auto",
 ) -> dict:
     """Batched-vs-per-call scheduler inference at ``peers`` concurrency
     (the ROADMAP item 1 acceptance soak): the SAME model ranks the same
@@ -908,7 +882,7 @@ def serving_soak(
     from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
     from dragonfly2_tpu.trainer.serving import bucket_rows
 
-    scorer, backend_used = _serving_scorer(backend)
+    scorer = _serving_scorer()
     parents, children, task = _serving_swarm(candidates, peers)
     total = task.total_piece_count
 
@@ -983,7 +957,7 @@ def serving_soak(
         # concurrent ops
         svc = ScoringService(ServingConfig(window_s=window_ms / 1e3))
         svc.start()
-        svc.install(MLPServed(scorer, kind=backend_used), version="soak/v1")
+        svc.install(MLPServed(scorer), version="soak/v1")
         try:
             b_rate, b_lat, b_done = run_arm(MLEvaluator(scorer, serving=svc))
         finally:
@@ -1018,7 +992,7 @@ def serving_soak(
     lat.sort()
     p99_us = _percentile(lat, 0.99) * 1e6
     return {
-        "serving_backend": backend_used,
+        "serving_backend": "jax",
         "serving_peers": peers,
         "serving_candidates": candidates,
         "serving_window_ms": window_ms,
@@ -1039,7 +1013,6 @@ def wave_soak(
     candidates: int = 12,
     wave_width: int = 8,
     window_ms: float = 2.0,
-    backend: str = "auto",
 ) -> dict:
     """Wave-packed vs per-op-batched scheduling on the SAME served
     model (the device-resident wave-scheduling acceptance soak): both
@@ -1064,7 +1037,7 @@ def wave_soak(
     from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
     from dragonfly2_tpu.trainer.serving import bucket_rows
 
-    scorer, backend_used = _serving_scorer(backend)
+    scorer = _serving_scorer()
     parents, children, task = _serving_swarm(candidates, peers)
     total = task.total_piece_count
 
@@ -1130,7 +1103,7 @@ def wave_soak(
     expected = peers * decisions_per_peer
     svc = ScoringService(ServingConfig(window_s=window_ms / 1e3))
     svc.start()
-    svc.install(MLPServed(scorer, kind=backend_used), version="soak/v1")
+    svc.install(MLPServed(scorer), version="soak/v1")
     try:
         # crosscheck first (untimed): wave rankings bit-identical to the
         # per-peer path on the same model
@@ -1169,7 +1142,7 @@ def wave_soak(
         unpack = sorted(svc.wave_unpack_us)
         svc.stop()
     return {
-        "serving_backend": backend_used,
+        "serving_backend": "jax",
         "wave_peers": peers,
         "wave_candidates": candidates,
         "wave_width": wave_width,
@@ -1420,7 +1393,8 @@ def _spawn_scheduler(workdir: str, kv_addr: str, lease_ttl: float,
         os.environ,
         PYTHONPATH=repo + os.pathsep + os.environ.get("PYTHONPATH", ""),
         PYTHONUNBUFFERED="1",
-        DF_JAX_PLATFORM=os.environ.get("DF_JAX_PLATFORM", "cpu"),
+        # a CPU harness: several shard processes cannot share one chip
+        JAX_PLATFORMS="cpu",
     )
     args = [
         sys.executable, "-m", "dragonfly2_tpu.scheduler",
@@ -2627,6 +2601,11 @@ def main(argv=None) -> int:
             > stats["data_plane_bytes_per_s_buffered"]
         )
         return 0 if ok else 1
+    if args.preheat or args.serving:
+        # the soaks that dispatch jitted work
+        from dragonfly2_tpu.utils.jitcache import enable_compile_cache
+
+        enable_compile_cache()
     if args.preheat:
         stats = preheat_soak(tasks=args.preheat_tasks, hot=args.preheat_hot)
         print(json.dumps(stats))

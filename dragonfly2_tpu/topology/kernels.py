@@ -252,11 +252,12 @@ class JaxKernels:
 def make_kernels(backend: str = "auto"):
     """``jax`` | ``numpy`` | ``auto`` (jax if importable, else numpy).
     Under ``JAX_PLATFORMS=cpu`` the jax path compiles for XLA:CPU — the
-    numpy twin is for environments where jax itself is unusable."""
-    if backend in ("auto", "jax"):
+    numpy twin is for environments where jax itself is not installed. A
+    jax that imports but cannot build its kernels is an error to raise,
+    not a reason to serve from the host silently."""
+    if backend == "auto":
         try:
-            return JaxKernels()
-        except Exception:
-            if backend == "jax":
-                raise
-    return NumpyKernels()
+            import jax  # noqa: F401
+        except ImportError:
+            backend = "numpy"
+    return NumpyKernels() if backend == "numpy" else JaxKernels()

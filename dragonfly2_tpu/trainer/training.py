@@ -149,23 +149,13 @@ class Training:
         self.manager_client = manager_client
         self.config = config or TrainingConfig()
         if mesh is None and self.config.auto_mesh:
-            mesh = self._auto_mesh()
-        self.mesh = mesh
-
-    @staticmethod
-    def _auto_mesh():
-        """Every-addressable-device dp mesh, or None on a single-device
-        host / unusable backend — a mesh-construction failure degrades
-        to the single-device fit, never fails training."""
-        try:
+            # every-addressable-device dp mesh (None on a single-device
+            # host). A failure to build it raises: a multi-chip host that
+            # quietly fits on one chip looks healthy and is not.
             from dragonfly2_tpu.parallel.mesh import auto_dp_mesh
 
-            return auto_dp_mesh()
-        except Exception:
-            logger.warning(
-                "auto dp mesh unavailable; fitting single-device", exc_info=True
-            )
-            return None
+            mesh = auto_dp_mesh()
+        self.mesh = mesh
 
     def train(self, ip: str, hostname: str) -> TrainingOutcome:
         """Fit MLP + GNN for one uploading scheduler host, concurrently

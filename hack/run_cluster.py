@@ -93,7 +93,9 @@ def main() -> int:
         os.environ,
         PYTHONPATH=REPO,
         PYTHONUNBUFFERED="1",
-        DF_JAX_PLATFORM=os.environ.get("DF_JAX_PLATFORM", "cpu"),
+        # a CPU harness: trainer and scheduler each own device planes,
+        # and a chip belongs to one process at a time
+        JAX_PLATFORMS="cpu",
         # service-plane spans in OTLP/JSON — the round-5 wire-parity leg:
         # every line a complete ExportTraceServiceRequest the otel
         # collector's otlpjsonfile receiver (→ Jaeger) ingests

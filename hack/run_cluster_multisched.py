@@ -48,7 +48,9 @@ def main() -> int:
         os.environ,
         PYTHONPATH=REPO,
         PYTHONUNBUFFERED="1",
-        DF_JAX_PLATFORM=os.environ.get("DF_JAX_PLATFORM", "cpu"),
+        # a CPU harness: trainer and scheduler each own device planes,
+        # and a chip belongs to one process at a time
+        JAX_PLATFORMS="cpu",
     )
     procs: list[Proc] = []
     try:

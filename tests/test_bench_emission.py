@@ -17,6 +17,9 @@ from dragonfly2_tpu.schema import synth, wire
 from dragonfly2_tpu.trainer import ingest
 from dragonfly2_tpu.trainer.ingest import StreamStats
 
+# these tests call main() in-process
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 
 def _fake_synthesize(d, shards, shard_bytes):
     paths = []
@@ -203,7 +206,6 @@ def _run_main(monkeypatch, capfd, fit_stub):
     monkeypatch.setattr(bench, "swarm_overhead_bench", _fake_swarm_overhead_bench)
     monkeypatch.setattr(ingest, "stream_train_mlp", fit_stub)
     monkeypatch.setenv("DF_BENCH_REPEATS", "3")
-    monkeypatch.delenv("DF_BENCH_CPU_FALLBACK", raising=False)
     bench.main()
     lines = [l for l in capfd.readouterr().out.splitlines() if l.strip()]
     assert len(lines) == 1, f"exactly one JSON line expected, got: {lines}"
@@ -261,6 +263,12 @@ def test_all_runs_complete_emits_best(monkeypatch, capfd):
     assert len(rec["run_rates"]) == 3
     assert rec["value"] == max(rec["run_rates"])
     assert "run_error" not in rec and "error" not in rec
+    # the line names the backend the fit ran on, as jax reports it — no
+    # relabelled fallback platform exists any more
+    import jax
+
+    assert rec["platform"] == jax.devices()[0].platform
+    assert "fallback_reason" not in rec
 
 
 def test_emits_decode_rate_per_payload_format(monkeypatch, capfd):
@@ -840,6 +848,10 @@ def test_serving_bench_failure_rides_exit_path(monkeypatch, capfd):
     monkeypatch.setattr(bench, "fleet_shard_kill_bench", _fake_fleet_soak)
     monkeypatch.setattr(bench, "serving_bench", broken_serving)
     monkeypatch.setattr(bench, "wave_bench", _fake_wave_bench)
+    # stubbed like in every sibling: left real by omission, the multichip
+    # curve alone spawned four subprocess fits (~1 min) this test never reads
+    monkeypatch.setattr(bench, "data_plane_bench", _fake_data_plane_bench)
+    monkeypatch.setattr(bench, "multichip_scaling_bench", _fake_multichip_bench)
     monkeypatch.setattr(bench, "preheat_bench", _fake_preheat_bench)
     monkeypatch.setattr(bench, "registry_bench", _fake_registry_bench)
     monkeypatch.setattr(bench, "flow_overhead_bench", _fake_flow_overhead_bench)

@@ -14,7 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_run_cluster_script():
-    env = dict(os.environ, DF_QUIET="1", DF_JAX_PLATFORM="cpu")
+    env = dict(os.environ, DF_QUIET="1", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "hack", "run_cluster.py")],
         env=env,
@@ -35,7 +35,7 @@ def test_run_cluster_two_schedulers_shared_kv():
     role through the manager's embedded RESP KV server — consistent-hash
     affinity splits tasks, SyncProbes from both daemons land in one
     store, and each scheduler snapshots the whole shared probe graph."""
-    env = dict(os.environ, DF_QUIET="1", DF_JAX_PLATFORM="cpu")
+    env = dict(os.environ, DF_QUIET="1", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "hack", "run_cluster_multisched.py")],
         env=env,

@@ -15,7 +15,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 sys.path.insert(0, "@REPO@")
 import jax
-jax.config.update("jax_platforms", "cpu")
 from dragonfly2_tpu.parallel.distributed import ensure_initialized
 assert ensure_initialized(
     coordinator_address="@COORD@", num_processes=2, process_id=int(sys.argv[1])
@@ -101,7 +100,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 sys.path.insert(0, "@REPO@")
 import jax
-jax.config.update("jax_platforms", "cpu")
 from dragonfly2_tpu.parallel.distributed import ensure_initialized
 pid = int(sys.argv[1])
 assert ensure_initialized(

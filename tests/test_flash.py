@@ -148,8 +148,8 @@ def test_block_hint_legalization_properties():
     bounded padding: sublane dims multiples of 8, the LSE lane dim a
     multiple of 128 or equal to t_pad, bk dividing bq, and t_pad within
     one block of t. (The TPU lowering rules the CPU interpreter cannot
-    enforce — hack/tpu_smoke.py compiles a sample of these on the real
-    chip; this pins the arithmetic for the whole space.)"""
+    enforce — chip_smoke.py compiles a sample of these on the chip;
+    this pins the arithmetic for the whole space.)"""
     hypothesis = pytest.importorskip("hypothesis")
     given, settings, st = hypothesis.given, hypothesis.settings, hypothesis.strategies
 

@@ -219,6 +219,41 @@ def test_refresher_hot_swaps_serving_slot(manager):
         svc.stop()
 
 
+def test_refresher_compiles_every_serving_rung_before_install(manager, monkeypatch):
+    """A rung first met on the serving thread stalls every queued
+    decision behind its XLA compile — on the chip for longer than the
+    service's grace, so decisions drop a rung (chip_smoke caught it).
+    The refresher therefore runs the scorer at every rung a packed batch
+    can reach, plain and wave-ranked, BEFORE the swap."""
+    from dragonfly2_tpu.scheduler.serving import ScoringService, ServingConfig
+    from dragonfly2_tpu.trainer.serving import MLPScorer
+
+    calls: list = []  # ("predict" | "predict_ranked", rows) … then "install"
+    for name in ("predict", "predict_ranked"):
+        real = getattr(MLPScorer, name)
+
+        def spied(self, features, *rest, _real=real, _name=name):
+            calls.append((_name, features.shape[0]))
+            return _real(self, features, *rest)
+
+        monkeypatch.setattr(MLPScorer, name, spied)
+    svc = ScoringService(ServingConfig(max_rows=64))
+    real_install = svc.install
+    monkeypatch.setattr(
+        svc, "install", lambda *a, **kw: (calls.append("install"), real_install(*a, **kw))
+    )
+    refresher = ModelRefresher(manager, MLEvaluator(), scheduler_cluster_id=1, serving=svc)
+    _upload(manager, _mlp_params(0))
+    manager.UpdateModel(
+        manager_pb2.UpdateModelRequest(model_id="mlp-model", version=1, state="active")
+    )
+    assert refresher.refresh_once()
+    rungs = (8, 16, 32, 64, 128)  # the ladder + one overshooting request
+    assert set(calls[: calls.index("install")]) == {
+        (name, rows) for name in ("predict", "predict_ranked") for rows in rungs
+    }
+
+
 def test_refresher_gnn_occupies_serving_and_withdraws_to_mlp(manager):
     """An active GNN takes the batched serving slot (embeddings built at
     swap time from the live probe graph); withdrawing it falls serving

@@ -239,6 +239,21 @@ def test_training_builds_dp_mesh_by_default(tmp_path):
     assert t_off.mesh is None
 
 
+def test_training_raises_when_the_fit_mesh_cannot_be_built(tmp_path, monkeypatch):
+    """A multi-chip host whose mesh construction fails must not turn
+    into a single-device fit that looks healthy."""
+    from dragonfly2_tpu.parallel import mesh as mesh_mod
+    from dragonfly2_tpu.trainer.storage import TrainerStorage
+    from dragonfly2_tpu.trainer.training import Training
+
+    def broken():
+        raise RuntimeError("device enumeration failed")
+
+    monkeypatch.setattr(mesh_mod, "auto_dp_mesh", broken)
+    with pytest.raises(RuntimeError, match="device enumeration failed"):
+        Training(TrainerStorage(tmp_path / "store"))
+
+
 # ---------------------------------------------------------------------------
 # the subprocess harness (bench's multichip_scaling backend)
 # ---------------------------------------------------------------------------

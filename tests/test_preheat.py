@@ -222,6 +222,21 @@ def test_forecast_path_compiles_once_zero_steady_retraces():
     assert tt.h2d == 6  # the per-sweep feature upload, nothing else
 
 
+def test_forecaster_raises_when_jax_imports_but_finds_no_backend(monkeypatch):
+    """The numpy twin is for hosts without jax installed; a backend that
+    cannot enumerate its devices is an error, not a reason to forecast
+    on the host quietly."""
+    import jax
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        DemandForecaster(window_buckets=8)
+    assert DemandForecaster(window_buckets=8, use_device=False).backend == "numpy"
+
+
 def test_device_and_numpy_twin_parity_on_device_backend():
     f = _device_forecaster()
     counts = _ramping_window(t=12, seed=3)

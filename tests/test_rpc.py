@@ -388,3 +388,43 @@ class TestConsistentHash:
     def test_empty_ring_raises(self):
         with pytest.raises(ValueError):
             ConsistentHashRing().pick("t")
+
+
+def test_train_stream_accepts_a_chunk_past_grpc_default_limit():
+    """The announcer ships the dataset in 128 MiB chunks. grpc servers
+    refuse anything past 4 MiB unless told otherwise, and only the
+    dialing side was told: every upload of real size died with
+    RESOURCE_EXHAUSTED while toy-sized tests passed."""
+
+    class Sink:
+        received = 0
+
+        def Capabilities(self, request, context):
+            return trainer_pb2.CapabilitiesResponse()
+
+        def Train(self, request_iterator, context):
+            for req in request_iterator:
+                self.received += len(req.train_mlp_binary.dataset)
+            return trainer_pb2.TrainResponse()
+
+    sink = Sink()
+    server, port = serve({TRAINER_SERVICE: sink})
+    channel = dial(f"127.0.0.1:{port}")
+    try:
+        chunk = b"\0" * (8 * 1024 * 1024)
+        ServiceClient(channel, TRAINER_SERVICE).Train(
+            iter(
+                [
+                    trainer_pb2.TrainRequest(
+                        ip="127.0.0.1",
+                        hostname="h",
+                        train_mlp_binary=trainer_pb2.TrainMlpBinaryRequest(dataset=chunk),
+                    )
+                ]
+            ),
+            timeout=60,
+        )
+        assert sink.received == len(chunk)
+    finally:
+        channel.close()
+        server.stop(0)

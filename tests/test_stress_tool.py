@@ -17,6 +17,9 @@ from dragonfly2_tpu.scheduler.service import SERVICE_NAME as SCHED_SERVICE
 from dragonfly2_tpu.scheduler.service import SchedulerService
 from dragonfly2_tpu.tools import stress
 
+# these tests call main() in-process
+pytestmark = pytest.mark.usefixtures("compile_cache_off")
+
 
 @pytest.fixture
 def cluster(tmp_path):

@@ -199,6 +199,19 @@ class TestLandmarkInference:
                 engines["jax"].est_rtt_ns(a, b), rel=1e-5
             )
 
+    def test_auto_raises_when_jax_imports_but_its_kernels_fail(self, monkeypatch):
+        """``auto`` means "jax when installed": a jax that imports and
+        then fails must surface, not hand back the numpy twin."""
+        from dragonfly2_tpu.topology import kernels
+
+        def broken(self):
+            raise RuntimeError("backend refused")
+
+        monkeypatch.setattr(kernels.JaxKernels, "__init__", broken)
+        with pytest.raises(RuntimeError, match="backend refused"):
+            kernels.make_kernels("auto")
+        assert isinstance(kernels.make_kernels("numpy"), NumpyKernels)
+
 
 class TestStalenessDecay:
     def test_quiet_edges_lose_aggregation_weight(self):

@@ -340,3 +340,36 @@ def test_deploy_manifests_set_keys_exist_on_dataclasses():
             args = c.get("args") or []
             if args:
                 check_args(args[0], args)
+
+
+def test_compile_cache_is_placed_from_outside_or_at_one_fixed_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the helper touches no directory
+    setting (jax reads the variable itself). Unset: one absolute path
+    inside the checkout, the same on every call — the path is part of
+    the cache key, so a directory that moves never hits."""
+    import os
+
+    import jax
+
+    from dragonfly2_tpu.utils import jitcache
+
+    saved = (
+        jax.config.jax_compilation_cache_dir,
+        jax.config.jax_persistent_cache_min_compile_time_secs,
+    )
+    try:
+        jax.config.update("jax_compilation_cache_dir", "/placed/by/the/test")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/from/outside")
+        assert jitcache.enable_compile_cache() == "/from/outside"
+        assert jax.config.jax_compilation_cache_dir == "/placed/by/the/test"
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first, second = jitcache.enable_compile_cache(), jitcache.enable_compile_cache()
+        assert first == second == jax.config.jax_compilation_cache_dir
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(root, ".jax_cache")
+        # every compile is stored, the sub-second bucket rungs included
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
