@@ -16,7 +16,9 @@ ring, Ulysses, sharded GNN, orbax) and checks them against their oracles.
 Every check prints ``ok`` or ``FAIL``; any FAIL exits non-zero. Without
 an accelerator the script exits non-zero before doing any work: nothing
 here may carry on on the CPU. One process holds the chip — no child is
-started. The last stdout line is one JSON object naming the device.
+started. A ``summary:`` line carries the per-phase walls and compile
+counts; the last stdout line is the result, one JSON object with exactly
+``ok`` and ``device``.
 """
 
 from __future__ import annotations
@@ -833,6 +835,22 @@ def run_kernels(report: Report, seed: int) -> None:
         )
 
 
+def result_line(ok: bool, device: dict) -> str:
+    """The last stdout line: exactly ``ok`` and ``device`` (platform,
+    kind, count as jax reports them). Everything else the run has to say
+    goes on the ``summary:`` line before it."""
+    return json.dumps(
+        {
+            "ok": bool(ok),
+            "device": {
+                "platform": str(device["platform"]),
+                "kind": str(device["kind"]),
+                "count": int(device["count"]),
+            },
+        }
+    )
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--seed", type=int, default=0, help="data and weights")
@@ -868,26 +886,22 @@ def main(argv=None) -> int:
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     ok = not report.failed
-    print(
-        json.dumps(
-            {
-                "ok": ok,
-                "device": device,
-                "seed": args.seed,
-                "failed": report.failed,
-                "wall_s": round(time.perf_counter() - t0, 1),
-                "cache_dir": cache_dir,
-                "compile": {k: round(v, 1) for k, v in _COMPILES.items()},
-                "phases": report.phases,
-                "reduced": [
-                    f"{size.unique_records} seeded download records replicated to"
-                    f" {size.download_bytes // MIB} MiB of blocks",
-                    "probe RTTs drawn from a seeded model, not pinged",
-                ],
-                "claim": None,
-            }
-        )
-    )
+    summary = {
+        "seed": args.seed,
+        "failed": report.failed,
+        "wall_s": round(time.perf_counter() - t0, 1),
+        "cache_dir": cache_dir,
+        "compile": {k: round(v, 1) for k, v in _COMPILES.items()},
+        "phases": report.phases,
+        "reduced": [
+            f"{size.unique_records} seeded download records replicated to"
+            f" {size.download_bytes // MIB} MiB of blocks",
+            "probe RTTs drawn from a seeded model, not pinged",
+        ],
+        "claim": None,
+    }
+    print(f"summary: {json.dumps(summary)}")
+    print(result_line(ok, device), flush=True)
     return 0 if ok else 1
 
 

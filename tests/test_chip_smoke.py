@@ -4,6 +4,7 @@ changed signature fails here and not on a chip call. What the chip run
 is for — the TPU compiler, device placement at real sizes — this cannot
 show."""
 
+import json
 import os
 import subprocess
 import sys
@@ -70,6 +71,14 @@ def test_a_failing_served_score_fails_the_smoke(trained):
         faults.clear()
     assert "faulted: no serving fallback, no serving error" in trained.report.failed
     assert "faulted: decisions scored by the served model" in trained.report.failed
+
+
+def test_result_line_is_exactly_ok_and_device():
+    """The driver parses the last stdout line and refuses any other key."""
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    line = chip_smoke.result_line(True, device)
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": device}
 
 
 def test_script_entry_refuses_a_cpu_backend():
