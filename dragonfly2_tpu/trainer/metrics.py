@@ -1,6 +1,10 @@
 """Trainer Prometheus series (reference trainer/metrics/metrics.go:38-52
 plus fit-duration/ingest visibility the TPU trainer adds)."""
 
+from types import SimpleNamespace
+
+import jax
+
 from dragonfly2_tpu.utils import profiling
 from dragonfly2_tpu.utils.metrics import default_registry as _r
 
@@ -48,28 +52,48 @@ INGEST_BUFFER_WAIT_SECONDS = _r.histogram(
     "Packing thread blocked on the superbatch buffer pool, per superbatch",
     buckets=_INGEST_BUCKETS,
 )
-# device-side attribution for the jit-witness taps
-# (hack/dfanalyze/jitwitness.py): transfers are timed, compiles are
-# count-markers — both land in the dfprof phase ledger per fit
-PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
-PH_DEVICE_TRANSFER = profiling.phase_type("trainer.device_transfer")
 DATASET_BYTES_TOTAL = _r.counter(
     "trainer_dataset_bytes_total", "Dataset bytes received on Train streams", ("kind",)
 )
-# dispatch-plane hygiene counters, fed by the jit witness's bench taps
-# (hack/dfanalyze/jitwitness.py): XLA compilations and host→device
-# conversions observed while a tap is armed. Steady state on a warm fit
-# is ZERO recompiles and one H2D per superbatch — a moving recompile
-# counter mid-fit is the retrace storm bench.py's
-# jit_recompiles_per_fit key exists to catch.
+# Executables this process asked of the XLA backend: compiled, or loaded
+# from the persistent compile cache (a short one). Steady state on a
+# warm streamed fit is ZERO; the resident fits rebuild their epoch
+# function per fit and ask for it again every round. Both are fed by
+# the listener below, in every process that imports the trainer, with
+# each compile's real duration; a moving count mid-fit is the retrace
+# storm, visible on /metrics and /debug/prof.
 JIT_RECOMPILES_TOTAL = _r.counter(
     "trainer_jit_recompiles_total",
-    "XLA compilations observed by the jit witness taps",
+    "Executables asked of the XLA backend (compiled or loaded from the compile cache)",
 )
-H2D_TRANSFERS_TOTAL = _r.counter(
-    "trainer_h2d_transfers_total",
-    "Host-to-device conversions observed by the jit witness taps",
+PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
+
+# The resident fits' phases, one vocabulary for the three legs
+# (trainer/training.py wraps load and register, trainer/train.py the
+# rest). load/split/holdout/register are entered once a fit, the other
+# four once an epoch, never twice for one piece of work: total / count
+# reads as seconds a fit or seconds an epoch. Nothing here synchronises:
+# a phase times the call as the code makes it, and epoch_wait is the one
+# whose wall is the device's.
+FIT_STAGES = (
+    "load",  # bytes on disk -> host arrays
+    "split",  # the permutation that sets the holdout apart
+    "gather",  # the epoch's permutation, fancy-index gather and reshape
+    "feed",  # host wall of handing the epoch's arrays to the device
+    "epoch_dispatch",  # the epoch call until it returns: trace, cache look-up, enqueue
+    "epoch_wait",  # the blocking read of the epoch's mean loss
+    "holdout",  # holdout gather, forward and read-back
+    "register",  # params to the host and create_model
 )
+
+
+def _fit_phases(leg: str) -> SimpleNamespace:
+    return SimpleNamespace(
+        **{stage: profiling.phase_type(f"trainer.{leg}_{stage}") for stage in FIT_STAGES}
+    )
+
+
+PH_MLP, PH_GNN, PH_GRU = (_fit_phases(leg) for leg in ("mlp", "gnn", "gru"))
 # unix timestamp of the last SUCCESSFUL fit per model: the telemetry
 # plane's fit-freshness source (freshness = now - value; 0 = never) —
 # a gauge, so the manager can compute staleness without rate math
@@ -78,3 +102,17 @@ LAST_FIT_TIMESTAMP = _r.gauge(
     "Unix time of the last successful fit",
     ("model",),
 )
+
+
+def _on_compile(event: str, seconds: float, **_) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        JIT_RECOMPILES_TOTAL.inc()
+        # jax calls its listeners on the thread that compiled, so a fit
+        # leg's open split (Training._timed_fit) books its own compiles
+        PH_JIT_COMPILE.book(seconds)
+
+
+# once a process: a module body runs once, and every path to a fit
+# imports this module first (the benchmark builds the server and never
+# serves, so TrainerServer.serve() would be too late)
+jax.monitoring.register_event_duration_secs_listener(_on_compile)

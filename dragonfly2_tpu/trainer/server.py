@@ -44,8 +44,9 @@ class TrainerServerConfig:
     # to pin a deploy to single-device fits
     auto_mesh: bool = True
     # on-demand jax.profiler capture: a non-empty dir writes one XLA
-    # trace per fit under <profile_dir>/<model> (view with TensorBoard);
-    # settable per-deploy via config file or DF_TRAINER_PROFILE_DIR
+    # trace per round under <profile_dir>/round, around the three fits
+    # (view with TensorBoard); settable per-deploy via config file or
+    # DF_TRAINER_PROFILE_DIR
     profile_dir: str = ""
     # elastic restart: per-(model, host) fit snapshots under this dir —
     # a crashed fit resumes from its last epoch after the process comes

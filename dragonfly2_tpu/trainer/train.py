@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 from dragonfly2_tpu.models import gnn as gnn_mod
 from dragonfly2_tpu.models import gru as gru_mod
 from dragonfly2_tpu.models import mlp as mlp_mod
+from dragonfly2_tpu.trainer.metrics import PH_GNN, PH_GRU, PH_MLP
 from dragonfly2_tpu.utils import faults
 from dragonfly2_tpu.utils.jitcache import jit_once
 
@@ -119,19 +120,25 @@ def make_epoch_fn(
     optimizer: optax.GradientTransformation,
 ):
     """Build a jitted whole-epoch function: scan over [steps, batch, ...]
-    stacked minibatches, donating the carried state."""
+    stacked minibatches, donating the carried state. The function takes
+    its name from the loss's (``mlp_loss`` -> ``mlp_epoch``), so that a
+    trace's host events (``PjitFunction(mlp_epoch)``), the XLA module
+    and the scope of the step's ops say which leg they belong to."""
+    name = getattr(loss_fn, "__name__", "loss").removesuffix("_loss") + "_epoch"
 
     def epoch(params, opt_state, batches):
         def body(carry, batch):
-            params, opt_state = carry
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope(name):
+                params, opt_state = carry
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                updates, opt_state = optimizer.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return (params, opt_state), loss
 
         (params, opt_state), losses = jax.lax.scan(body, (params, opt_state), batches)
         return params, opt_state, losses.mean()
 
+    epoch.__name__ = epoch.__qualname__ = name
     return jax.jit(epoch, donate_argnums=(0, 1))
 
 
@@ -153,7 +160,8 @@ def train_mlp(
     """
     cfg = config or FitConfig()
     n, f = features.shape
-    train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
+    with PH_MLP.split:
+        train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
     steps, used, batch = _batch_steps(len(train_idx), cfg.batch_size)
 
     key = jax.random.PRNGKey(cfg.seed)
@@ -170,12 +178,12 @@ def train_mlp(
     optimizer = _optimizer(cfg, total_steps)
     opt_state = optimizer.init(params)
 
-    def loss_fn(p, batch):
+    def mlp_loss(p, batch):
         x, y = batch
         pred = mlp_mod.score_parents(p, x)
         return jnp.mean((pred - y) ** 2)
 
-    epoch_fn = make_epoch_fn(loss_fn, optimizer)
+    epoch_fn = make_epoch_fn(mlp_loss, optimizer)
 
     ckpt, start_epoch = _open_checkpoint(cfg)
     try:
@@ -190,15 +198,22 @@ def train_mlp(
             FP_FIT_STEP()
             # per-epoch rng: a resumed run replays the exact shuffle schedule
             rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            order = train_idx[rng.permutation(len(train_idx))][:used]
-            xb = features[order].reshape(steps, batch, f)
-            yb = labels[order].reshape(steps, batch)
-            xb, yb = _shard_arrays(mesh, xb, yb)
-            params, opt_state, mean_loss = epoch_fn(params, opt_state, (xb, yb))
-            history.append(float(mean_loss))
+            with PH_MLP.gather:
+                order = train_idx[rng.permutation(len(train_idx))][:used]
+                xb = features[order].reshape(steps, batch, f)
+                yb = labels[order].reshape(steps, batch)
+            with PH_MLP.feed:
+                xb, yb = _shard_arrays(mesh, xb, yb)
+            with PH_MLP.epoch_dispatch:
+                params, opt_state, mean_loss = epoch_fn(params, opt_state, (xb, yb))
+            with PH_MLP.epoch_wait:
+                history.append(float(mean_loss))
             _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
 
-        metrics = evaluate_mlp(params, features[eval_idx], labels[eval_idx]) if len(eval_idx) else {}
+        metrics = {}
+        if len(eval_idx):
+            with PH_MLP.holdout:
+                metrics = evaluate_mlp(params, features[eval_idx], labels[eval_idx])
         _finish_checkpoint(ckpt)
         ckpt = None
         return FitResult(params=params, metrics=metrics, history=history)
@@ -283,7 +298,8 @@ def train_gnn(
     """
     cfg = config or GNNFitConfig()
     e = len(graph.edge_src)
-    train_idx, eval_idx = _split_eval(e, cfg.eval_fraction, cfg.seed)
+    with PH_GNN.split:
+        train_idx, eval_idx = _split_eval(e, cfg.eval_fraction, cfg.seed)
     params = _init_gnn(graph, cfg)
     if mesh is not None:
         from dragonfly2_tpu.parallel.sharding import replicate
@@ -298,12 +314,12 @@ def train_gnn(
     optimizer = _optimizer(cfg, steps * cfg.epochs)
     opt_state = optimizer.init(params)
 
-    def loss_fn(p, b):
+    def gnn_loss(p, b):
         src, dst, y = b
         pred = gnn_mod.forward_edge_rtt(p, node_features, neighbors, neighbor_mask, src, dst)
         return jnp.mean((pred - y) ** 2)
 
-    epoch_fn = make_epoch_fn(loss_fn, optimizer)
+    epoch_fn = make_epoch_fn(gnn_loss, optimizer)
 
     ckpt, start_epoch = _open_checkpoint(cfg)
     try:
@@ -316,17 +332,23 @@ def train_gnn(
         history: list[float] = []
         for epoch in range(start_epoch, cfg.epochs):
             rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            order = train_idx[rng.permutation(len(train_idx))][:used]
-            sb = graph.edge_src[order].reshape(steps, batch)
-            db = graph.edge_dst[order].reshape(steps, batch)
-            yb = graph.edge_rtt_log_ms[order].reshape(steps, batch)
-            params, opt_state, mean_loss = epoch_fn(params, opt_state, (jnp.asarray(sb), jnp.asarray(db), jnp.asarray(yb)))
-            history.append(float(mean_loss))
+            with PH_GNN.gather:
+                order = train_idx[rng.permutation(len(train_idx))][:used]
+                sb = graph.edge_src[order].reshape(steps, batch)
+                db = graph.edge_dst[order].reshape(steps, batch)
+                yb = graph.edge_rtt_log_ms[order].reshape(steps, batch)
+            with PH_GNN.feed:
+                batches = (jnp.asarray(sb), jnp.asarray(db), jnp.asarray(yb))
+            with PH_GNN.epoch_dispatch:
+                params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
+            with PH_GNN.epoch_wait:
+                history.append(float(mean_loss))
             _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
 
         metrics: dict[str, float] = {}
         if len(eval_idx):
-            metrics = evaluate_gnn(params, graph, eval_idx)
+            with PH_GNN.holdout:
+                metrics = evaluate_gnn(params, graph, eval_idx)
         _finish_checkpoint(ckpt)
         ckpt = None
         return FitResult(params=params, metrics=metrics, history=history)
@@ -487,7 +509,8 @@ def train_gru(
     """Fit the next-piece-cost predictor over piece history sequences."""
     cfg = config or FitConfig(hidden_dims=(64,), batch_size=256, epochs=5)
     n, t, f = sequences.shape
-    train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
+    with PH_GRU.split:
+        train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
     if lengths is None:
         lengths = np.full((n,), t, np.int32)
 
@@ -503,31 +526,36 @@ def train_gru(
     optimizer = _optimizer(cfg, steps * cfg.epochs)
     opt_state = optimizer.init(params)
 
-    def loss_fn(p, b):
+    def gru_loss(p, b):
         x, y, ln = b
         pred = gru_mod.predict_next_cost(p, x, ln)
         return jnp.mean((pred - y) ** 2)
 
-    epoch_fn = make_epoch_fn(loss_fn, optimizer)
+    epoch_fn = make_epoch_fn(gru_loss, optimizer)
 
     history: list[float] = []
     rng = np.random.default_rng(cfg.seed + 1)
     for _ in range(cfg.epochs):
-        order = train_idx[rng.permutation(len(train_idx))][:used]
-        xb = sequences[order].reshape(steps, batch, t, f)
-        yb = labels[order].reshape(steps, batch)
-        lb = lengths[order].reshape(steps, batch)
-        xb, yb, lb = _shard_arrays(mesh, xb, yb, lb)
-        params, opt_state, mean_loss = epoch_fn(params, opt_state, (xb, yb, lb))
-        history.append(float(mean_loss))
+        with PH_GRU.gather:
+            order = train_idx[rng.permutation(len(train_idx))][:used]
+            xb = sequences[order].reshape(steps, batch, t, f)
+            yb = labels[order].reshape(steps, batch)
+            lb = lengths[order].reshape(steps, batch)
+        with PH_GRU.feed:
+            xb, yb, lb = _shard_arrays(mesh, xb, yb, lb)
+        with PH_GRU.epoch_dispatch:
+            params, opt_state, mean_loss = epoch_fn(params, opt_state, (xb, yb, lb))
+        with PH_GRU.epoch_wait:
+            history.append(float(mean_loss))
 
     metrics: dict[str, float] = {}
     if len(eval_idx):
-        pred = np.asarray(
-            jit_once(gru_mod.predict_next_cost)(
-                params, jnp.asarray(sequences[eval_idx]), jnp.asarray(lengths[eval_idx])
+        with PH_GRU.holdout:
+            pred = np.asarray(
+                jit_once(gru_mod.predict_next_cost)(
+                    params, jnp.asarray(sequences[eval_idx]), jnp.asarray(lengths[eval_idx])
+                )
             )
-        )
-        err = pred - labels[eval_idx]
-        metrics = {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
+            err = pred - labels[eval_idx]
+            metrics = {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
     return FitResult(params=params, metrics=metrics, history=history)

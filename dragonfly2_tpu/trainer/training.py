@@ -16,6 +16,7 @@ manager/models/model.go:20-26 state machine).
 from __future__ import annotations
 
 import concurrent.futures
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Protocol
@@ -28,7 +29,7 @@ from dragonfly2_tpu.schema.features import build_probe_graph, extract_pair_featu
 from dragonfly2_tpu.trainer.storage import TrainerStorage
 from dragonfly2_tpu.trainer.train import FitConfig, GNNFitConfig, train_gnn, train_mlp
 from dragonfly2_tpu.trainer import metrics as M
-from dragonfly2_tpu.utils import dflog, flight
+from dragonfly2_tpu.utils import dflog, flight, profiling
 from dragonfly2_tpu.utils.idgen import gnn_model_id_v1, host_id_v2, mlp_model_id_v1
 
 logger = dflog.get("trainer")
@@ -114,13 +115,48 @@ class TrainingConfig:
     # dp>1 path (sharded puts, replicated params, donation, scan+dp
     # layout) through this same switch every round.
     auto_mesh: bool = True
-    # jax.profiler trace dir per fit ("" = off); view with TensorBoard
+    # jax.profiler trace dir ("" = off): one trace per round, around
+    # the three fits, under <profile_dir>/round; view with TensorBoard
     profile_dir: str = ""
     # elastic restart: per-(model, host) orbax snapshots under this dir
     # (trainer/checkpoint.py) — a mid-fit crash resumes from the last
     # epoch snapshot on the next round instead of retraining from zero;
     # "" disables (the reference's behavior)
     checkpoint_dir: str = ""
+
+
+@dataclass
+class LegSplit:
+    """One fit leg's own account of one round, measured on the leg's
+    thread (``profiling.split``), so that two hosts' rounds running at
+    once do not mix as they do in the process-wide ledger."""
+
+    wall_s: float = 0.0
+    phase_s: dict[str, float] = field(default_factory=dict)  # seconds by phase
+    phase_n: dict[str, int] = field(default_factory=dict)  # entries by phase
+    # executables the leg's thread asked of the backend, and the seconds
+    # they took: spent INSIDE the phases above (the epoch's dispatch, the
+    # holdout's forward), not beside them
+    compiles: int = 0
+    compile_s: float = 0.0
+    # the streamed MLP fit's own split, when the leg streamed
+    stream: Any = None  # ingest.StreamStats | None
+
+    @property
+    def self_s(self) -> float:
+        """What no phase covers: the leg's own bookkeeping."""
+        return round(self.wall_s - sum(self.phase_s.values()), 6)
+
+    def fields(self) -> dict:
+        """As the ``trainer.fit`` event and the ``fit`` span carry it."""
+        return {
+            "wall_s": self.wall_s,
+            "self_s": self.self_s,
+            "phase_s": self.phase_s,
+            "phase_n": self.phase_n,
+            "compiles": self.compiles,
+            "compile_s": self.compile_s,
+        }
 
 
 @dataclass
@@ -131,6 +167,8 @@ class TrainingOutcome:
     mlp_error: str | None = None
     gnn_error: str | None = None
     gru_error: str | None = None  # GRU is optional; never gates .ok
+    wall_s: float = 0.0  # the round: the three fits, side by side
+    splits: dict[str, LegSplit] = field(default_factory=dict)  # by leg
 
     @property
     def ok(self) -> bool:
@@ -172,18 +210,22 @@ class Training:
         # the post-fit clear drops exactly that form, so other-era data
         # from a format switch survives to train next round
         mlp_info: dict = {}
-        with concurrent.futures.ThreadPoolExecutor(max_workers=3) as pool:
+        splits = outcome.splits
+        t0 = time.perf_counter()
+        with self._round_profile(), concurrent.futures.ThreadPoolExecutor(
+            max_workers=3
+        ) as pool:
             f_mlp = pool.submit(
-                self._timed_fit, "mlp", parent_span, self._train_mlp,
+                self._timed_fit, "mlp", parent_span, splits, self._train_mlp,
                 host_id, ip, hostname, mlp_info,
             )
             f_gnn = pool.submit(
-                self._timed_fit, "gnn", parent_span, self._train_gnn,
+                self._timed_fit, "gnn", parent_span, splits, self._train_gnn,
                 host_id, ip, hostname,
             )
             f_gru = (
                 pool.submit(
-                    self._timed_fit, "gru", parent_span, self._train_gru,
+                    self._timed_fit, "gru", parent_span, splits, self._train_gru,
                     host_id, ip, hostname,
                 )
                 if self.config.gru
@@ -205,10 +247,14 @@ class Training:
                 except Exception as e:
                     logger.exception("trainGRU failed for %s", host_id)
                     outcome.gru_error = str(e)
+        outcome.wall_s = round(time.perf_counter() - t0, 6)
+        if "mlp" in splits:
+            splits["mlp"].stream = mlp_info.get("stream")
 
         EV_ROUND(
             host_id=host_id,
             ok=outcome.ok,
+            wall_s=outcome.wall_s,
             mlp_error=outcome.mlp_error or "",
             gnn_error=outcome.gnn_error or "",
             gru_error=outcome.gru_error or "",
@@ -225,29 +271,43 @@ class Training:
                 self.storage.clear_network_topology(host_id)
         return outcome
 
-    def _timed_fit(self, model: str, parent_span, fn, *args):
+    def _timed_fit(self, model: str, parent_span, splits: dict, fn, *args):
         from dragonfly2_tpu.utils import tracing
 
         span = tracing.get("trainer").start_span("fit", parent=parent_span, model=model)
-        profiler_cm = self._maybe_profile(model)
         t0 = time.perf_counter()
+
+        def close(mine: dict) -> dict:
+            """The leg's split of this round, left in ``splits`` and
+            handed back as the fields the event and the span carry."""
+            compiles, compile_s = mine.pop(M.PH_JIT_COMPILE.name, (0, 0.0))
+            leg = splits[model] = LegSplit(
+                wall_s=round(time.perf_counter() - t0, 6),
+                phase_s={k: round(v[1], 6) for k, v in mine.items()},
+                phase_n={k: v[0] for k, v in mine.items()},
+                compiles=compiles,
+                compile_s=round(compile_s, 6),
+            )
+            fields = leg.fields()
+            span.set(**fields)
+            return fields
+
         # the fit span is active while fn runs so the ingest pipeline can
-        # stamp its exemplars with the owning trace_id
-        with M.FIT_DURATION.labels(model).time(), profiler_cm, tracing.use_span(span):
+        # stamp its exemplars with the owning trace_id; the split is this
+        # thread's, so the phases fn enters are credited to this leg
+        with (
+            M.FIT_DURATION.labels(model).time(),
+            tracing.use_span(span),
+            profiling.split() as mine,
+        ):
             try:
                 result = fn(*args)
             except Exception as e:
-                EV_FIT(
-                    model=model, outcome="failure", error=str(e),
-                    wall_s=round(time.perf_counter() - t0, 3),
-                )
+                EV_FIT(model=model, outcome="failure", error=str(e), **close(mine))
                 span.end("error")
                 M.FIT_TOTAL.labels(model, "failure").inc()
                 raise
-            EV_FIT(
-                model=model, outcome="success",
-                wall_s=round(time.perf_counter() - t0, 3),
-            )
+            EV_FIT(model=model, outcome="success", **close(mine))
         span.end("ok")
         M.FIT_TOTAL.labels(model, "success").inc()
         # fit-freshness source for the cluster telemetry plane: the SLO
@@ -255,19 +315,35 @@ class Training:
         M.LAST_FIT_TIMESTAMP.labels(model).set(time.time())
         return result
 
-    def _maybe_profile(self, model: str):
-        """jax.profiler trace per fit when profile_dir is set — the
-        XLA-side observability the reference's pprof flag provides for
-        Go (cmd/dependency/dependency.go:95)."""
-        import contextlib
+    @contextlib.contextmanager
+    def _round_profile(self):
+        """One ``jax.profiler`` trace a round when ``profile_dir`` is
+        set, around the three fits — the XLA-side observability the
+        reference's pprof flag provides for Go
+        (cmd/dependency/dependency.go:95). JAX allows one session a
+        process: a round that starts while another trace is open (a
+        second host's round, the stall watchdog's capture) runs inside
+        that one and opens none. The interpreter's own calls are not
+        traced: the legs' phases say what the host was doing, and a
+        tracer on every Python call slows the round it records many
+        times over."""
+        with contextlib.ExitStack() as stack:
+            if self.config.profile_dir:
+                import jax
 
-        if not self.config.profile_dir:
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.trace(
-            f"{self.config.profile_dir}/{model}", create_perfetto_trace=False
-        )
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                try:
+                    stack.enter_context(
+                        jax.profiler.trace(
+                            f"{self.config.profile_dir}/round",
+                            create_perfetto_trace=False,
+                            profiler_options=options,
+                        )
+                    )
+                except RuntimeError as e:
+                    logger.warning("round runs without a trace of its own: %s", e)
+            yield
 
     # -- trainMLP (reference training.go:92-98) ---------------------------
     def _train_mlp(
@@ -304,11 +380,12 @@ class Training:
                     host_id,
                     e,
                 )
+                metrics = self._train_mlp_from(
+                    host_id, ip, hostname, binary=True, info=info
+                )
                 if info is not None:
                     info["binary"] = None
-                return self._train_mlp_from(
-                    host_id, ip, hostname, binary=True, info=None
-                )
+                return metrics
         return self._train_mlp_from(
             host_id, ip, hostname, binary=has_bin, info=info
         )
@@ -339,25 +416,26 @@ class Training:
         boundary = self.storage.download_round_boundary(host_id, binary=binary)
         if self._use_streaming(path, offset, binary):
             return self._train_mlp_streaming(
-                host_id, ip, hostname, path, offset, boundary, binary
+                host_id, ip, hostname, path, offset, boundary, binary, info
             )
-        if binary:
-            pairs = wire.read_train_pairs(path, offset=offset, end=boundary)
-        else:
-            # bounded at the round boundary exactly like the binary and
-            # streaming paths: the in-flight tail past it may be
-            # truncated by a failed stream, and the offset commit below
-            # wouldn't cover it anyway
-            pairs = native.decode_pairs_file(path, offset=offset, end=boundary)
-            if pairs is None:
-                recs = [
-                    r
-                    for chunk in self.storage.iter_download_chunks(
-                        host_id, max_bytes=boundary
-                    )
-                    for r in chunk
-                ]
-                pairs = extract_pair_features(records_to_columns(recs))
+        with M.PH_MLP.load:
+            if binary:
+                pairs = wire.read_train_pairs(path, offset=offset, end=boundary)
+            else:
+                # bounded at the round boundary exactly like the binary and
+                # streaming paths: the in-flight tail past it may be
+                # truncated by a failed stream, and the offset commit below
+                # wouldn't cover it anyway
+                pairs = native.decode_pairs_file(path, offset=offset, end=boundary)
+                if pairs is None:
+                    recs = [
+                        r
+                        for chunk in self.storage.iter_download_chunks(
+                            host_id, max_bytes=boundary
+                        )
+                        for r in chunk
+                    ]
+                    pairs = extract_pair_features(records_to_columns(recs))
         if pairs.num_downloads < self.config.min_download_records:
             raise BelowMinRecords(
                 f"{pairs.num_downloads} download records for host {host_id}"
@@ -372,14 +450,15 @@ class Training:
             config=self._fit_config(self.config.mlp, "mlp", host_id),
         )
         if self.manager_client is not None:
-            self.manager_client.create_model(
-                model_id=mlp_model_id_v1(ip, hostname),
-                model_type="mlp",
-                ip=ip,
-                hostname=hostname,
-                params=_to_host(result.params),
-                evaluation=result.metrics,
-            )
+            with M.PH_MLP.register:
+                self.manager_client.create_model(
+                    model_id=mlp_model_id_v1(ip, hostname),
+                    model_type="mlp",
+                    ip=ip,
+                    hostname=hostname,
+                    params=_to_host(result.params),
+                    evaluation=result.metrics,
+                )
         if self.config.incremental:
             # commit only after a fully successful round (incl. upload) —
             # a crashed round re-decodes from the previous offset
@@ -445,6 +524,7 @@ class Training:
         offset: int,
         boundary: int,
         binary: bool = False,
+        info: dict | None = None,
     ) -> dict[str, float]:
         """Large-dataset path: bounded-memory overlapped decode+train
         (trainer.ingest.stream_train_mlp) instead of materializing every
@@ -500,6 +580,8 @@ class Training:
             # same profile_dir plumbing on-demand profiling uses
             stall_profile_dir=self.config.profile_dir,
         )
+        if info is not None:
+            info["stream"] = stats  # the leg's split carries it (LegSplit)
         # rows counted once per pass — gate on a single pass's worth.
         # A time-budget truncation may have stopped mid-pass; dividing
         # by the CONFIGURED pass count would then undercount what was
@@ -522,14 +604,15 @@ class Training:
             stats.records_per_s,
         )
         if self.manager_client is not None:
-            self.manager_client.create_model(
-                model_id=mlp_model_id_v1(ip, hostname),
-                model_type="mlp",
-                ip=ip,
-                hostname=hostname,
-                params=_to_host(params),
-                evaluation=stats.metrics,
-            )
+            with M.PH_MLP.register:
+                self.manager_client.create_model(
+                    model_id=mlp_model_id_v1(ip, hostname),
+                    model_type="mlp",
+                    ip=ip,
+                    hostname=hostname,
+                    params=_to_host(params),
+                    evaluation=stats.metrics,
+                )
         if self.config.incremental:
             self.storage.commit_download_offset(host_id, boundary, binary=binary)
         return stats.metrics
@@ -543,45 +626,46 @@ class Training:
         cpath = self.storage.network_topology_path(host_id)
         has_bin = bpath.exists() and bpath.stat().st_size > 0
         has_csv = cpath.exists() and cpath.stat().st_size > 0
-        graph = None
-        if has_bin and has_csv:
-            # format-switch history: merge BOTH eras (CSV rows first —
-            # they predate the binary era, and edge RTT is
-            # last-write-wins in the graph build)
-            from dragonfly2_tpu.schema.columnar import concat_columns
+        with M.PH_GNN.load:
+            graph = None
+            if has_bin and has_csv:
+                # format-switch history: merge BOTH eras (CSV rows first —
+                # they predate the binary era, and edge RTT is
+                # last-write-wins in the graph build)
+                from dragonfly2_tpu.schema.columnar import concat_columns
 
-            cols = concat_columns(
-                [
-                    records_to_columns(self.storage.list_network_topology(host_id)),
-                    wire.read_columns(
-                        bpath,
-                        kind=wire.KIND_TOPOLOGY,
-                        end=self.storage.network_topology_round_boundary(
-                            host_id, binary=True
+                cols = concat_columns(
+                    [
+                        records_to_columns(self.storage.list_network_topology(host_id)),
+                        wire.read_columns(
+                            bpath,
+                            kind=wire.KIND_TOPOLOGY,
+                            end=self.storage.network_topology_round_boundary(
+                                host_id, binary=True
+                            ),
                         ),
-                    ),
-                ]
-            )
-            graph = build_probe_graph(cols, max_degree=self.config.gnn_max_degree)
-        elif has_bin:
-            # binary topology upload: raw record columns, decoded straight
-            # into the vectorized graph build (read bounded by the round
-            # boundary so a concurrent upload's tail is never decoded)
-            cols = wire.read_columns(
-                bpath,
-                kind=wire.KIND_TOPOLOGY,
-                end=self.storage.network_topology_round_boundary(host_id, binary=True),
-            )
-            graph = build_probe_graph(cols, max_degree=self.config.gnn_max_degree)
-        else:
-            graph = native.build_probe_graph_file(
-                cpath, max_degree=self.config.gnn_max_degree
-            )
-        if graph is None:
-            recs = self.storage.list_network_topology(host_id)
-            graph = build_probe_graph(
-                records_to_columns(recs), max_degree=self.config.gnn_max_degree
-            )
+                    ]
+                )
+                graph = build_probe_graph(cols, max_degree=self.config.gnn_max_degree)
+            elif has_bin:
+                # binary topology upload: raw record columns, decoded straight
+                # into the vectorized graph build (read bounded by the round
+                # boundary so a concurrent upload's tail is never decoded)
+                cols = wire.read_columns(
+                    bpath,
+                    kind=wire.KIND_TOPOLOGY,
+                    end=self.storage.network_topology_round_boundary(host_id, binary=True),
+                )
+                graph = build_probe_graph(cols, max_degree=self.config.gnn_max_degree)
+            else:
+                graph = native.build_probe_graph_file(
+                    cpath, max_degree=self.config.gnn_max_degree
+                )
+            if graph is None:
+                recs = self.storage.list_network_topology(host_id)
+                graph = build_probe_graph(
+                    records_to_columns(recs), max_degree=self.config.gnn_max_degree
+                )
         if graph.num_records < self.config.min_topology_records:
             raise ValueError(
                 f"{graph.num_records} network topology records for host {host_id}"
@@ -591,14 +675,15 @@ class Training:
             graph, mesh=self.mesh, config=self._fit_config(self.config.gnn, "gnn", host_id)
         )
         if self.manager_client is not None:
-            self.manager_client.create_model(
-                model_id=gnn_model_id_v1(ip, hostname),
-                model_type="gnn",
-                ip=ip,
-                hostname=hostname,
-                params=_to_host(result.params),
-                evaluation=result.metrics,
-            )
+            with M.PH_GNN.register:
+                self.manager_client.create_model(
+                    model_id=gnn_model_id_v1(ip, hostname),
+                    model_type="gnn",
+                    ip=ip,
+                    hostname=hostname,
+                    params=_to_host(result.params),
+                    evaluation=result.metrics,
+                )
         return result.metrics
 
 
@@ -632,39 +717,40 @@ class Training:
         # still bounds memory.
         import itertools
 
-        seq_iters = []
-        cpath = self.storage.download_path(host_id)
-        if cpath.exists() and cpath.stat().st_size:
-            boundary = self.storage.download_round_boundary(host_id)
-            seq_iters.append(
-                extract_piece_sequences(records_to_columns(chunk))
-                for chunk in self.storage.iter_download_chunks(
-                    host_id, max_bytes=boundary
+        with M.PH_GRU.load:
+            seq_iters = []
+            cpath = self.storage.download_path(host_id)
+            if cpath.exists() and cpath.stat().st_size:
+                boundary = self.storage.download_round_boundary(host_id)
+                seq_iters.append(
+                    extract_piece_sequences(records_to_columns(chunk))
+                    for chunk in self.storage.iter_download_chunks(
+                        host_id, max_bytes=boundary
+                    )
                 )
-            )
-        bpath = self.storage.download_blocks_path(host_id)
-        if bpath.exists() and bpath.stat().st_size:
-            seq_iters.append(
-                wire.stream_gru_sequences(
-                    bpath,
-                    end=self.storage.download_round_boundary(host_id, binary=True),
+            bpath = self.storage.download_blocks_path(host_id)
+            if bpath.exists() and bpath.stat().st_size:
+                seq_iters.append(
+                    wire.stream_gru_sequences(
+                        bpath,
+                        end=self.storage.download_round_boundary(host_id, binary=True),
+                    )
                 )
-            )
-        for s in itertools.chain(*seq_iters):
-            if s.sequences.shape[0]:
-                parts.append(s)
-                total += s.sequences.shape[0]
-            while parts and total - parts[0].sequences.shape[0] >= cap:
-                total -= parts[0].sequences.shape[0]
-                parts.pop(0)
-        if parts:
-            seqs = PieceSequences(
-                sequences=np.concatenate([p.sequences for p in parts])[-cap:],
-                labels=np.concatenate([p.labels for p in parts])[-cap:],
-                lengths=np.concatenate([p.lengths for p in parts])[-cap:],
-            )
-        else:
-            seqs = extract_piece_sequences({})
+            for s in itertools.chain(*seq_iters):
+                if s.sequences.shape[0]:
+                    parts.append(s)
+                    total += s.sequences.shape[0]
+                while parts and total - parts[0].sequences.shape[0] >= cap:
+                    total -= parts[0].sequences.shape[0]
+                    parts.pop(0)
+            if parts:
+                seqs = PieceSequences(
+                    sequences=np.concatenate([p.sequences for p in parts])[-cap:],
+                    labels=np.concatenate([p.labels for p in parts])[-cap:],
+                    lengths=np.concatenate([p.lengths for p in parts])[-cap:],
+                )
+            else:
+                seqs = extract_piece_sequences({})
         n = seqs.sequences.shape[0]
         if n < self.config.gru_min_sequences:
             raise ValueError(
@@ -679,14 +765,15 @@ class Training:
             config=self._fit_config(self.config.gru_config, "gru", host_id),
         )
         if self.manager_client is not None:
-            self.manager_client.create_model(
-                model_id=gru_model_id_v1(ip, hostname),
-                model_type="gru",
-                ip=ip,
-                hostname=hostname,
-                params=_to_host(result.params),
-                evaluation=result.metrics,
-            )
+            with M.PH_GRU.register:
+                self.manager_client.create_model(
+                    model_id=gru_model_id_v1(ip, hostname),
+                    model_type="gru",
+                    ip=ip,
+                    hostname=hostname,
+                    params=_to_host(result.params),
+                    evaluation=result.metrics,
+                )
         return result.metrics
 
     # -- federated round over every uploading host's shard ----------------

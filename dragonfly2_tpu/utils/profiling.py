@@ -16,7 +16,14 @@ Two instruments, both cheap enough to leave on in production:
 - **Phase ledger**: named wall-clock phases declared once per module
   (``PH = profiling.phase_type("trainer.buffer_wait")``) and accounted
   continuously — ``with PH: ...`` for timed blocks, ``PH.observe(dt)``
-  where the caller already measured. The ledger generalizes the
+  where the caller already measured. A ``with`` block is also a
+  ``jax.profiler.TraceAnnotation`` of the phase's name while a
+  profiler session is open (in a process that has loaded jax; never
+  loaded for this), so any ``jax.profiler`` trace shows the program's
+  phases on the host plane beside the device's ops; and it is
+  credited to the thread's own
+  :func:`split`, when one is open, so a caller can read what ITS work
+  took apart from the process-wide totals. The ledger generalizes the
   trainer's per-fit StreamStats split into live, cross-service
   counters: the same buffer_wait/decode_wait/h2d/step attribution,
   scrapeable mid-fit via ``/metrics`` (``prof_phase_seconds``) and
@@ -51,6 +58,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import contextlib
 import os
 import sys
 import threading
@@ -388,11 +396,51 @@ class SamplingProfiler:
 
 _PHASE_BUCKETS = (1e-5, 1e-4, 1e-3, 0.01, 0.1, 0.5, 1.0, 5.0, 30.0)
 
+# jax.profiler.TraceAnnotation, bound the first time a phase is entered
+# in a process that has loaded jax
+_annotation = None
+_modules = sys.modules
+
+
+def _bind_annotation():
+    """The profiler's annotation class if this process has imported
+    jax, looked up in ``sys.modules`` and never imported from here: the
+    dfdaemon runs without jax and a phase must not load it. A jax that
+    is still mid-import has no ``profiler`` yet; the next entry asks
+    again."""
+    global _annotation
+    profiler = getattr(_modules.get("jax"), "profiler", None)
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class _ThreadState(threading.local):
+    split = None  # the dict this thread's phases are credited to, if open
+
+
+_thread = _ThreadState()
+
+
+@contextlib.contextmanager
+def split():
+    """``with profiling.split() as mine:`` — ``mine`` fills with
+    ``{phase: [entries, seconds]}`` for every phase THIS thread leaves
+    (the ``with`` form) or books (``Phase.book``) inside the block. The
+    ledger sums a process; two fits of the same model running at once
+    share its totals, and each still reads its own work here."""
+    prev, mine = _thread.split, {}
+    _thread.split = mine
+    try:
+        yield mine
+    finally:
+        _thread.split = prev
+
 
 class Phase:
     """One named wall-clock phase. Declared once per module via
     :func:`phase_type`; usable as a (re-entrant, thread-safe) context
-    manager or fed pre-measured durations with ``observe``.
+    manager or fed pre-measured durations with ``observe`` (ledger
+    only) or ``book`` (ledger and the calling thread's open split).
 
     The hot path is ledger-only — one bisect + one short lock per
     ``observe``, plain GIL int adds for the active counter (the flight
@@ -427,18 +475,45 @@ class Phase:
                 self.max_s = seconds
             self.bucket_counts[i] += 1
 
+    def book(self, seconds: float) -> None:
+        """``observe``, and credit the calling thread's open
+        :func:`split` — for work measured on the thread that did it."""
+        self.observe(seconds)
+        mine = _thread.split
+        if mine is not None:
+            rec = mine.get(self.name)
+            if rec is None:
+                mine[self.name] = [1, seconds]
+            else:
+                rec[0] += 1
+                rec[1] += seconds
+
     def __enter__(self):
         starts = getattr(self._tls, "starts", None)
         if starts is None:
             starts = self._tls.starts = []
         self.active_n += 1  # GIL add; synced to the gauge at snapshot
-        starts.append(time.perf_counter())
+        # on the profiler's clock too, while a profiler session is open
+        # (an annotation entered outside one is never recorded, so none
+        # is built: the test is one flag read in native code)
+        cls = _annotation
+        if cls is None and "jax" in _modules:
+            cls = _bind_annotation()
+        if cls is not None and cls.is_enabled():
+            ann = cls(self.name)
+            ann.__enter__()
+        else:
+            ann = None
+        starts.append((ann, time.perf_counter()))
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._tls.starts.pop()
+        ann, t0 = self._tls.starts.pop()
+        dt = time.perf_counter() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
         self.active_n -= 1
-        self.observe(dt)
+        self.book(dt)
         return False
 
     @property

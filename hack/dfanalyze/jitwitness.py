@@ -43,7 +43,6 @@ import logging
 import os
 import sys
 import threading
-import time
 
 _state_lock = threading.Lock()
 
@@ -314,6 +313,10 @@ def dump(path: str | None = None) -> str:
 # Lightweight context managers for bench.py's jit-hygiene keys: count
 # compiles and host→device conversions over a measured region without
 # installing the full witness (no jax.jit patch, no site attribution).
+# The counts are the taps' own: the live series
+# (trainer_jit_recompiles_total, the trainer.jit_compile phase) are fed
+# by the program's jax.monitoring listener in trainer/metrics.py,
+# whether or not a tap is armed.
 
 
 class compile_tap:
@@ -336,7 +339,6 @@ class compile_tap:
                 if msg.startswith("Compiling "):
                     outer.count += 1
                     outer.names.append(msg[len("Compiling "):].split(" ", 1)[0])
-                    _metric_inc("jit_recompiles")
 
         self._h = _H(level=logging.DEBUG)
         lg = logging.getLogger(_PXLA_LOGGER)
@@ -391,27 +393,16 @@ class transfer_tap:
         def put(x, *a, **kw):
             if getattr(tls, "depth", 0) == 0 and _any_np(x, np):
                 outer._note()
-                _metric_inc("h2d_transfers")
-                t0 = time.perf_counter()
-                try:
-                    return outer._raw_put(x, *a, **kw)
-                finally:
-                    _phase_observe("device_transfer", time.perf_counter() - t0)
             return outer._raw_put(x, *a, **kw)
 
         def asarray(x, *a, **kw):
-            timed = isinstance(x, np.ndarray)
-            if timed:
+            if isinstance(x, np.ndarray):
                 outer._note()
-                _metric_inc("h2d_transfers")
-                t0 = time.perf_counter()
             tls.depth = getattr(tls, "depth", 0) + 1
             try:
                 return outer._raw_asarray(x, *a, **kw)
             finally:
                 tls.depth -= 1
-                if timed:
-                    _phase_observe("device_transfer", time.perf_counter() - t0)
 
         jax.device_put = put
         jnp.asarray = asarray
@@ -426,32 +417,3 @@ def _any_np(tree, np) -> bool:
     from jax import tree_util
 
     return any(isinstance(l, np.ndarray) for l in tree_util.tree_leaves(tree))
-
-
-def _metric_inc(kind: str) -> None:
-    """Feed the live trainer series when the package is importable —
-    the witness's counts double as scrapeable counters (census-covered
-    in trainer/metrics.py)."""
-    try:
-        from dragonfly2_tpu.trainer import metrics as M
-    except Exception:
-        return
-    if kind == "jit_recompiles":
-        M.JIT_RECOMPILES_TOTAL.inc()
-        # count-marker in the dfprof ledger: a moving trainer.jit_compile
-        # count mid-fit IS the retrace storm, visible on /debug/prof
-        _phase_observe("jit_compile", 0.0)
-    else:
-        M.H2D_TRANSFERS_TOTAL.inc()
-
-
-def _phase_observe(kind: str, seconds: float) -> None:
-    """Attribute device-side time into the dfprof phase ledger
-    (trainer.device_transfer timed per conversion, trainer.jit_compile
-    a count marker) while a tap is armed."""
-    try:
-        from dragonfly2_tpu.trainer import metrics as M
-    except Exception:
-        return
-    ph = M.PH_DEVICE_TRANSFER if kind == "device_transfer" else M.PH_JIT_COMPILE
-    ph.observe(seconds)

@@ -92,32 +92,43 @@ def test_ingest_unsampled_run_records_no_exemplars(tmp_path):
 
 
 def test_profile_dir_drives_jax_profiler(tmp_path, monkeypatch):
-    """TrainingConfig.profile_dir → jax.profiler.trace per fit; empty
-    stays a nullcontext (no profiler import on the default path)."""
+    """TrainingConfig.profile_dir → ONE jax.profiler.trace a round,
+    open around the three fits (JAX allows one session a process, and
+    the fits run on three threads); empty opens none. A real round
+    under the real profiler is in tests/test_round_phases.py."""
     import jax
 
     from dragonfly2_tpu.trainer.storage import TrainerStorage
     from dragonfly2_tpu.trainer.training import Training, TrainingConfig
 
-    calls = []
+    calls, open_ = [], []
 
     @contextlib.contextmanager
     def fake_trace(path, **kw):
         calls.append(path)
-        yield
+        open_.append(path)
+        try:
+            yield
+        finally:
+            open_.remove(path)
+
+    fits = []
+
+    def fake_fit(self, model, parent_span, splits, fn, *args):
+        fits.append((model, list(open_)))
+        return {}
 
     monkeypatch.setattr(jax.profiler, "trace", fake_trace)
+    monkeypatch.setattr(Training, "_timed_fit", fake_fit)
     storage = TrainerStorage(tmp_path)
-    off = Training(storage, config=TrainingConfig(profile_dir=""))
-    with off._maybe_profile("mlp"):
-        pass
-    assert calls == []
-    on = Training(
-        storage, config=TrainingConfig(profile_dir=str(tmp_path / "prof"))
-    )
-    with on._maybe_profile("mlp"):
-        pass
-    assert calls == [f"{tmp_path / 'prof'}/mlp"]
+    Training(storage, config=TrainingConfig(profile_dir="")).train("10.0.0.1", "h")
+    assert calls == [] and sorted(m for m, _ in fits) == ["gnn", "gru", "mlp"]
+    del fits[:]
+    prof = str(tmp_path / "prof")
+    Training(storage, config=TrainingConfig(profile_dir=prof)).train("10.0.0.1", "h")
+    assert calls == [f"{prof}/round"]
+    assert sorted(fits) == [(m, [f"{prof}/round"]) for m in ("gnn", "gru", "mlp")]
+    assert open_ == []
 
 
 def test_trainer_server_config_plumbs_profile_dir(tmp_path):

@@ -1,0 +1,362 @@
+"""A round's time, accounted from inside: the resident legs' phases
+(trainer/metrics.py FIT_STAGES), the split each leg hands back, the
+profiler's clock under every ``with`` phase, compiles counted by the
+program's own listener, and one ``jax.profiler`` trace a round."""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+
+from dragonfly2_tpu.schema import synth, wire
+from dragonfly2_tpu.trainer import metrics as M
+from dragonfly2_tpu.trainer import train as train_mod
+from dragonfly2_tpu.trainer.storage import TrainerStorage
+from dragonfly2_tpu.trainer.train import FitConfig, GNNFitConfig
+from dragonfly2_tpu.trainer.training import Training, TrainingConfig
+from dragonfly2_tpu.utils import flight, profiling, tracing
+from dragonfly2_tpu.utils.idgen import host_id_v2
+
+IP, HOSTNAME = "10.0.0.7", "sched-phases"
+LEGS = ("mlp", "gnn", "gru")
+EPOCHS = {"mlp": 2, "gnn": 4, "gru": 3}
+ONCE_A_FIT = ("load", "split", "holdout", "register")
+ONCE_AN_EPOCH = ("gather", "feed", "epoch_dispatch", "epoch_wait")
+STREAM_PHASES = ("trainer.decode_wait", "trainer.buffer_wait", "trainer.h2d", "trainer.step")
+
+
+class Manager:
+    def __init__(self):
+        self.registered = []
+
+    def create_model(self, model_id, model_type, ip, hostname, params, evaluation):
+        self.registered.append(model_type)
+
+
+def _stage(storage: TrainerStorage, host_id: str) -> None:
+    """One scheduler's binary upload, as the Train stream leaves it."""
+    downloads = synth.make_download_records(768, seed=5)
+    topology = synth.make_topology_records(400, num_hosts=32, seed=6)
+    rpb = wire.BLOCK_RECORDS
+    for i in range(0, len(downloads), rpb):
+        storage.append_download_blocks(host_id, wire.encode_train_block(downloads[i : i + rpb]))
+    for i in range(0, len(topology), rpb):
+        storage.append_network_topology_blocks(
+            host_id, wire.encode_topology_block(topology[i : i + rpb])
+        )
+    storage.mark_download_round(host_id)
+
+
+def _training(root, streaming: bool, **config) -> Training:
+    cfg = TrainingConfig(
+        mlp=FitConfig(hidden_dims=(32,), batch_size=256, epochs=EPOCHS["mlp"]),
+        gnn=GNNFitConfig(hidden_dims=(16,), batch_size=256, epochs=EPOCHS["gnn"]),
+        gru_config=FitConfig(hidden_dims=(8,), batch_size=64, epochs=EPOCHS["gru"]),
+        streaming=streaming,
+        streaming_threshold_bytes=0,
+        auto_mesh=False,
+        **config,
+    )
+    return Training(TrainerStorage(root), Manager(), cfg)
+
+
+class _Compiles:
+    """The test's own count of what the backend was asked for, beside
+    the program's listener."""
+
+    def __init__(self):
+        self.by_thread: dict = {}
+
+    def __call__(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            n, s = self.by_thread.get(threading.get_ident(), (0, 0.0))
+            self.by_thread[threading.get_ident()] = (n + 1, s + seconds)
+
+    @property
+    def count(self) -> int:
+        return sum(n for n, _ in self.by_thread.values())
+
+    @property
+    def seconds(self) -> float:
+        return sum(s for _, s in self.by_thread.values())
+
+
+@pytest.fixture(scope="module", params=["resident", "streamed"])
+def round_(request, tmp_path_factory):
+    """One toy round on the CPU through ``Training.train()``, after a
+    first that pays the process's one-time compiles (parameter init,
+    the holdout forwards), with everything a case below compares."""
+    streaming = request.param == "streamed"
+    training = _training(tmp_path_factory.mktemp(request.param), streaming)
+    host_id = host_id_v2(IP, HOSTNAME)
+    _stage(training.storage, host_id)
+    assert training.train(IP, HOSTNAME).ok
+    _stage(training.storage, host_id)
+    names = [ph.name for leg in (M.PH_MLP, M.PH_GNN, M.PH_GRU) for ph in vars(leg).values()]
+    names += [M.PH_JIT_COMPILE.name, *STREAM_PHASES]
+    before = {n: profiling.phase_type(n).snapshot() for n in names}
+    series0 = M.JIT_RECOMPILES_TOTAL.value
+    compiles = _Compiles()
+    jax.monitoring.register_event_duration_secs_listener(compiles)
+    try:
+        with tracing.get("trainer").start_span("test-round") as root:
+            outcome = training.train(IP, HOSTNAME)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(compiles)
+    after = {n: profiling.phase_type(n).snapshot() for n in names}
+    events = [
+        e for e in flight.snapshot(["trainer"])["trainer"] if e["trace_id"] == root.trace_id
+    ]
+    fit_events = [e for e in events if e["type"] == "trainer.fit"]
+    round_events = [e for e in events if e["type"] == "trainer.round"]
+    spans = [
+        s for s in tracing.get("trainer").finished
+        if s.name == "fit" and s.trace_id == root.trace_id
+    ]
+    return {
+        "streaming": streaming,
+        "training": training,
+        "outcome": outcome,
+        "ledger": {
+            n: (after[n]["count"] - before[n]["count"], after[n]["total_s"] - before[n]["total_s"])
+            for n in names
+        },
+        "series_moved": M.JIT_RECOMPILES_TOTAL.value - series0,
+        "compiles": compiles,
+        "fit_events": {e["model"]: e for e in fit_events},
+        "round_events": round_events,
+        "spans": {s.attributes["model"]: s for s in spans},
+    }
+
+
+def _expected_counts(leg: str, streaming: bool) -> dict:
+    if leg == "mlp" and streaming:
+        # the streamed fit keeps its own phases and gains the register
+        return {"trainer.mlp_register": 1}
+    want = {f"trainer.{leg}_{stage}": 1 for stage in ONCE_A_FIT}
+    want.update({f"trainer.{leg}_{stage}": EPOCHS[leg] for stage in ONCE_AN_EPOCH})
+    return want
+
+
+@pytest.mark.parametrize("leg", LEGS)
+def test_every_phase_once_a_fit_or_once_an_epoch(round_, leg):
+    outcome = round_["outcome"]
+    assert outcome.ok and outcome.gru_error is None, outcome
+    split = outcome.splits[leg]
+    want = _expected_counts(leg, round_["streaming"])
+    assert split.phase_n == want
+    assert all(split.phase_s[name] > 0 for name in want)
+    # the process-wide ledger moved by the same entries
+    for name, n in want.items():
+        assert round_["ledger"][name][0] == n, name
+    if leg == "mlp" and round_["streaming"]:
+        assert split.stream is not None and split.stream.steps > 0
+        assert all(round_["ledger"][name][0] > 0 for name in STREAM_PHASES)
+    else:
+        assert split.stream is None
+
+
+@pytest.mark.parametrize("leg", LEGS)
+def test_phases_cover_the_fit_wall(round_, leg):
+    """A leg's phases sum to its wall but for its own bookkeeping
+    (parameter and optimizer init, the configuration): what is left is
+    ``self_s``, reported beside them. The leg runs as a round runs it,
+    but alone: beside two other legs on this machine's one core its
+    bookkeeping waits for the interpreter more than it works."""
+    split = round_["outcome"].splits[leg]
+    assert split.self_s == pytest.approx(split.wall_s - sum(split.phase_s.values()), abs=1e-5)
+    assert 0 <= split.self_s < split.wall_s <= round_["outcome"].wall_s
+    training = round_["training"]
+    host_id = host_id_v2(IP, HOSTNAME)
+    _stage(training.storage, host_id)
+    alone: dict = {}
+    fit = getattr(training, f"_train_{leg}")
+    training._timed_fit(leg, None, alone, fit, host_id, IP, HOSTNAME)
+    split = alone[leg]
+    assert split.phase_n == _expected_counts(leg, round_["streaming"])
+    covered = sum(split.phase_s.values())
+    if leg == "mlp" and round_["streaming"]:
+        # the streamed fit splits its pipeline's wall itself (StreamStats,
+        # handed to the outcome); its set-up and holdout around that are
+        # the leg's self time, most of a toy fit and little of a real one
+        assert 0 < covered < split.wall_s
+    else:
+        assert 0.9 * split.wall_s <= covered <= split.wall_s
+
+
+@pytest.mark.parametrize("leg", LEGS)
+def test_outcome_event_and_span_carry_the_same_split(round_, leg):
+    fields = round_["outcome"].splits[leg].fields()
+    assert set(fields) == {"wall_s", "self_s", "phase_s", "phase_n", "compiles", "compile_s"}
+    event = round_["fit_events"][leg]
+    assert event["outcome"] == "success"
+    assert {k: event[k] for k in fields} == fields
+    span = round_["spans"][leg]
+    assert {k: span.attributes[k] for k in fields} == fields
+
+
+def test_round_event_carries_the_rounds_wall(round_):
+    (event,) = round_["round_events"]
+    assert event["wall_s"] == round_["outcome"].wall_s > 0
+    assert event["ok"] is True
+
+
+def test_compiles_are_counted_and_booked_to_the_leg_that_asked(round_):
+    """Every executable asked of the backend moves the series and the
+    ``trainer.jit_compile`` phase by one, with its seconds, whatever
+    thread asked; a leg's split holds those its own thread asked for
+    (the streamed fit's step compiles on its stage thread, for none)."""
+    tap, splits = round_["compiles"], round_["outcome"].splits
+    assert tap.count >= len(LEGS)  # each leg rebuilds and asks for its epoch
+    assert round_["series_moved"] == tap.count
+    n, seconds = round_["ledger"][M.PH_JIT_COMPILE.name]
+    assert n == tap.count
+    assert seconds == pytest.approx(tap.seconds, abs=1e-4)
+    booked = sorted((s.compiles, s.compile_s) for s in splits.values())
+    by_thread = sorted(tap.by_thread.values())
+    if not round_["streaming"]:
+        assert [c for c, _ in booked] == [c for c, _ in by_thread]
+        assert [s for _, s in booked] == pytest.approx([s for _, s in by_thread], abs=1e-4)
+    for leg in LEGS:
+        if not (leg == "mlp" and round_["streaming"]):
+            assert splits[leg].compiles >= 1 and splits[leg].compile_s > 0
+        assert M.PH_JIT_COMPILE.name not in splits[leg].phase_s
+
+
+def _fit_inputs(leg: str):
+    rng = np.random.default_rng(0)
+    if leg == "mlp":
+        x = rng.normal(size=(600, 6)).astype(np.float32)
+        return train_mod.train_mlp, (x, x[:, 0].copy())
+    if leg == "gru":
+        s = rng.normal(size=(300, 10, 2)).astype(np.float32)
+        return train_mod.train_gru, (s, s[:, 0, 0].copy())
+    from dragonfly2_tpu.schema.columnar import records_to_columns
+    from dragonfly2_tpu.schema.features import build_probe_graph
+
+    cols = records_to_columns(synth.make_topology_records(200, num_hosts=16, seed=1))
+    return train_mod.train_gnn, (build_probe_graph(cols, max_degree=8),)
+
+
+@pytest.mark.parametrize("leg", LEGS)
+def test_each_leg_names_its_epoch_function(leg, monkeypatch):
+    """``PjitFunction(<leg>_epoch)`` and the XLA module say which leg; the
+    scan body's ops sit under a scope of the same name."""
+    names, lowered = [], []
+    real = train_mod.make_epoch_fn
+
+    def spy(loss_fn, optimizer):
+        fn = real(loss_fn, optimizer)
+        names.append(fn.__name__)
+
+        def epoch(*args):
+            lowered.append(fn.lower(*args).as_text(debug_info=True))
+            return fn(*args)
+
+        return epoch
+
+    monkeypatch.setattr(train_mod, "make_epoch_fn", spy)
+    fit, args = _fit_inputs(leg)
+    small = {"hidden_dims": (8,), "batch_size": 64, "epochs": 1}
+    fit(*args, config=GNNFitConfig(**small) if leg == "gnn" else FitConfig(**small))
+    assert names == [f"{leg}_epoch"]
+    assert f"jit_{leg}_epoch" in lowered[0]
+    assert f"{leg}_epoch/" in lowered[0]  # the named scope on the step's ops
+
+
+def _host_event_names(trace_dir: str) -> set:
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    return {
+        ev.name
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+
+
+def test_a_with_phase_is_on_the_profilers_clock(tmp_path):
+    entered = profiling.phase_type("trainer.test_on_profiler_clock")
+    fed = profiling.phase_type("trainer.test_ledger_only")
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=False):
+        with entered:
+            np.ones(1000).sum()
+        fed.observe(0.001)
+    names = _host_event_names(str(tmp_path))
+    assert entered.name in names
+    assert fed.name not in names  # observe(dt) feeds the ledger alone
+
+
+def test_a_phase_does_not_import_jax():
+    code = (
+        "import sys\n"
+        "from dragonfly2_tpu.utils import profiling\n"
+        "ph = profiling.phase_type('daemon.test_no_jax')\n"
+        "with profiling.split() as mine:\n"
+        "    with ph:\n"
+        "        pass\n"
+        "assert ph.snapshot()['count'] == 1 and mine[ph.name][0] == 1\n"
+        "assert 'jax' not in sys.modules, 'a phase imported jax'\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True, text=True, timeout=120
+    )
+    assert done.returncode == 0, done.stderr
+
+
+def test_a_split_is_the_threads_own():
+    """Two threads in the same phase at once: the ledger sums them, each
+    split holds its own."""
+    ph = profiling.phase_type("trainer.test_split_own")
+    base = ph.snapshot()["count"]
+    got = {}
+    gate = threading.Barrier(2, timeout=30)
+
+    def work(key, entries):
+        with profiling.split() as mine:
+            gate.wait()
+            for _ in range(entries):
+                with ph:
+                    pass
+            ph.observe(0.5)  # ledger only
+        got[key] = mine
+
+    threads = [threading.Thread(target=work, args=(k, n)) for k, n in (("a", 2), ("b", 5))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    assert got["a"][ph.name][0] == 2 and got["b"][ph.name][0] == 5
+    assert ph.snapshot()["count"] - base == 9
+    with ph:  # no split open: the ledger alone
+        pass
+    assert got["a"][ph.name][0] == 2
+
+
+def test_a_round_with_profile_dir_is_one_trace(tmp_path):
+    """The operator's switch, on a real round: one ``jax.profiler``
+    session around the three fits (one a fit, from three threads, is
+    two refused), all three legs ok, and the legs' phases and epoch
+    functions named on the host plane."""
+    prof = tmp_path / "prof"
+    training = _training(tmp_path / "store", False, profile_dir=str(prof))
+    _stage(training.storage, host_id_v2(IP, HOSTNAME))
+    outcome = training.train(IP, HOSTNAME)
+    assert outcome.ok and outcome.gru_error is None, outcome
+    assert sorted(training.manager_client.registered) == ["gnn", "gru", "mlp"]
+    assert os.listdir(prof) == ["round"]
+    names = _host_event_names(str(prof / "round"))
+    for leg in LEGS:
+        assert f"trainer.{leg}_gather" in names
+        assert f"trainer.{leg}_epoch_wait" in names
+        assert any(n.startswith(f"PjitFunction({leg}_epoch)") for n in names), leg
+    assert not any(n.startswith("PjitFunction(epoch)") for n in names)
