@@ -53,6 +53,7 @@ CSV instead of mis-decoding.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import mmap
 import os
@@ -71,9 +72,11 @@ KIND_TRAIN = "train"
 KIND_TOPOLOGY = "networktopology"
 
 # records batched into one block by producers (scheduler sink flush,
-# bench synthesis): enough to amortize per-block decode overhead
-# (measured 609k rec/s at 64-record blocks vs 792k at 256, one thread)
-# without buffering unbounded record objects in producer RAM
+# bench synthesis): enough to amortize per-block decode overhead (a
+# header parse, a CRC call and the column views are paid once a block,
+# whatever it holds) without buffering unbounded record objects in
+# producer RAM. A format decision (ROADMAP S1): it sets how many
+# blocks, so how many of those, a reader of an upload pays for.
 BLOCK_RECORDS = 256
 
 _PREAMBLE = struct.Struct("<4sIQ")  # magic, header_len, payload_len
@@ -200,23 +203,35 @@ def _decode_col(entry: list, payload: memoryview) -> np.ndarray:
     raise WireError(f"unknown column encoding {enc!r} for {name!r}")
 
 
-def decode_block(buf, pos: int = 0, verify_crc: bool = True):
-    """Decode the block at ``pos`` → (header, cols, end_pos). ``raw``
-    column arrays are zero-copy views into ``buf`` (read-only when it is
-    an mmap); consumers that outlive ``buf`` must copy."""
-    total = len(buf)
-    parsed = _parse_preamble(buf, pos, total)
-    if parsed is None:
-        raise WireError(f"truncated block at byte {pos}")
-    header_len, payload_len = parsed
+def _decode_body(buf, pos: int, header_len: int, payload_len: int, verify_crc, columns):
+    """Header and columns of the block at ``pos``, its preamble already
+    parsed. ``columns`` names the columns to build (None: all of them);
+    the CRC covers the whole payload whatever is built."""
     hstart = pos + _PREAMBLE.size
     header = json.loads(bytes(buf[hstart : hstart + header_len]))
     pstart = hstart + header_len
     payload = memoryview(buf)[pstart : pstart + payload_len]
     if verify_crc and zlib.crc32(payload) & 0xFFFFFFFF != header["crc32"]:
         raise WireError(f"block crc mismatch at byte {pos}")
-    cols = {e[0]: _decode_col(e, payload) for e in header["cols"]}
-    return header, cols, pstart + payload_len
+    cols = {
+        e[0]: _decode_col(e, payload)
+        for e in header["cols"]
+        if columns is None or e[0] in columns
+    }
+    return header, cols
+
+
+def decode_block(buf, pos: int = 0, verify_crc: bool = True, columns=None):
+    """Decode the block at ``pos`` → (header, cols, end_pos). Only the
+    columns named in ``columns`` are built (None: every column of the
+    block); with ``verify_crc`` the whole payload is checked either
+    way. ``raw`` column arrays are zero-copy views into ``buf``
+    (read-only when it is an mmap, which a live view keeps mapped)."""
+    parsed = _parse_preamble(buf, pos, len(buf))
+    if parsed is None:
+        raise WireError(f"truncated block at byte {pos}")
+    header, cols = _decode_body(buf, pos, *parsed, verify_crc, columns)
+    return header, cols, pos + _PREAMBLE.size + sum(parsed)
 
 
 # ---------------------------------------------------------------------------
@@ -352,40 +367,76 @@ def split_block_spans(
     return out
 
 
-def iter_blocks(
-    path: str | os.PathLike,
-    start: int = 0,
-    end: int | None = None,
-    verify_crc: bool = True,
-) -> Iterator[tuple[dict, dict[str, np.ndarray]]]:
-    """Yield ``(header, cols)`` per block in ``[start, end)`` via one
-    mmap. ``raw`` columns are zero-copy views valid only inside the
-    consuming iteration step (copy to keep)."""
-    size = os.path.getsize(path)
-    if end is None or end > size:
-        end = size
-    if start >= end:
-        return
+@dataclass
+class BlockTally:
+    """What a reader did with the blocks of its range, added up by the
+    caller that hands it in: ``decoded`` blocks had their header parsed,
+    their payload CRC-checked and the asked-for columns built;
+    ``hopped`` blocks were stepped over by their 16-byte preamble and
+    nothing of them was read."""
+
+    decoded: int = 0
+    hopped: int = 0
+
+
+@contextlib.contextmanager
+def _mapped(path):
+    """The file as one read-only mmap. It is NOT closed eagerly:
+    consumers may still hold zero-copy views when the block ends, and
+    ``mmap.close()`` raises BufferError while any exported view lives.
+    Refcounting reclaims the mapping once the last view dies — the same
+    lifetime model as ``np.load(mmap_mode=...)``."""
     with open(path, "rb") as f:
         mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
-    # the mapping is NOT closed eagerly: consumers may still hold
-    # zero-copy views when this generator exits, and mmap.close() raises
-    # BufferError while any exported view lives. Refcounting reclaims
-    # the mapping once the last view dies — the same lifetime model as
-    # np.load(mmap_mode=...)
     try:
-        pos = start
-        while pos < end:
-            if _parse_preamble(mm, pos, end) is None:
-                break  # torn tail
-            header, cols, pos = decode_block(mm, pos, verify_crc=verify_crc)
-            yield header, cols
-            del header, cols  # release this block's views before the next hop
+        yield mm
     finally:
         try:
             mm.close()
         except BufferError:
             pass  # views still alive; GC closes the mapping later
+
+
+def _hop_mapped(mm, start: int, end: int):
+    """The preamble walk over a mapping: ``(pos, header_len,
+    payload_len)`` per complete block in ``[start, end)``, under the
+    rules of ``_hop_blocks`` (a torn tail ends it, garbage at a block
+    edge raises). It makes no system call, so a thread that hops a
+    whole upload does not hand the interpreter lock to another on every
+    block, as two calls a block do; the file scanners above keep plain
+    reads, which a file truncated under them cannot fault."""
+    pos = start
+    while pos < end:
+        parsed = _parse_preamble(mm, pos, end)
+        if parsed is None:
+            break  # torn tail
+        yield (pos, *parsed)
+        pos += _PREAMBLE.size + sum(parsed)
+
+
+def iter_blocks(
+    path: str | os.PathLike,
+    start: int = 0,
+    end: int | None = None,
+    verify_crc: bool = True,
+    columns=None,
+    tally: BlockTally | None = None,
+) -> Iterator[tuple[dict, dict[str, np.ndarray]]]:
+    """Yield ``(header, cols)`` per block in ``[start, end)`` via one
+    mmap: one preamble parse, one header parse and (with ``verify_crc``)
+    one CRC a block, and only the columns named in ``columns`` built
+    (None: all). ``raw`` columns are zero-copy views; a consumer that
+    keeps one keeps the mapping alive with it."""
+    end = _clamped_end(path, end)
+    if start >= end:
+        return
+    with _mapped(path) as mm:
+        for pos, header_len, payload_len in _hop_mapped(mm, start, end):
+            header, cols = _decode_body(mm, pos, header_len, payload_len, verify_crc, columns)
+            if tally is not None:
+                tally.decoded += 1
+            yield header, cols
+            del header, cols  # release this block's views before the next hop
 
 
 def read_columns(
@@ -394,14 +445,15 @@ def read_columns(
     offset: int = 0,
     end: int | None = None,
     verify_crc: bool = True,
+    tally: BlockTally | None = None,
 ) -> dict[str, np.ndarray]:
     """Concatenated columns of every block (optionally of one ``kind``)
     — the batch read for fits that want the whole dataset in memory
-    (topology graph builds)."""
+    (topology graph builds). Every column of every block is built."""
     from dragonfly2_tpu.schema.columnar import concat_columns
 
     batches = []
-    for header, cols in iter_blocks(path, offset, end, verify_crc=verify_crc):
+    for header, cols in iter_blocks(path, offset, end, verify_crc=verify_crc, tally=tally):
         if kind is None or header["kind"] == kind:
             # copy: the result must outlive the mmap
             batches.append({n: np.array(a) for n, a in cols.items()})
@@ -456,6 +508,11 @@ def encode_topology_block(recs) -> bytes:
     return encode_block(records_to_columns(recs), KIND_TOPOLOGY, records=len(recs))
 
 
+# what each consumer of a ``train`` block asks ``iter_blocks`` to build
+_PAIR_COLUMNS = ("pairs.features", "pairs.labels", "pairs.download_index")
+_GRU_COLUMNS = ("gru.sequences", "gru.labels", "gru.lengths")
+
+
 def _train_tensors(header: dict, cols: dict[str, np.ndarray]):
     from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
 
@@ -493,7 +550,9 @@ def stream_train_pairs(
     for _ in range(max(1, passes)):
         for path, start, end in spans:
             t0 = _time.perf_counter()
-            for header, cols in iter_blocks(path, start, end, verify_crc=verify_crc):
+            for header, cols in iter_blocks(
+                path, start, end, verify_crc=verify_crc, columns=_PAIR_COLUMNS[:2]
+            ):
                 if header["kind"] != KIND_TRAIN:
                     continue
                 feats, labels = _train_tensors(header, cols)
@@ -518,25 +577,30 @@ def read_train_pairs(
     offset: int = 0,
     end: int | None = None,
     verify_crc: bool = True,
+    tally: BlockTally | None = None,
 ):
     """Every ``train`` block's pairs, concatenated → ``PairExamples`` —
-    the batch read for small datasets (below the streaming threshold)
-    and federation shards."""
+    the batch read for small datasets (below the streaming threshold),
+    resident fits and federation shards. Only the three pair columns
+    are built, as views into the mapping, and each pair is copied once:
+    into the array the caller is handed. Every block of ``[offset,
+    end)`` is CRC-checked, so this read is also the round's check of
+    the blocks a newest-first reader (``read_gru_tail``) hops over."""
     from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
 
     feats, labels, idx = [], [], []
+    bases: list[int] = []  # records before each block: its indices' base
     records = 0
-    for header, cols in iter_blocks(path, offset, end, verify_crc=verify_crc):
+    for header, cols in iter_blocks(
+        path, offset, end, verify_crc=verify_crc, columns=_PAIR_COLUMNS, tally=tally
+    ):
         if header["kind"] != KIND_TRAIN:
             continue
         f, l = _train_tensors(header, cols)
-        feats.append(np.array(f))
-        labels.append(np.array(l))
-        # per-block indices are 0-based within their block's record
-        # batch — rebase onto the running record count so the
-        # concatenated result keeps the documented "row in the source
-        # batch" invariant instead of aliasing records across blocks
-        idx.append(np.asarray(cols["pairs.download_index"]) + np.int32(records))
+        feats.append(f)
+        labels.append(l)
+        idx.append(cols["pairs.download_index"])
+        bases.append(records)
         records += int(header.get("records", header["rows"]))
     if not feats:
         return PairExamples(
@@ -545,30 +609,62 @@ def read_train_pairs(
             download_index=np.zeros((0,), np.int32),
             num_downloads=records,
         )
+    # per-block indices are 0-based within their block's record batch —
+    # rebase onto the running record count so the concatenated result
+    # keeps the documented "row in the source batch" invariant instead
+    # of aliasing records across blocks
+    download_index = np.concatenate(idx)
+    download_index += np.repeat(np.asarray(bases, np.int32), [len(i) for i in idx])
     return PairExamples(
         features=np.concatenate(feats),
         labels=np.concatenate(labels),
-        download_index=np.concatenate(idx),
+        download_index=download_index,
         num_downloads=records,
     )
 
 
-def stream_gru_sequences(
+def read_gru_tail(
     path: str | os.PathLike,
+    cap: int,
     offset: int = 0,
     end: int | None = None,
     verify_crc: bool = True,
+    tally: BlockTally | None = None,
 ):
-    """Yield one ``PieceSequences`` per ``train`` block — the GRU leg's
-    bounded-memory binary read (same chunk-wise contract as
-    ``TrainerStorage.iter_download_chunks`` + extraction)."""
-    from dragonfly2_tpu.schema.features import PieceSequences
+    """The newest ``cap`` piece sequences of the ``train`` blocks in
+    ``[offset, end)``, in file order → ``PieceSequences`` (all of them
+    when the range holds fewer): what a walk of every block that kept
+    the last ``cap`` would return, element for element. Records append
+    in time order, so the range is hopped once by its preambles (16
+    bytes a block of the mapping, no header, no payload) and decoded
+    from the last block backwards until ``cap`` sequences are held or the start is
+    reached; an upload under the cap is a full read by the same code.
 
-    for header, cols in iter_blocks(path, offset, end, verify_crc=verify_crc):
-        if header["kind"] != KIND_TRAIN:
-            continue
-        yield PieceSequences(
-            sequences=np.array(cols["gru.sequences"]),
-            labels=np.array(cols["gru.labels"]),
-            lengths=np.array(cols["gru.lengths"]),
-        )
+    Only the blocks decoded here are CRC-checked here. The blocks hopped
+    over are checked by the round's MLP read of the same range, which
+    decodes every block (``read_train_pairs``, ``stream_train_pairs``):
+    a corrupt block anywhere still fails that fit."""
+    from dragonfly2_tpu.schema.features import PieceSequences, extract_piece_sequences
+
+    end = _clamped_end(path, end)
+    if offset >= end:
+        return extract_piece_sequences({})
+    parts: list[dict[str, np.ndarray]] = []  # newest block first
+    held = 0
+    with _mapped(path) as mm:
+        blocks = list(_hop_mapped(mm, offset, end))
+        at = len(blocks)
+        while at and held < cap:
+            at -= 1
+            header, cols = _decode_body(mm, *blocks[at], verify_crc, _GRU_COLUMNS)
+            if header["kind"] == KIND_TRAIN and len(cols["gru.sequences"]):
+                parts.append(cols)
+                held += len(cols["gru.sequences"])
+    if tally is not None:
+        tally.decoded += len(blocks) - at
+        tally.hopped += at
+    if not parts:
+        return extract_piece_sequences({})
+    return PieceSequences(
+        *(np.concatenate([p[c] for p in reversed(parts)])[-cap:] for c in _GRU_COLUMNS)
+    )

@@ -17,6 +17,17 @@ FIT_DURATION = _r.histogram(
     "trainer_fit_duration_seconds", "Fit wall time", ("model",),
     buckets=(0.1, 0.5, 1, 5, 15, 60, 300, 1200, 3600, float("inf")),
 )
+# What a fit leg's block reader did with the upload, per round: blocks
+# decoded (header parsed, payload CRC-checked, the leg's columns built)
+# and blocks hopped over by the 16-byte preamble alone. The GRU leg
+# trains on the newest gru_max_sequences and reads the upload from its
+# end, so on a large upload it hops nearly every block; the resident MLP
+# leg decodes every one. The streamed MLP fit counts none here.
+FIT_BLOCKS_TOTAL = _r.counter(
+    "trainer_fit_blocks_total",
+    "Upload blocks a fit leg's reader decoded or hopped over",
+    ("model", "fate"),
+)
 INGEST_RECORDS_TOTAL = _r.counter(
     "trainer_ingest_records_total", "Download records decoded for training"
 )

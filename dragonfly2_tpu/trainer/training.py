@@ -15,6 +15,7 @@ manager/models/model.go:20-26 state machine).
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import contextlib
 import time
@@ -139,6 +140,12 @@ class LegSplit:
     # holdout's forward), not beside them
     compiles: int = 0
     compile_s: float = 0.0
+    # upload blocks the leg's reader decoded (header, CRC, the columns it
+    # trains on) and blocks it hopped over by the preamble alone. The
+    # streamed MLP fit decodes on its pool's threads and counts none
+    # here: its account is ``stream``
+    blocks_decoded: int = 0
+    blocks_hopped: int = 0
     # the streamed MLP fit's own split, when the leg streamed
     stream: Any = None  # ingest.StreamStats | None
 
@@ -156,6 +163,8 @@ class LegSplit:
             "phase_n": self.phase_n,
             "compiles": self.compiles,
             "compile_s": self.compile_s,
+            "blocks_decoded": self.blocks_decoded,
+            "blocks_hopped": self.blocks_hopped,
         }
 
 
@@ -276,6 +285,7 @@ class Training:
 
         span = tracing.get("trainer").start_span("fit", parent=parent_span, model=model)
         t0 = time.perf_counter()
+        blocks = wire.BlockTally()  # filled by the leg's block readers
 
         def close(mine: dict) -> dict:
             """The leg's split of this round, left in ``splits`` and
@@ -287,7 +297,11 @@ class Training:
                 phase_n={k: v[0] for k, v in mine.items()},
                 compiles=compiles,
                 compile_s=round(compile_s, 6),
+                blocks_decoded=blocks.decoded,
+                blocks_hopped=blocks.hopped,
             )
+            M.FIT_BLOCKS_TOTAL.labels(model, "decoded").inc(blocks.decoded)
+            M.FIT_BLOCKS_TOTAL.labels(model, "hopped").inc(blocks.hopped)
             fields = leg.fields()
             span.set(**fields)
             return fields
@@ -301,7 +315,7 @@ class Training:
             profiling.split() as mine,
         ):
             try:
-                result = fn(*args)
+                result = fn(*args, blocks=blocks)
             except Exception as e:
                 EV_FIT(model=model, outcome="failure", error=str(e), **close(mine))
                 span.end("error")
@@ -347,7 +361,12 @@ class Training:
 
     # -- trainMLP (reference training.go:92-98) ---------------------------
     def _train_mlp(
-        self, host_id: str, ip: str, hostname: str, info: dict | None = None
+        self,
+        host_id: str,
+        ip: str,
+        hostname: str,
+        info: dict | None = None,
+        blocks: wire.BlockTally | None = None,
     ) -> dict[str, float]:
         # payload selection: binary columnar stream (zero-parse ingest)
         # or CSV via the native fused decoder (numpy fallback) — all
@@ -364,7 +383,7 @@ class Training:
         if has_csv and has_bin:
             try:
                 return self._train_mlp_from(
-                    host_id, ip, hostname, binary=False, info=info
+                    host_id, ip, hostname, binary=False, info=info, blocks=blocks
                 )
             except BelowMinRecords as e:
                 # the CSV-era leftover alone can't train (below the
@@ -381,13 +400,13 @@ class Training:
                     e,
                 )
                 metrics = self._train_mlp_from(
-                    host_id, ip, hostname, binary=True, info=info
+                    host_id, ip, hostname, binary=True, info=info, blocks=blocks
                 )
                 if info is not None:
                     info["binary"] = None
                 return metrics
         return self._train_mlp_from(
-            host_id, ip, hostname, binary=has_bin, info=info
+            host_id, ip, hostname, binary=has_bin, info=info, blocks=blocks
         )
 
     def _train_mlp_from(
@@ -397,6 +416,7 @@ class Training:
         hostname: str,
         binary: bool,
         info: dict | None = None,
+        blocks: wire.BlockTally | None = None,
     ) -> dict[str, float]:
         if info is not None:
             info["binary"] = binary
@@ -420,7 +440,9 @@ class Training:
             )
         with M.PH_MLP.load:
             if binary:
-                pairs = wire.read_train_pairs(path, offset=offset, end=boundary)
+                pairs = wire.read_train_pairs(
+                    path, offset=offset, end=boundary, tally=blocks
+                )
             else:
                 # bounded at the round boundary exactly like the binary and
                 # streaming paths: the in-flight tail past it may be
@@ -618,7 +640,9 @@ class Training:
         return stats.metrics
 
     # -- trainGNN (reference training.go:82-88) ---------------------------
-    def _train_gnn(self, host_id: str, ip: str, hostname: str) -> dict[str, float]:
+    def _train_gnn(
+        self, host_id: str, ip: str, hostname: str, blocks: wire.BlockTally | None = None
+    ) -> dict[str, float]:
         # the probe graph is cumulative state (EWMA RTT edges), so the GNN
         # always rebuilds from the whole history — no offset decode here;
         # the incremental win is on the (much larger) download stream
@@ -643,6 +667,7 @@ class Training:
                             end=self.storage.network_topology_round_boundary(
                                 host_id, binary=True
                             ),
+                            tally=blocks,
                         ),
                     ]
                 )
@@ -655,6 +680,7 @@ class Training:
                     bpath,
                     kind=wire.KIND_TOPOLOGY,
                     end=self.storage.network_topology_round_boundary(host_id, binary=True),
+                    tally=blocks,
                 )
                 graph = build_probe_graph(cols, max_degree=self.config.gnn_max_degree)
             else:
@@ -688,69 +714,54 @@ class Training:
 
 
     # -- trainGRU (piece time-series; our addition over the reference) -----
-    def _train_gru(self, host_id: str, ip: str, hostname: str) -> dict[str, float]:
-        from dragonfly2_tpu.schema.features import PieceSequences, extract_piece_sequences
+    def _train_gru(
+        self, host_id: str, ip: str, hostname: str, blocks: wire.BlockTally | None = None
+    ) -> dict[str, float]:
+        from dragonfly2_tpu.schema.features import extract_piece_sequences
         from dragonfly2_tpu.trainer.train import train_gru
         from dragonfly2_tpu.utils.idgen import gru_model_id_v1
 
-        # sequence extraction is row-local (each Download record yields
-        # its own per-parent sequences), so read the dataset in bounded
-        # chunks instead of materializing the whole file — this leg must
-        # hold the same memory bound as the streaming MLP path. The
-        # sequence count is capped at the NEWEST gru_max_sequences:
-        # records append in time order, so trimming from the front keeps
-        # the fit tracking recent link behavior — in incremental mode
-        # the file is never cleared, and an oldest-first cap would pin
-        # the model to stale history forever.
-        parts: list[PieceSequences] = []
-        total = 0
+        # The fit is handed the NEWEST gru_max_sequences sequences:
+        # records append in time order, so keeping the tail keeps the fit
+        # tracking recent link behavior — in incremental mode the file is
+        # never cleared, and an oldest-first cap would pin the model to
+        # stale history forever. Nothing older is decoded: the binary
+        # upload carries the sequences pre-extracted in each train block
+        # and is read from its last block backwards until the cap is held
+        # (wire.read_gru_tail, which CRC-checks the blocks it decodes;
+        # the MLP leg's read of the whole upload checks the rest). The
+        # CSV era is older than the binary one, so it is read only when
+        # the blocks hold fewer than the cap: chunk-wise, re-extracting,
+        # keeping its newest — a host that switched payload formats
+        # keeps its whole recent history feeding the next-cost model.
+        # Both reads stop at the committed round boundary (a concurrent
+        # Train stream may be appending past it, same protocol as the
+        # MLP leg's offset/boundary machinery), and both hold the memory
+        # bound of the streaming MLP path: the cap, not the file.
         cap = self.config.gru_max_sequences
-        # read only up to the committed round boundary: this generator
-        # stays open across extraction pauses, and a concurrent Train
-        # stream may be appending past it (same protocol as the MLP
-        # leg's offset/boundary machinery). Binary uploads carry the
-        # sequences pre-extracted in each train block; CSV re-extracts
-        # chunk-wise — both sides of the same bounded-memory contract.
-        # BOTH sources are consumed (CSV era first, it's older): a host
-        # that switched payload formats keeps its whole recent history
-        # feeding the next-cost model, and the newest-kept cap below
-        # still bounds memory.
-        import itertools
-
         with M.PH_GRU.load:
-            seq_iters = []
-            cpath = self.storage.download_path(host_id)
-            if cpath.exists() and cpath.stat().st_size:
-                boundary = self.storage.download_round_boundary(host_id)
-                seq_iters.append(
-                    extract_piece_sequences(records_to_columns(chunk))
-                    for chunk in self.storage.iter_download_chunks(
-                        host_id, max_bytes=boundary
-                    )
-                )
+            seqs = extract_piece_sequences({})
             bpath = self.storage.download_blocks_path(host_id)
             if bpath.exists() and bpath.stat().st_size:
-                seq_iters.append(
-                    wire.stream_gru_sequences(
-                        bpath,
-                        end=self.storage.download_round_boundary(host_id, binary=True),
-                    )
+                seqs = wire.read_gru_tail(
+                    bpath,
+                    cap,
+                    end=self.storage.download_round_boundary(host_id, binary=True),
+                    tally=blocks,
                 )
-            for s in itertools.chain(*seq_iters):
-                if s.sequences.shape[0]:
-                    parts.append(s)
-                    total += s.sequences.shape[0]
-                while parts and total - parts[0].sequences.shape[0] >= cap:
-                    total -= parts[0].sequences.shape[0]
-                    parts.pop(0)
-            if parts:
-                seqs = PieceSequences(
-                    sequences=np.concatenate([p.sequences for p in parts])[-cap:],
-                    labels=np.concatenate([p.labels for p in parts])[-cap:],
-                    lengths=np.concatenate([p.lengths for p in parts])[-cap:],
+            cpath = self.storage.download_path(host_id)
+            if seqs.sequences.shape[0] < cap and cpath.exists() and cpath.stat().st_size:
+                boundary = self.storage.download_round_boundary(host_id)
+                seqs = _newest_sequences(
+                    (
+                        extract_piece_sequences(records_to_columns(chunk))
+                        for chunk in self.storage.iter_download_chunks(
+                            host_id, max_bytes=boundary
+                        )
+                    ),
+                    seqs,
+                    cap,
                 )
-            else:
-                seqs = extract_piece_sequences({})
         n = seqs.sequences.shape[0]
         if n < self.config.gru_min_sequences:
             raise ValueError(
@@ -802,6 +813,28 @@ class Training:
                 evaluation=result.metrics,
             )
         return result.metrics
+
+
+def _newest_sequences(older, seqs, cap: int):
+    """``seqs`` behind the newest of ``older`` (PieceSequences in time
+    order, from a format that only reads forwards) that fill ``cap``:
+    an older part goes as soon as what follows it holds the cap."""
+    from dragonfly2_tpu.schema.features import PieceSequences
+
+    kept = collections.deque([seqs])
+    total = seqs.sequences.shape[0]
+    for s in older:
+        if s.sequences.shape[0]:
+            kept.insert(len(kept) - 1, s)
+            total += s.sequences.shape[0]
+        while len(kept) > 1 and total - kept[0].sequences.shape[0] >= cap:
+            total -= kept.popleft().sequences.shape[0]
+    return PieceSequences(
+        *(
+            np.concatenate([getattr(p, f) for p in kept])[-cap:]
+            for f in ("sequences", "labels", "lengths")
+        )
+    )
 
 
 def _to_host(params) -> Any:

@@ -537,3 +537,318 @@ class TestTornStreamRecovery:
         # both forms cleared: the binary was consumed, the tail dropped
         assert not t_storage.download_path(hid).exists()
         assert not t_storage.download_blocks_path(hid).exists()
+
+
+# ---------------------------------------------------------------------------
+# the resident round's block readers: each leg decodes only the blocks and
+# columns it trains on (ISSUE 26). The plain full-walk readers they replaced
+# are kept here as the references.
+# ---------------------------------------------------------------------------
+
+
+def _block(rng, pairs: int, seqs: int, records: int) -> bytes:
+    """One ``train`` block of the given sizes with seeded contents (0
+    pairs or 0 sequences serialize as ``zero`` columns)."""
+    from dragonfly2_tpu.schema.features import GRU_FEATURE_DIM, GRU_MAX_SEQ, MLP_FEATURE_DIM
+
+    cols = {
+        "pairs.features": rng.random((pairs, MLP_FEATURE_DIM), np.float32),
+        "pairs.labels": rng.random(pairs, np.float32),
+        "pairs.download_index": np.sort(rng.integers(0, records, pairs)).astype(np.int32),
+        "gru.sequences": rng.random((seqs, GRU_MAX_SEQ, GRU_FEATURE_DIM), np.float32),
+        "gru.labels": rng.random(seqs, np.float32),
+        "gru.lengths": rng.integers(1, GRU_MAX_SEQ + 1, seqs).astype(np.int32),
+    }
+    return wire.encode_block(
+        cols, wire.KIND_TRAIN, records=records, meta={"feature_dim": MLP_FEATURE_DIM}
+    )
+
+
+def _topology_block(seed: int) -> bytes:
+    return wire.encode_topology_block(synth.make_topology_records(6, num_hosts=4, seed=seed))
+
+
+# name → (blocks as (pairs, sequences, records) or "topology", torn tail?)
+_UPLOADS = {
+    "even": ([(7, 5, 3)] * 6, False),
+    "ragged": ([(4, 9, 2), (0, 0, 1), (11, 1, 5), (3, 0, 2), (6, 7, 3), (0, 4, 1)], False),
+    "interleaved": (
+        [(5, 4, 2), "topology", (8, 6, 4), "topology", "topology", (2, 3, 1), "topology"],
+        False,
+    ),
+    "torn": ([(5, 4, 2), (9, 6, 3), (7, 2, 3)], True),
+    "no-train": (["topology", "topology"], False),
+    "no-sequences": ([(5, 0, 2), (3, 0, 1)], False),
+}
+
+
+def _write_upload(tmp_path, name):
+    """→ (path, [(start, end) per whole block])."""
+    spec, torn = _UPLOADS[name]
+    rng = np.random.default_rng(sorted(_UPLOADS).index(name))
+    blocks = [
+        _topology_block(i) if b == "topology" else _block(rng, *b) for i, b in enumerate(spec)
+    ]
+    edges = np.cumsum([0] + [len(b) for b in blocks]).tolist()
+    path = tmp_path / f"{name}.dfb"
+    path.write_bytes(b"".join(blocks) + (blocks[0][: len(blocks[0]) // 2] if torn else b""))
+    return path, list(zip(edges[:-1], edges[1:]))
+
+
+def _walk(path, offset=0, end=None):
+    """Every whole block of ``[offset, end)``, all six columns, oldest
+    first: the plain walk both old readers made."""
+    buf = path.read_bytes()
+    for start, _ in wire.scan_block_extents(path, offset, end):
+        header, cols, _ = wire.decode_block(buf, start)
+        yield header, cols
+
+
+def _reference_gru(path, cap, offset=0, end=None):
+    """The full walk with the pop-from-the-front cap, as ``_train_gru``
+    ran it before the newest-first read."""
+    parts, total = [], 0
+    for header, cols in _walk(path, offset, end):
+        if header["kind"] != wire.KIND_TRAIN:
+            continue
+        if cols["gru.sequences"].shape[0]:
+            parts.append(cols)
+            total += cols["gru.sequences"].shape[0]
+        while parts and total - parts[0]["gru.sequences"].shape[0] >= cap:
+            total -= parts.pop(0)["gru.sequences"].shape[0]
+    if not parts:
+        empty = extract_piece_sequences({})
+        return empty.sequences, empty.labels, empty.lengths
+    return tuple(
+        np.concatenate([p[c] for p in parts])[-cap:]
+        for c in ("gru.sequences", "gru.labels", "gru.lengths")
+    )
+
+
+def _reference_pairs(path, offset=0, end=None):
+    """A copy of every block's pair columns, then one concatenate."""
+    feats, labels, idx, records = [], [], [], 0
+    for header, cols in _walk(path, offset, end):
+        if header["kind"] != wire.KIND_TRAIN:
+            continue
+        feats.append(np.array(cols["pairs.features"]))
+        labels.append(np.array(cols["pairs.labels"]))
+        idx.append(np.asarray(cols["pairs.download_index"]) + np.int32(records))
+        records += header["records"]
+    if not feats:
+        return None, records
+    return (np.concatenate(feats), np.concatenate(labels), np.concatenate(idx)), records
+
+
+def _bounds(extents, which):
+    """Byte bounds by name: the whole file, or cut at block edges."""
+    if which == "whole" or len(extents) < 3:
+        return 0, None
+    if which == "from-second-block":
+        return extents[1][0], None
+    if which == "to-last-block":
+        return 0, extents[-1][0]
+    return extents[1][0], extents[-2][1]  # "inner"
+
+
+def _assert_same_array(got, want):
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+
+
+class TestNewestFirstGruRead:
+    # caps against "even" (6 blocks of 5 sequences): inside a block, at a
+    # block's edge, at the total, above it, none
+    @pytest.mark.parametrize("cap", [1, 3, 5, 10, 12, 29, 30, 31, 1000, 0])
+    def test_cap_below_at_and_above_the_total(self, tmp_path, cap):
+        path, extents = _write_upload(tmp_path, "even")
+        tally = wire.BlockTally()
+        got = wire.read_gru_tail(path, cap, tally=tally)
+        for g, w in zip((got.sequences, got.labels, got.lengths), _reference_gru(path, cap)):
+            _assert_same_array(g, w)
+        # no block is decoded that holds none of the cap
+        assert tally.decoded == min(-(-cap // 5), len(extents))
+        assert tally.hopped == len(extents) - tally.decoded
+
+    @pytest.mark.parametrize("which", ["whole", "from-second-block", "to-last-block", "inner"])
+    @pytest.mark.parametrize("cap", [2, 8, 1000])
+    @pytest.mark.parametrize("name", sorted(_UPLOADS))
+    def test_equals_the_full_walk(self, tmp_path, name, cap, which):
+        """Blocks with no sequences, non-``train`` blocks between, a torn
+        tail, no ``train`` block at all, under ``offset`` / ``end``."""
+        path, extents = _write_upload(tmp_path, name)
+        offset, end = _bounds(extents, which)
+        tally = wire.BlockTally()
+        got = wire.read_gru_tail(path, cap, offset=offset, end=end, tally=tally)
+        want = _reference_gru(path, cap, offset, end)
+        for g, w in zip((got.sequences, got.labels, got.lengths), want):
+            _assert_same_array(g, w)
+        assert tally.decoded + tally.hopped == len(wire.scan_block_extents(path, offset, end))
+
+    def test_end_past_the_file_and_empty_range(self, tmp_path):
+        path, extents = _write_upload(tmp_path, "even")
+        whole = wire.read_gru_tail(path, 7)
+        _assert_same_array(wire.read_gru_tail(path, 7, end=10**12).labels, whole.labels)
+        none = wire.read_gru_tail(path, 7, offset=extents[2][0], end=extents[2][0])
+        assert none.sequences.shape == extract_piece_sequences({}).sequences.shape
+
+    @pytest.mark.parametrize("corrupt,gru_raises", [(5, True), (4, True), (3, False), (0, False)])
+    def test_who_checks_which_blocks_crc(self, tmp_path, corrupt, gru_raises):
+        """Cap 8 of "even" is held by the last two blocks: a flipped
+        byte there fails the GRU read; before them it is the MLP leg's
+        read of the whole upload that fails, as the round relies on."""
+        path, extents = _write_upload(tmp_path, "even")
+        buf = bytearray(path.read_bytes())
+        buf[extents[corrupt][1] - 3] ^= 0xFF
+        path.write_bytes(bytes(buf))
+        if gru_raises:
+            with pytest.raises(wire.WireError, match="crc"):
+                wire.read_gru_tail(path, 8)
+        else:
+            assert wire.read_gru_tail(path, 8).sequences.shape[0] == 8
+        with pytest.raises(wire.WireError, match="crc"):
+            wire.read_train_pairs(path)
+        with pytest.raises(wire.WireError, match="crc"):
+            for _ in wire.stream_train_pairs(path):
+                pass
+
+    def test_garbage_at_a_block_edge_raises(self, tmp_path):
+        path, extents = _write_upload(tmp_path, "even")
+        buf = bytearray(path.read_bytes())
+        buf[extents[1][0]] ^= 0xFF  # the second block's magic
+        path.write_bytes(bytes(buf))
+        with pytest.raises(wire.WireError, match="magic"):
+            wire.read_gru_tail(path, 3)
+
+    @pytest.mark.parametrize("cap_of", ["under-binary", "binary-exactly", "into-csv", "over-both"])
+    def test_csv_era_then_binary_era(self, tmp_path, monkeypatch, cap_of):
+        """The binary era is newer: the CSV chunks are read only when the
+        blocks hold fewer than the cap, and the fit is handed what the
+        old chained walk gave it."""
+        import dragonfly2_tpu.trainer.train as T
+
+        older = synth.make_download_records(30, seed=51)
+        newer = [synth.make_download_records(12, seed=52 + i) for i in range(3)]
+        storage = TrainerStorage(tmp_path / "t")
+        hid = host_id_v2("5.5.5.5", "s5")
+        write_csv(storage.download_path(hid), older)
+        for recs in newer:
+            storage.append_download_blocks(hid, wire.encode_train_block(recs))
+        storage.mark_download_round(hid)
+        chain = [extract_piece_sequences(records_to_columns(r)) for r in (older, *newer)]
+        n_csv = chain[0].sequences.shape[0]
+        n_bin = sum(p.sequences.shape[0] for p in chain[1:])
+        assert n_csv > 3 and n_bin > 3
+        cap = {
+            "under-binary": n_bin - 3, "binary-exactly": n_bin,
+            "into-csv": n_bin + 3, "over-both": n_bin + n_csv + 5,
+        }[cap_of]
+        fed = {}
+
+        def spy(sequences, labels, lengths=None, **kw):
+            fed.update(sequences=sequences, labels=labels, lengths=lengths)
+            raise RuntimeError("handed over")
+
+        monkeypatch.setattr(T, "train_gru", spy)
+        chunks_read = []
+        real_chunks = storage.iter_download_chunks
+
+        def counting_chunks(*a, **kw):
+            chunks_read.append(1)
+            return real_chunks(*a, **kw)
+
+        monkeypatch.setattr(storage, "iter_download_chunks", counting_chunks)
+        training = Training(
+            storage, None, TrainingConfig(gru_max_sequences=cap, gru_min_sequences=1, auto_mesh=False)
+        )
+        with pytest.raises(RuntimeError, match="handed over"):
+            training._train_gru(hid, "5.5.5.5", "s5")
+        for f in ("sequences", "labels", "lengths"):
+            want = np.concatenate([getattr(p, f) for p in chain])[-cap:]
+            _assert_same_array(fed[f], want)
+        assert bool(chunks_read) == (cap > n_bin)
+
+
+class TestPairsWrittenOnce:
+    @pytest.mark.parametrize("which", ["whole", "from-second-block", "to-last-block", "inner"])
+    @pytest.mark.parametrize("name", sorted(_UPLOADS))
+    def test_equals_the_concatenation_reference(self, tmp_path, name, which):
+        from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
+
+        path, extents = _write_upload(tmp_path, name)
+        offset, end = _bounds(extents, which)
+        tally = wire.BlockTally()
+        got = wire.read_train_pairs(path, offset=offset, end=end, tally=tally)
+        want, records = _reference_pairs(path, offset, end)
+        assert got.num_downloads == records
+        assert (tally.decoded, tally.hopped) == (len(wire.scan_block_extents(path, offset, end)), 0)
+        arrays = (got.features, got.labels, got.download_index)
+        if want is None:  # no train block in the range
+            want = (
+                np.zeros((0, MLP_FEATURE_DIM), np.float32),
+                np.zeros((0,), np.float32),
+                np.zeros((0,), np.int32),
+            )
+        for g, w in zip(arrays, want):
+            _assert_same_array(g, w)
+            assert g.flags.c_contiguous and g.flags.writeable and g.flags.owndata
+
+    @pytest.mark.parametrize("columns", [None, ("gru.labels",), ("pairs.features", "pairs.labels"), ()])
+    def test_only_the_asked_for_columns_are_built(self, tmp_path, columns):
+        path, _ = _write_upload(tmp_path, "interleaved")
+        every = list(wire.iter_blocks(path))
+        asked = list(wire.iter_blocks(path, columns=columns))
+        assert [h for h, _ in asked] == [h for h, _ in every]
+        for (_, some), (_, full) in zip(asked, every):
+            assert set(some) == (set(full) if columns is None else set(full) & set(columns))
+            for name, arr in some.items():
+                _assert_same_array(arr, full[name])
+
+
+def test_round_reports_blocks_decoded_and_hopped(tmp_path):
+    """A round on a multi-block upload with a small GRU cap: the GRU leg
+    decodes only the blocks that hold the cap and hops the rest, the
+    resident MLP leg decodes every block, and the split, the event's
+    fields and the ``/metrics`` counter say so."""
+    from dragonfly2_tpu.trainer import metrics as M
+
+    hid = host_id_v2("6.6.6.6", "s6")
+    storage = TrainerStorage(tmp_path / "t")
+    blocks = [synth.make_download_records(16, seed=60 + i) for i in range(9)]
+    for recs in blocks:
+        storage.append_download_blocks(hid, wire.encode_train_block(recs))
+    storage.mark_download_round(hid)
+    per_block = [
+        extract_piece_sequences(records_to_columns(recs)).sequences.shape[0] for recs in blocks
+    ]
+    cap = per_block[-1] + per_block[-2] - 1  # held by the last two blocks
+    assert per_block[-1] < cap
+    training = Training(
+        storage,
+        RecordingManager(),
+        TrainingConfig(
+            mlp=FitConfig(hidden_dims=(8,), batch_size=64, epochs=1),
+            gru_config=FitConfig(hidden_dims=(4,), batch_size=16, epochs=1),
+            gru_max_sequences=cap,
+            gru_min_sequences=1,
+            min_topology_records=10**9,  # no topology uploaded here
+            streaming=False,
+            auto_mesh=False,
+        ),
+    )
+    series = {
+        (leg, fate): M.FIT_BLOCKS_TOTAL.labels(leg, fate).value
+        for leg in ("mlp", "gru")
+        for fate in ("decoded", "hopped")
+    }
+    outcome = training.train("6.6.6.6", "s6")
+    assert outcome.mlp_error is None and outcome.gru_error is None, outcome
+    gru, mlp = outcome.splits["gru"], outcome.splits["mlp"]
+    assert (gru.blocks_decoded, gru.blocks_hopped) == (2, len(blocks) - 2)
+    assert (mlp.blocks_decoded, mlp.blocks_hopped) == (len(blocks), 0)
+    assert gru.fields()["blocks_hopped"] == len(blocks) - 2
+    moved = {k: M.FIT_BLOCKS_TOTAL.labels(*k).value - v for k, v in series.items()}
+    assert moved == {
+        ("mlp", "decoded"): len(blocks), ("mlp", "hopped"): 0,
+        ("gru", "decoded"): 2, ("gru", "hopped"): len(blocks) - 2,
+    }

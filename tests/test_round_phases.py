@@ -193,7 +193,10 @@ def test_phases_cover_the_fit_wall(round_, leg):
 @pytest.mark.parametrize("leg", LEGS)
 def test_outcome_event_and_span_carry_the_same_split(round_, leg):
     fields = round_["outcome"].splits[leg].fields()
-    assert set(fields) == {"wall_s", "self_s", "phase_s", "phase_n", "compiles", "compile_s"}
+    assert set(fields) == {
+        "wall_s", "self_s", "phase_s", "phase_n", "compiles", "compile_s",
+        "blocks_decoded", "blocks_hopped",
+    }
     event = round_["fit_events"][leg]
     assert event["outcome"] == "success"
     assert {k: event[k] for k in fields} == fields
