@@ -5,7 +5,9 @@ A server object provides ``serve() -> address`` (bind, start background
 loops, return the bound gRPC address) and ``stop()`` (graceful teardown).
 ``run()`` installs SIGINT/SIGTERM handlers, prints a machine-readable
 ``READY <name> <addr>`` line (hack/run_cluster.sh and the subprocess e2e
-test wait for it), and blocks until signalled.
+test wait for it), and blocks until signalled. A server that is several
+services in one process (``colocated``) returns ``{name: address}`` and
+gets one ``READY`` line for each.
 """
 
 from __future__ import annotations
@@ -20,7 +22,7 @@ logger = dflog.get("cli")
 
 # the binaries that own JAX device planes (fit, scoring service, topology
 # engine, forecaster); manager and daemon never import jax
-_DEVICE_SERVICES = ("scheduler", "trainer")
+_DEVICE_SERVICES = ("scheduler", "trainer", "colocated")
 
 
 def run(name: str, server) -> int:
@@ -50,7 +52,8 @@ def run(name: str, server) -> int:
     kaddr = getattr(server, "kv_addr", None)
     if kaddr:
         print(f"KV {name} {kaddr}", flush=True)
-    print(f"READY {name} {addr}", flush=True)
+    for part, part_addr in (addr if isinstance(addr, dict) else {name: addr}).items():
+        print(f"READY {part} {part_addr}", flush=True)
     try:
         stop_event.wait()
     finally:

@@ -396,6 +396,7 @@ class MLEvaluator(BaseEvaluator):
         serving_up = serving is not None and serving.available()
         if self._model is None and not serving_up:
             self._note_rung("base", "no model loaded; base evaluator ranking")
+            M.DECISION_RUNG_TOTAL.labels("base").inc(sum(1 for ps in candidate_sets if ps))
             base_rank = super().evaluate_parents
             return [
                 base_rank(ps, c, t)
@@ -420,6 +421,7 @@ class MLEvaluator(BaseEvaluator):
                 self._note_rung(
                     "base", "feature build failed; base evaluator ranking"
                 )
+                M.DECISION_RUNG_TOTAL.labels("base").inc(len(live))
                 base_rank = super().evaluate_parents
                 for j in live:
                     results[j] = base_rank(
@@ -493,6 +495,16 @@ class MLEvaluator(BaseEvaluator):
                 )
         if any(r is None for r in scored) and not per_request and not served_any:
             self._note_rung("base", "ml predict failed; base evaluator ranking")
+        # one count a decision, by the rung that ranked it (the ladder
+        # state above is edge-triggered: it says when the service fell,
+        # not what share of decisions the model ranked)
+        n_base = sum(1 for r in scored if r is None)
+        n_mlp = len(demoted) - n_base
+        for rung, k in (
+            ("serving", len(live) - len(demoted)), ("mlp", n_mlp), ("base", n_base),
+        ):
+            if k:
+                M.DECISION_RUNG_TOTAL.labels(rung).inc(k)
 
         sampled = tracing.is_sampling() or flight.dump_armed()
         base_rank = super().evaluate_parents

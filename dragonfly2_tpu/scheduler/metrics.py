@@ -131,6 +131,11 @@ SERVING_FALLBACK_TOTAL = _r.counter(
     "Evaluator degradation-ladder rung drops",
     ("to",),  # mlp | base
 )
+DECISION_RUNG_TOTAL = _r.counter(
+    "scheduler_decision_rung_total",
+    "Decisions ranked, by the ladder rung that ranked each one",
+    ("rung",),  # serving | mlp | base
+)
 
 # -- wave scheduling (scheduler/wave.py, docs/serving.md "wave
 # scheduling"): W decisions × C candidates packed into one scoring

@@ -58,6 +58,18 @@ logger = dflog.get("scheduler.serving")
 # (queue wait + batch service), and per-batch pack+forward wall
 PH_SERVING_WAIT = profiling.phase_type("scheduler.serving_wait")
 PH_SERVING_BATCH = profiling.phase_type("scheduler.serving_batch")
+# where a batch's time goes, inside serving_batch: the concatenate and
+# the segment vector; the put of the padded batch; the forward until its
+# result is ready on the device (a batch that queues behind another
+# tenant's device programs waits here); the blocking read; the hand-back
+# to the waiters
+PH_SCORE_PACK = profiling.phase_type("scheduler.score_pack")
+PH_SCORE_H2D = profiling.phase_type("scheduler.score_h2d")
+PH_SCORE_FORWARD = profiling.phase_type("scheduler.score_forward")
+PH_SCORE_D2H = profiling.phase_type("scheduler.score_d2h")
+PH_SCORE_UNPACK = profiling.phase_type("scheduler.score_unpack")
+# what a scorer's dispatch accounts apart (trainer.serving's ``stages``)
+_SCORE_STAGES = (PH_SCORE_H2D, PH_SCORE_FORWARD, PH_SCORE_D2H)
 
 # flight events: model hot-swaps and serving-path score failures (the
 # per-decision explain/schedule events stay in evaluator/scheduling)
@@ -121,7 +133,7 @@ class MLPServed:
         return True
 
     def score(self, features: np.ndarray, pairs) -> np.ndarray:
-        return np.asarray(self._scorer.predict(features))
+        return np.asarray(self._scorer.predict(features, stages=_SCORE_STAGES))
 
     def score_ranked(self, features: np.ndarray, pairs, seg_ids):
         """(scores, segment-grouped rank permutation) for a packed wave
@@ -130,7 +142,7 @@ class MLPServed:
         lexsort — same contract, same orders."""
         pr = getattr(self._scorer, "predict_ranked", None)
         if pr is not None:
-            return pr(features, seg_ids)
+            return pr(features, seg_ids, stages=_SCORE_STAGES)
         scores = self.score(features, pairs)
         return scores, wavelib.rank_order(scores, seg_ids)
 
@@ -156,7 +168,7 @@ class GNNServed:
     def score(self, features: np.ndarray, pairs) -> np.ndarray:
         src = [a for a, _ in pairs]
         dst = [b for _, b in pairs]
-        return np.asarray(self._scorer.predict_rtt_log_ms(src, dst))
+        return np.asarray(self._scorer.predict_rtt_log_ms(src, dst, stages=_SCORE_STAGES))
 
     def score_ranked(self, features: np.ndarray, pairs, seg_ids):
         # the GNN head returns host scores (index-vector dispatch); the
@@ -520,31 +532,34 @@ class ScoringService:
             has_wave = any(r.counts is not None for r in batch)
             try:
                 FP_SCORE()
-                if len(batch) == 1:
-                    feats = batch[0].features
-                    pairs = batch[0].pairs
-                else:
-                    feats = np.concatenate([r.features for r in batch])
-                    pairs = (
-                        [p for r in batch for p in (r.pairs or ())]
-                        if any(r.pairs for r in batch)
-                        else None
-                    )
+                with PH_SCORE_PACK:
+                    if len(batch) == 1:
+                        feats = batch[0].features
+                        pairs = batch[0].pairs
+                    else:
+                        feats = np.concatenate([r.features for r in batch])
+                        pairs = (
+                            [p for r in batch for p in (r.pairs or ())]
+                            if any(r.pairs for r in batch)
+                            else None
+                        )
+                    if has_wave:
+                        # one GLOBAL segment vector over the packed
+                        # matrix: each wave decision is its own segment,
+                        # each plain request one singleton segment — the
+                        # fused forward returns scores AND the
+                        # segment-grouped rank permutation in the same
+                        # dispatch (score_ranked), so no per-decision
+                        # host sort ever happens
+                        seg_parts = []
+                        seg_off = 0
+                        for r in batch:
+                            cs = r.counts if r.counts is not None else [r.rows]
+                            seg_parts.append(wavelib.segment_ids(cs) + seg_off)
+                            seg_off += len(cs)
+                        seg = np.concatenate(seg_parts)
                 order = None
                 if has_wave:
-                    # one GLOBAL segment vector over the packed matrix:
-                    # each wave decision is its own segment, each plain
-                    # request one singleton segment — the fused forward
-                    # returns scores AND the segment-grouped rank
-                    # permutation in the same dispatch (score_ranked),
-                    # so no per-decision host sort ever happens
-                    seg_parts = []
-                    seg_off = 0
-                    for r in batch:
-                        cs = r.counts if r.counts is not None else [r.rows]
-                        seg_parts.append(wavelib.segment_ids(cs) + seg_off)
-                        seg_off += len(cs)
-                    seg = np.concatenate(seg_parts)
                     sr = getattr(model, "score_ranked", None)
                     if sr is not None:
                         scores, order = sr(feats, pairs, seg)
@@ -575,19 +590,21 @@ class ScoringService:
                 M.WAVE_OCCUPANCY_ROWS.observe(rows)
                 self.waves += 1
                 self.wave_rows += rows
-            off = 0
-            for req in batch:
-                req.scores = scores[off : off + req.rows]
-                if req.counts is not None:
-                    # the request's rows are one contiguous run of
-                    # segments, so its slice of the global permutation
-                    # is already its local segment-grouped order
-                    t0 = time.perf_counter()
-                    local = order[off : off + req.rows] - off
-                    req.rankings = wavelib.split_order(local, req.counts)
-                    self._note_unpack(time.perf_counter() - t0)
-                off += req.rows
-                req.done.set()
+            with PH_SCORE_UNPACK:
+                off = 0
+                for req in batch:
+                    req.scores = scores[off : off + req.rows]
+                    if req.counts is not None:
+                        # the request's rows are one contiguous run of
+                        # segments, so its slice of the global
+                        # permutation is already its local
+                        # segment-grouped order
+                        t0 = time.perf_counter()
+                        local = order[off : off + req.rows] - off
+                        req.rankings = wavelib.split_order(local, req.counts)
+                        self._note_unpack(time.perf_counter() - t0)
+                    off += req.rows
+                    req.done.set()
 
     # -- introspection (flight probe, bench) ---------------------------
     def snapshot(self) -> dict:

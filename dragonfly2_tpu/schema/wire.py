@@ -612,15 +612,36 @@ def read_train_pairs(
     # per-block indices are 0-based within their block's record batch —
     # rebase onto the running record count so the concatenated result
     # keeps the documented "row in the source batch" invariant instead
-    # of aliasing records across blocks
-    download_index = np.concatenate(idx)
-    download_index += np.repeat(np.asarray(bases, np.int32), [len(i) for i in idx])
+    # of aliasing records across blocks. Each block's indices are rebased
+    # as they are copied into the array the caller is handed: one short
+    # add a block, not a pass over the whole upload under the interpreter
+    # lock (``np.repeat`` of the bases held it 0.2 s at 55M pairs)
+    download_index = np.empty(sum(len(i) for i in idx), idx[0].dtype)
+    at = 0
+    for base, i in zip(bases, idx):
+        np.add(i, base, out=download_index[at : at + len(i)])
+        at += len(i)
     return PairExamples(
-        features=np.concatenate(feats),
-        labels=np.concatenate(labels),
+        features=_concatenate(feats),
+        labels=_concatenate(labels),
         download_index=download_index,
         num_downloads=records,
     )
+
+
+def _concatenate(parts: list) -> np.ndarray:
+    """``np.concatenate(parts)``, a thousand parts a call into one
+    preallocated array: numpy looks at every part under the interpreter
+    lock before it copies without it (0.11 s for an upload's 53,760
+    blocks in one call)."""
+    out = np.empty((sum(len(p) for p in parts), *parts[0].shape[1:]), parts[0].dtype)
+    at = 0
+    for lo in range(0, len(parts), 1024):
+        group = parts[lo : lo + 1024]
+        n = sum(len(p) for p in group)
+        np.concatenate(group, out=out[at : at + n])
+        at += n
+    return out
 
 
 def read_gru_tail(

@@ -35,7 +35,7 @@ from dragonfly2_tpu.topology.csr import NS_PER_MS, AdjacencyStore
 from dragonfly2_tpu.topology.delta import DeltaQueue, EdgeDelta
 from dragonfly2_tpu.topology.kernels import INF_MS, make_kernels
 from dragonfly2_tpu.trainer.serving import bucket_rows, pad_batch
-from dragonfly2_tpu.utils import dflog, flight
+from dragonfly2_tpu.utils import dflog, flight, profiling
 
 logger = dflog.get("topology.engine")
 
@@ -45,6 +45,10 @@ logger = dflog.get("topology.engine")
 # hot and too boring for a permanent record
 EV_FLUSH = flight.event_type("topology.flush")
 EV_INFERENCE = flight.event_type("topology.inference")
+
+# dfprof phase: the wave join's round trip to the backend — the puts of
+# the padded index vectors, the gather kernel, the blocking read
+PH_RTT_GATHER = profiling.phase_type("topology.rtt_gather")
 
 
 @dataclass
@@ -451,26 +455,27 @@ class TopologyEngine:
             out[m] = np.log1p(direct_ms[m]) / np.float32(10.0)
             return out
         rows = bucket_rows(n)
-        dev = self._to_backend(
-            {
-                "src": pad_batch(need_src, rows),
-                "dst": pad_batch(need_dst, rows),
-                "direct_ms": pad_batch(direct_ms, rows),
-                "has_direct": pad_batch(has_direct.astype(np.float32), rows),
-                "known": pad_batch(known.astype(np.float32), rows),
-            }
-        )
-        padded = self.kernels.gather_rtt_affinity(
-            D,
-            dev["src"],
-            dev["dst"],
-            dev["direct_ms"],
-            dev["has_direct"],
-            dev["known"],
-        )
-        # whole-rung D2H then host slice (allowlisted host-pull): a
-        # device [:n] would retrace a dynamic_slice per distinct n
-        aff = np.asarray(padded)[:n]
+        with PH_RTT_GATHER:
+            dev = self._to_backend(
+                {
+                    "src": pad_batch(need_src, rows),
+                    "dst": pad_batch(need_dst, rows),
+                    "direct_ms": pad_batch(direct_ms, rows),
+                    "has_direct": pad_batch(has_direct.astype(np.float32), rows),
+                    "known": pad_batch(known.astype(np.float32), rows),
+                }
+            )
+            padded = self.kernels.gather_rtt_affinity(
+                D,
+                dev["src"],
+                dev["dst"],
+                dev["direct_ms"],
+                dev["has_direct"],
+                dev["known"],
+            )
+            # whole-rung D2H then host slice (allowlisted host-pull): a
+            # device [:n] would retrace a dynamic_slice per distinct n
+            aff = np.asarray(padded)[:n]
         return aff.astype(np.float32, copy=False)
 
     def rtt_affinity_batch(

@@ -81,20 +81,27 @@ PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
 
 # The resident fits' phases, one vocabulary for the three legs
 # (trainer/training.py wraps load and register, trainer/train.py the
-# rest). load/split/holdout/register are entered once a fit, the other
+# rest). load/split/holdout/register are entered once a fit, the next
 # four once an epoch, never twice for one piece of work: total / count
-# reads as seconds a fit or seconds an epoch. Nothing here synchronises:
-# a phase times the call as the code makes it, and epoch_wait is the one
-# whose wall is the device's.
+# reads as seconds a fit or seconds an epoch. A phase times the call as
+# the code makes it; epoch_dispatch waits for each of its slices
+# (trainer/train.py), so the device's time lies in it.
 FIT_STAGES = (
     "load",  # bytes on disk -> host arrays
     "split",  # the permutation that sets the holdout apart
-    "gather",  # the epoch's permutation, fancy-index gather and reshape
-    "feed",  # host wall of handing the epoch's arrays to the device
-    "epoch_dispatch",  # the epoch call until it returns: trace, cache look-up, enqueue
-    "epoch_wait",  # the blocking read of the epoch's mean loss
+    "gather",  # the epoch's permutation and fancy-index gather, each slice handed to the device as it is built
+    "feed",  # the wait for what of the epoch had not landed on the device when the gather ended
+    "epoch_dispatch",  # the epoch call until it returns: trace, cache look-up, every slice run
+    "epoch_wait",  # the read of the epoch's mean loss (on the host once the last slice is in)
     "holdout",  # holdout gather, forward and read-back
     "register",  # params to the host and create_model
+    # the bounded slices inside gather and epoch_dispatch (trainer/train.py):
+    # one put and the wait for the put before it, one dispatch and its wait.
+    # Fed with observe(), so a leg's split counts their seconds once, under
+    # the two above; entries say the slices engaged, total / count how long
+    # one holds its leg
+    "feed_slice",
+    "epoch_slice",
 )
 
 

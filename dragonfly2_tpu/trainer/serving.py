@@ -17,6 +17,7 @@ columnar codec, readable anywhere numpy exists.
 
 from __future__ import annotations
 
+import contextlib
 import io
 from typing import Any
 
@@ -54,6 +55,30 @@ def pad_batch(a: np.ndarray, rows: int) -> np.ndarray:
     out = np.zeros((rows,) + a.shape[1:], a.dtype)
     out[:n] = a
     return out
+
+
+# A scorer's dispatch has three stages: the put of the padded batch,
+# the forward until its result is ready on the device, the read back to
+# the host. A caller that accounts them apart (the scoring service's
+# ``scheduler.score_*`` phases) passes three context managers as
+# ``stages``; every other caller runs the same code under these.
+NO_STAGES = (contextlib.nullcontext(),) * 3
+
+
+def _served_mlp(params, x):
+    """``score_parents`` under a name of its own: the trainer's holdout
+    evaluation jits the same function, and a device trace names an op by
+    the jitted function it belongs to, so this is what tells a served
+    forward from a fit's op there."""
+    from dragonfly2_tpu.models.mlp import score_parents
+
+    return score_parents(params, x)
+
+
+def _served_gnn_edge(params, emb, src, dst):
+    from dragonfly2_tpu.models.gnn import predict_edge
+
+    return predict_edge(params, emb, src, dst)
 
 
 def _device_params(params: Any) -> Any:
@@ -129,11 +154,9 @@ def _score_ranked(params, packed):
     one-feature-upload-per-wave contract)."""
     import jax.numpy as jnp
 
-    from dragonfly2_tpu.models.mlp import score_parents
-
     x = packed[:, :-1]
     seg = packed[:, -1]
-    s = score_parents(params, x)
+    s = _served_mlp(params, x)
     return s, jnp.lexsort((jnp.arange(s.shape[0]), s, seg))
 
 
@@ -142,10 +165,8 @@ class MLPScorer:
     scheduler's MLEvaluator calls ``predict`` on."""
 
     def __init__(self, params: Any):
-        from dragonfly2_tpu.models.mlp import score_parents
-
         self._params = _device_params(params)
-        self._fn = _jit_once(score_parents)
+        self._fn = _jit_once(_served_mlp)
         self._ranked = _jit_once(_score_ranked)
 
     @property
@@ -154,7 +175,8 @@ class MLPScorer:
         refuses a scorer whose dim doesn't match the live schema."""
         return int(self._params["layers"][0]["w"].shape[0])
 
-    def predict(self, features: np.ndarray) -> np.ndarray:
+    def predict(self, features: np.ndarray, stages=NO_STAGES) -> np.ndarray:
+        import jax
         import jax.numpy as jnp
 
         # bucketed dispatch: the forward sees ladder shapes only, so a
@@ -162,10 +184,16 @@ class MLPScorer:
         # the candidate count (retired the score_parents retrace entry)
         n = features.shape[0]
         padded = pad_batch(np.asarray(features, np.float32), bucket_rows(n))
-        return np.asarray(self._fn(self._params, jnp.asarray(padded)))[:n]
+        h2d, forward, d2h = stages
+        with h2d:
+            x = jnp.asarray(padded)
+        with forward:
+            s = jax.block_until_ready(self._fn(self._params, x))
+        with d2h:
+            return np.asarray(s)[:n]
 
     def predict_ranked(
-        self, features: np.ndarray, seg_ids: np.ndarray
+        self, features: np.ndarray, seg_ids: np.ndarray, stages=NO_STAGES
     ) -> "tuple[np.ndarray, np.ndarray]":
         """Wave scoring: [n, F] flattened candidate rows whose
         non-decreasing ``seg_ids`` mark decision boundaries → (scores
@@ -177,6 +205,7 @@ class MLPScorer:
         packed as a trailing float column on the padded matrix — one
         upload per wave, not two (float32 holds segment ids exactly up
         to 2^24; a wave is bounded far below that)."""
+        import jax
         import jax.numpy as jnp
 
         n = features.shape[0]
@@ -188,11 +217,16 @@ class MLPScorer:
         packed[:n, :-1] = np.asarray(features, np.float32)
         packed[:, -1] = sentinel
         packed[:n, -1] = np.asarray(seg_ids, np.float32)
-        s, order = self._ranked(self._params, jnp.asarray(packed))
+        h2d, forward, d2h = stages
+        with h2d:
+            x = jnp.asarray(packed)
+        with forward:
+            s, order = jax.block_until_ready(self._ranked(self._params, x))
         # whole-rung D2H then host slice: a device-side [:n] would
         # compile one dynamic_slice per distinct n — the retrace class
         # the ladder exists to kill (allowlisted host-pull, like predict)
-        return np.asarray(s)[:n], np.asarray(order)[:n]
+        with d2h:
+            return np.asarray(s)[:n], np.asarray(order)[:n]
 
 
 def _np_gelu(x: np.ndarray) -> np.ndarray:
@@ -218,7 +252,8 @@ class NumpyMLPScorer:
     def feature_dim(self) -> int:
         return int(self._layers[0][0].shape[0])
 
-    def predict(self, features: np.ndarray) -> np.ndarray:
+    def predict(self, features: np.ndarray, stages=NO_STAGES) -> np.ndarray:
+        # ``stages``: a host forward has no put and no read to account
         n = features.shape[0]
         # same bucket discipline as the jitted twin: the pad is free
         # correctness-wise (rows are independent) and keeps the two
@@ -232,7 +267,7 @@ class NumpyMLPScorer:
         return np.ascontiguousarray(h[:n, 0])
 
     def predict_ranked(
-        self, features: np.ndarray, seg_ids: np.ndarray
+        self, features: np.ndarray, seg_ids: np.ndarray, stages=NO_STAGES
     ) -> "tuple[np.ndarray, np.ndarray]":
         """Numpy twin of :meth:`MLPScorer.predict_ranked`: same
         (scores, segment-grouped permutation) contract, same lexsort
@@ -300,7 +335,7 @@ class GNNScorer:
     def __init__(self, params: Any, graph, mesh=None, axis: str = "gp"):
         import jax.numpy as jnp
 
-        from dragonfly2_tpu.models.gnn import apply_graphsage, predict_edge
+        from dragonfly2_tpu.models.gnn import apply_graphsage
 
         self._params = _device_params(params)
         self._node_index = {hid: i for i, hid in enumerate(graph.node_ids)}
@@ -313,7 +348,7 @@ class GNNScorer:
                 jnp.asarray(graph.neighbors),
                 jnp.asarray(graph.neighbor_mask),
             )
-        self._predict = _jit_once(predict_edge)
+        self._predict = _jit_once(_served_gnn_edge)
 
     def _sharded_embed(self, graph, mesh, axis: str):
         """Graph-parallel embed at swap time: pad node tables to the
@@ -338,7 +373,10 @@ class GNNScorer:
     def has_host(self, host_id: str) -> bool:
         return host_id in self._node_index
 
-    def predict_rtt_log_ms(self, src_ids: list[str], dst_ids: list[str]) -> np.ndarray:
+    def predict_rtt_log_ms(
+        self, src_ids: list[str], dst_ids: list[str], stages=NO_STAGES
+    ) -> np.ndarray:
+        import jax
         import jax.numpy as jnp
 
         # bucketed like every serving forward: the pairwise head compiles
@@ -350,9 +388,13 @@ class GNNScorer:
         dst = np.zeros((rows,), np.int32)
         src[:n] = [self._node_index[s] for s in src_ids]
         dst[:n] = [self._node_index[d] for d in dst_ids]
-        return np.asarray(
-            self._predict(self._params, self._emb, jnp.asarray(src), jnp.asarray(dst))
-        )[:n]
+        h2d, forward, d2h = stages
+        with h2d:
+            s, d = jnp.asarray(src), jnp.asarray(dst)
+        with forward:
+            out = jax.block_until_ready(self._predict(self._params, self._emb, s, d))
+        with d2h:
+            return np.asarray(out)[:n]
 
 
 class GRUScorer:

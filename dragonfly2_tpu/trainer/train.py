@@ -3,8 +3,10 @@ stubs (reference trainer/training/training.go:60-98; intended flow per its
 comments: load from storage → preprocess → train → upload model to manager).
 
 Throughput design (north star: 1B records in <10 min on v5e-8):
-- whole-epoch `lax.scan` over device-resident minibatches — one XLA call
-  per epoch, zero host↔device traffic inside the loop;
+- a device loop over device-resident minibatches, zero host↔device
+  traffic inside it, an epoch fed and run in bounded slices (below) so that
+  another tenant of the process is never kept from the interpreter or
+  from the device's queue for longer than one;
 - bfloat16 matmuls with float32 accumulation (models.*);
 - data parallelism by sharding the batch dim over the mesh's `dp` axis
   with NamedSharding and letting XLA insert the gradient all-reduce;
@@ -18,6 +20,7 @@ Throughput design (north star: 1B records in <10 min on v5e-8):
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -76,9 +79,22 @@ def _optimizer(cfg: FitConfig, total_steps: int) -> optax.GradientTransformation
     return optax.adamw(schedule, weight_decay=cfg.weight_decay)
 
 
+def _permutation(rng: np.random.Generator, n: int) -> np.ndarray:
+    """``rng.permutation(n)``, element for element, with the identity it
+    shuffles filled a slice at a time: numpy's own ``arange`` writes all
+    of it under the interpreter lock (0.12 s at 55M pairs; the shuffle
+    itself runs without the lock)."""
+    perm = np.empty(n, np.int64)
+    ramp = np.arange(min(n, max(FEED_SLICE_BYTES // perm.itemsize, 1)), dtype=np.int64)
+    for lo in range(0, n, max(len(ramp), 1)):
+        np.add(ramp[: n - lo], lo, out=perm[lo : lo + len(ramp)])
+    rng.shuffle(perm)
+    return perm
+
+
 def _split_eval(n: int, eval_fraction: float, seed: int) -> tuple[np.ndarray, np.ndarray]:
     rng = np.random.default_rng(seed)
-    perm = rng.permutation(n)
+    perm = _permutation(rng, n)
     n_eval = int(n * eval_fraction)
     return perm[n_eval:], perm[:n_eval]
 
@@ -115,31 +131,189 @@ def _batch_steps(n: int, batch: int) -> tuple[int, int, int]:
     return steps, steps * batch, batch
 
 
+# Bounded slices. A trainer may share its process, and so its interpreter
+# and its chip, with a scheduler that answers peers inside a deadline
+# (dragonfly2_tpu.colocated). No call of a fit may then hold either for
+# long: an epoch's arrays are gathered, put on the chip and freed on the
+# host a slice at a time, each put landing while the next slice is
+# gathered and waited for before a third is handed over, and the epoch
+# runs as one dispatch a slice, each waited for before the next is
+# enqueued (the device's queue is first in, first out: a served forward
+# enqueued behind a whole epoch waits for all of it, however the epoch is
+# cut). A slice is as many steps as fit FEED_SLICE_BYTES, and at most
+# EPOCH_SLICE_STEPS, the epoch's steps spread evenly over the slices that
+# takes; every slice of an epoch has the same shape (the last is padded,
+# by fewer steps than there are slices, with rows no step reads), so a
+# leg compiles one executable whatever its step count. Every slice stays
+# on the chip until the fit drops the epoch, and the steps, their order
+# and their batches are those of one loop over the whole epoch.
+FEED_SLICE_BYTES = 64 << 20  # one put: 10-17 ms of transfer on a v5e host
+EPOCH_SLICE_STEPS = 512  # one dispatch: 20-30 ms of the chip at these models' steps
+
+
+class _Epoch(tuple):
+    """An epoch on the chip: per column, the list of its device slices
+    ``[k, batch, ...]``; ``steps`` steps in all, so the last slice may
+    hold fewer than ``k`` (its other rows are padding)."""
+
+    steps: int
+
+
+def _slice_steps(steps: int, step_bytes: int) -> int:
+    """How many steps a slice of an epoch holds: the fewest slices that
+    keep both bounds, all of one length (so the last is padded by fewer
+    steps than there are slices)."""
+    most = max(1, min(EPOCH_SLICE_STEPS, FEED_SLICE_BYTES // max(step_bytes, 1), steps))
+    slices = -(-steps // most)
+    return -(-steps // max(slices, 1))
+
+
+def _slice_rows(a: np.ndarray) -> int:
+    """How many rows of ``a`` make a slice of ``FEED_SLICE_BYTES``."""
+    return max(FEED_SLICE_BYTES // max(a.nbytes // max(len(a), 1), 1), 1)
+
+
+def _gather_slices(index: np.ndarray, rng: np.random.Generator, steps: int, batch: int, *columns: np.ndarray):
+    """The epoch's ``[steps, batch, ...]`` arrays as host slices of
+    bounded bytes, ``(column slice, ...)`` in step order, each gathered
+    when it is asked for (a generator; the shuffle ``rng.permutation``
+    of ``index`` on the first): row ``i`` of the epoch is
+    ``column[index[perm[i]]]``. Each slice is an allocation of its own,
+    so the host never holds an epoch whole, to free in one call (and no
+    fancy index is longer than a slice: numpy checks every index under
+    the interpreter lock before it copies without it, 0.11 s for an
+    epoch's 49.5M); all have ``k`` steps, the last zero past the epoch's
+    end."""
+    k = _slice_steps(
+        steps, batch * sum(c.dtype.itemsize * int(np.prod(c.shape[1:], dtype=np.int64)) for c in columns)
+    )
+    perm = _permutation(rng, len(index))
+
+    def gathered(lo: int) -> tuple:
+        rows = index[perm[lo * batch : min(lo + k, steps) * batch]]
+        part = []
+        for c in columns:
+            a = c[rows]
+            if len(rows) < k * batch:
+                a = np.concatenate([a, np.zeros((k * batch - len(rows), *c.shape[1:]), c.dtype)])
+            part.append(a.reshape(k, batch, *c.shape[1:]))
+        return tuple(part)
+
+    for lo in range(0, steps, k):
+        yield gathered(lo)  # under no name here: it is the taker's to drop
+
+
+def _feed_slices(mesh, host, steps: int, phases) -> _Epoch:
+    """Put an epoch's host slices (``_gather_slices``) on the chip, one
+    put a slice. A put is asynchronous: it lands while the next slice is
+    gathered, and is waited for before the slice after that is handed
+    over, so the transfer queue never holds more than two (a served
+    batch's put waits behind 128 MiB at most) and the host no more than
+    the two being gathered and sent. Returns the epoch of ``steps``
+    steps as ``make_epoch_fn`` takes it, every slice on the chip.
+    ``phases`` is the leg's: ``gather`` times the loop, ``feed_slice``
+    is fed what each slice's put and wait took of it, ``feed`` times
+    the wait for what had not landed when the gather ended."""
+    fed: list = []
+    with phases.gather:
+        for part in host:
+            t0 = time.perf_counter()
+            fed.append(_shard_arrays(mesh, *part))
+            del part  # the transfer holds it until it is through
+            jax.block_until_ready(fed[-2:-1])
+            phases.feed_slice.observe(time.perf_counter() - t0)
+    with phases.feed:
+        jax.block_until_ready(fed)
+    epoch = _Epoch(list(column) for column in zip(*fed))
+    epoch.steps = steps
+    return epoch
+
+
+def release_in_pieces(owned: list) -> int:
+    """Free the host arrays in ``owned`` (emptied) a slice at a time;
+    returns how many of them went that way. One ``free`` of the 4.6 GB a
+    resident fit was handed unmaps them under the interpreter lock (0.4 s
+    on a v5e host); here each array is shrunk from its tail by
+    ``ndarray.resize``, a ``realloc`` that gives the tail's pages back in
+    place, ``FEED_SLICE_BYTES`` a call, and as long again is slept after
+    each (a run of such calls back to back would keep the interpreter
+    nine tenths of the time, each for too short to see). An array that
+    anyone else still references, or that does not own its memory, is
+    refused by ``resize`` and left whole to its last holder. (A thread that walks
+    ``sys._current_frames()``, as the sampling profiler does, holds this
+    frame for an instant and is refused the same way: hence the second
+    and third try.) The caller keeps no other name for what it hands in."""
+    released = 0
+    while owned:
+        a = owned.pop()
+        rows = _slice_rows(a)
+        keep, tries = len(a) - rows, 3
+        while keep > 0 and tries:
+            try:
+                t0 = time.perf_counter()
+                a.resize((keep, *a.shape[1:]), refcheck=True)
+                keep, tries = keep - rows, 3
+                time.sleep(time.perf_counter() - t0)
+            except ValueError:
+                tries -= 1
+                time.sleep(0.001)
+        released += keep <= 0
+    return released
+
+
 def make_epoch_fn(
     loss_fn: Callable[[Any, Any], jax.Array],
     optimizer: optax.GradientTransformation,
 ):
-    """Build a jitted whole-epoch function: scan over [steps, batch, ...]
-    stacked minibatches, donating the carried state. The function takes
-    its name from the loss's (``mlp_loss`` -> ``mlp_epoch``), so that a
-    trace's host events (``PjitFunction(mlp_epoch)``), the XLA module
-    and the scope of the step's ops say which leg they belong to."""
-    name = getattr(loss_fn, "__name__", "loss").removesuffix("_loss") + "_epoch"
+    """Build the epoch function ``epoch(params, opt_state, batches)`` ->
+    ``(params, opt_state, mean loss)`` over the ``_Epoch`` that
+    ``_feed_slices`` returns. Each slice is one dispatch of a jitted loop
+    over its steps (as many as the epoch has left: a traced count, so
+    the padded last slice runs on the same executable), the carried
+    state donated from one to the next and the slice waited for before
+    the next is enqueued. The jitted slice takes its name from the
+    loss's (``mlp_loss`` -> ``mlp_epoch``), so that a trace's host events
+    (``PjitFunction(mlp_epoch)``), the XLA module and the scope of the
+    step's ops say which leg they belong to; a leg's ``epoch_slice``
+    phase is fed each slice's wall."""
+    leg = getattr(loss_fn, "__name__", "loss").removesuffix("_loss")
+    name = leg + "_epoch"
+    slice_phase = {"mlp": PH_MLP, "gnn": PH_GNN, "gru": PH_GRU}.get(leg)
 
-    def epoch(params, opt_state, batches):
-        def body(carry, batch):
+    def epoch_slice(params, opt_state, batches, steps):
+        def body(i, carry):
             with jax.named_scope(name):
-                params, opt_state = carry
+                params, opt_state, loss_sum = carry
+                batch = jax.tree_util.tree_map(lambda a: a[i], batches)
                 loss, grads = jax.value_and_grad(loss_fn)(params, batch)
                 updates, opt_state = optimizer.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
-            return (params, opt_state), loss
+            return params, opt_state, loss_sum + loss
 
-        (params, opt_state), losses = jax.lax.scan(body, (params, opt_state), batches)
-        return params, opt_state, losses.mean()
+        return jax.lax.fori_loop(0, steps, body, (params, opt_state, jnp.zeros((), jnp.float32)))
+
+    epoch_slice.__name__ = epoch_slice.__qualname__ = name
+    run_slice = jax.jit(epoch_slice, donate_argnums=(0, 1))
+
+    def epoch(params, opt_state, batches):
+        left, loss_sum = batches.steps, 0.0
+        for part in zip(*batches):
+            t0 = time.perf_counter()
+            steps = min(part[0].shape[0], left)
+            params, opt_state, part_sum = run_slice(params, opt_state, part, steps)
+            loss_sum += float(part_sum)  # the wait: nothing is queued behind a running slice
+            left -= steps
+            if slice_phase is not None:
+                slice_phase.epoch_slice.observe(time.perf_counter() - t0)
+        return params, opt_state, loss_sum / batches.steps
+
+    def lower(params, opt_state, batches):
+        """The lowering of the epoch's slice, for a reader of its HLO."""
+        return run_slice.lower(params, opt_state, tuple(column[0] for column in batches), batches.steps)
 
     epoch.__name__ = epoch.__qualname__ = name
-    return jax.jit(epoch, donate_argnums=(0, 1))
+    epoch.lower = lower
+    return epoch
 
 
 # ---------------------------------------------------------------------------
@@ -162,7 +336,7 @@ def train_mlp(
     n, f = features.shape
     with PH_MLP.split:
         train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
-    steps, used, batch = _batch_steps(len(train_idx), cfg.batch_size)
+    steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
 
     key = jax.random.PRNGKey(cfg.seed)
     params = mlp_mod.init_mlp(key, [f, *cfg.hidden_dims, 1])
@@ -196,16 +370,13 @@ def train_mlp(
         history: list[float] = []
         for epoch in range(start_epoch, cfg.epochs):
             FP_FIT_STEP()
+            batches = None  # the chip holds one epoch: the last goes before the next is fed
             # per-epoch rng: a resumed run replays the exact shuffle schedule
             rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            with PH_MLP.gather:
-                order = train_idx[rng.permutation(len(train_idx))][:used]
-                xb = features[order].reshape(steps, batch, f)
-                yb = labels[order].reshape(steps, batch)
-            with PH_MLP.feed:
-                xb, yb = _shard_arrays(mesh, xb, yb)
+            host = _gather_slices(train_idx, rng, steps, batch, features, labels)
+            batches = _feed_slices(mesh, host, steps, PH_MLP)
             with PH_MLP.epoch_dispatch:
-                params, opt_state, mean_loss = epoch_fn(params, opt_state, (xb, yb))
+                params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
             with PH_MLP.epoch_wait:
                 history.append(float(mean_loss))
             _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
@@ -249,7 +420,18 @@ def _finish_checkpoint(ckpt) -> None:
 
 
 def evaluate_mlp(params, features: np.ndarray, labels: np.ndarray) -> dict[str, float]:
-    pred = np.asarray(jit_once(mlp_mod.score_parents)(params, jnp.asarray(features)))
+    """The holdout's error. The holdout goes to the chip whole, as it
+    always has (beside the epoch, which the fit still holds: the peak of
+    a resident fit's device memory is here), but in bounded slices like
+    the epoch: one put a slice, each waited for, then one forward a
+    slice, each read back before the next is enqueued."""
+    forward = jit_once(mlp_mod.score_parents)
+    rows = _slice_rows(features)
+    on_chip = [
+        jax.block_until_ready(jnp.asarray(features[lo : lo + rows]))
+        for lo in range(0, len(features), rows)
+    ]
+    pred = np.concatenate([np.asarray(forward(params, x)) for x in on_chip])
     err = pred - labels
     return {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
 
@@ -310,7 +492,7 @@ def train_gnn(
     neighbors = jnp.asarray(graph.neighbors)
     neighbor_mask = jnp.asarray(graph.neighbor_mask)
 
-    steps, used, batch = _batch_steps(len(train_idx), cfg.batch_size)
+    steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
     optimizer = _optimizer(cfg, steps * cfg.epochs)
     opt_state = optimizer.init(params)
 
@@ -332,13 +514,11 @@ def train_gnn(
         history: list[float] = []
         for epoch in range(start_epoch, cfg.epochs):
             rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            with PH_GNN.gather:
-                order = train_idx[rng.permutation(len(train_idx))][:used]
-                sb = graph.edge_src[order].reshape(steps, batch)
-                db = graph.edge_dst[order].reshape(steps, batch)
-                yb = graph.edge_rtt_log_ms[order].reshape(steps, batch)
-            with PH_GNN.feed:
-                batches = (jnp.asarray(sb), jnp.asarray(db), jnp.asarray(yb))
+            host = _gather_slices(
+                train_idx, rng, steps, batch, graph.edge_src, graph.edge_dst, graph.edge_rtt_log_ms
+            )
+            # never sharded: the edge batches are replicated beside the graph
+            batches = _feed_slices(None, host, steps, PH_GNN)
             with PH_GNN.epoch_dispatch:
                 params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
             with PH_GNN.epoch_wait:
@@ -522,7 +702,7 @@ def train_gru(
 
         params = replicate(mesh, params)
 
-    steps, used, batch = _batch_steps(len(train_idx), cfg.batch_size)
+    steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
     optimizer = _optimizer(cfg, steps * cfg.epochs)
     opt_state = optimizer.init(params)
 
@@ -536,15 +716,11 @@ def train_gru(
     history: list[float] = []
     rng = np.random.default_rng(cfg.seed + 1)
     for _ in range(cfg.epochs):
-        with PH_GRU.gather:
-            order = train_idx[rng.permutation(len(train_idx))][:used]
-            xb = sequences[order].reshape(steps, batch, t, f)
-            yb = labels[order].reshape(steps, batch)
-            lb = lengths[order].reshape(steps, batch)
-        with PH_GRU.feed:
-            xb, yb, lb = _shard_arrays(mesh, xb, yb, lb)
+        batches = None  # the chip holds one epoch: the last goes before the next is fed
+        host = _gather_slices(train_idx, rng, steps, batch, sequences, labels, lengths)
+        batches = _feed_slices(mesh, host, steps, PH_GRU)
         with PH_GRU.epoch_dispatch:
-            params, opt_state, mean_loss = epoch_fn(params, opt_state, (xb, yb, lb))
+            params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
         with PH_GRU.epoch_wait:
             history.append(float(mean_loss))
 

@@ -28,7 +28,13 @@ from dragonfly2_tpu.schema import native, wire
 from dragonfly2_tpu.schema.columnar import records_to_columns
 from dragonfly2_tpu.schema.features import build_probe_graph, extract_pair_features
 from dragonfly2_tpu.trainer.storage import TrainerStorage
-from dragonfly2_tpu.trainer.train import FitConfig, GNNFitConfig, train_gnn, train_mlp
+from dragonfly2_tpu.trainer.train import (
+    FitConfig,
+    GNNFitConfig,
+    release_in_pieces,
+    train_gnn,
+    train_mlp,
+)
 from dragonfly2_tpu.trainer import metrics as M
 from dragonfly2_tpu.utils import dflog, flight, profiling
 from dragonfly2_tpu.utils.idgen import gnn_model_id_v1, host_id_v2, mlp_model_id_v1
@@ -471,6 +477,10 @@ class Training:
             mesh=self.mesh,
             config=self._fit_config(self.config.mlp, "mlp", host_id),
         )
+        # the upload's pairs go a slice at a time, not in one free on return
+        owned = [pairs.features, pairs.labels, pairs.download_index]
+        del pairs
+        release_in_pieces(owned)
         if self.manager_client is not None:
             with M.PH_MLP.register:
                 self.manager_client.create_model(

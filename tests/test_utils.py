@@ -305,6 +305,7 @@ def test_deploy_manifests_set_keys_exist_on_dataclasses():
     import yaml
 
     from dragonfly2_tpu.client.daemon import DaemonConfig
+    from dragonfly2_tpu.colocated import ColocatedConfig
     from dragonfly2_tpu.manager.server import ManagerServerConfig
     from dragonfly2_tpu.scheduler.server import SchedulerServerConfig
     from dragonfly2_tpu.trainer.server import TrainerServerConfig
@@ -313,6 +314,7 @@ def test_deploy_manifests_set_keys_exist_on_dataclasses():
         "manager": ManagerServerConfig,
         "scheduler": SchedulerServerConfig,
         "trainer": TrainerServerConfig,
+        "colocated": ColocatedConfig,
         "daemon": DaemonConfig,
     }
     fields = {
