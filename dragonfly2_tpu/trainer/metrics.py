@@ -89,26 +89,44 @@ PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
 FIT_STAGES = (
     "load",  # bytes on disk -> host arrays
     "split",  # the permutation that sets the holdout apart
-    "gather",  # the epoch's permutation and fancy-index gather, each slice handed to the device as it is built
-    "feed",  # the wait for what of the epoch had not landed on the device when the gather ended
+    "gather",  # the epoch's permutation and its row numbers index[perm], each slice of them handed to the device as it is composed
+    "feed",  # the wait for what of the epoch's row numbers had not landed on the device when the gather ended
     "epoch_dispatch",  # the epoch call until it returns: trace, cache look-up, every slice run
     "epoch_wait",  # the read of the epoch's mean loss (on the host once the last slice is in)
     "holdout",  # holdout gather, forward and read-back
     "register",  # params to the host and create_model
-    # the bounded slices inside gather and epoch_dispatch (trainer/train.py):
-    # one put and the wait for the put before it, one dispatch and its wait.
-    # Fed with observe(), so a leg's split counts their seconds once, under
-    # the two above; entries say the slices engaged, total / count how long
-    # one holds its leg
+    # the bounded slices (trainer/train.py): one put and the wait for the put
+    # before it (a slice of the fit's table, 64 MiB, once a fit and under no
+    # other phase: booked, so a leg's split holds the table's put under this
+    # name; a slice of an epoch's row numbers, inside gather: observed), one
+    # dispatch and its wait (inside epoch_dispatch: observed). A leg's split
+    # counts no second twice; the ledger's entries say the slices engaged,
+    # total / count how long one holds its leg
     "feed_slice",
     "epoch_slice",
 )
 
 
-def _fit_phases(leg: str) -> SimpleNamespace:
-    return SimpleNamespace(
-        **{stage: profiling.phase_type(f"trainer.{leg}_{stage}") for stage in FIT_STAGES}
-    )
+# What a resident fit puts on the chip: its table (every column, once a
+# fit: 4.4 GB for a week's pairs) and the row numbers of each epoch and
+# of the holdout (4 B a row: 0.2 GB an epoch). Before the table, every
+# epoch put its gathered columns again (3.96 GB an epoch).
+FIT_PUT_BYTES_TOTAL = _r.counter(
+    "trainer_fit_put_bytes_total", "Bytes a resident fit leg put on the device", ("leg",)
+)
+
+
+class _LegPhases(SimpleNamespace):
+    """A leg's phases by stage (``vars()`` is those and nothing else),
+    and beside them ``put_bytes``, the leg's child of the counter above."""
+
+    __slots__ = ("put_bytes",)
+
+
+def _fit_phases(leg: str) -> _LegPhases:
+    phases = _LegPhases(**{stage: profiling.phase_type(f"trainer.{leg}_{stage}") for stage in FIT_STAGES})
+    phases.put_bytes = FIT_PUT_BYTES_TOTAL.labels(leg)
+    return phases
 
 
 PH_MLP, PH_GNN, PH_GRU = (_fit_phases(leg) for leg in ("mlp", "gnn", "gru"))

@@ -3,10 +3,12 @@ stubs (reference trainer/training/training.go:60-98; intended flow per its
 comments: load from storage → preprocess → train → upload model to manager).
 
 Throughput design (north star: 1B records in <10 min on v5e-8):
-- a device loop over device-resident minibatches, zero host↔device
-  traffic inside it, an epoch fed and run in bounded slices (below) so that
-  another tenant of the process is never kept from the interpreter or
-  from the device's queue for longer than one;
+- a fit's columns on the chip once a fit, in the caller's order; an
+  epoch is the host's permutation as row numbers, and a device loop
+  takes each step's batch from the resident table, zero host↔device
+  traffic inside it; table, row numbers and epochs go in bounded slices
+  (below) so that another tenant of the process is never kept from the
+  interpreter or from the device's queue for longer than one;
 - bfloat16 matmuls with float32 accumulation (models.*);
 - data parallelism by sharding the batch dim over the mesh's `dp` axis
   with NamedSharding and letting XLA insert the gradient all-reduce;
@@ -20,6 +22,7 @@ Throughput design (north star: 1B records in <10 min on v5e-8):
 
 from __future__ import annotations
 
+import math
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -79,6 +82,17 @@ def _optimizer(cfg: FitConfig, total_steps: int) -> optax.GradientTransformation
     return optax.adamw(schedule, weight_decay=cfg.weight_decay)
 
 
+def _head_bias(labels: np.ndarray):
+    """The output bias a fit starts from: the mean label, as float32 in
+    its own right. (Filled from a Python float without a type it is
+    weakly typed, the optimizer's moments after it, and the epoch's
+    slice is traced for the first epoch, again for the second, whose
+    parameters are no longer weak, and again for the third, whose
+    moments are not either: 0.28 s a trace for the GraphSAGE leg alone
+    on the chip. The values are the same either way.)"""
+    return jnp.full((1,), float(labels.mean()), jnp.float32)
+
+
 def _permutation(rng: np.random.Generator, n: int) -> np.ndarray:
     """``rng.permutation(n)``, element for element, with the identity it
     shuffles filled a slice at a time: numpy's own ``arange`` writes all
@@ -104,22 +118,6 @@ def _split_eval(n: int, eval_fraction: float, seed: int) -> tuple[np.ndarray, np
 _jit_cache: dict = {}
 
 
-def _shard_arrays(mesh, *arrays, axis: str = "dp"):
-    if mesh is None:
-        # explicit H2D at the boundary: feeding numpy straight into the
-        # jitted epoch is an implicit per-epoch transfer the jit witness
-        # (rightly) flags; the cost is identical, the site is visible
-        return tuple(jnp.asarray(a) for a in arrays)
-    if arrays and arrays[0].shape[1] % mesh.shape[axis]:
-        # _batch_steps clamps the batch to tiny shards, and a clamped
-        # batch rarely divides the dp axis — feed replicated rather
-        # than fail the fit (the auto-mesh default must be safe for
-        # every dataset size; one small fit doesn't need parallelism)
-        return tuple(jnp.asarray(a) for a in arrays)
-    s = NamedSharding(mesh, P(None, axis))  # [steps, batch, ...] — batch dim sharded
-    return tuple(jax.device_put(a, s) for a in arrays)
-
-
 def _batch_steps(n: int, batch: int) -> tuple[int, int, int]:
     """→ (steps, rows_used, batch) with batch clamped to the training-set
     size. Shared by every fit loop so small per-host datasets and the
@@ -134,28 +132,164 @@ def _batch_steps(n: int, batch: int) -> tuple[int, int, int]:
 # Bounded slices. A trainer may share its process, and so its interpreter
 # and its chip, with a scheduler that answers peers inside a deadline
 # (dragonfly2_tpu.colocated). No call of a fit may then hold either for
-# long: an epoch's arrays are gathered, put on the chip and freed on the
-# host a slice at a time, each put landing while the next slice is
-# gathered and waited for before a third is handed over, and the epoch
-# runs as one dispatch a slice, each waited for before the next is
-# enqueued (the device's queue is first in, first out: a served forward
-# enqueued behind a whole epoch waits for all of it, however the epoch is
-# cut). A slice is as many steps as fit FEED_SLICE_BYTES, and at most
-# EPOCH_SLICE_STEPS, the epoch's steps spread evenly over the slices that
-# takes; every slice of an epoch has the same shape (the last is padded,
-# by fewer steps than there are slices, with rows no step reads), so a
-# leg compiles one executable whatever its step count. Every slice stays
-# on the chip until the fit drops the epoch, and the steps, their order
-# and their batches are those of one loop over the whole epoch.
+# long. A fit's columns go to the chip once a fit, in the order the caller
+# handed them, a slice of FEED_SLICE_BYTES a put (contiguous views of the
+# caller's arrays: the host copies nothing but the last slice's padding),
+# each put landing while the next is handed over and waited for before a
+# third is, and are packed there into the fit's table (_Table), which
+# stays until the fit returns. An epoch is row numbers: the host draws the
+# permutation and composes ``index[perm]`` a slice at a time, the slices
+# go to the chip the same way (4 B a row where the columns themselves
+# went before), and each step takes its batch from the table by them, on
+# the chip. The epoch runs as one dispatch a slice, each waited for before
+# the next is enqueued (the device's queue is first in, first out: a
+# served forward enqueued behind a whole epoch waits for all of it,
+# however the epoch is cut). A slice is as many steps as take
+# FEED_SLICE_BYTES from the table, and at most EPOCH_SLICE_STEPS, the
+# epoch's steps spread evenly over the slices that takes; every slice of
+# an epoch has the same shape (the last is padded, by fewer steps than
+# there are slices, with row 0, which no step reads), so a leg compiles
+# one executable whatever its step count. The holdout's rows come from
+# the same table by their numbers, a slice a dispatch. The steps, their
+# order and their batches are those of one loop over the whole epoch
+# gathered on the host.
 FEED_SLICE_BYTES = 64 << 20  # one put: 10-17 ms of transfer on a v5e host
 EPOCH_SLICE_STEPS = 512  # one dispatch: 20-30 ms of the chip at these models' steps
+TABLE_LANES = 128  # a table row: one row of the chip's (8, 128) tile, 512 B
+
+
+@jax.tree_util.register_pytree_node_class
+class _Table:
+    """A fit's columns on the chip, row for row as the caller handed
+    them. A row's values (every column's, flattened; all of 32 bits) lie
+    side by side as ``words`` words, and as many rows as fit share a
+    table row of ``TABLE_LANES`` lanes (a row wider than that takes
+    whole table rows of its own): XLA lays a ``[N, 19]`` array out with
+    N along the lanes, from where a row comes element by element; from
+    here it comes as one 512 B row of a tile and is picked out of it
+    (PERF.md, PR 29: 118 us against 351 us for a batch of 8,192).
+    ``columns`` is each column's ``(row shape, dtype)``."""
+
+    def __init__(self, packed, columns: tuple):
+        self.packed, self.columns = packed, columns
+
+    def tree_flatten(self):
+        return (self.packed,), self.columns
+
+    @classmethod
+    def tree_unflatten(cls, columns, children):
+        return cls(children[0], columns)
+
+    @staticmethod
+    def geometry(columns: tuple) -> tuple[int, int, int]:
+        """-> (words a row, rows a table row, lanes of a table row)."""
+        words = sum(math.prod(shape) for shape, _ in columns)
+        per_row = max(TABLE_LANES // words, 1)
+        return words, per_row, -(-per_row * words // TABLE_LANES) * TABLE_LANES
+
+    @property
+    def row_bytes(self) -> int:
+        return 4 * self.geometry(self.columns)[0]
+
+    def take(self, rows) -> tuple:
+        """Rows ``rows`` ([B] int32) of every column, ``(column[rows],
+        ...)``, bit for bit: one gather of table rows, then each row's
+        words moved to the front of its table row, by whole lanes and a
+        select for every bit of its place there (three selects for the
+        six places of the MLP's pairs, six for the 42 of an edge), and
+        bitcasts."""
+        words, per_row, _ = self.geometry(self.columns)
+        picked = self.packed.at[rows // per_row].get(mode="promise_in_bounds")
+        place = (rows % per_row)[:, None]
+        bit = 1
+        while bit < per_row:
+            nearer = jnp.pad(picked[:, bit * words :], ((0, 0), (0, bit * words)))
+            picked = jnp.where(place & bit != 0, nearer, picked)
+            bit <<= 1
+        out, at = [], 0
+        for shape, dtype in self.columns:
+            n = math.prod(shape)
+            column = jax.lax.bitcast_convert_type(picked[:, at : at + n], dtype)
+            out.append(column.reshape(rows.shape[0], *shape))
+            at += n
+        return tuple(out)
+
+
+def _pack(packed, at, *parts):
+    """Write a slice of the columns (``parts``: each column's rows, as
+    they were put) into the table from table row ``at``."""
+    columns = tuple((p.shape[1:], p.dtype) for p in parts)
+    words, per_row, lanes = _Table.geometry(columns)
+    rows = parts[0].shape[0]
+    side_by_side = jnp.concatenate(
+        [jax.lax.bitcast_convert_type(p, jnp.uint32).reshape(rows, -1) for p in parts], axis=1
+    ).reshape(rows // per_row, per_row * words)
+    block = jnp.pad(side_by_side, ((0, 0), (0, lanes - per_row * words)))
+    return jax.lax.dynamic_update_slice(packed, block, (at, 0))
+
+
+_pack_slice = jax.jit(_pack, donate_argnums=0)
+
+
+def _on_mesh(mesh, a, spec=P()):
+    """``a`` on the chip: explicitly (feeding numpy straight into a
+    jitted call is an implicit transfer the jit witness, rightly,
+    flags), and under a mesh with ``spec``."""
+    return jnp.asarray(a) if mesh is None else jax.device_put(a, NamedSharding(mesh, spec))
+
+
+def _put_table(mesh, phases, *columns: np.ndarray) -> _Table:
+    """The fit's table from its host columns: a put a slice of
+    ``FEED_SLICE_BYTES`` (each column's rows ``[lo:hi]``, a view: nothing
+    is copied on the host but the last slice, padded to the others'
+    shape: one pack executable a fit), and one small dispatch that
+    packs it. A put is asynchronous: it lands while the next is
+    handed over, and is waited for before the one after that is, so the
+    transfer queue never holds more than two (a served batch's put
+    waits behind 128 MiB at most). ``phases.feed_slice`` is booked what
+    each put and wait took: no other phase is open here, so the leg's
+    split counts the table's put under that name. Under a mesh every
+    chip holds the table whole (the row numbers carry the batch's
+    sharding)."""
+    spec = tuple((c.shape[1:], jax.dtypes.canonicalize_dtype(c.dtype)) for c in columns)
+    if any(dtype.itemsize != 4 for _, dtype in spec):
+        raise TypeError(f"a fit's columns hold 32-bit values, not {[str(d) for _, d in spec]}")
+    words, per_row, lanes = _Table.geometry(spec)
+    n = len(columns[0])
+    if n >= 1 << 31:
+        raise ValueError(f"{n} rows: an epoch's row numbers are 32-bit")
+    # the fewest slices under the bound, the rows spread evenly over them in whole table rows
+    slices = max(-(-n // (max(FEED_SLICE_BYTES // (4 * words) // per_row, 1) * per_row)), 1)
+    rows = -(-max(n, 1) // (slices * per_row)) * per_row
+    everywhere = None if mesh is None else NamedSharding(mesh, P())
+    packed = jnp.zeros((slices * rows // per_row, lanes), jnp.uint32, device=everywhere)
+    last = None
+    for lo in range(0, n, rows):
+        t0 = time.perf_counter()
+        parts = []
+        for c, (_, dtype) in zip(columns, spec):
+            part = c[lo : lo + rows]
+            if len(part) < rows:
+                part = np.concatenate([part, np.zeros((rows - len(part), *c.shape[1:]), c.dtype)])
+            parts.append(_on_mesh(mesh, part.astype(dtype, copy=False)))
+            phases.put_bytes.inc(parts[-1].nbytes)
+        packed = _pack_slice(packed, lo // per_row, *parts)
+        jax.block_until_ready(last)
+        last = parts
+        phases.feed_slice.book(time.perf_counter() - t0)
+    return _Table(jax.block_until_ready(packed), spec)
 
 
 class _Epoch(tuple):
-    """An epoch on the chip: per column, the list of its device slices
-    ``[k, batch, ...]``; ``steps`` steps in all, so the last slice may
-    hold fewer than ``k`` (its other rows are padding)."""
+    """An epoch on the chip, as ``make_epoch_fn``'s function takes it:
+    one entry a column of the fit's ``table`` (its row shape and type),
+    the table itself, and ``rows``, the list of the epoch's row numbers
+    in device slices ``[k, batch]`` int32; ``steps`` steps in all, so
+    the last slice may hold fewer than ``k`` (its other rows are
+    padding)."""
 
+    table: _Table
+    rows: list
     steps: int
 
 
@@ -173,60 +307,67 @@ def _slice_rows(a: np.ndarray) -> int:
     return max(FEED_SLICE_BYTES // max(a.nbytes // max(len(a), 1), 1), 1)
 
 
-def _gather_slices(index: np.ndarray, rng: np.random.Generator, steps: int, batch: int, *columns: np.ndarray):
-    """The epoch's ``[steps, batch, ...]`` arrays as host slices of
-    bounded bytes, ``(column slice, ...)`` in step order, each gathered
-    when it is asked for (a generator; the shuffle ``rng.permutation``
-    of ``index`` on the first): row ``i`` of the epoch is
-    ``column[index[perm[i]]]``. Each slice is an allocation of its own,
-    so the host never holds an epoch whole, to free in one call (and no
-    fancy index is longer than a slice: numpy checks every index under
-    the interpreter lock before it copies without it, 0.11 s for an
-    epoch's 49.5M); all have ``k`` steps, the last zero past the epoch's
-    end."""
-    k = _slice_steps(
-        steps, batch * sum(c.dtype.itemsize * int(np.prod(c.shape[1:], dtype=np.int64)) for c in columns)
-    )
+def _gather_slices(index: np.ndarray, rng: np.random.Generator, steps: int, batch: int, row_bytes: int):
+    """The epoch's row numbers as host slices ``[k, batch]`` int32 in
+    step order, each composed when it is asked for (a generator; the
+    shuffle ``rng.permutation`` of ``index`` on the first): row ``i`` of
+    the epoch is row ``index[perm[i]]`` of the fit's table. ``k`` steps
+    take ``FEED_SLICE_BYTES`` from a table of ``row_bytes`` a row at
+    most; no index is longer than a slice (numpy checks every index
+    under the interpreter lock before it copies without it, 0.11 s for
+    an epoch's 49.5M); all slices have ``k`` steps, the last row 0 past
+    the epoch's end."""
+    k = _slice_steps(steps, batch * row_bytes)
     perm = _permutation(rng, len(index))
-
-    def gathered(lo: int) -> tuple:
-        rows = index[perm[lo * batch : min(lo + k, steps) * batch]]
-        part = []
-        for c in columns:
-            a = c[rows]
-            if len(rows) < k * batch:
-                a = np.concatenate([a, np.zeros((k * batch - len(rows), *c.shape[1:]), c.dtype)])
-            part.append(a.reshape(k, batch, *c.shape[1:]))
-        return tuple(part)
-
     for lo in range(0, steps, k):
-        yield gathered(lo)  # under no name here: it is the taker's to drop
+        rows = np.zeros(k * batch, np.int32)
+        part = perm[lo * batch : min(lo + k, steps) * batch]
+        rows[: len(part)] = index[part]
+        yield rows.reshape(k, batch)  # under no name here: it is the taker's to drop
 
 
-def _feed_slices(mesh, host, steps: int, phases) -> _Epoch:
-    """Put an epoch's host slices (``_gather_slices``) on the chip, one
-    put a slice. A put is asynchronous: it lands while the next slice is
-    gathered, and is waited for before the slice after that is handed
-    over, so the transfer queue never holds more than two (a served
-    batch's put waits behind 128 MiB at most) and the host no more than
-    the two being gathered and sent. Returns the epoch of ``steps``
-    steps as ``make_epoch_fn`` takes it, every slice on the chip.
-    ``phases`` is the leg's: ``gather`` times the loop, ``feed_slice``
-    is fed what each slice's put and wait took of it, ``feed`` times
-    the wait for what had not landed when the gather ended."""
+def _feed_slices(mesh, host, table: _Table, steps: int, phases, axis: str = "dp") -> _Epoch:
+    """Put an epoch's row numbers (``_gather_slices``) on the chip, one
+    put a slice, two in flight at most as in ``_put_table``, the batch
+    dimension sharded over the mesh's ``axis`` where it divides it (a
+    batch clamped to a tiny dataset rarely does: those rows go
+    replicated rather than fail the fit; one small fit doesn't need
+    parallelism). Returns the epoch of ``steps`` steps over ``table``
+    as ``make_epoch_fn`` takes it. ``phases`` is the leg's: ``gather``
+    times the loop (the permutation, the row numbers, their puts),
+    ``feed_slice`` is fed what each slice's put and wait took of it,
+    ``feed`` times the wait for what had not landed when it ended."""
     fed: list = []
     with phases.gather:
-        for part in host:
+        for rows in host:
             t0 = time.perf_counter()
-            fed.append(_shard_arrays(mesh, *part))
-            del part  # the transfer holds it until it is through
+            sharded = mesh is not None and rows.shape[1] % mesh.shape[axis] == 0
+            fed.append(_on_mesh(mesh, rows, P(None, axis) if sharded else P()))
+            phases.put_bytes.inc(rows.nbytes)
+            del rows  # the transfer holds it until it is through
             jax.block_until_ready(fed[-2:-1])
             phases.feed_slice.observe(time.perf_counter() - t0)
     with phases.feed:
         jax.block_until_ready(fed)
-    epoch = _Epoch(list(column) for column in zip(*fed))
-    epoch.steps = steps
+    epoch = _Epoch(table.columns)
+    epoch.table, epoch.rows, epoch.steps = table, fed, steps
     return epoch
+
+
+def _take_holdout(predict, table: _Table, idx: np.ndarray, phases, *args) -> tuple:
+    """``predict(*args, table, rows)`` over the table's rows ``idx``, as
+    many a dispatch as a table slice holds, every dispatch of one shape
+    (the last padded with row 0) and read back before the next is
+    enqueued: the host arrays of what ``predict`` returns, row for row
+    of ``idx``."""
+    per = min(max(FEED_SLICE_BYTES // table.row_bytes, 1), len(idx))
+    out = []
+    for lo in range(0, len(idx), per):
+        rows = np.zeros(per, np.int32)
+        rows[: len(idx) - lo] = idx[lo : lo + per]
+        phases.put_bytes.inc(rows.nbytes)
+        out.append([np.asarray(a) for a in predict(*args, table, jnp.asarray(rows))])
+    return tuple(np.concatenate(parts)[: len(idx)] for parts in zip(*out))
 
 
 def release_in_pieces(owned: list) -> int:
@@ -267,25 +408,30 @@ def make_epoch_fn(
 ):
     """Build the epoch function ``epoch(params, opt_state, batches)`` ->
     ``(params, opt_state, mean loss)`` over the ``_Epoch`` that
-    ``_feed_slices`` returns. Each slice is one dispatch of a jitted loop
-    over its steps (as many as the epoch has left: a traced count, so
-    the padded last slice runs on the same executable), the carried
-    state donated from one to the next and the slice waited for before
-    the next is enqueued. The jitted slice takes its name from the
-    loss's (``mlp_loss`` -> ``mlp_epoch``), so that a trace's host events
-    (``PjitFunction(mlp_epoch)``), the XLA module and the scope of the
-    step's ops say which leg they belong to; a leg's ``epoch_slice``
-    phase is fed each slice's wall."""
+    ``_feed_slices`` returns. Each slice of row numbers is one dispatch
+    of a jitted loop over its steps (as many as the epoch has left: a
+    traced count, so the padded last slice runs on the same executable),
+    each step's batch taken from the table by its row numbers, on the
+    chip; the table is an argument of every dispatch (not donated, not
+    closed over), and a slice is waited for before the next is enqueued.
+    The carried state is not donated either: it is a quarter of a
+    megabyte at most, and a carry aliased to the outputs has to live in
+    HBM, where one left free XLA keeps in VMEM for the whole loop (a GRU
+    slice of 503 steps over the table: 30.9 ms donated, 20.6 ms not;
+    PERF.md, PR 29). The jitted slice
+    takes its name from the loss's (``mlp_loss`` -> ``mlp_epoch``), so
+    that a trace's host events (``PjitFunction(mlp_epoch)``), the XLA
+    module and the scope of the step's ops say which leg they belong to;
+    a leg's ``epoch_slice`` phase is fed each slice's wall."""
     leg = getattr(loss_fn, "__name__", "loss").removesuffix("_loss")
     name = leg + "_epoch"
     slice_phase = {"mlp": PH_MLP, "gnn": PH_GNN, "gru": PH_GRU}.get(leg)
 
-    def epoch_slice(params, opt_state, batches, steps):
+    def epoch_slice(params, opt_state, table, rows, steps):
         def body(i, carry):
             with jax.named_scope(name):
                 params, opt_state, loss_sum = carry
-                batch = jax.tree_util.tree_map(lambda a: a[i], batches)
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+                loss, grads = jax.value_and_grad(loss_fn)(params, table.take(rows[i]))
                 updates, opt_state = optimizer.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
             return params, opt_state, loss_sum + loss
@@ -293,14 +439,14 @@ def make_epoch_fn(
         return jax.lax.fori_loop(0, steps, body, (params, opt_state, jnp.zeros((), jnp.float32)))
 
     epoch_slice.__name__ = epoch_slice.__qualname__ = name
-    run_slice = jax.jit(epoch_slice, donate_argnums=(0, 1))
+    run_slice = jax.jit(epoch_slice)
 
     def epoch(params, opt_state, batches):
         left, loss_sum = batches.steps, 0.0
-        for part in zip(*batches):
+        for rows in batches.rows:
             t0 = time.perf_counter()
-            steps = min(part[0].shape[0], left)
-            params, opt_state, part_sum = run_slice(params, opt_state, part, steps)
+            steps = min(rows.shape[0], left)
+            params, opt_state, part_sum = run_slice(params, opt_state, batches.table, rows, steps)
             loss_sum += float(part_sum)  # the wait: nothing is queued behind a running slice
             left -= steps
             if slice_phase is not None:
@@ -309,7 +455,7 @@ def make_epoch_fn(
 
     def lower(params, opt_state, batches):
         """The lowering of the epoch's slice, for a reader of its HLO."""
-        return run_slice.lower(params, opt_state, tuple(column[0] for column in batches), batches.steps)
+        return run_slice.lower(params, opt_state, batches.table, batches.rows[0], batches.steps)
 
     epoch.__name__ = epoch.__qualname__ = name
     epoch.lower = lower
@@ -342,7 +488,7 @@ def train_mlp(
     params = mlp_mod.init_mlp(key, [f, *cfg.hidden_dims, 1])
     # warm-start the output bias at the label mean — the regression head
     # starts unbiased instead of spending its first epochs drifting there
-    params["layers"][-1]["b"] = jnp.full((1,), float(labels.mean()))
+    params["layers"][-1]["b"] = _head_bias(labels)
     if mesh is not None:
         from dragonfly2_tpu.parallel.sharding import replicate
 
@@ -358,6 +504,7 @@ def train_mlp(
         return jnp.mean((pred - y) ** 2)
 
     epoch_fn = make_epoch_fn(mlp_loss, optimizer)
+    table = _put_table(mesh, PH_MLP, features, labels)  # once a fit: every epoch and the holdout take from it
 
     ckpt, start_epoch = _open_checkpoint(cfg)
     try:
@@ -370,11 +517,11 @@ def train_mlp(
         history: list[float] = []
         for epoch in range(start_epoch, cfg.epochs):
             FP_FIT_STEP()
-            batches = None  # the chip holds one epoch: the last goes before the next is fed
+            batches = None  # the chip holds one epoch's row numbers: the last go before the next are fed
             # per-epoch rng: a resumed run replays the exact shuffle schedule
             rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            host = _gather_slices(train_idx, rng, steps, batch, features, labels)
-            batches = _feed_slices(mesh, host, steps, PH_MLP)
+            host = _gather_slices(train_idx, rng, steps, batch, table.row_bytes)
+            batches = _feed_slices(mesh, host, table, steps, PH_MLP)
             with PH_MLP.epoch_dispatch:
                 params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
             with PH_MLP.epoch_wait:
@@ -384,7 +531,7 @@ def train_mlp(
         metrics = {}
         if len(eval_idx):
             with PH_MLP.holdout:
-                metrics = evaluate_mlp(params, features[eval_idx], labels[eval_idx])
+                metrics = _mlp_error(params, table, eval_idx)
         _finish_checkpoint(ckpt)
         ckpt = None
         return FitResult(params=params, metrics=metrics, history=history)
@@ -419,21 +566,24 @@ def _finish_checkpoint(ckpt) -> None:
         ckpt.close()
 
 
-def evaluate_mlp(params, features: np.ndarray, labels: np.ndarray) -> dict[str, float]:
-    """The holdout's error. The holdout goes to the chip whole, as it
-    always has (beside the epoch, which the fit still holds: the peak of
-    a resident fit's device memory is here), but in bounded slices like
-    the epoch: one put a slice, each waited for, then one forward a
-    slice, each read back before the next is enqueued."""
-    forward = jit_once(mlp_mod.score_parents)
-    rows = _slice_rows(features)
-    on_chip = [
-        jax.block_until_ready(jnp.asarray(features[lo : lo + rows]))
-        for lo in range(0, len(features), rows)
-    ]
-    pred = np.concatenate([np.asarray(forward(params, x)) for x in on_chip])
-    err = pred - labels
+def _mlp_holdout(params, table: _Table, rows):
+    x, y = table.take(rows)
+    return mlp_mod.score_parents(params, x), y
+
+
+def _mlp_error(params, table: _Table, idx: np.ndarray) -> dict[str, float]:
+    """The error over the table's rows ``idx``: their features and
+    labels taken on the chip, a slice a forward (``_take_holdout``)."""
+    pred, y = _take_holdout(jit_once(_mlp_holdout), table, idx, PH_MLP, params)
+    err = pred - y
     return {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
+
+
+def evaluate_mlp(params, features: np.ndarray, labels: np.ndarray) -> dict[str, float]:
+    """The error of ``params`` on a set of its own: put as a fit's table
+    is (``_put_table``), every row scored in order."""
+    table = _put_table(None, PH_MLP, features, labels)
+    return _mlp_error(params, table, np.arange(len(features)))
 
 
 # ---------------------------------------------------------------------------
@@ -459,9 +609,7 @@ def _init_gnn(graph, cfg: GNNFitConfig):
     params = gnn_mod.init_graphsage(
         key, graph.node_features.shape[1], cfg.hidden_dims, num_nodes=graph.num_nodes
     )
-    params["head"]["layers"][-1]["b"] = jnp.full(
-        (1,), float(graph.edge_rtt_log_ms.mean())
-    )
+    params["head"]["layers"][-1]["b"] = _head_bias(graph.edge_rtt_log_ms)
     return params
 
 
@@ -502,6 +650,8 @@ def train_gnn(
         return jnp.mean((pred - y) ** 2)
 
     epoch_fn = make_epoch_fn(gnn_loss, optimizer)
+    # never sharded: the edges and their batches are replicated beside the graph
+    table = _put_table(None, PH_GNN, graph.edge_src, graph.edge_dst, graph.edge_rtt_log_ms)
 
     ckpt, start_epoch = _open_checkpoint(cfg)
     try:
@@ -514,11 +664,8 @@ def train_gnn(
         history: list[float] = []
         for epoch in range(start_epoch, cfg.epochs):
             rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            host = _gather_slices(
-                train_idx, rng, steps, batch, graph.edge_src, graph.edge_dst, graph.edge_rtt_log_ms
-            )
-            # never sharded: the edge batches are replicated beside the graph
-            batches = _feed_slices(None, host, steps, PH_GNN)
+            host = _gather_slices(train_idx, rng, steps, batch, table.row_bytes)
+            batches = _feed_slices(None, host, table, steps, PH_GNN)
             with PH_GNN.epoch_dispatch:
                 params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
             with PH_GNN.epoch_wait:
@@ -528,7 +675,7 @@ def train_gnn(
         metrics: dict[str, float] = {}
         if len(eval_idx):
             with PH_GNN.holdout:
-                metrics = evaluate_gnn(params, graph, eval_idx)
+                metrics = _gnn_error(params, graph, (node_features, neighbors, neighbor_mask), table, eval_idx)
         _finish_checkpoint(ckpt)
         ckpt = None
         return FitResult(params=params, metrics=metrics, history=history)
@@ -658,20 +805,22 @@ def _edge_metrics(pred: np.ndarray, y: np.ndarray, thresh: float) -> dict[str, f
     }
 
 
+def _gnn_holdout(params, node_features, neighbors, neighbor_mask, table: _Table, rows):
+    src, dst, y = table.take(rows)
+    return gnn_mod.forward_edge_rtt(params, node_features, neighbors, neighbor_mask, src, dst), y
+
+
+def _gnn_error(params, graph, on_chip: tuple, table: _Table, edge_idx: np.ndarray) -> dict[str, float]:
+    """The evaluation over the edge table's rows ``edge_idx``, taken on
+    the chip; ``on_chip`` is the graph's node arrays there."""
+    pred, y = _take_holdout(jit_once(_gnn_holdout), table, edge_idx, PH_GNN, params, *on_chip)
+    return _edge_metrics(pred, y, float(np.median(graph.edge_rtt_log_ms)))
+
+
 def evaluate_gnn(params, graph, edge_idx: np.ndarray) -> dict[str, float]:
-    pred = np.asarray(
-        jit_once(gnn_mod.forward_edge_rtt)(
-            params,
-            jnp.asarray(graph.node_features),
-            jnp.asarray(graph.neighbors),
-            jnp.asarray(graph.neighbor_mask),
-            jnp.asarray(graph.edge_src[edge_idx]),
-            jnp.asarray(graph.edge_dst[edge_idx]),
-        )
-    )
-    return _edge_metrics(
-        pred, graph.edge_rtt_log_ms[edge_idx], float(np.median(graph.edge_rtt_log_ms))
-    )
+    on_chip = tuple(jnp.asarray(a) for a in (graph.node_features, graph.neighbors, graph.neighbor_mask))
+    table = _put_table(None, PH_GNN, graph.edge_src, graph.edge_dst, graph.edge_rtt_log_ms)
+    return _gnn_error(params, graph, on_chip, table, edge_idx)
 
 
 # ---------------------------------------------------------------------------
@@ -696,7 +845,7 @@ def train_gru(
 
     key = jax.random.PRNGKey(cfg.seed)
     params = gru_mod.init_gru(key, f, cfg.hidden_dims[0])
-    params["head"]["layers"][-1]["b"] = jnp.full((1,), float(labels.mean()))
+    params["head"]["layers"][-1]["b"] = _head_bias(labels)
     if mesh is not None:
         from dragonfly2_tpu.parallel.sharding import replicate
 
@@ -712,13 +861,14 @@ def train_gru(
         return jnp.mean((pred - y) ** 2)
 
     epoch_fn = make_epoch_fn(gru_loss, optimizer)
+    table = _put_table(mesh, PH_GRU, sequences, labels, lengths)
 
     history: list[float] = []
     rng = np.random.default_rng(cfg.seed + 1)
     for _ in range(cfg.epochs):
-        batches = None  # the chip holds one epoch: the last goes before the next is fed
-        host = _gather_slices(train_idx, rng, steps, batch, sequences, labels, lengths)
-        batches = _feed_slices(mesh, host, steps, PH_GRU)
+        batches = None  # the chip holds one epoch's row numbers: the last go before the next are fed
+        host = _gather_slices(train_idx, rng, steps, batch, table.row_bytes)
+        batches = _feed_slices(mesh, host, table, steps, PH_GRU)
         with PH_GRU.epoch_dispatch:
             params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
         with PH_GRU.epoch_wait:
@@ -727,11 +877,12 @@ def train_gru(
     metrics: dict[str, float] = {}
     if len(eval_idx):
         with PH_GRU.holdout:
-            pred = np.asarray(
-                jit_once(gru_mod.predict_next_cost)(
-                    params, jnp.asarray(sequences[eval_idx]), jnp.asarray(lengths[eval_idx])
-                )
-            )
-            err = pred - labels[eval_idx]
+            pred, y = _take_holdout(jit_once(_gru_holdout), table, eval_idx, PH_GRU, params)
+            err = pred - y
             metrics = {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
     return FitResult(params=params, metrics=metrics, history=history)
+
+
+def _gru_holdout(params, table: _Table, rows):
+    x, y, lengths = table.take(rows)
+    return gru_mod.predict_next_cost(params, x, lengths), y
