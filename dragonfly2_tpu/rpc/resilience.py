@@ -561,10 +561,11 @@ def wrap_call(service: str, method: str, kind: str, target: str, inner):
     maybe_hedgeable = is_unary and method in HEDGEABLE.get(service, frozenset())
     short = service.rsplit(".", 1)[-1]
     # hot-path pre-binds: every line of `call` below is fault-free
-    # pre-flight budget (bench.py resilience_overhead_pct < 2% of the
-    # schedule op) — module/attr lookups are hoisted, the common-case
-    # deadline header is cached per deadline value, and the healthy-path
-    # breaker/budget bookkeeping is lock-free (see their fast paths)
+    # pre-flight budget, paid by every RPC of a schedule op (its cost
+    # there on the chip: not measured) — module/attr lookups are
+    # hoisted, the common-case deadline header is cached per deadline
+    # value, and the healthy-path breaker/budget bookkeeping is
+    # lock-free (see their fast paths)
     _policies_get = _POLICIES.get
     _breakers_get = _breakers.get
     _budgets_get = _budgets.get

@@ -43,8 +43,7 @@ PH_SCHEDULE = profiling.phase_type("scheduler.schedule_op")
 PH_EVALUATE = profiling.phase_type("scheduler.evaluate")
 
 # flight-recorder emitters: one event per scheduling decision, always on
-# (the per-decision record the sampled trace usually misses); bench.py
-# recorder_overhead_pct keeps the emit cost < 2% of the schedule op
+# (the per-decision record the sampled trace usually misses)
 EV_SCHEDULE = flight.event_type("scheduler.schedule")
 EV_BACK_TO_SOURCE = flight.event_type("scheduler.schedule_back_to_source")
 EV_SCHEDULE_FAILED = flight.event_type("scheduler.schedule_failed")
@@ -130,8 +129,7 @@ class Scheduling:
         # the per-schedule span only exists when something will record
         # it: the unsampled/disabled path (is_sampling False — this IS
         # the hot path when no collector is drinking) pays a predicate
-        # and no-op calls, < 2% of the schedule wall (bench.py
-        # tracing_overhead_pct keeps that measured)
+        # and no-op calls
         if tracing.is_sampling():
             _span = tracing.get("scheduler").start_span(
                 "schedule", peer_id=peer.id, task_id=peer.task.id
@@ -283,66 +281,6 @@ class Scheduling:
         PH_EVALUATE.observe(time.perf_counter() - _e0)
         limit = self._candidate_parent_limit()
         return candidates[:limit], True
-
-    def find_candidate_parents_wave(
-        self, peers: "list[Peer]", blocklist: set[str] | None = None
-    ) -> "list[tuple[list[Peer], bool]]":
-        """The wave form of :meth:`find_candidate_parents`: filter each
-        peer's candidates on host, then rank the WHOLE wave in one
-        fused evaluator dispatch (``evaluate_wave``). Per-peer results
-        keep :meth:`find_candidate_parents` semantics exactly — a peer
-        in the wrong state or with nothing after filtering contributes
-        ``([], False)`` without costing the wave a rung."""
-        blocklist = blocklist or set()
-        sets: "list[list[Peer]]" = []
-        live: "list[int]" = []
-        out: "list[tuple[list[Peer], bool]]" = [([], False)] * len(peers)
-        for i, peer in enumerate(peers):
-            if not peer.fsm.is_state(
-                PEER_STATE_RECEIVED_NORMAL, PEER_STATE_RUNNING
-            ):
-                continue
-            candidates = self._filter_candidate_parents(peer, blocklist)
-            if not candidates:
-                continue
-            live.append(i)
-            sets.append(candidates)
-        if not live:
-            return out
-        children = [peers[i] for i in live]
-        totals = [peers[i].task.total_piece_count for i in live]
-        # plugin evaluators may predate the wave API — fall back to the
-        # per-decision loop rather than failing the whole wave
-        wave = getattr(self.evaluator, "evaluate_wave", None)
-        _e0 = time.perf_counter()
-        if tracing.is_sampling():
-            with tracing.get("scheduler").span(
-                "evaluate_wave",
-                decisions=len(live),
-                rows=sum(len(s) for s in sets),
-            ):
-                ranked = (
-                    wave(children, sets, totals)
-                    if wave is not None
-                    else [
-                        self.evaluator.evaluate_parents(s, c, t)
-                        for c, s, t in zip(children, sets, totals)
-                    ]
-                )
-        else:
-            ranked = (
-                wave(children, sets, totals)
-                if wave is not None
-                else [
-                    self.evaluator.evaluate_parents(s, c, t)
-                    for c, s, t in zip(children, sets, totals)
-                ]
-            )
-        PH_EVALUATE.observe(time.perf_counter() - _e0)
-        limit = self._candidate_parent_limit()
-        for i, rk in zip(live, ranked):
-            out[i] = (rk[:limit], True)
-        return out
 
     def find_success_parent(
         self, peer: Peer, blocklist: set[str] | None = None

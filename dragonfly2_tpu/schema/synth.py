@@ -221,8 +221,8 @@ def synthesize_dataset_csv(d: str, shards: int, shard_bytes: int) -> list:
     """Write ``shards`` download-record CSV files of ~shard_bytes each by
     replicating a 2,000-record synthetic body (per-record decode cost is
     content-size driven, not uniqueness driven). Returns the shard
-    paths. Shared by bench.py and tools/soak_ingest.py so both measure
-    the same byte format the scheduler's Train-stream upload produces."""
+    paths: the byte format the scheduler's Train-stream upload produces
+    (tools/soak_ingest.py streams it)."""
     import os
 
     from dragonfly2_tpu.schema.columnar import write_csv

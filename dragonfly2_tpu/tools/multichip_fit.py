@@ -8,9 +8,8 @@ Sets ``XLA_FLAGS=--xla_force_host_platform_device_count`` BEFORE jax
 initializes (when the caller didn't), so the dp>1 ingest code path —
 per-device sharded puts, replicated params, donated step state, the
 scan+dp batch layout — runs end to end in a CPU-only image. This is the
-harness behind bench.py's ``multichip_scaling`` curve, the
-``tools/soak_ingest.py --mesh`` arm, and the subprocess test in
-tests/test_multichip_ingest.py.
+harness behind the ``tools/soak_ingest.py --mesh`` arm and the
+subprocess test in tests/test_multichip_ingest.py.
 
 The harness also enforces the dispatch-plane contract with the
 jit-witness taps (hack/dfanalyze/jitwitness.py):
@@ -46,9 +45,8 @@ def ensure_devices(n: int) -> None:
     Only the host-platform device-count flag is set — it is inert
     unless the CPU backend ends up selected, so a host with ≥ n REAL
     chips runs on them (the platform is labeled in every artifact).
-    Callers that specifically want the CPU code-path proof (bench's
-    multichip_scaling, the subprocess test) export JAX_PLATFORMS=cpu
-    themselves."""
+    Callers that specifically want the CPU code-path proof (the
+    subprocess test) export JAX_PLATFORMS=cpu themselves."""
     if "jax" in sys.modules and getattr(sys.modules["jax"], "devices", None):
         import jax
 
@@ -100,7 +98,7 @@ def run(
         raise ValueError(f"batch_size {batch_size} not divisible by dp={dp}")
 
     # the witness taps are optional: the harness is spawned from the
-    # repo root (bench/tests/soak), where hack/ is importable; a
+    # repo root (tests/soak), where hack/ is importable; a
     # site-installed package still measures throughput without them
     try:
         from hack.dfanalyze import jitwitness

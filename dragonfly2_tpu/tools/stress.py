@@ -20,8 +20,7 @@ fault schedule (5% RPC errors on every send, a parent upload-server
 kill, a scheduler restart mid-swarm) while a download series runs; the
 resilience layer (rpc/resilience.py) must carry every download to
 correct bytes with zero hangs. Prints the soak statistics as one JSON
-line (``chaos_success_rate``, ``chaos_hangs``, …) — the same numbers
-bench.py folds into its artifact.
+line (``chaos_success_rate``, ``chaos_hangs``, …).
 
 Fourth mode: ``--chaos --shard-kill`` runs the scheduler-fleet failover
 soak (scheduler/fleet.py, docs/fleet.md): N real scheduler processes
@@ -250,7 +249,7 @@ def chaos_soak(
     download runs under a propagated deadline budget and a hard watchdog
     join — a hang is counted, never waited out.
 
-    Returns the chaos-soak statistics bench.py re-emits:
+    Returns the chaos-soak statistics:
     ``chaos_success_rate`` (correct-bytes completions / downloads),
     ``chaos_hangs``, ``chaos_faults_injected``, ``chaos_wall_s``.
 
@@ -562,12 +561,12 @@ def data_plane_soak(
     ONE client-side selector loop (so the harness itself scales to the
     connection counts it claims). Every response's length is checked.
 
-    Gates (CLI exit / bench re-emission): zero hangs (the soak thread is
+    Gates (CLI exit): zero hangs (the soak thread is
     watchdog-joined), zero short/corrupt responses, and the aggregate
     ``data_plane_bytes_per_s`` + ``piece_serve_p99_us`` +
     ``daemon_rss_mb`` land in the stats. Run once with
-    ``use_sendfile=False`` for the buffered arm the bench compares
-    against.
+    ``use_sendfile=False`` for the buffered arm ``data_plane_race``
+    compares against.
     """
     import selectors as _selectors
     import shutil
@@ -767,8 +766,7 @@ def data_plane_race(
     **kw,
 ) -> dict:
     """The acceptance comparison: sendfile vs buffered arms, alternated
-    ``repeats`` times each with best-of per arm (the same
-    best-of-repeats discipline the e2e bench uses — on a shared
+    ``repeats`` times each with best-of per arm (on a shared
     container a single draw measures the neighbors, not the path).
     Returns the best sendfile arm's stats + the buffered best +
     cumulative hang/error counts across every run."""
@@ -798,363 +796,6 @@ def data_plane_race(
     stats["data_plane_hangs"] = hangs
     stats["data_plane_errors"] = errors
     return stats
-
-
-# ---------------------------------------------------------------------------
-# serving soak: batched vs per-call scheduler inference (ROADMAP item 1)
-# ---------------------------------------------------------------------------
-
-
-def _serving_swarm(candidates: int, peers: int):
-    """(parents, children, task) — one task with ``candidates`` feedable
-    SUCCEEDED parents and ``peers`` registered children, the state every
-    ml-ranked schedule decision reads."""
-    from dragonfly2_tpu.scheduler import resource as res
-
-    task = res.Task("serving-soak-task", "https://origin/x")
-    task.content_length = 64 * 1024 * 1024
-    task.total_piece_count = 16
-    parents = []
-    for i in range(candidates):
-        h = res.Host(id=f"parent-host-{i}", type=res.HostType.SUPER)
-        h.network.idc = f"idc-{i % 3}"
-        h.network.location = f"r{i % 4}|z{i % 2}"
-        p = res.Peer(f"parent-{i}", task, h)
-        p.fsm.event(res.PEER_EVENT_REGISTER_NORMAL)
-        p.fsm.event(res.PEER_EVENT_DOWNLOAD)
-        p.fsm.event(res.PEER_EVENT_DOWNLOAD_SUCCEEDED)
-        p.finished_pieces |= set(range(i % 16))
-        parents.append(p)
-    children = []
-    for i in range(peers):
-        h = res.Host(id=f"child-host-{i}")
-        h.network.idc = f"idc-{i % 3}"
-        c = res.Peer(f"child-{i}", task, h)
-        c.fsm.event(res.PEER_EVENT_REGISTER_NORMAL)
-        children.append(c)
-    return parents, children, task
-
-
-def _serving_scorer():
-    """The jitted MLPScorer both soak arms share (per-call dispatch cost
-    is what batching amortizes), compiled once before any timing."""
-    import jax
-    import numpy as np
-
-    from dragonfly2_tpu.models.mlp import init_mlp
-    from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
-    from dragonfly2_tpu.trainer import serving as tserving
-
-    params = init_mlp(jax.random.PRNGKey(0), [MLP_FEATURE_DIM, 64, 1])
-    scorer = tserving.MLPScorer(
-        tserving.deserialize_params_auto(tserving.serialize_params(params))
-    )
-    scorer.predict(np.zeros((1, MLP_FEATURE_DIM), np.float32))
-    return scorer
-
-
-def serving_soak(
-    peers: int = 32,
-    decisions_per_peer: int = 20,
-    candidates: int = 12,
-    window_ms: float = 2.0,
-) -> dict:
-    """Batched-vs-per-call scheduler inference at ``peers`` concurrency
-    (the ROADMAP item 1 acceptance soak): the SAME model ranks the same
-    candidate sets through (a) a per-decision forward and (b) the
-    scoring service's deadline-aware micro-batches, with per-decision
-    latency sampled throughout.
-
-    Gates (CLI exit / bench re-emission): aggregate ``schedule_ops_per_s``
-    (batched) strictly above ``schedule_ops_per_s_per_call``, zero lost
-    submissions (every decision returns a full ranking), and
-    ``schedule_decision_p99_us`` within the batching window + a few
-    single-batch service times (``serving_p99_bound_us``).
-    """
-    import numpy as np
-
-    from dragonfly2_tpu.scheduler.evaluator import MLEvaluator
-    from dragonfly2_tpu.scheduler.serving import (
-        MLPServed,
-        ScoringService,
-        ServingConfig,
-    )
-    from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
-    from dragonfly2_tpu.trainer.serving import bucket_rows
-
-    scorer = _serving_scorer()
-    parents, children, task = _serving_swarm(candidates, peers)
-    total = task.total_piece_count
-
-    # warm EVERY bucket rung a packed batch can reach — the ladder up to
-    # max_rows plus one overshooting request — so the timed arms never
-    # pay a compile (a cold rung mid-arm would stall every queued
-    # decision behind an XLA compile and poison the p99 sample)
-    max_rows = ServingConfig().max_rows
-    top = bucket_rows(max_rows + candidates)
-    rungs = {bucket_rows(n) for n in range(1, top + 1, 1)}
-    for rung in sorted(rungs):
-        scorer.predict(np.zeros((rung, MLP_FEATURE_DIM), np.float32))
-
-    def run_arm(evaluator) -> tuple[float, list, int]:
-        """→ (ops/s, per-decision latencies, completed) across ``peers``
-        worker threads × ``decisions_per_peer`` decisions."""
-        lat: list = []
-        done = [0]
-        lock = threading.Lock()
-        start = threading.Barrier(peers + 1)
-
-        def worker(child):
-            mine = []
-            ok = 0
-            start.wait()
-            for _ in range(decisions_per_peer):
-                t0 = time.perf_counter()
-                ranked = evaluator.evaluate_parents(parents, child, total)
-                mine.append(time.perf_counter() - t0)
-                ok += int(len(ranked) == len(parents))
-            with lock:
-                lat.extend(mine)
-                done[0] += ok
-
-        threads = [
-            threading.Thread(
-                target=worker, args=(children[i],),
-                name=f"stress.serving-{i}", daemon=True,
-            )
-            for i in range(peers)
-        ]
-        for t in threads:
-            t.start()
-        start.wait()
-        t0 = time.perf_counter()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t0
-        ops = peers * decisions_per_peer
-        return (ops / wall if wall else 0.0), lat, done[0]
-
-    expected = peers * decisions_per_peer
-
-    # single-batch service time (warm, full bucket): the p99 bound's
-    # second term, measured not assumed
-    feats64 = np.zeros((max_rows, MLP_FEATURE_DIM), np.float32)
-    t0 = time.perf_counter()
-    for _ in range(5):
-        scorer.predict(feats64)
-    batch_service_us = (time.perf_counter() - t0) / 5 * 1e6
-    # the acceptance bound: batching window + single-batch service time,
-    # with slack for batches queued back-to-back under full concurrency
-    # (a decision can wait out one in-flight batch plus its own) and
-    # for scheduler jitter on a shared container
-    bound_us = window_ms * 1e3 + 4 * batch_service_us + 20_000
-
-    def one_round() -> tuple:
-        """Per-call arm, then batched arm against a fresh service."""
-        # arm 1: per-call — every decision pays its own model dispatch
-        pc_rate, _, pc_done = run_arm(MLEvaluator(scorer))
-        # arm 2: batched — the scoring service micro-batches
-        # concurrent ops
-        svc = ScoringService(ServingConfig(window_s=window_ms / 1e3))
-        svc.start()
-        svc.install(MLPServed(scorer), version="soak/v1")
-        try:
-            b_rate, b_lat, b_done = run_arm(MLEvaluator(scorer, serving=svc))
-        finally:
-            occ = svc.rows_scored / svc.batches if svc.batches else 0.0
-            svc.stop()
-        return pc_rate, pc_done, b_rate, b_lat, b_done, occ
-
-    # best-of rounds: each arm timed exactly once is one GC pause away
-    # from flipping the batched-vs-per-call gate on a contended core.
-    # Rounds stay COHERENT — one round's per-call rate, batched rate,
-    # latency sample, and occupancy are reported together, never mixed
-    # across rounds — and completions SUM so a lost submission in any
-    # round still counts. Extra rounds (at most two) run only while
-    # the round in hand fails a gate; a gate-clean round beats a
-    # faster-but-dirty one.
-    percall_done = batched_done = passes = 0
-    best_key = best = None
-    for _ in range(3):
-        pc_rate, pc_done, b_rate, b_lat, b_done, occ = one_round()
-        percall_done += pc_done
-        batched_done += b_done
-        passes += 1
-        p99 = _percentile(sorted(b_lat), 0.99) * 1e6
-        clean = b_rate > pc_rate and 0 < p99 <= bound_us
-        key = (clean, b_rate)
-        if best_key is None or key > best_key:
-            best_key, best = key, (pc_rate, b_rate, b_lat, occ)
-        if clean:
-            break
-    percall_rate, batched_rate, lat, occupancy = best
-
-    lat.sort()
-    p99_us = _percentile(lat, 0.99) * 1e6
-    return {
-        "serving_backend": "jax",
-        "serving_peers": peers,
-        "serving_candidates": candidates,
-        "serving_window_ms": window_ms,
-        "schedule_ops_per_s": round(batched_rate, 1),
-        "schedule_ops_per_s_per_call": round(percall_rate, 1),
-        "evaluator_batch_occupancy": round(occupancy, 2),
-        "schedule_decision_p99_us": round(p99_us, 1),
-        "serving_batch_service_us": round(batch_service_us, 1),
-        "serving_p99_bound_us": round(bound_us, 1),
-        "serving_lost": (expected * passes - batched_done)
-        + (expected * passes - percall_done),
-    }
-
-
-def wave_soak(
-    peers: int = 32,
-    decisions_per_peer: int = 20,
-    candidates: int = 12,
-    wave_width: int = 8,
-    window_ms: float = 2.0,
-) -> dict:
-    """Wave-packed vs per-op-batched scheduling on the SAME served
-    model (the device-resident wave-scheduling acceptance soak): both
-    arms push ``peers × decisions_per_peer`` decisions through the
-    scoring service; the per-op arm submits one ``evaluate_parents``
-    call per decision, the wave arm packs ``wave_width`` decisions per
-    ``evaluate_wave`` call. Rankings are crosschecked bit-identical to
-    the per-peer path before the timed arms run.
-
-    Gates (CLI exit / bench re-emission): ``wave_decisions_per_s``
-    strictly above ``wave_decisions_per_s_per_op``, zero lost
-    submissions, and ``wave_rankings_match`` == 1.
-    """
-    import numpy as np
-
-    from dragonfly2_tpu.scheduler.evaluator import MLEvaluator
-    from dragonfly2_tpu.scheduler.serving import (
-        MLPServed,
-        ScoringService,
-        ServingConfig,
-    )
-    from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
-    from dragonfly2_tpu.trainer.serving import bucket_rows
-
-    scorer = _serving_scorer()
-    parents, children, task = _serving_swarm(candidates, peers)
-    total = task.total_piece_count
-
-    # warm every rung either arm can reach: per-op batches pack up to
-    # max_rows + one overshoot; wave batches reach wave_width × C rows.
-    # Both the plain forward AND the fused score+rank twin are warmed —
-    # the wave path dispatches predict_ranked, a separate executable
-    max_rows = ServingConfig().max_rows
-    top = bucket_rows(max(max_rows + candidates, wave_width * candidates))
-    rungs = {bucket_rows(n) for n in range(1, top + 1)}
-    ranked = getattr(scorer, "predict_ranked", None)
-    for rung in sorted(rungs):
-        scorer.predict(np.zeros((rung, MLP_FEATURE_DIM), np.float32))
-        if ranked is not None:
-            ranked(
-                np.zeros((rung, MLP_FEATURE_DIM), np.float32),
-                np.zeros(rung, np.int32),
-            )
-
-    def run_arm(svc, waved: bool) -> tuple[float, int]:
-        """→ (decisions/s, completed) across ``peers`` worker threads."""
-        done = [0]
-        lock = threading.Lock()
-        start = threading.Barrier(peers + 1)
-        ev = MLEvaluator(scorer, serving=svc)
-
-        def worker(child):
-            ok = 0
-            start.wait()
-            if waved:
-                left = decisions_per_peer
-                while left > 0:
-                    w = min(wave_width, left)
-                    ranked = ev.evaluate_wave(
-                        [child] * w, [parents] * w, [total] * w
-                    )
-                    ok += sum(int(len(r) == len(parents)) for r in ranked)
-                    left -= w
-            else:
-                for _ in range(decisions_per_peer):
-                    ranked = ev.evaluate_parents(parents, child, total)
-                    ok += int(len(ranked) == len(parents))
-            with lock:
-                done[0] += ok
-
-        threads = [
-            threading.Thread(
-                target=worker, args=(children[i],),
-                name=f"stress.wave-{i}", daemon=True,
-            )
-            for i in range(peers)
-        ]
-        for t in threads:
-            t.start()
-        start.wait()
-        t0 = time.perf_counter()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t0
-        ops = peers * decisions_per_peer
-        return (ops / wall if wall else 0.0), done[0]
-
-    expected = peers * decisions_per_peer
-    svc = ScoringService(ServingConfig(window_s=window_ms / 1e3))
-    svc.start()
-    svc.install(MLPServed(scorer), version="soak/v1")
-    try:
-        # crosscheck first (untimed): wave rankings bit-identical to the
-        # per-peer path on the same model
-        ev = MLEvaluator(scorer, serving=svc)
-        wave = ev.evaluate_wave(
-            children[:3], [parents] * 3, [total] * 3
-        )
-        per_peer = [
-            MLEvaluator(scorer).evaluate_parents(parents, c, total)
-            for c in children[:3]
-        ]
-        match = int(
-            all(
-                [p.id for p in w] == [p.id for p in pp]
-                for w, pp in zip(wave, per_peer)
-            )
-        )
-        # interleaved passes, best-of per arm: each arm timed once is
-        # one GC pause away from flipping the packed-vs-per-op gate on
-        # a contended core. Completions are SUMMED across passes so a
-        # lost submission in any pass still trips wave_lost. Up to two
-        # tie-break rounds run only when the gate would fail.
-        perop_rate = wave_rate = 0.0
-        perop_done = wave_done = 0
-        passes = 0
-        for round_ in range(4):
-            if round_ and wave_rate > perop_rate:
-                break
-            r, d = run_arm(svc, waved=False)
-            perop_rate, perop_done = max(perop_rate, r), perop_done + d
-            r, d = run_arm(svc, waved=True)
-            wave_rate, wave_done = max(wave_rate, r), wave_done + d
-            passes += 1
-    finally:
-        occupancy = svc.wave_rows / svc.waves if svc.waves else 0.0
-        unpack = sorted(svc.wave_unpack_us)
-        svc.stop()
-    return {
-        "serving_backend": "jax",
-        "wave_peers": peers,
-        "wave_candidates": candidates,
-        "wave_width": wave_width,
-        "wave_window_ms": window_ms,
-        "wave_decisions_per_s": round(wave_rate, 1),
-        "wave_decisions_per_s_per_op": round(perop_rate, 1),
-        "wave_occupancy_rows": round(occupancy, 2),
-        "wave_unpack_p99_us": round(_percentile(unpack, 0.99), 1),
-        "wave_rankings_match": match,
-        "wave_lost": (expected * passes - wave_done)
-        + (expected * passes - perop_done),
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -1224,11 +865,11 @@ def preheat_soak(
     (``miss_ms``). The off arm runs the same rush with no planner, so
     every first access is cold.
 
-    Gates (CLI exit / bench re-emission): ``preheat_cold_p50_ms``
+    Gates (CLI exit): ``preheat_cold_p50_ms``
     strictly below ``preheat_cold_p50_ms_nopreheat``, zero lost
     downloads, the sweep's forecast→plan→job→seed-trigger spans linked
     into ONE dftrace timeline, and zero steady-state retraces on the
-    forecast path (measured with the same compile tap bench.py uses).
+    forecast path (``hack.dfanalyze.jitwitness.compile_tap``).
     """
     from dragonfly2_tpu.preheat.demand import DemandWindow
     from dragonfly2_tpu.preheat.forecast import DemandForecaster
@@ -2518,28 +2159,6 @@ def main(argv=None) -> int:
     p.add_argument("--data-plane-duration", type=float, default=10.0,
                    help="seconds of sustained load per arm")
     p.add_argument(
-        "--serving",
-        action="store_true",
-        help="run the batched-vs-per-call scheduler inference soak"
-        " (ROADMAP item 1 acceptance: aggregate schedule_ops_per_s"
-        " strictly above the per-call baseline, zero lost submissions,"
-        " p99 decision latency bounded)",
-    )
-    p.add_argument("--serving-peers", type=int, default=32,
-                   help="concurrent simulated peers for --serving")
-    p.add_argument("--serving-decisions", type=int, default=20,
-                   help="decisions per simulated peer for --serving")
-    p.add_argument(
-        "--wave",
-        action="store_true",
-        help="with --serving: race wave-packed scheduling (evaluate_wave,"
-        " W decisions per fused dispatch) against the per-op-batched arm"
-        " on the same model (wave_decisions_per_s strictly above the"
-        " per-op arm, zero lost, rankings bit-identical to per-peer)",
-    )
-    p.add_argument("--wave-width", type=int, default=8,
-                   help="decisions packed per wave for --wave")
-    p.add_argument(
         "--preheat",
         action="store_true",
         help="run the predictive-preheat soak: forecasted-hot workload"
@@ -2601,12 +2220,11 @@ def main(argv=None) -> int:
             > stats["data_plane_bytes_per_s_buffered"]
         )
         return 0 if ok else 1
-    if args.preheat or args.serving:
-        # the soaks that dispatch jitted work
+    if args.preheat:
+        # the one soak that dispatches jitted work
         from dragonfly2_tpu.utils.jitcache import enable_compile_cache
 
         enable_compile_cache()
-    if args.preheat:
         stats = preheat_soak(tasks=args.preheat_tasks, hot=args.preheat_hot)
         print(json.dumps(stats))
         ok = (
@@ -2614,30 +2232,6 @@ def main(argv=None) -> int:
             and stats["preheat_lost"] == 0
             and stats["preheat_trace_linked"] == 1
             and stats["preheat_retraces"] == 0
-        )
-        return 0 if ok else 1
-    if args.serving and args.wave:
-        stats = wave_soak(
-            peers=args.serving_peers,
-            decisions_per_peer=args.serving_decisions,
-            wave_width=args.wave_width,
-        )
-        print(json.dumps(stats))
-        ok = (
-            stats["wave_decisions_per_s"] > stats["wave_decisions_per_s_per_op"]
-            and stats["wave_lost"] == 0
-            and stats["wave_rankings_match"] == 1
-        )
-        return 0 if ok else 1
-    if args.serving:
-        stats = serving_soak(
-            peers=args.serving_peers, decisions_per_peer=args.serving_decisions
-        )
-        print(json.dumps(stats))
-        ok = (
-            stats["schedule_ops_per_s"] > stats["schedule_ops_per_s_per_call"]
-            and stats["serving_lost"] == 0
-            and stats["schedule_decision_p99_us"] <= stats["serving_p99_bound_us"]
         )
         return 0 if ok else 1
     if args.chaos and args.shard_kill:

@@ -96,7 +96,7 @@ class StreamStats:
     # longer serializes into the superbatch wall:
     # h2d_overlap_s — the portion of h2d_s spent while the step stage
     # was busy, i.e. transfer wall HIDDEN behind device compute
-    # (h2d_overlap_s / h2d_s is bench.py's h2d_overlap_pct)
+    # (h2d_overlap_s / h2d_s is h2d_overlap_pct below)
     h2d_s: float = 0.0
     step_s: float = 0.0
     h2d_overlap_s: float = 0.0
@@ -127,8 +127,8 @@ class StreamStats:
     def h2d_overlap_pct(self) -> float:
         """Percentage of the H2D wall hidden behind device steps — the
         overlapped pipeline's direct measure, shared by every artifact
-        that reports it (bench.py, soak_ingest, multichip_fit) so the
-        key can never drift between them."""
+        that reports it (soak_ingest, multichip_fit) so the key can
+        never drift between them."""
         return (
             round(100.0 * self.h2d_overlap_s / self.h2d_s, 1) if self.h2d_s else 0.0
         )

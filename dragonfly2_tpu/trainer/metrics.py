@@ -54,10 +54,9 @@ INGEST_STEP_SECONDS = _r.histogram(
     "Compiled train-step dispatch + prior-step confirmation, per superbatch",
     buckets=_INGEST_BUCKETS,
 )
-# the packing thread blocked on the superbatch pool — the single
-# largest wall component in BENCH_r06 (~79%), live per superbatch like
-# its decode_wait/h2d/step siblings, exemplars carrying the fit's
-# trace_id the same way
+# the packing thread blocked on the superbatch pool, live per
+# superbatch like its decode_wait/h2d/step siblings, exemplars carrying
+# the fit's trace_id the same way
 INGEST_BUFFER_WAIT_SECONDS = _r.histogram(
     "trainer_ingest_buffer_wait_seconds",
     "Packing thread blocked on the superbatch buffer pool, per superbatch",

@@ -138,8 +138,8 @@ class EventType:
         self._dropbox = recorder._dropboxes[self.category]
 
     def __call__(self, **fields) -> None:
-        # every line here is hot-path budget (bench.py recorder_emit_us /
-        # recorder_overhead_pct): the ring holds a plain tuple around the
+        # every line here is hot-path budget (one call per scheduling
+        # decision, always on): the ring holds a plain tuple around the
         # kwargs dict Python already built — the event dict shape is
         # assembled lazily at snapshot/dump time, where cost is free
         if not _enabled:

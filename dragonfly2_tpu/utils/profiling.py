@@ -10,8 +10,7 @@ Two instruments, both cheap enough to leave on in production:
   last N seconds" (the window flight-recorder dumps attach, so a wedged
   fit names its hot frames in the postmortem). Node growth past
   ``DF_PROF_NODES`` drop-counts instead of allocating, like a full
-  flight ring. bench.py's ``prof_overhead_pct`` keeps the whole sweep
-  under 2% of one core at the configured rate.
+  flight ring.
 
 - **Phase ledger**: named wall-clock phases declared once per module
   (``PH = profiling.phase_type("trainer.buffer_wait")``) and accounted

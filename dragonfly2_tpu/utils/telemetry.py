@@ -223,8 +223,8 @@ class TelemetryReporter:
         self._stop = threading.Event()
         self._thread: "threading.Thread | None" = None
 
-    # the payload builder is also the bench surface (bench.py
-    # telemetry_push_overhead_pct charges exactly this per push)
+    # all of a push's own cost (snapshot + delta + encode) is in here:
+    # the part a caller times to price the reporter
     def build_payload(self) -> tuple[dict, dict]:
         """(payload, full_cumulative_snapshot) for one push."""
         cur = registry_snapshot(self.registry, self.prefixes)

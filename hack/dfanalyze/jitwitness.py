@@ -84,7 +84,9 @@ def _note_compile(name: str, sig: str) -> None:
 class _CompileLogHandler(logging.Handler):
     """Parses pxla's per-compilation record. Message shape (stable since
     the pjit unification): ``Compiling <name> with global shapes and
-    types [<avals>]. Argument mapping: ...``."""
+    types [<avals>]. Argument mapping: ...``; ``<name>`` is the wrapped
+    function's own, or ``jit(<name>)`` as jax 0.9 writes it — recorded
+    bare either way, since the static pass joins on the function name."""
 
     def emit(self, record: logging.LogRecord) -> None:
         try:
@@ -97,6 +99,8 @@ class _CompileLogHandler(logging.Handler):
         name, sep, tail = rest.partition(" with global shapes and types ")
         if not sep:
             return
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[len("jit("):-1]
         sig = tail.split(". Argument mapping", 1)[0]
         _note_compile(name, sig)
 
@@ -309,9 +313,10 @@ def dump(path: str | None = None) -> str:
     return path
 
 
-# -- bench taps --------------------------------------------------------------
-# Lightweight context managers for bench.py's jit-hygiene keys: count
-# compiles and host→device conversions over a measured region without
+# -- taps --------------------------------------------------------------------
+# Lightweight context managers for the steady-state checks of tests and
+# soaks (tools/stress.preheat_soak, tools/multichip_fit): count
+# compiles and host→device conversions over a region without
 # installing the full witness (no jax.jit patch, no site attribution).
 # The counts are the taps' own: the live series
 # (trainer_jit_recompiles_total, the trainer.jit_compile phase) are fed

@@ -1107,10 +1107,10 @@ def test_jit_witness_report_flag_requires_dump(fakepkg, capsys):
 
 
 def test_bench_taps_count_compiles_and_h2d():
-    """The bench taps (compile_tap/transfer_tap) behind bench.py's
-    jit_recompiles_per_fit and h2d_transfers_per_superbatch keys: a
-    fresh shape compiles and counts, a warm shape counts zero, and the
-    H2D tap sees exactly the numpy→device conversions."""
+    """The taps (compile_tap/transfer_tap) behind the steady-state
+    checks of tests and soaks: a fresh shape compiles and counts, a
+    warm shape counts zero, and the H2D tap sees exactly the
+    numpy→device conversions."""
     pytest.importorskip("jax")
     import numpy as np
 

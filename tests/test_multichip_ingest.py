@@ -255,7 +255,7 @@ def test_training_raises_when_the_fit_mesh_cannot_be_built(tmp_path, monkeypatch
 
 
 # ---------------------------------------------------------------------------
-# the subprocess harness (bench's multichip_scaling backend)
+# the subprocess harness (tools/multichip_fit)
 # ---------------------------------------------------------------------------
 
 
@@ -263,8 +263,7 @@ def test_multichip_fit_subprocess_witness_gates(tmp_path):
     """tools/multichip_fit in a fresh process with forced host-platform
     devices: the dp=2 fit must report exactly one H2D per device shard
     per superbatch (no double upload via resharding) and ZERO device
-    feeds from the packing thread — the ISSUE 15 dispatch-plane gates,
-    exactly as bench.py's multichip_scaling_bench runs them."""
+    feeds from the packing thread — the ISSUE 15 dispatch-plane gates."""
     env = {
         k: v
         for k, v in os.environ.items()

@@ -280,49 +280,6 @@ class TestFilterRules:
         got, found = sched.find_candidate_parents(child)
         assert not found
 
-    def test_wave_finder_matches_per_peer(self):
-        """``find_candidate_parents_wave`` keeps per-peer semantics
-        exactly: same filtering, same ranking, same candidate limit —
-        and a peer in the wrong state or with nothing after filtering
-        contributes ([], False) without disturbing its siblings."""
-        t, child, sched = self._setup()
-        parents = [running_parent(i, t) for i in range(1, 8)]
-        for i, p in enumerate(parents):
-            p.finished_pieces |= set(range(i + 1))
-        # a second schedulable child on its own host, and one in the
-        # wrong state
-        child2 = make_peer(20, t, make_host(20))
-        child2.fsm.event(res.PEER_EVENT_REGISTER_NORMAL)
-        child3 = make_peer(30, t, make_host(30))
-        child3.fsm.event(res.PEER_EVENT_REGISTER_NORMAL)
-        child3.fsm.event(res.PEER_EVENT_DOWNLOAD_BACK_TO_SOURCE)
-
-        wave = sched.find_candidate_parents_wave([child, child3, child2])
-        one = sched.find_candidate_parents(child)
-        two = sched.find_candidate_parents(child2)
-        assert wave[1] == ([], False)
-        assert [p.id for p in wave[0][0]] == [p.id for p in one[0]]
-        assert [p.id for p in wave[2][0]] == [p.id for p in two[0]]
-        assert wave[0][1] and wave[2][1]
-        assert len(wave[0][0]) == sched.config.candidate_parent_limit
-
-    def test_wave_finder_falls_back_without_wave_evaluator(self):
-        """A plugin evaluator that predates ``evaluate_wave`` still
-        serves the wave finder through the per-decision loop."""
-        t, child, sched = self._setup()
-        running_parent(1, t)
-
-        class LegacyEvaluator:
-            def evaluate_parents(self, parents, c, total):
-                return list(parents)
-
-            def is_bad_node(self, peer):
-                return False
-
-        sched.evaluator = LegacyEvaluator()
-        wave = sched.find_candidate_parents_wave([child])
-        assert wave[0][1] and len(wave[0][0]) == 1
-
 
 class TestScheduleCandidateParents:
     def test_schedules_and_adds_edges(self):

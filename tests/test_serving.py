@@ -607,8 +607,8 @@ def test_gnn_unknown_host_falls_back_per_request(clean_state):
 
 def test_mlp_scorer_zero_retraces_within_bucket(clean_state):
     """Varying candidate counts inside one bucket rung dispatch ONE
-    compiled executable (the jit-witness acceptance, measured with the
-    same compile tap bench.py uses)."""
+    compiled executable (the jit-witness acceptance, measured with
+    ``jitwitness.compile_tap``)."""
     import jax
 
     from hack.dfanalyze import jitwitness

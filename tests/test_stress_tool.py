@@ -173,63 +173,6 @@ def test_shard_kill_cli_gates_on_success(capsys):
     assert parsed["fleet_success_rate"] == 1.0
 
 
-def test_serving_soak_batched_beats_per_call():
-    """Acceptance (ISSUE 13): at ≥32 concurrent simulated peers the
-    batched scoring service's aggregate ``schedule_ops_per_s`` is
-    strictly above the per-call baseline (same model, same candidate
-    sets), zero submissions are lost, and the p99 decision latency
-    stays inside the batching window + single-batch service time
-    (the tool's measured ``serving_p99_bound_us``)."""
-    stats = stress.serving_soak(peers=32, decisions_per_peer=15)
-    assert stats["serving_lost"] == 0, stats
-    assert (
-        stats["schedule_ops_per_s"] > stats["schedule_ops_per_s_per_call"]
-    ), stats
-    # co-batching really happened: more than one request per batch
-    assert stats["evaluator_batch_occupancy"] > stats["serving_candidates"], stats
-    assert (
-        0 < stats["schedule_decision_p99_us"] <= stats["serving_p99_bound_us"]
-    ), stats
-    json.dumps(stats)  # one JSON-serializable line
-
-
-def test_serving_soak_cli_gates(capsys):
-    rc = stress.main(["--serving", "--serving-peers", "16",
-                      "--serving-decisions", "10"])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    parsed = json.loads(line)
-    assert rc == 0, parsed
-    assert parsed["serving_lost"] == 0
-
-
-def test_wave_soak_packed_beats_per_op():
-    """Acceptance (ISSUE 16): wave-packed scheduling's aggregate
-    ``wave_decisions_per_s`` is strictly above the per-op-batched
-    baseline (same model, same candidate sets), zero submissions are
-    lost, rankings stay bit-identical to a serving-free per-peer
-    evaluator, and the reported occupancy shows whole waves packing
-    (rows per wave > candidates per decision)."""
-    stats = stress.wave_soak(peers=24, decisions_per_peer=12)
-    assert stats["wave_lost"] == 0, stats
-    assert stats["wave_rankings_match"] == 1, stats
-    assert (
-        stats["wave_decisions_per_s"] > stats["wave_decisions_per_s_per_op"]
-    ), stats
-    assert stats["wave_occupancy_rows"] > stats["wave_candidates"], stats
-    assert stats["wave_unpack_p99_us"] > 0, stats
-    json.dumps(stats)  # one JSON-serializable line
-
-
-def test_wave_soak_cli_gates(capsys):
-    rc = stress.main(["--serving", "--wave", "--serving-peers", "16",
-                      "--serving-decisions", "10"])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    parsed = json.loads(line)
-    assert rc == 0, parsed
-    assert parsed["wave_lost"] == 0
-    assert parsed["wave_rankings_match"] == 1
-
-
 def test_soak_ingest_tool_reports_bounded_memory():
     """The soak tool streams a multi-shard dataset and reports flat RSS
     (working set independent of decoded bytes — the 1B-record property).
