@@ -572,6 +572,85 @@ def stream_train_pairs(
                 t0 = _time.perf_counter()
 
 
+@dataclass
+class TrainPairsWalk:
+    """What ``walk_train_pairs`` found, before a pair is copied: a view
+    into the mapping a pair column a ``train`` block (they keep it
+    open), the record count and the pair count. ``assemble`` makes the
+    arrays a fit is handed; whoever needs only the counts (a fit's order
+    is a function of ``num_pairs``: trainer/train.py ``FitOrder``) has
+    them before that."""
+
+    features: list  # a block's [m, F] float32
+    labels: list  # a block's [m] float32
+    download_index: list  # a block's [m] int32, 0-based within its block's records
+    bases: list  # records before each block: its indices' base
+    num_downloads: int = 0
+    num_pairs: int = 0
+
+    def assemble(self):
+        """The blocks' pairs concatenated → ``PairExamples``: each pair
+        copied once, into the array the caller is handed (the first
+        touch and fill of what an upload holds: 4.6 GB for a week's
+        pairs, copied without the interpreter lock)."""
+        from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
+
+        if not self.features:
+            return PairExamples(
+                features=np.zeros((0, MLP_FEATURE_DIM), np.float32),
+                labels=np.zeros((0,), np.float32),
+                download_index=np.zeros((0,), np.int32),
+                num_downloads=self.num_downloads,
+            )
+        # per-block indices are 0-based within their block's record batch —
+        # rebase onto the running record count so the concatenated result
+        # keeps the documented "row in the source batch" invariant instead
+        # of aliasing records across blocks. Each block's indices are rebased
+        # as they are copied into the array the caller is handed: one short
+        # add a block, not a pass over the whole upload under the interpreter
+        # lock (``np.repeat`` of the bases held it 0.2 s at 55M pairs)
+        download_index = np.empty(self.num_pairs, self.download_index[0].dtype)
+        at = 0
+        for base, i in zip(self.bases, self.download_index):
+            np.add(i, base, out=download_index[at : at + len(i)])
+            at += len(i)
+        return PairExamples(
+            features=_concatenate(self.features),
+            labels=_concatenate(self.labels),
+            download_index=download_index,
+            num_downloads=self.num_downloads,
+        )
+
+
+def walk_train_pairs(
+    path: str | os.PathLike,
+    offset: int = 0,
+    end: int | None = None,
+    verify_crc: bool = True,
+    tally: BlockTally | None = None,
+) -> TrainPairsWalk:
+    """One pass over the blocks of ``[offset, end)``: every header
+    parsed, every payload CRC-checked (so this walk is also the round's
+    check of the blocks a newest-first reader, ``read_gru_tail``, hops
+    over), and of each ``train`` block only the three pair columns
+    built, as views into the mapping. Nothing is copied: when it
+    returns, the counts are known and the pairs are still the file's."""
+    walk = TrainPairsWalk([], [], [], [])
+    for header, cols in iter_blocks(
+        path, offset, end, verify_crc=verify_crc, columns=_PAIR_COLUMNS, tally=tally
+    ):
+        if header["kind"] != KIND_TRAIN:
+            continue
+        f, l = _train_tensors(header, cols)
+        walk.features.append(f)
+        walk.labels.append(l)
+        walk.download_index.append(cols["pairs.download_index"])
+        walk.bases.append(walk.num_downloads)
+        walk.num_downloads += int(header.get("records", header["rows"]))
+        walk.num_pairs += len(l)
+    return walk
+
+
 def read_train_pairs(
     path: str | os.PathLike,
     offset: int = 0,
@@ -581,52 +660,8 @@ def read_train_pairs(
 ):
     """Every ``train`` block's pairs, concatenated → ``PairExamples`` —
     the batch read for small datasets (below the streaming threshold),
-    resident fits and federation shards. Only the three pair columns
-    are built, as views into the mapping, and each pair is copied once:
-    into the array the caller is handed. Every block of ``[offset,
-    end)`` is CRC-checked, so this read is also the round's check of
-    the blocks a newest-first reader (``read_gru_tail``) hops over."""
-    from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
-
-    feats, labels, idx = [], [], []
-    bases: list[int] = []  # records before each block: its indices' base
-    records = 0
-    for header, cols in iter_blocks(
-        path, offset, end, verify_crc=verify_crc, columns=_PAIR_COLUMNS, tally=tally
-    ):
-        if header["kind"] != KIND_TRAIN:
-            continue
-        f, l = _train_tensors(header, cols)
-        feats.append(f)
-        labels.append(l)
-        idx.append(cols["pairs.download_index"])
-        bases.append(records)
-        records += int(header.get("records", header["rows"]))
-    if not feats:
-        return PairExamples(
-            features=np.zeros((0, MLP_FEATURE_DIM), np.float32),
-            labels=np.zeros((0,), np.float32),
-            download_index=np.zeros((0,), np.int32),
-            num_downloads=records,
-        )
-    # per-block indices are 0-based within their block's record batch —
-    # rebase onto the running record count so the concatenated result
-    # keeps the documented "row in the source batch" invariant instead
-    # of aliasing records across blocks. Each block's indices are rebased
-    # as they are copied into the array the caller is handed: one short
-    # add a block, not a pass over the whole upload under the interpreter
-    # lock (``np.repeat`` of the bases held it 0.2 s at 55M pairs)
-    download_index = np.empty(sum(len(i) for i in idx), idx[0].dtype)
-    at = 0
-    for base, i in zip(bases, idx):
-        np.add(i, base, out=download_index[at : at + len(i)])
-        at += len(i)
-    return PairExamples(
-        features=_concatenate(feats),
-        labels=_concatenate(labels),
-        download_index=download_index,
-        num_downloads=records,
-    )
+    resident fits and federation shards: the walk, then the assembly."""
+    return walk_train_pairs(path, offset, end, verify_crc, tally).assemble()
 
 
 def _concatenate(parts: list) -> np.ndarray:

@@ -84,11 +84,15 @@ PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
 # four once an epoch, never twice for one piece of work: total / count
 # reads as seconds a fit or seconds an epoch. A phase times the call as
 # the code makes it; epoch_dispatch waits for each of its slices
-# (trainer/train.py), so the device's time lies in it.
+# (trainer/train.py), so the device's time lies in it. A fit's order is
+# drawn ahead of it on threads of its own (trainer/train.py FitOrder):
+# split and gather time what the leg's thread waits for it, order what
+# the drawing threads took, and 1 - (split + gather's wait) / order is
+# the share of the draws that the leg did not sit out.
 FIT_STAGES = (
     "load",  # bytes on disk -> host arrays
-    "split",  # the permutation that sets the holdout apart
-    "gather",  # the epoch's permutation and its row numbers index[perm], each slice of them handed to the device as it is composed
+    "split",  # the wait for the permutation that sets the holdout apart
+    "gather",  # the wait for the epoch's permutation, then its row numbers index[perm], each slice of them handed to the device as it is composed
     "feed",  # the wait for what of the epoch's row numbers had not landed on the device when the gather ended
     "epoch_dispatch",  # the epoch call until it returns: trace, cache look-up, every slice run
     "epoch_wait",  # the read of the epoch's mean loss (on the host once the last slice is in)
@@ -103,6 +107,11 @@ FIT_STAGES = (
     # total / count how long one holds its leg
     "feed_slice",
     "epoch_slice",
+    # a permutation drawn (the holdout's, an epoch's): entered on the drawing
+    # thread, once a permutation, 1 + epochs a fit; its seconds are drawn beside
+    # the leg's, so the ledger and a trace's host plane hold them and no leg's
+    # split does
+    "order",
 )
 
 

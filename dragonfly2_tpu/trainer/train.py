@@ -4,7 +4,8 @@ comments: load from storage → preprocess → train → upload model to manager
 
 Throughput design (north star: 1B records in <10 min on v5e-8):
 - a fit's columns on the chip once a fit, in the caller's order; an
-  epoch is the host's permutation as row numbers, and a device loop
+  epoch is the host's permutation (drawn ahead of the fit, from its row
+  count: FitOrder) as row numbers, and a device loop
   takes each step's batch from the resident table, zero host↔device
   traffic inside it; table, row numbers and epochs go in bounded slices
   (below) so that another tenant of the process is never kept from the
@@ -24,7 +25,9 @@ from __future__ import annotations
 
 import math
 import time
-from dataclasses import dataclass, field
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Any, Callable
 
 import numpy as np
@@ -113,6 +116,86 @@ def _split_eval(n: int, eval_fraction: float, seed: int) -> tuple[np.ndarray, np
     return perm[n_eval:], perm[:n_eval]
 
 
+class FitOrder:
+    """A fit's order, drawn ahead of the fit: the permutation that sets
+    the holdout apart and every epoch's. All of it is a function of the
+    row count ``n`` and of ``cfg`` (``eval_fraction``, ``seed``,
+    ``epochs``) and needs no byte of the data, so it is drawn on threads
+    of its own from the moment it is made (the shuffles run without the
+    interpreter lock), and the fit waits for each permutation only where
+    it uses it: the holdout's and the first epoch's side by side from
+    the start, epoch ``e + 1``'s from when epoch ``e``'s is taken, so
+    one is drawn while the epoch before it runs. Who knows ``n`` before
+    the fit has its arrays makes the order then and hands it to the fit
+    (``Training._train_mlp_from``: beside the load's assembly); a fit
+    handed none makes its own on entry: one path, started sooner or
+    later.
+
+    The numbers are ``_split_eval``'s and ``_permutation``'s with the
+    generators the fits always had: ``default_rng(seed)`` for the
+    holdout, ``default_rng(seed + 1 + epoch)`` for an epoch (a resumed
+    run replays the exact shuffle schedule), or with ``carried`` the one
+    generator ``default_rng(seed + 1)`` handed from each epoch's draw to
+    the next. ``phases.order`` is entered on the drawing thread, once a
+    permutation: its seconds are drawn, not waited, and are in no leg's
+    split. A ``with`` block around the fit ends the threads and drops
+    what was drawn and not taken, whether the fit returns or raises."""
+
+    def __init__(self, phases, n: int, cfg: FitConfig, carried: bool = False):
+        self._drawn_for = (n, cfg.eval_fraction, cfg.seed, cfg.epochs)
+        self._phase, self._epochs, self._seed = phases.order, cfg.epochs, cfg.seed
+        self._n_train = n - int(n * cfg.eval_fraction)
+        self._carry = np.random.default_rng(cfg.seed + 1) if carried else None
+        self._threads = ThreadPoolExecutor(max_workers=2, thread_name_prefix=self._phase.name)
+        self._split = self._threads.submit(self._draw, _split_eval, n, cfg.eval_fraction, cfg.seed)
+        self._ahead: dict = {}  # epoch -> its permutation's future: the one being drawn, or drawn and not taken yet
+        self._start(0)
+
+    def _draw(self, draw, *args):
+        with self._phase:
+            return draw(*args)
+
+    def _start(self, epoch: int) -> None:
+        if epoch < self._epochs and epoch not in self._ahead:
+            rng = self._carry or np.random.default_rng(self._seed + 1 + epoch)
+            self._ahead[epoch] = self._threads.submit(self._draw, _permutation, rng, self._n_train)
+
+    def is_for(self, n: int, cfg: FitConfig) -> bool:
+        """Whether a fit of ``n`` rows under ``cfg`` draws this order."""
+        return self._drawn_for == (n, cfg.eval_fraction, cfg.seed, cfg.epochs)
+
+    def split(self) -> tuple[np.ndarray, np.ndarray]:
+        """``_split_eval(n, eval_fraction, seed)``: the rows to train on
+        and the holdout's, waited for."""
+        return self._split.result()
+
+    def epoch(self, epoch: int) -> np.ndarray:
+        """Epoch ``epoch``'s permutation of the training rows, waited
+        for, and the next epoch's begun. (A fit resumed from a
+        checkpoint asks for a later epoch first: that one is drawn
+        then.)"""
+        self._start(epoch)
+        perm = self._ahead.pop(epoch).result()
+        self._start(epoch + 1)
+        return perm
+
+    def close(self) -> None:
+        """No draw begins after this and none is referenced from here;
+        a thread in the middle of one ends with it. Not waited for: a
+        fit that failed does not sit out a shuffle it will not use."""
+        self._epochs = 0
+        self._threads.shutdown(wait=False, cancel_futures=True)
+        self._split = None
+        self._ahead.clear()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
+
+
 # eval forwards ride the shared memoized jit (utils.jitcache.jit_once);
 # this local cache only keys the (mesh, axis)-specific sharded forward
 _jit_cache: dict = {}
@@ -137,8 +220,11 @@ def _batch_steps(n: int, batch: int) -> tuple[int, int, int]:
 # caller's arrays: the host copies nothing but the last slice's padding),
 # each put landing while the next is handed over and waited for before a
 # third is, and are packed there into the fit's table (_Table), which
-# stays until the fit returns. An epoch is row numbers: the host draws the
-# permutation and composes ``index[perm]`` a slice at a time, the slices
+# stays until the fit returns. An epoch is row numbers: its permutation
+# comes from the fit's order (FitOrder, above: drawn on a thread of its
+# own from the moment the row count is known, so by the time an epoch
+# asks for it the host has it or is an epoch ahead with it), the host
+# composes ``index[perm]`` a slice at a time, the slices
 # go to the chip the same way (4 B a row where the columns themselves
 # went before), and each step takes its batch from the table by them, on
 # the chip. The epoch runs as one dispatch a slice, each waited for before
@@ -307,18 +393,19 @@ def _slice_rows(a: np.ndarray) -> int:
     return max(FEED_SLICE_BYTES // max(a.nbytes // max(len(a), 1), 1), 1)
 
 
-def _gather_slices(index: np.ndarray, rng: np.random.Generator, steps: int, batch: int, row_bytes: int):
+def _gather_slices(index: np.ndarray, drawn: Callable[[], np.ndarray], steps: int, batch: int, row_bytes: int):
     """The epoch's row numbers as host slices ``[k, batch]`` int32 in
     step order, each composed when it is asked for (a generator; the
-    shuffle ``rng.permutation`` of ``index`` on the first): row ``i`` of
-    the epoch is row ``index[perm[i]]`` of the fit's table. ``k`` steps
+    epoch's permutation of ``index``, ``drawn()``, waited for on the
+    first): row ``i`` of the epoch is row ``index[perm[i]]`` of the
+    fit's table. ``k`` steps
     take ``FEED_SLICE_BYTES`` from a table of ``row_bytes`` a row at
     most; no index is longer than a slice (numpy checks every index
     under the interpreter lock before it copies without it, 0.11 s for
     an epoch's 49.5M); all slices have ``k`` steps, the last row 0 past
     the epoch's end."""
     k = _slice_steps(steps, batch * row_bytes)
-    perm = _permutation(rng, len(index))
+    perm = drawn()
     for lo in range(0, steps, k):
         rows = np.zeros(k * batch, np.int32)
         part = perm[lo * batch : min(lo + k, steps) * batch]
@@ -334,7 +421,7 @@ def _feed_slices(mesh, host, table: _Table, steps: int, phases, axis: str = "dp"
     replicated rather than fail the fit; one small fit doesn't need
     parallelism). Returns the epoch of ``steps`` steps over ``table``
     as ``make_epoch_fn`` takes it. ``phases`` is the leg's: ``gather``
-    times the loop (the permutation, the row numbers, their puts),
+    times the loop (the wait for the permutation, the row numbers, their puts),
     ``feed_slice`` is fed what each slice's put and wait took of it,
     ``feed`` times the wait for what had not landed when it ended."""
     fed: list = []
@@ -472,72 +559,80 @@ def train_mlp(
     labels: np.ndarray,
     mesh=None,
     config: FitConfig | None = None,
+    order: FitOrder | None = None,
 ) -> FitResult:
     """Fit the pair scorer: features [N, F] → label log piece cost [N].
 
     Evaluation metrics are MSE/MAE, matching what the manager stores with
     an MLP model upload (reference manager_server_v1.go:847-851).
+    ``order`` is the fit's order where the caller began it before it had
+    the arrays (``FitOrder``); handed none, or one that was drawn for
+    other than these ``N`` rows and this ``config``, the fit begins its
+    own here.
     """
     cfg = config or FitConfig()
     n, f = features.shape
-    with PH_MLP.split:
-        train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
-    steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
+    if order is not None and not order.is_for(n, cfg):
+        # row numbers past the table would be clamped on the chip, not refused
+        order.close()
+        order = None
+    with order or FitOrder(PH_MLP, n, cfg) as order:
+        with PH_MLP.split:
+            train_idx, eval_idx = order.split()
+        steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
 
-    key = jax.random.PRNGKey(cfg.seed)
-    params = mlp_mod.init_mlp(key, [f, *cfg.hidden_dims, 1])
-    # warm-start the output bias at the label mean — the regression head
-    # starts unbiased instead of spending its first epochs drifting there
-    params["layers"][-1]["b"] = _head_bias(labels)
-    if mesh is not None:
-        from dragonfly2_tpu.parallel.sharding import replicate
+        key = jax.random.PRNGKey(cfg.seed)
+        params = mlp_mod.init_mlp(key, [f, *cfg.hidden_dims, 1])
+        # warm-start the output bias at the label mean — the regression head
+        # starts unbiased instead of spending its first epochs drifting there
+        params["layers"][-1]["b"] = _head_bias(labels)
+        if mesh is not None:
+            from dragonfly2_tpu.parallel.sharding import replicate
 
-        params = replicate(mesh, params)
+            params = replicate(mesh, params)
 
-    total_steps = steps * cfg.epochs
-    optimizer = _optimizer(cfg, total_steps)
-    opt_state = optimizer.init(params)
+        total_steps = steps * cfg.epochs
+        optimizer = _optimizer(cfg, total_steps)
+        opt_state = optimizer.init(params)
 
-    def mlp_loss(p, batch):
-        x, y = batch
-        pred = mlp_mod.score_parents(p, x)
-        return jnp.mean((pred - y) ** 2)
+        def mlp_loss(p, batch):
+            x, y = batch
+            pred = mlp_mod.score_parents(p, x)
+            return jnp.mean((pred - y) ** 2)
 
-    epoch_fn = make_epoch_fn(mlp_loss, optimizer)
-    table = _put_table(mesh, PH_MLP, features, labels)  # once a fit: every epoch and the holdout take from it
+        epoch_fn = make_epoch_fn(mlp_loss, optimizer)
+        table = _put_table(mesh, PH_MLP, features, labels)  # once a fit: every epoch and the holdout take from it
 
-    ckpt, start_epoch = _open_checkpoint(cfg)
-    try:
-        if ckpt is not None and start_epoch > 0:
-            restored = ckpt.restore_latest({"params": params, "opt_state": opt_state})
-            if restored is not None:
-                _, state = restored
-                params, opt_state = state["params"], state["opt_state"]
+        ckpt, start_epoch = _open_checkpoint(cfg)
+        try:
+            if ckpt is not None and start_epoch > 0:
+                restored = ckpt.restore_latest({"params": params, "opt_state": opt_state})
+                if restored is not None:
+                    _, state = restored
+                    params, opt_state = state["params"], state["opt_state"]
 
-        history: list[float] = []
-        for epoch in range(start_epoch, cfg.epochs):
-            FP_FIT_STEP()
-            batches = None  # the chip holds one epoch's row numbers: the last go before the next are fed
-            # per-epoch rng: a resumed run replays the exact shuffle schedule
-            rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            host = _gather_slices(train_idx, rng, steps, batch, table.row_bytes)
-            batches = _feed_slices(mesh, host, table, steps, PH_MLP)
-            with PH_MLP.epoch_dispatch:
-                params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
-            with PH_MLP.epoch_wait:
-                history.append(float(mean_loss))
-            _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
+            history: list[float] = []
+            for epoch in range(start_epoch, cfg.epochs):
+                FP_FIT_STEP()
+                batches = None  # the chip holds one epoch's row numbers: the last go before the next are fed
+                host = _gather_slices(train_idx, partial(order.epoch, epoch), steps, batch, table.row_bytes)
+                batches = _feed_slices(mesh, host, table, steps, PH_MLP)
+                with PH_MLP.epoch_dispatch:
+                    params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
+                with PH_MLP.epoch_wait:
+                    history.append(float(mean_loss))
+                _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
 
-        metrics = {}
-        if len(eval_idx):
-            with PH_MLP.holdout:
-                metrics = _mlp_error(params, table, eval_idx)
-        _finish_checkpoint(ckpt)
-        ckpt = None
-        return FitResult(params=params, metrics=metrics, history=history)
-    finally:
-        if ckpt is not None:
-            ckpt.close()
+            metrics = {}
+            if len(eval_idx):
+                with PH_MLP.holdout:
+                    metrics = _mlp_error(params, table, eval_idx)
+            _finish_checkpoint(ckpt)
+            ckpt = None
+            return FitResult(params=params, metrics=metrics, history=history)
+        finally:
+            if ckpt is not None:
+                ckpt.close()
 
 
 def _open_checkpoint(cfg: FitConfig):
@@ -628,60 +723,60 @@ def train_gnn(
     """
     cfg = config or GNNFitConfig()
     e = len(graph.edge_src)
-    with PH_GNN.split:
-        train_idx, eval_idx = _split_eval(e, cfg.eval_fraction, cfg.seed)
-    params = _init_gnn(graph, cfg)
-    if mesh is not None:
-        from dragonfly2_tpu.parallel.sharding import replicate
+    with FitOrder(PH_GNN, e, cfg) as order:
+        with PH_GNN.split:
+            train_idx, eval_idx = order.split()
+        params = _init_gnn(graph, cfg)
+        if mesh is not None:
+            from dragonfly2_tpu.parallel.sharding import replicate
 
-        params = replicate(mesh, params)
+            params = replicate(mesh, params)
 
-    node_features = jnp.asarray(graph.node_features)
-    neighbors = jnp.asarray(graph.neighbors)
-    neighbor_mask = jnp.asarray(graph.neighbor_mask)
+        node_features = jnp.asarray(graph.node_features)
+        neighbors = jnp.asarray(graph.neighbors)
+        neighbor_mask = jnp.asarray(graph.neighbor_mask)
 
-    steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
-    optimizer = _optimizer(cfg, steps * cfg.epochs)
-    opt_state = optimizer.init(params)
+        steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
+        optimizer = _optimizer(cfg, steps * cfg.epochs)
+        opt_state = optimizer.init(params)
 
-    def gnn_loss(p, b):
-        src, dst, y = b
-        pred = gnn_mod.forward_edge_rtt(p, node_features, neighbors, neighbor_mask, src, dst)
-        return jnp.mean((pred - y) ** 2)
+        def gnn_loss(p, b):
+            src, dst, y = b
+            pred = gnn_mod.forward_edge_rtt(p, node_features, neighbors, neighbor_mask, src, dst)
+            return jnp.mean((pred - y) ** 2)
 
-    epoch_fn = make_epoch_fn(gnn_loss, optimizer)
-    # never sharded: the edges and their batches are replicated beside the graph
-    table = _put_table(None, PH_GNN, graph.edge_src, graph.edge_dst, graph.edge_rtt_log_ms)
+        epoch_fn = make_epoch_fn(gnn_loss, optimizer)
+        # never sharded: the edges and their batches are replicated beside the graph
+        table = _put_table(None, PH_GNN, graph.edge_src, graph.edge_dst, graph.edge_rtt_log_ms)
 
-    ckpt, start_epoch = _open_checkpoint(cfg)
-    try:
-        if ckpt is not None and start_epoch > 0:
-            restored = ckpt.restore_latest({"params": params, "opt_state": opt_state})
-            if restored is not None:
-                _, state = restored
-                params, opt_state = state["params"], state["opt_state"]
+        ckpt, start_epoch = _open_checkpoint(cfg)
+        try:
+            if ckpt is not None and start_epoch > 0:
+                restored = ckpt.restore_latest({"params": params, "opt_state": opt_state})
+                if restored is not None:
+                    _, state = restored
+                    params, opt_state = state["params"], state["opt_state"]
 
-        history: list[float] = []
-        for epoch in range(start_epoch, cfg.epochs):
-            rng = np.random.default_rng(cfg.seed + 1 + epoch)
-            host = _gather_slices(train_idx, rng, steps, batch, table.row_bytes)
-            batches = _feed_slices(None, host, table, steps, PH_GNN)
-            with PH_GNN.epoch_dispatch:
-                params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
-            with PH_GNN.epoch_wait:
-                history.append(float(mean_loss))
-            _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
+            history: list[float] = []
+            for epoch in range(start_epoch, cfg.epochs):
+                host = _gather_slices(train_idx, partial(order.epoch, epoch), steps, batch, table.row_bytes)
+                batches = _feed_slices(None, host, table, steps, PH_GNN)
+                with PH_GNN.epoch_dispatch:
+                    params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
+                with PH_GNN.epoch_wait:
+                    history.append(float(mean_loss))
+                _maybe_save_tree(ckpt, cfg, epoch, {"params": params, "opt_state": opt_state})
 
-        metrics: dict[str, float] = {}
-        if len(eval_idx):
-            with PH_GNN.holdout:
-                metrics = _gnn_error(params, graph, (node_features, neighbors, neighbor_mask), table, eval_idx)
-        _finish_checkpoint(ckpt)
-        ckpt = None
-        return FitResult(params=params, metrics=metrics, history=history)
-    finally:
-        if ckpt is not None:
-            ckpt.close()
+            metrics: dict[str, float] = {}
+            if len(eval_idx):
+                with PH_GNN.holdout:
+                    metrics = _gnn_error(params, graph, (node_features, neighbors, neighbor_mask), table, eval_idx)
+            _finish_checkpoint(ckpt)
+            ckpt = None
+            return FitResult(params=params, metrics=metrics, history=history)
+        finally:
+            if ckpt is not None:
+                ckpt.close()
 
 
 def train_gnn_sharded(
@@ -701,7 +796,8 @@ def train_gnn_sharded(
     cfg = config or GNNFitConfig()
     e = len(graph.edge_src)
     shards = mesh.shape[axis]
-    _, eval_idx = _split_eval(e, cfg.eval_fraction, cfg.seed)
+    with FitOrder(PH_GNN, e, replace(cfg, epochs=0)) as order:  # full-batch steps: the holdout's draw alone
+        _, eval_idx = order.split()
     params = _init_gnn(graph, cfg)
 
     nf, nbrs, mask, src_all, dst_all, y_all, w_all = gs.pad_graph(graph, shards)
@@ -838,49 +934,50 @@ def train_gru(
     """Fit the next-piece-cost predictor over piece history sequences."""
     cfg = config or FitConfig(hidden_dims=(64,), batch_size=256, epochs=5)
     n, t, f = sequences.shape
-    with PH_GRU.split:
-        train_idx, eval_idx = _split_eval(n, cfg.eval_fraction, cfg.seed)
-    if lengths is None:
-        lengths = np.full((n,), t, np.int32)
+    # one generator for the fit, carried from epoch to epoch: each draw waits for the one before
+    with FitOrder(PH_GRU, n, cfg, carried=True) as order:
+        with PH_GRU.split:
+            train_idx, eval_idx = order.split()
+        if lengths is None:
+            lengths = np.full((n,), t, np.int32)
 
-    key = jax.random.PRNGKey(cfg.seed)
-    params = gru_mod.init_gru(key, f, cfg.hidden_dims[0])
-    params["head"]["layers"][-1]["b"] = _head_bias(labels)
-    if mesh is not None:
-        from dragonfly2_tpu.parallel.sharding import replicate
+        key = jax.random.PRNGKey(cfg.seed)
+        params = gru_mod.init_gru(key, f, cfg.hidden_dims[0])
+        params["head"]["layers"][-1]["b"] = _head_bias(labels)
+        if mesh is not None:
+            from dragonfly2_tpu.parallel.sharding import replicate
 
-        params = replicate(mesh, params)
+            params = replicate(mesh, params)
 
-    steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
-    optimizer = _optimizer(cfg, steps * cfg.epochs)
-    opt_state = optimizer.init(params)
+        steps, _, batch = _batch_steps(len(train_idx), cfg.batch_size)
+        optimizer = _optimizer(cfg, steps * cfg.epochs)
+        opt_state = optimizer.init(params)
 
-    def gru_loss(p, b):
-        x, y, ln = b
-        pred = gru_mod.predict_next_cost(p, x, ln)
-        return jnp.mean((pred - y) ** 2)
+        def gru_loss(p, b):
+            x, y, ln = b
+            pred = gru_mod.predict_next_cost(p, x, ln)
+            return jnp.mean((pred - y) ** 2)
 
-    epoch_fn = make_epoch_fn(gru_loss, optimizer)
-    table = _put_table(mesh, PH_GRU, sequences, labels, lengths)
+        epoch_fn = make_epoch_fn(gru_loss, optimizer)
+        table = _put_table(mesh, PH_GRU, sequences, labels, lengths)
 
-    history: list[float] = []
-    rng = np.random.default_rng(cfg.seed + 1)
-    for _ in range(cfg.epochs):
-        batches = None  # the chip holds one epoch's row numbers: the last go before the next are fed
-        host = _gather_slices(train_idx, rng, steps, batch, table.row_bytes)
-        batches = _feed_slices(mesh, host, table, steps, PH_GRU)
-        with PH_GRU.epoch_dispatch:
-            params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
-        with PH_GRU.epoch_wait:
-            history.append(float(mean_loss))
+        history: list[float] = []
+        for epoch in range(cfg.epochs):
+            batches = None  # the chip holds one epoch's row numbers: the last go before the next are fed
+            host = _gather_slices(train_idx, partial(order.epoch, epoch), steps, batch, table.row_bytes)
+            batches = _feed_slices(mesh, host, table, steps, PH_GRU)
+            with PH_GRU.epoch_dispatch:
+                params, opt_state, mean_loss = epoch_fn(params, opt_state, batches)
+            with PH_GRU.epoch_wait:
+                history.append(float(mean_loss))
 
-    metrics: dict[str, float] = {}
-    if len(eval_idx):
-        with PH_GRU.holdout:
-            pred, y = _take_holdout(jit_once(_gru_holdout), table, eval_idx, PH_GRU, params)
-            err = pred - y
-            metrics = {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
-    return FitResult(params=params, metrics=metrics, history=history)
+        metrics: dict[str, float] = {}
+        if len(eval_idx):
+            with PH_GRU.holdout:
+                pred, y = _take_holdout(jit_once(_gru_holdout), table, eval_idx, PH_GRU, params)
+                err = pred - y
+                metrics = {"mse": float(np.mean(err**2)), "mae": float(np.mean(np.abs(err)))}
+        return FitResult(params=params, metrics=metrics, history=history)
 
 
 def _gru_holdout(params, table: _Table, rows):

@@ -30,6 +30,7 @@ from dragonfly2_tpu.schema.features import build_probe_graph, extract_pair_featu
 from dragonfly2_tpu.trainer.storage import TrainerStorage
 from dragonfly2_tpu.trainer.train import (
     FitConfig,
+    FitOrder,
     GNNFitConfig,
     release_in_pieces,
     train_gnn,
@@ -444,39 +445,41 @@ class Training:
             return self._train_mlp_streaming(
                 host_id, ip, hostname, path, offset, boundary, binary, info
             )
-        with M.PH_MLP.load:
-            if binary:
-                pairs = wire.read_train_pairs(
-                    path, offset=offset, end=boundary, tally=blocks
+        cfg = self._fit_config(self.config.mlp, "mlp", host_id)
+        # the order's threads end with this block, fit or no fit
+        with contextlib.ExitStack() as drawing:
+            order = None
+            with M.PH_MLP.load:
+                if binary:
+                    walk = wire.walk_train_pairs(path, offset=offset, end=boundary, tally=blocks)
+                    # the fit's order needs the pair count and not the pairs:
+                    # it is drawn beside the assembly, the load's last seconds
+                    order = drawing.enter_context(FitOrder(M.PH_MLP, walk.num_pairs, cfg))
+                    pairs = walk.assemble()
+                    del walk
+                else:
+                    # bounded at the round boundary exactly like the binary and
+                    # streaming paths: the in-flight tail past it may be
+                    # truncated by a failed stream, and the offset commit below
+                    # wouldn't cover it anyway
+                    pairs = native.decode_pairs_file(path, offset=offset, end=boundary)
+                    if pairs is None:
+                        recs = [
+                            r
+                            for chunk in self.storage.iter_download_chunks(
+                                host_id, max_bytes=boundary
+                            )
+                            for r in chunk
+                        ]
+                        pairs = extract_pair_features(records_to_columns(recs))
+            if pairs.num_downloads < self.config.min_download_records:
+                raise BelowMinRecords(
+                    f"{pairs.num_downloads} download records for host {host_id}"
+                    f" < min {self.config.min_download_records}"
                 )
-            else:
-                # bounded at the round boundary exactly like the binary and
-                # streaming paths: the in-flight tail past it may be
-                # truncated by a failed stream, and the offset commit below
-                # wouldn't cover it anyway
-                pairs = native.decode_pairs_file(path, offset=offset, end=boundary)
-                if pairs is None:
-                    recs = [
-                        r
-                        for chunk in self.storage.iter_download_chunks(
-                            host_id, max_bytes=boundary
-                        )
-                        for r in chunk
-                    ]
-                    pairs = extract_pair_features(records_to_columns(recs))
-        if pairs.num_downloads < self.config.min_download_records:
-            raise BelowMinRecords(
-                f"{pairs.num_downloads} download records for host {host_id}"
-                f" < min {self.config.min_download_records}"
-            )
-        if pairs.features.shape[0] == 0:
-            raise BelowMinRecords("no trainable (download, parent) pairs")
-        result = train_mlp(
-            pairs.features,
-            pairs.labels,
-            mesh=self.mesh,
-            config=self._fit_config(self.config.mlp, "mlp", host_id),
-        )
+            if pairs.features.shape[0] == 0:
+                raise BelowMinRecords("no trainable (download, parent) pairs")
+            result = train_mlp(pairs.features, pairs.labels, mesh=self.mesh, config=cfg, order=order)
         # the upload's pairs go a slice at a time, not in one free on return
         owned = [pairs.features, pairs.labels, pairs.download_index]
         del pairs

@@ -793,6 +793,33 @@ class TestPairsWrittenOnce:
             _assert_same_array(g, w)
             assert g.flags.c_contiguous and g.flags.writeable and g.flags.owndata
 
+    @pytest.mark.parametrize(
+        "name, which",
+        [("interleaved", "whole"), ("torn", "whole"), ("even", "empty-range"), ("no-train", "whole"), ("ragged", "inner")],
+    )
+    def test_the_walk_counts_what_the_assembly_holds(self, tmp_path, name, which):
+        """The walk knows the pair count and the record count before a
+        pair is copied (its columns are views into the mapping), they are
+        the assembled ``PairExamples``' own, and the walk followed by the
+        assembly is ``read_train_pairs``: non-``train`` blocks between,
+        a torn tail, an empty range, no ``train`` block, an inner cut."""
+        path, extents = _write_upload(tmp_path, name)
+        offset, end = (extents[2][0], extents[2][0]) if which == "empty-range" else _bounds(extents, which)
+        tally = wire.BlockTally()
+        walk = wire.walk_train_pairs(path, offset=offset, end=end, tally=tally)
+        want, records = _reference_pairs(path, offset, end)
+        assert walk.num_downloads == records
+        assert walk.num_pairs == (0 if want is None else len(want[1]))
+        assert (tally.decoded, tally.hopped) == (len(wire.scan_block_extents(path, offset, end)), 0)
+        assert len(walk.bases) == len(walk.features) and not any(v.flags.owndata for v in walk.features if v.size)
+        pairs, whole = walk.assemble(), wire.read_train_pairs(path, offset=offset, end=end)
+        assert pairs.num_downloads == whole.num_downloads == walk.num_downloads
+        assert pairs.features.shape[0] == len(pairs.labels) == len(pairs.download_index) == walk.num_pairs
+        for field, w in zip(("features", "labels", "download_index"), want or (None,) * 3):
+            _assert_same_array(getattr(pairs, field), getattr(whole, field))
+            if w is not None:
+                _assert_same_array(getattr(pairs, field), w)
+
     @pytest.mark.parametrize("columns", [None, ("gru.labels",), ("pairs.features", "pairs.labels"), ()])
     def test_only_the_asked_for_columns_are_built(self, tmp_path, columns):
         path, _ = _write_upload(tmp_path, "interleaved")

@@ -116,7 +116,7 @@ def test_a_step_takes_from_the_table_what_the_host_gathered(leg, slice_steps, sl
     floats[:2] = np.array([0x80000000, 0x7FC12345], np.uint32).view(np.float32)
     index, _ = train_mod._split_eval(700, 0.1, 0)
     table = train_mod._put_table(None, PHASES[leg], *columns)
-    host = train_mod._gather_slices(index, np.random.default_rng(7), 10, 63, table.row_bytes)
+    host = train_mod._gather_slices(index, lambda: train_mod._permutation(np.random.default_rng(7), 630), 10, 63, table.row_bytes)
     epoch = train_mod._feed_slices(None, host, table, 10, PHASES[leg])
     assert len(epoch) == len(columns) and epoch.steps == 10 and epoch.table is table
     k = train_mod._slice_steps(10, 63 * table.row_bytes)
@@ -150,8 +150,9 @@ def test_the_sliced_feed_hands_the_epoch_what_the_single_put_did(slice_bytes, sl
     table_slices = M.PH_MLP.feed_slice.snapshot()["count"] - before["feed_slice"]
     assert table_slices == -(-90 // (max(slice_bytes // 36 // 14, 1) * 14))  # 14 rows of 9 words share 128 lanes
     assert table.packed.shape[0] == table_slices * -(-90 // (table_slices * 14))  # spread evenly, in whole table rows
-    host = train_mod._gather_slices(index, np.random.default_rng(7), 10, 7, table.row_bytes)
-    shapes = {part.shape for part in train_mod._gather_slices(index, np.random.default_rng(7), 10, 7, 36)}
+    drawn = lambda: train_mod._permutation(np.random.default_rng(7), 80)  # noqa: E731
+    host = train_mod._gather_slices(index, drawn, 10, 7, table.row_bytes)
+    shapes = {part.shape for part in train_mod._gather_slices(index, drawn, 10, 7, 36)}
     assert len(shapes) == 1 and iter(host) is host  # one shape; nothing composed until it is asked for
     epoch = train_mod._feed_slices(None, host, table, 10, M.PH_MLP)
     entered = {k: getattr(M.PH_MLP, k).snapshot()["count"] - n for k, n in before.items()}
@@ -183,7 +184,6 @@ def test_the_feed_keeps_two_puts_in_flight_and_no_more(what, monkeypatch):
         return real_wait(tree)
 
     monkeypatch.setattr(train_mod, "FEED_SLICE_BYTES", 7 * 36)  # a step a slice; 7 rows a slice of the table
-    monkeypatch.setattr(train_mod, "_permutation", lambda rng, n: np.arange(n))
     x = np.repeat(np.arange(4, dtype=np.float32), 7 * 8).reshape(28, 2, 4)  # rows 7i to 7i+6 hold i
     table = train_mod._put_table(None, M.PH_MLP, x, x[:, 0, 0].copy())
     monkeypatch.setattr(train_mod, "_on_mesh", put)
@@ -196,7 +196,7 @@ def test_the_feed_keeps_two_puts_in_flight_and_no_more(what, monkeypatch):
         train_mod._put_table(None, M.PH_MLP, np.arange(256, dtype=np.float32).reshape(64, 4))
         assert events == [("put", 0), ("put", 128), ("wait", 0)]
         return
-    host = train_mod._gather_slices(7 * np.arange(4).repeat(7), None, 4, 7, table.row_bytes)  # step i names row 7i
+    host = train_mod._gather_slices(7 * np.arange(4).repeat(7), lambda: np.arange(28), 4, 7, table.row_bytes)  # step i names row 7i
     train_mod._feed_slices(None, host, table, 4, M.PH_MLP)
     want = [("put", 0), ("put", 7), ("wait", 0), ("put", 14), ("wait", 7), ("put", 21), ("wait", 14)]
     assert events[:7] == want and ("wait", 21) in events[7:]
@@ -282,6 +282,106 @@ def test_the_sliced_identity_shuffles_to_numpys_permutation(n, monkeypatch):
         want = np.random.default_rng(seed).permutation(n)
         got = train_mod._permutation(np.random.default_rng(seed), n)
         assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+# a fit's order by leg: its phases, its epochs, whether one generator is carried from epoch to epoch
+ORDERS = {
+    "mlp-1-epoch": (M.PH_MLP, 1, False),
+    "mlp-3-epochs": (M.PH_MLP, 3, False),
+    "gnn": (M.PH_GNN, 4, False),
+    "gru-carried": (M.PH_GRU, 3, True),
+}
+
+
+def _order_as_the_fits_drew_it(n: int, eval_fraction: float, seed: int, epochs: int, carried: bool):
+    """The holdout's split and every epoch's permutation as the fits
+    drew them inline: numpy's own ``permutation``, ``default_rng(seed)``
+    for the split, ``default_rng(seed + 1 + epoch)`` an epoch, or the
+    GRU's one ``default_rng(seed + 1)`` for all its epochs in turn."""
+    perm = np.random.default_rng(seed).permutation(n)
+    n_eval = int(n * eval_fraction)
+    carry = np.random.default_rng(seed + 1)
+    rngs = [carry if carried else np.random.default_rng(seed + 1 + e) for e in range(epochs)]
+    return perm[n_eval:], perm[:n_eval], [rng.permutation(n - n_eval) for rng in rngs]
+
+
+@pytest.mark.parametrize("eval_fraction", [0.0, 0.1])
+@pytest.mark.parametrize("n", [1, 8192, 70_001], ids=["one-row", "a-batch", "70001-rows"])
+@pytest.mark.parametrize("leg", sorted(ORDERS))
+def test_the_order_drawn_ahead_is_the_order_the_fits_drew(leg, n, eval_fraction):
+    """Element for element, for every leg's rule of generators; and the
+    ``order`` phase was entered once a permutation, on a thread that is
+    not this one (this thread's split holds none of it)."""
+    from dragonfly2_tpu.utils import profiling
+
+    phases, epochs, carried = ORDERS[leg]
+    cfg = FitConfig(eval_fraction=eval_fraction, seed=11, epochs=epochs)
+    want_train, want_eval, want_epochs = _order_as_the_fits_drew_it(n, eval_fraction, 11, epochs, carried)
+    before = phases.order.snapshot()["count"]
+    with profiling.split() as mine, train_mod.FitOrder(phases, n, cfg, carried=carried) as order:
+        train_idx, eval_idx = order.split()
+        assert train_idx.dtype == want_train.dtype and np.array_equal(train_idx, want_train)
+        assert np.array_equal(eval_idx, want_eval) and len(eval_idx) == int(n * eval_fraction)
+        for epoch, want in enumerate(want_epochs):
+            got = order.epoch(epoch)
+            assert got.dtype == want.dtype and np.array_equal(got, want)
+    assert phases.order.snapshot()["count"] - before == 1 + epochs
+    assert phases.order.name not in mine
+
+
+def test_an_order_asked_for_a_later_epoch_first_draws_that_one():
+    """A fit resumed from a checkpoint starts at a later epoch: it gets
+    that epoch's own generator, and the next is begun behind it."""
+    cfg = FitConfig(eval_fraction=0.1, seed=3, epochs=4)
+    with train_mod.FitOrder(M.PH_MLP, 1000, cfg) as order:
+        for epoch in (2, 3):
+            assert np.array_equal(order.epoch(epoch), np.random.default_rng(3 + 1 + epoch).permutation(900))
+
+
+def _order_threads() -> list:
+    import threading
+
+    return [t for t in threading.enumerate() if t.name.startswith(M.PH_MLP.order.name)]
+
+
+def _no_order_thread_is_left() -> bool:
+    for t in _order_threads():
+        t.join(timeout=30)
+    return not _order_threads()
+
+
+@pytest.mark.parametrize("epochs", [1, 3])
+def test_train_mlp_fits_the_same_handed_its_order_or_drawing_it_on_entry(epochs):
+    """An order begun before the call (as the round begins it, from the
+    pair count) and one begun on entry: parameters, history and metrics
+    bit for bit; either way no drawing thread outlives the fit."""
+    x, y = _upload()
+    cfg = FitConfig(**{**PARENT_CONFIG, "epochs": epochs})
+    ahead = train_mod.FitOrder(M.PH_MLP, len(x), cfg)
+    ahead.split()  # drawn before the fit is called at all
+    handed = train_mod.train_mlp(x, y, config=cfg, order=ahead)
+    on_entry = train_mod.train_mlp(x, y, config=cfg)
+    assert handed.history == on_entry.history and len(handed.history) == epochs
+    assert handed.metrics == on_entry.metrics and set(handed.metrics) == {"mse", "mae"}
+    got, want = jax.tree_util.tree_leaves(handed.params), jax.tree_util.tree_leaves(on_entry.params)
+    assert len(got) == len(want) > 0
+    for a, b in zip(got, want):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert _no_order_thread_is_left()
+
+
+def test_train_mlp_sets_aside_an_order_drawn_for_another_fit():
+    """Row numbers past the table are clamped on the chip, not refused:
+    an order for another row count or config (a caller that fits a part
+    of what it was handed) is ended and the fit draws its own."""
+    x, y = _upload()
+    cfg = FitConfig(**{**PARENT_CONFIG, "epochs": 1})
+    want = train_mod.train_mlp(x, y, config=cfg)
+    for other in (train_mod.FitOrder(M.PH_MLP, len(x) + 1, cfg), train_mod.FitOrder(M.PH_MLP, len(x), FitConfig(seed=5))):
+        got = train_mod.train_mlp(x, y, config=cfg, order=other)
+        assert got.history == want.history and got.metrics == want.metrics
+        assert other._split is None and not other._ahead  # ended: nothing drawn is referenced from it
+    assert _no_order_thread_is_left()
 
 
 def test_a_slice_holds_both_bounds():

@@ -166,6 +166,63 @@ def test_every_phase_once_a_fit_or_once_an_epoch(round_, leg):
 
 
 @pytest.mark.parametrize("leg", LEGS)
+def test_the_order_is_drawn_beside_the_leg_and_waited_for_inside_it(round_, leg):
+    """A fit's order is drawn on threads of its own, once a permutation
+    (the holdout's and one an epoch): ``order`` moved by ``1 + epochs``
+    in the ledger and is in no leg's split, which holds the waits,
+    ``split`` and ``gather``, as before."""
+    split = round_["outcome"].splits[leg]
+    drawn, seconds = round_["ledger"][f"trainer.{leg}_order"]
+    assert f"trainer.{leg}_order" not in split.phase_n
+    if leg == "mlp" and round_["streaming"]:
+        assert drawn == 0  # the streamed fit draws no order
+        return
+    assert drawn == 1 + EPOCHS[leg] and seconds > 0
+    assert split.phase_n[f"trainer.{leg}_split"] == 1 and split.phase_n[f"trainer.{leg}_gather"] == EPOCHS[leg]
+
+
+def test_a_round_below_its_minimum_leaves_no_drawing_thread(tmp_path, monkeypatch):
+    """The count is known when the walk ends and the order is begun
+    there, before the gate on the records can refuse the round: the
+    draws under way end with their shuffle, no later epoch's is begun,
+    and no thread is left."""
+    from dragonfly2_tpu.trainer.training import BelowMinRecords
+
+    def order_threads():
+        return [t for t in threading.enumerate() if t.name.startswith(M.PH_MLP.order.name)]
+
+    gate, begun, real = threading.Event(), threading.Semaphore(0), train_mod._permutation
+    assemble = wire.TrainPairsWalk.assemble
+
+    def held(rng, n):
+        begun.release()
+        assert gate.wait(timeout=60)
+        return real(rng, n)
+
+    def assemble_beside_both_draws(walk):
+        assert begun.acquire(timeout=30) and begun.acquire(timeout=30)
+        return assemble(walk)
+
+    monkeypatch.setattr(train_mod, "_permutation", held)
+    monkeypatch.setattr(wire.TrainPairsWalk, "assemble", assemble_beside_both_draws)
+    training = _training(tmp_path, False, min_download_records=10**6)
+    host_id = host_id_v2(IP, HOSTNAME)
+    _stage(training.storage, host_id)
+    before = M.PH_MLP.order.snapshot()["count"]
+    try:
+        with pytest.raises(BelowMinRecords, match="< min 1000000"):
+            training._timed_fit("mlp", None, {}, training._train_mlp, host_id, IP, HOSTNAME)
+        drawing = order_threads()
+        assert len(drawing) == 2 and all(t.is_alive() for t in drawing)  # the holdout's and the first epoch's, mid-draw
+    finally:
+        gate.set()
+    for t in drawing:
+        t.join(timeout=30)
+    assert not order_threads()
+    assert M.PH_MLP.order.snapshot()["count"] - before == 2  # of 1 + 2 epochs: the second epoch's was never begun
+
+
+@pytest.mark.parametrize("leg", LEGS)
 def test_phases_cover_the_fit_wall(round_, leg):
     """A leg's phases sum to its wall but for its own bookkeeping
     (parameter and optimizer init, the configuration): what is left is
@@ -364,6 +421,7 @@ def test_a_round_with_profile_dir_is_one_trace(tmp_path):
     names = _host_event_names(str(prof / "round"))
     for leg in LEGS:
         assert f"trainer.{leg}_gather" in names
+        assert f"trainer.{leg}_order" in names  # from the drawing threads
         assert f"trainer.{leg}_epoch_wait" in names
         assert any(n.startswith(f"PjitFunction({leg}_epoch)") for n in names), leg
     assert not any(n.startswith("PjitFunction(epoch)") for n in names)
