@@ -29,6 +29,28 @@ from dragonfly2_tpu.ops.segment import aggregate_neighbors
 Params = dict
 
 
+class NodeIds(tuple):
+    """The host ids a fitted ``node_embed`` was learned for, row by row.
+
+    A version's rows mean nothing without them: the fit interns hosts in
+    the order its upload names them, the scheduler's live graph in the
+    order its probes arrived, and ``apply_graphsage`` joins the rows to a
+    graph by position. They ride the parameter tree under ``node_ids`` as
+    a node with no leaves (the ids are the tree's structure, not its
+    numbers), so every walk over a version's arrays — ``tree_map`` to the
+    host, a norm per leaf, the serializer's flatten — sees the same
+    leaves as before; ``trainer.serving`` writes and reads them beside
+    the arrays, and ``GNNScorer`` takes them out before anything is
+    traced."""
+
+    __slots__ = ()
+
+
+jax.tree_util.register_pytree_node(
+    NodeIds, lambda ids: ((), tuple(ids)), lambda ids, _: NodeIds(ids)
+)
+
+
 def init_graphsage(
     key: jax.Array,
     in_dim: int,

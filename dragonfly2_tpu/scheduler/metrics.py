@@ -136,6 +136,16 @@ DECISION_RUNG_TOTAL = _r.counter(
     "Decisions ranked, by the ladder rung that ranked each one",
     ("rung",),  # serving | mlp | base
 )
+GNN_INSTALL_TOTAL = _r.counter(
+    "scheduler_gnn_install_total",
+    "GraphSAGE swaps into the batched serving slot, by how each ended",
+    ("result",),  # ok | failed | skipped (no probe-graph source, or a graph too small to embed)
+)
+GNN_ROWS_TOTAL = _r.counter(
+    "scheduler_gnn_rows_total",
+    "Learned node rows of installed GraphSAGE versions, by what became of each on the live graph",
+    ("row",),  # placed (by host id) | default (a host the version never saw) | dropped (a host that left)
+)
 
 # -- wave scheduling (scheduler/wave.py, docs/serving.md "wave
 # scheduling"): W decisions × C candidates packed into one scoring

@@ -20,6 +20,7 @@ import threading
 from dragonfly2_tpu.rpc import gen  # noqa: F401
 import manager_pb2  # noqa: E402
 
+from dragonfly2_tpu.scheduler import metrics as M
 from dragonfly2_tpu.scheduler.evaluator import MLEvaluator
 from dragonfly2_tpu.trainer.serving import (
     BUCKET_LADDER,
@@ -27,9 +28,19 @@ from dragonfly2_tpu.trainer.serving import (
     bucket_rows,
     deserialize_params_auto,
 )
-from dragonfly2_tpu.utils import dflog
+from dragonfly2_tpu.utils import dflog, profiling
 
 logger = dflog.get("scheduler.model_refresher")
+
+# a GraphSAGE swap, by step: the whole of it (weights fetched, graph
+# read and built, rows placed, embeddings computed, every rung warmed,
+# the slot swapped), and inside it the walk of the engine's edges into
+# records, the graph build from them, and the scorer's construction
+# until its embeddings are ready on the chip
+PH_GNN_INSTALL = profiling.phase_type("scheduler.gnn_install")
+PH_GNN_EXPORT = profiling.phase_type("scheduler.gnn_export")
+PH_GNN_GRAPH_BUILD = profiling.phase_type("scheduler.gnn_graph_build")
+PH_GNN_EMBED = profiling.phase_type("scheduler.gnn_embed")
 
 
 def _serving_rungs(serving) -> list[int]:
@@ -190,16 +201,19 @@ class ModelRefresher:
         if key == self.loaded_gnn_version:
             return False
         try:
-            w = self.manager.GetModelWeights(
-                manager_pb2.GetModelRequest(model_id=m.model_id, version=m.version)
-            )
-            scorer = self._build_gnn_scorer(deserialize_params_auto(w.weights))
-            if scorer is None:
-                return False
-            from dragonfly2_tpu.scheduler.serving import GNNServed
+            with PH_GNN_INSTALL:
+                w = self.manager.GetModelWeights(
+                    manager_pb2.GetModelRequest(model_id=m.model_id, version=m.version)
+                )
+                scorer = self._build_gnn_scorer(deserialize_params_auto(w.weights))
+                if scorer is None:
+                    M.GNN_INSTALL_TOTAL.labels("skipped").inc()
+                    return False
+                from dragonfly2_tpu.scheduler.serving import GNNServed
 
-            self.serving.install(GNNServed(scorer), version=f"{key[0]}/v{key[1]}")
+                self.serving.install(GNNServed(scorer), version=f"{key[0]}/v{key[1]}")
         except Exception as e:
+            M.GNN_INSTALL_TOTAL.labels("failed").inc()
             logger.warning(
                 "loading gnn %s v%d failed (%s); keeping previous serving model",
                 m.model_id,
@@ -207,15 +221,21 @@ class ModelRefresher:
                 e,
             )
             return False
+        M.GNN_INSTALL_TOTAL.labels("ok").inc()
+        for row, count in scorer.rows.items():
+            M.GNN_ROWS_TOTAL.labels(row).inc(count)
         self.loaded_gnn_version = key
         logger.info(
-            "installed gnn %s v%d as the batched serving model", m.model_id, m.version
+            "installed gnn %s v%d as the batched serving model (rows %s)",
+            m.model_id, m.version, scorer.rows,
         )
         return True
 
     def _build_gnn_scorer(self, params):
         """Probe graph → swap-time-embedded GNNScorer (None when the
-        graph can't embed yet: no topology source or < 2 hosts)."""
+        graph can't embed yet: no topology source or < 2 hosts). The
+        live graph is not the one the version was fitted on: the scorer
+        places the learned rows on it by host id."""
         if self.networktopology is None:
             logger.info("gnn active but no probe-graph source; not serving it")
             return None
@@ -223,12 +243,15 @@ class ModelRefresher:
         from dragonfly2_tpu.schema.features import build_probe_graph
         from dragonfly2_tpu.trainer.serving import GNNScorer
 
-        records = self.networktopology.export_records()
-        graph = build_probe_graph(records_to_columns(records)) if records else None
+        with PH_GNN_EXPORT:
+            records = self.networktopology.export_records()
+        with PH_GNN_GRAPH_BUILD:
+            graph = build_probe_graph(records_to_columns(records)) if records else None
         if graph is None or graph.num_nodes < 2:
             logger.info("probe graph too small to embed; not serving the gnn")
             return None
-        scorer = GNNScorer(params, graph)
+        with PH_GNN_EMBED:
+            scorer = GNNScorer(params, graph)
         # compile + sanity-check at swap time, like the MLP install
         for rows in _serving_rungs(self.serving):
             scorer.predict_rtt_log_ms(

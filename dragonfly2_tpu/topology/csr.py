@@ -126,8 +126,12 @@ class AdjacencyStore:
         age = np.zeros(edge_cap, dtype=np.float32)
         valid = np.zeros(edge_cap, dtype=np.float32)
         if e:
-            keys = np.array(sorted(self.edges), dtype=np.int64)  # CSR order
-            vals = np.array([self.edges[(s, d)] for s, d in keys], dtype=np.float64)
+            # the caller holds the engine's lock: two C-level walks of the
+            # dict and a sort in numpy, no Python per edge
+            keys = np.array(list(self.edges), dtype=np.int64)
+            vals = np.array(list(self.edges.values()), dtype=np.float64)
+            csr = np.lexsort((keys[:, 1], keys[:, 0]))  # CSR order: by src, then dst
+            keys, vals = keys[csr], vals[csr]
             src[:e] = keys[:, 0]
             dst[:e] = keys[:, 1]
             rtt[:e] = np.log1p(np.maximum(vals[:, 0], 0.0) / NS_PER_MS)

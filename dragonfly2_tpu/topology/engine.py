@@ -206,8 +206,8 @@ class TopologyEngine:
             arr["edge_dst"][:e], minlength=ncap
         )
         live = np.zeros(ncap, dtype=bool)
-        for i, hid in enumerate(self.store.ids):
-            live[i] = bool(hid)  # tombstoned hosts keep their slot, not their rank
+        ids = self.store.ids  # tombstoned hosts keep their slot, not their rank
+        live[: len(ids)] = np.fromiter(map(bool, ids), bool, len(ids))
         deg = np.where(live, deg, -1)
         L = self.cfg.num_landmarks
         order = np.argsort(-deg, kind="stable")[:L]
@@ -589,56 +589,64 @@ class TopologyEngine:
         # _lock; the reverse would ABBA-deadlock with a concurrent
         # flusher, e.g. the 30s GC flush task)
         self.flush()
+        # the hold is the copy alone (two C-level walks, no Python per
+        # edge): the walk below runs outside it, so a decision's rtt join
+        # (rtt_affinity_pairs takes this lock) never waits for an export.
+        # An edge's [avg, updated] list is shared with the store; a probe
+        # folded in meanwhile shows as the newer measurement, which is
+        # what the next export would carry anyway
         with self._lock:
-            by_src: dict[int, list[tuple[int, list[float]]]] = {}
-            for (s, d), v in self.store.edges.items():
-                by_src.setdefault(s, []).append((d, [v[0], v[1]]))
+            edges = list(self.store.edges.items())
+            ids = list(self.store.ids)
+        by_src: dict[int, list[tuple[int, list[float]]]] = {}
+        for (s, d), v in edges:
+            by_src.setdefault(s, []).append((d, [v[0], v[1]]))
 
-            out = []
-            now_ns = int(time.time() * 1e9)
-            for s, dests in by_src.items():
-                sh = host_manager.load(self.store.ids[s])
-                if sh is None:
+        out = []
+        now_ns = int(time.time() * 1e9)
+        for s, dests in by_src.items():
+            sh = host_manager.load(ids[s])
+            if sh is None:
+                continue
+            dests.sort(key=lambda t: -t[1][1])  # most recently updated first
+            dest_hosts = []
+            for d, v in dests[:dest_limit]:
+                dh = host_manager.load(ids[d])
+                if dh is None:
                     continue
-                dests.sort(key=lambda t: -t[1][1])  # most recently updated first
-                dest_hosts = []
-                for d, v in dests[:dest_limit]:
-                    dh = host_manager.load(self.store.ids[d])
-                    if dh is None:
-                        continue
-                    dest_hosts.append(
-                        R.DestHost(
-                            id=dh.id,
-                            type=dh.type.value,
-                            hostname=dh.hostname,
-                            ip=dh.ip,
-                            port=dh.port,
-                            network=dh.network,
-                            probes=R.ProbesRecord(
-                                average_rtt=int(v[0]),
-                                created_at=int(v[1] * 1e9),
-                                updated_at=int(v[1] * 1e9),
-                            ),
-                        )
-                    )
-                if not dest_hosts:
-                    continue
-                out.append(
-                    R.NetworkTopologyRecord(
-                        id=str(uuid.uuid4()),
-                        host=R.SrcHost(
-                            id=sh.id,
-                            type=sh.type.value,
-                            hostname=sh.hostname,
-                            ip=sh.ip,
-                            port=sh.port,
-                            network=sh.network,
+                dest_hosts.append(
+                    R.DestHost(
+                        id=dh.id,
+                        type=dh.type.value,
+                        hostname=dh.hostname,
+                        ip=dh.ip,
+                        port=dh.port,
+                        network=dh.network,
+                        probes=R.ProbesRecord(
+                            average_rtt=int(v[0]),
+                            created_at=int(v[1] * 1e9),
+                            updated_at=int(v[1] * 1e9),
                         ),
-                        dest_hosts=dest_hosts,
-                        created_at=now_ns,
                     )
                 )
-            return out
+            if not dest_hosts:
+                continue
+            out.append(
+                R.NetworkTopologyRecord(
+                    id=str(uuid.uuid4()),
+                    host=R.SrcHost(
+                        id=sh.id,
+                        type=sh.type.value,
+                        hostname=sh.hostname,
+                        ip=sh.ip,
+                        port=sh.port,
+                        network=sh.network,
+                    ),
+                    dest_hosts=dest_hosts,
+                    created_at=now_ns,
+                )
+            )
+        return out
 
     # ------------------------------------------------------------------
     def _note_latency(self, t0: float) -> None:

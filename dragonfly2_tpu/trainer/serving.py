@@ -81,6 +81,61 @@ def _served_gnn_edge(params, emb, src, dst):
     return predict_edge(params, emb, src, dst)
 
 
+def _served_gnn_embed(params, node_features, neighbors, neighbor_mask):
+    """``apply_graphsage`` under a name of its own, as ``_served_mlp``:
+    the GraphSAGE fit runs the same forward every step, and this is what
+    tells a swap's embed from a fit's op in a device trace."""
+    from dragonfly2_tpu.models.gnn import apply_graphsage
+
+    return apply_graphsage(params, node_features, neighbors, neighbor_mask)
+
+
+def node_capacity(n: int) -> int:
+    """The node count a served probe graph is padded to: the power of two
+    at or above ``n``, from 64. The embed's and the edge head's traced
+    shapes follow this and not the live host count, so a host that joins
+    changes neither until the fleet outgrows its rung."""
+    return max(64, 1 << max(n - 1, 0).bit_length())
+
+
+def place_node_rows(params: Any, node_ids: list) -> "tuple[dict, dict]":
+    """A GraphSAGE version's learned rows on another graph than the one
+    it was fitted on: ``params`` without its ``node_ids`` and with
+    ``node_embed`` one row a node of ``node_ids``, in that order, each
+    row the one fitted for that host's id. A host the version never saw
+    gets the zero row (``init_graphsage``: the embedding localizes a
+    known host; an unknown one is placed by its features alone); a host
+    that has left is dropped. Returns the tree and how many rows were
+    ``placed``, ``default`` and ``dropped``.
+
+    A tree that names no hosts (seeded weights made for this very graph,
+    a version registered before versions carried ids) is taken to be in
+    the graph's own order, and refused when the counts differ."""
+    params = dict(params)
+    fitted = params.pop("node_ids", None)
+    embed = params.get("node_embed")
+    n = len(node_ids)
+    if embed is None:
+        return params, {"placed": 0, "default": 0, "dropped": 0}
+    embed = np.asarray(embed, np.float32)
+    if fitted is None:
+        if embed.shape[0] != n:
+            raise ValueError(
+                f"node_embed has {embed.shape[0]} rows and names no hosts; the graph has {n} nodes"
+            )
+        return params, {"placed": n, "default": 0, "dropped": 0}
+    if len(fitted) != embed.shape[0]:
+        raise ValueError(f"node_embed has {embed.shape[0]} rows for {len(fitted)} node_ids")
+    row_of = {hid: i for i, hid in enumerate(fitted)}
+    take = np.fromiter((row_of.get(hid, -1) for hid in node_ids), np.int64, n)
+    seen = take >= 0
+    placed = np.zeros((n, embed.shape[1]), np.float32)
+    placed[seen] = embed[take[seen]]
+    params["node_embed"] = placed
+    known = int(seen.sum())
+    return params, {"placed": known, "default": n - known, "dropped": len(row_of) - known}
+
+
 def _device_params(params: Any) -> Any:
     """Pin a parameter pytree on device ONCE, at scorer construction.
     The deserialized pytree is numpy, and feeding numpy leaves into a
@@ -102,9 +157,21 @@ def serialize_params(params: Any) -> bytes:
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
         arrays[key] = np.asarray(leaf)
+    if isinstance(params, dict) and params.get("node_ids") is not None:
+        # models.gnn.NodeIds: part of the tree's structure, no leaf of it
+        arrays["node_ids"] = np.asarray(list(params["node_ids"]), dtype=np.str_)
     buf = io.BytesIO()
     np.savez(buf, **arrays)
     return buf.getvalue()
+
+
+def _with_node_ids(tree: Any, z) -> Any:
+    """``tree`` with the host ids ``serialize_params`` wrote beside its arrays."""
+    if isinstance(tree, dict) and "node_ids" in z.files:
+        from dragonfly2_tpu.models.gnn import NodeIds
+
+        tree["node_ids"] = NodeIds(z["node_ids"].tolist())
+    return tree
 
 
 def deserialize_params(blob: bytes, like: Any) -> Any:
@@ -117,7 +184,7 @@ def deserialize_params(blob: bytes, like: Any) -> Any:
         for path, leaf in flat_like:
             key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
             leaves.append(z[key])
-        return jax.tree_util.tree_unflatten(treedef, leaves)
+        return _with_node_ids(jax.tree_util.tree_unflatten(treedef, leaves), z)
 
 
 def deserialize_params_auto(blob: bytes) -> Any:
@@ -125,14 +192,6 @@ def deserialize_params_auto(blob: bytes) -> Any:
     alone (all-integer dict levels become lists). The serving side needs
     this because a downloaded model's layer count/dims aren't known until
     the weights arrive."""
-    with np.load(io.BytesIO(blob)) as z:
-        tree: dict = {}
-        for key in z.files:
-            node = tree
-            parts = key.split("/")
-            for part in parts[:-1]:
-                node = node.setdefault(part, {})
-            node[parts[-1]] = z[key]
 
     def listify(node):
         if not isinstance(node, dict):
@@ -141,7 +200,17 @@ def deserialize_params_auto(blob: bytes) -> Any:
             return [listify(node[k]) for k in sorted(node, key=int)]
         return {k: listify(v) for k, v in node.items()}
 
-    return listify(tree)
+    with np.load(io.BytesIO(blob)) as z:
+        tree: dict = {}
+        for key in z.files:
+            if key == "node_ids":
+                continue
+            node = tree
+            parts = key.split("/")
+            for part in parts[:-1]:
+                node = node.setdefault(part, {})
+            node[parts[-1]] = z[key]
+        return _with_node_ids(listify(tree), z)
 
 
 def _score_ranked(params, packed):
@@ -325,6 +394,14 @@ class GNNScorer:
     pairs by predicted RTT (for seed placement / cross-host ranking, and
     the batched scoring service's GNN rung).
 
+    ``graph`` is the graph being served, which is as a rule not the one
+    ``params`` were fitted on: the learned rows are placed on it by host
+    id (``place_node_rows``; ``rows`` says how many were placed, given
+    the default row, dropped), and its node tables are padded to
+    ``node_capacity`` so that the traced shapes follow the capacity rung
+    and not the host count (a padded node has zero features, no
+    neighbors and is nobody's neighbor).
+
     Embeddings are computed ONCE at construction — swap time in the
     model-refresher's lifecycle — and stay resident on device next to
     the params; per predict only the (src, dst) index vectors move. With
@@ -333,20 +410,28 @@ class GNNScorer:
     so a fleet-scale graph never materializes on one chip."""
 
     def __init__(self, params: Any, graph, mesh=None, axis: str = "gp"):
+        import jax
         import jax.numpy as jnp
 
-        from dragonfly2_tpu.models.gnn import apply_graphsage
-
-        self._params = _device_params(params)
+        params, self.rows = place_node_rows(params, graph.node_ids)
         self._node_index = {hid: i for i, hid in enumerate(graph.node_ids)}
         if mesh is not None and dict(getattr(mesh, "shape", {})).get(axis, 1) > 1:
+            self._params = _device_params(params)
             self._emb = self._sharded_embed(graph, mesh, axis)
         else:
-            self._emb = _jit_once(apply_graphsage)(
-                self._params,
-                jnp.asarray(graph.node_features),
-                jnp.asarray(graph.neighbors),
-                jnp.asarray(graph.neighbor_mask),
+            from dragonfly2_tpu.models.gnn_sharded import pad_node_arrays
+
+            # the padding of the sharded embed, to the capacity rung in
+            # place of a shard multiple (the graph never outgrows its rung)
+            cap = node_capacity(graph.num_nodes)
+            feats, neighbors, mask = pad_node_arrays(graph, cap)
+            if "node_embed" in params:
+                params["node_embed"] = pad_batch(params["node_embed"], cap)
+            self._params = _device_params(params)
+            self._emb = jax.block_until_ready(
+                _jit_once(_served_gnn_embed)(
+                    self._params, jnp.asarray(feats), jnp.asarray(neighbors), jnp.asarray(mask)
+                )
             )
         self._predict = _jit_once(_served_gnn_edge)
 
@@ -372,6 +457,16 @@ class GNNScorer:
 
     def has_host(self, host_id: str) -> bool:
         return host_id in self._node_index
+
+    def node_rows(self) -> "dict[str, np.ndarray]":
+        """host id → the learned row this scorer holds for it (a host
+        copy of the table on the device): what a check that every host
+        of the served graph got the row fitted for its own id reads."""
+        embed = self._params.get("node_embed")
+        if embed is None:
+            return {}
+        embed = np.asarray(embed)
+        return {hid: embed[i] for hid, i in self._node_index.items()}
 
     def predict_rtt_log_ms(
         self, src_ids: list[str], dst_ids: list[str], stages=NO_STAGES

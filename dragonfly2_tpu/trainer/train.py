@@ -773,6 +773,9 @@ def train_gnn(
                     metrics = _gnn_error(params, graph, (node_features, neighbors, neighbor_mask), table, eval_idx)
             _finish_checkpoint(ckpt)
             ckpt = None
+            # the learned rows are one a host, in this graph's order: the
+            # version names them, so that it can be served on another graph
+            params = {**params, "node_ids": gnn_mod.NodeIds(graph.node_ids)}
             return FitResult(params=params, metrics=metrics, history=history)
         finally:
             if ckpt is not None:
@@ -876,6 +879,7 @@ def train_gnn_sharded(
     if embed is not None:
         # slice the padding off on device; transfer only the real rows
         out_params["node_embed"] = np.asarray(embed[: graph.num_nodes])
+    out_params["node_ids"] = gnn_mod.NodeIds(graph.node_ids)
     return FitResult(params=out_params, metrics=metrics, history=history)
 
 
