@@ -54,12 +54,15 @@ CSV instead of mis-decoding.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import mmap
 import os
 import struct
+import time
 import zlib
-from dataclasses import dataclass
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass, field
 from typing import Any, Iterable, Iterator
 
 import numpy as np
@@ -371,9 +374,10 @@ def split_block_spans(
 class BlockTally:
     """What a reader did with the blocks of its range, added up by the
     caller that hands it in: ``decoded`` blocks had their header parsed,
-    their payload CRC-checked and the asked-for columns built;
-    ``hopped`` blocks were stepped over by their 16-byte preamble and
-    nothing of them was read."""
+    their payload CRC-checked (by ``walk_train_pairs``' reader when it
+    assembles them, by every other reader as it decodes) and the
+    asked-for columns built; ``hopped`` blocks were stepped over by
+    their 16-byte preamble and nothing of them was read."""
 
     decoded: int = 0
     hopped: int = 0
@@ -572,11 +576,38 @@ def stream_train_pairs(
                 t0 = _time.perf_counter()
 
 
+# The resident read's assembly (``TrainPairsWalk.assemble``): a span is
+# this many consecutive blocks of the range, of whatever kind, checked and
+# then copied by one worker; a range of more than one span is assembled by
+# this many workers at a time. Span-sized units, because a worker takes
+# the interpreter lock again after every ``crc32`` and every copy: a few
+# microseconds a block, and between them the tens of milliseconds of a
+# span in which it holds no lock. Both chosen on the chip's host (13
+# cores, no huge pages: a first touch is 3.9 us a 4 KiB page) by
+# ``hack/load_spans.py`` over a week's upload (PERF.md §5): the assembly
+# takes 7.8 s on one worker, 4.4-4.7 on two, 3.9-4.0 on three, 4.5-4.7 on
+# four and 6.3-6.6 on six or eight (the page faults of more threads than
+# three get in each other's way); spans of 128, 448 and 896 blocks read
+# alike and spans of 32 a tenth slower.
+ASSEMBLY_SPAN_BLOCKS = 128
+ASSEMBLY_THREADS = 3
+# The walk in front of it is the interpreter's work alone (25 us a block)
+# and would hold the interpreter lock until another thread's switch
+# interval took it, 5 ms at a time in a trainer of its own: the other
+# legs' loads stood for its whole 1.9 s. Once this many blocks (0.8 ms)
+# it offers the lock. Not once a block: beside 16 decision workers every
+# offer costs the walk 0.2 ms of waiting to have it back (once a block
+# was measured: 9 s a round, PERF.md §6, PR 35).
+WALK_OFFER_BLOCKS = 32
+
+
 @dataclass
 class TrainPairsWalk:
-    """What ``walk_train_pairs`` found, before a pair is copied: a view
-    into the mapping a pair column a ``train`` block (they keep it
-    open), the record count and the pair count. ``assemble`` makes the
+    """What ``walk_train_pairs`` found, before a payload byte is read: a
+    view into the mapping a pair column a ``train`` block (they keep it
+    open), the record count and the pair count, and of every block of
+    the range, whatever its kind, where its payload lies and the
+    ``crc32`` its header states. ``assemble`` checks those and makes the
     arrays a fit is handed; whoever needs only the counts (a fit's order
     is a function of ``num_pairs``: trainer/train.py ``FitOrder``) has
     them before that."""
@@ -587,36 +618,90 @@ class TrainPairsWalk:
     bases: list  # records before each block: its indices' base
     num_downloads: int = 0
     num_pairs: int = 0
+    verify_crc: bool = True
+    mapped: memoryview | None = None  # the whole mapping: a payload is a slice of it
+    # every block of the range, whatever its kind, in file order: its first
+    # byte, its payload's, the payload's length, the crc32 its header states
+    blocks: list = field(default_factory=list)
+    trains_before: list = field(default_factory=list)  # the ``train`` blocks before each of ``blocks``
 
-    def assemble(self):
-        """The blocks' pairs concatenated → ``PairExamples``: each pair
-        copied once, into the array the caller is handed (the first
-        touch and fill of what an upload holds: 4.6 GB for a week's
-        pairs, copied without the interpreter lock)."""
+    def assemble(self, span_timer=None):
+        """The blocks' pairs concatenated → ``PairExamples``, and with
+        ``verify_crc`` every block of the range checked against the
+        ``crc32`` its header states: exactly once, here, and all of them
+        before an array is handed to anyone. A corrupt block anywhere in
+        the range, of any kind, raises ``WireError("block crc mismatch
+        at byte N")`` for the first such block in file order, and
+        nothing is returned.
+
+        The range is cut into spans of ``ASSEMBLY_SPAN_BLOCKS`` blocks.
+        A span's worker checks each of its blocks, then copies the
+        span's pairs to their place in the three arrays (each pair
+        copied once, into the array the caller is handed: the first
+        touch and fill of what an upload holds, 4.6 GB for a week's
+        pairs). Check and copy run without the interpreter lock, on
+        bytes no other span touches, so ``ASSEMBLY_THREADS`` spans run
+        side by side; a range of one span runs on the caller's thread:
+        one path, narrower or wider by the number of blocks.
+        ``span_timer``, when given, is called as ``span_timer(seconds)``
+        once a span by the thread that ran it."""
         from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
 
-        if not self.features:
-            return PairExamples(
-                features=np.zeros((0, MLP_FEATURE_DIM), np.float32),
-                labels=np.zeros((0,), np.float32),
-                download_index=np.zeros((0,), np.int32),
-                num_downloads=self.num_downloads,
-            )
-        # per-block indices are 0-based within their block's record batch —
-        # rebase onto the running record count so the concatenated result
-        # keeps the documented "row in the source batch" invariant instead
-        # of aliasing records across blocks. Each block's indices are rebased
-        # as they are copied into the array the caller is handed: one short
-        # add a block, not a pass over the whole upload under the interpreter
-        # lock (``np.repeat`` of the bases held it 0.2 s at 55M pairs)
-        download_index = np.empty(self.num_pairs, self.download_index[0].dtype)
-        at = 0
-        for base, i in zip(self.bases, self.download_index):
-            np.add(i, base, out=download_index[at : at + len(i)])
-            at += len(i)
+        features = np.empty((self.num_pairs, MLP_FEATURE_DIM), np.float32)
+        labels = np.empty((self.num_pairs,), np.float32)
+        download_index = np.empty(
+            (self.num_pairs,), self.download_index[0].dtype if self.download_index else np.int32
+        )
+        lengths = [len(l) for l in self.labels]
+        pairs_before = [0, *itertools.accumulate(lengths)]  # each ``train`` block's offset in the arrays
+        trains_before = [*self.trains_before, len(lengths)]
+
+        def assemble_span(lo: int) -> None:
+            t0 = time.perf_counter()
+            span = self.blocks[lo : lo + ASSEMBLY_SPAN_BLOCKS]
+            if self.verify_crc:
+                for pos, start, nbytes, crc in span:
+                    if zlib.crc32(self.mapped[start : start + nbytes]) & 0xFFFFFFFF != crc:
+                        raise WireError(f"block crc mismatch at byte {pos}")
+            # the span's ``train`` blocks, and where their pairs go
+            t_lo, t_hi = trains_before[lo], trains_before[lo + len(span)]
+            at, end = pairs_before[t_lo], pairs_before[t_hi]
+            if end > at:
+                np.concatenate(self.features[t_lo:t_hi], out=features[at:end])
+                np.concatenate(self.labels[t_lo:t_hi], out=labels[at:end])
+                # per-block indices are 0-based within their block's record batch —
+                # rebase onto the running record count so the concatenated result
+                # keeps the documented "row in the source batch" invariant instead
+                # of aliasing records across blocks. A span's indices are rebased
+                # in the array the caller is handed, by one add over the span: a
+                # few calls a span under the interpreter lock, not a pass over
+                # the whole upload (``np.repeat`` of every block's base held it
+                # 0.2 s at 55M pairs) and not a lock handed over once a block
+                index = download_index[at:end]
+                np.concatenate(self.download_index[t_lo:t_hi], out=index)
+                bases = np.asarray(self.bases[t_lo:t_hi], index.dtype)
+                np.add(index, np.repeat(bases, lengths[t_lo:t_hi]), out=index)
+            if span_timer is not None:
+                span_timer(time.perf_counter() - t0)
+
+        edges = range(0, len(self.blocks), ASSEMBLY_SPAN_BLOCKS)
+        if len(edges) <= 1:
+            for lo in edges:
+                assemble_span(lo)
+        else:
+            # leaving the block waits for the spans under way: when an
+            # error leaves it, no worker writes to the arrays any more
+            with ThreadPoolExecutor(ASSEMBLY_THREADS, thread_name_prefix="wire.assemble") as pool:
+                spans = [pool.submit(assemble_span, lo) for lo in edges]
+                try:
+                    for span in spans:  # in file order: the first corrupt block's error is the one raised
+                        span.result()
+                finally:
+                    for span in spans:
+                        span.cancel()
         return PairExamples(
-            features=_concatenate(self.features),
-            labels=_concatenate(self.labels),
+            features=features,
+            labels=labels,
             download_index=download_index,
             num_downloads=self.num_downloads,
         )
@@ -629,25 +714,44 @@ def walk_train_pairs(
     verify_crc: bool = True,
     tally: BlockTally | None = None,
 ) -> TrainPairsWalk:
-    """One pass over the blocks of ``[offset, end)``: every header
-    parsed, every payload CRC-checked (so this walk is also the round's
-    check of the blocks a newest-first reader, ``read_gru_tail``, hops
-    over), and of each ``train`` block only the three pair columns
-    built, as views into the mapping. Nothing is copied: when it
-    returns, the counts are known and the pairs are still the file's."""
-    walk = TrainPairsWalk([], [], [], [])
-    for header, cols in iter_blocks(
-        path, offset, end, verify_crc=verify_crc, columns=_PAIR_COLUMNS, tally=tally
-    ):
-        if header["kind"] != KIND_TRAIN:
-            continue
-        f, l = _train_tensors(header, cols)
-        walk.features.append(f)
-        walk.labels.append(l)
-        walk.download_index.append(cols["pairs.download_index"])
-        walk.bases.append(walk.num_downloads)
-        walk.num_downloads += int(header.get("records", header["rows"]))
-        walk.num_pairs += len(l)
+    """One pass over the headers of the blocks of ``[offset, end)``:
+    every header parsed, and of each ``train`` block the three pair
+    columns built, as views into the mapping. No payload page is
+    touched and nothing is copied: when it returns, the counts are known,
+    the pairs are still the file's, and no block has been checked yet.
+    The walk notes where each block's payload lies and the ``crc32``
+    its header states, for every block whatever its kind, and
+    ``TrainPairsWalk.assemble`` checks them all (with ``verify_crc``)
+    before it hands over an array: that is the round's check of the
+    blocks a newest-first reader, ``read_gru_tail``, hops over.
+
+    The walk is the interpreter's work from end to end (25 us a block,
+    1.4 s for a week's 53,760 blocks alone), where a walk that also
+    checked gave the interpreter lock up at every ``crc32``: it offers
+    the lock once ``WALK_OFFER_BLOCKS`` blocks."""
+    walk = TrainPairsWalk([], [], [], [], verify_crc=verify_crc)
+    end = _clamped_end(path, end)
+    if offset >= end:
+        return walk
+    with _mapped(path) as mm:
+        walk.mapped = memoryview(mm)
+        for pos, header_len, payload_len in _hop_mapped(mm, offset, end):
+            if len(walk.blocks) % WALK_OFFER_BLOCKS == WALK_OFFER_BLOCKS - 1:
+                time.sleep(0)  # the interpreter lock, offered to whoever waits for it
+            header, cols = _decode_body(mm, pos, header_len, payload_len, False, _PAIR_COLUMNS)
+            if tally is not None:
+                tally.decoded += 1
+            walk.blocks.append((pos, pos + _PREAMBLE.size + header_len, payload_len, header["crc32"]))
+            walk.trains_before.append(len(walk.bases))
+            if header["kind"] != KIND_TRAIN:
+                continue
+            f, l = _train_tensors(header, cols)
+            walk.features.append(f)
+            walk.labels.append(l)
+            walk.download_index.append(cols["pairs.download_index"])
+            walk.bases.append(walk.num_downloads)
+            walk.num_downloads += int(header.get("records", header["rows"]))
+            walk.num_pairs += len(l)
     return walk
 
 
@@ -660,23 +764,10 @@ def read_train_pairs(
 ):
     """Every ``train`` block's pairs, concatenated → ``PairExamples`` —
     the batch read for small datasets (below the streaming threshold),
-    resident fits and federation shards: the walk, then the assembly."""
+    resident fits and federation shards: the walk over the headers,
+    then the assembly, which with ``verify_crc`` checks every block of
+    the range once and raises before it returns an array."""
     return walk_train_pairs(path, offset, end, verify_crc, tally).assemble()
-
-
-def _concatenate(parts: list) -> np.ndarray:
-    """``np.concatenate(parts)``, a thousand parts a call into one
-    preallocated array: numpy looks at every part under the interpreter
-    lock before it copies without it (0.11 s for an upload's 53,760
-    blocks in one call)."""
-    out = np.empty((sum(len(p) for p in parts), *parts[0].shape[1:]), parts[0].dtype)
-    at = 0
-    for lo in range(0, len(parts), 1024):
-        group = parts[lo : lo + 1024]
-        n = sum(len(p) for p in group)
-        np.concatenate(group, out=out[at : at + n])
-        at += n
-    return out
 
 
 def read_gru_tail(

@@ -107,6 +107,12 @@ FIT_STAGES = (
     # total / count how long one holds its leg
     "feed_slice",
     "epoch_slice",
+    # a span of the upload's blocks checked against their CRCs and copied into
+    # the arrays the fit is handed (schema/wire.py TrainPairsWalk.assemble),
+    # inside load: observed once a span by the worker that ran it, so the count
+    # says the spans engaged, the total is seconds summed over the workers, and
+    # total / (load - the walk's seconds) is how many of them were busy
+    "load_span",
     # a permutation drawn (the holdout's, an epoch's): entered on the drawing
     # thread, once a permutation, 1 + epochs a fit; its seconds are drawn beside
     # the leg's, so the ledger and a trace's host plane hold them and no leg's

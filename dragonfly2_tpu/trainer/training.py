@@ -453,9 +453,10 @@ class Training:
                 if binary:
                     walk = wire.walk_train_pairs(path, offset=offset, end=boundary, tally=blocks)
                     # the fit's order needs the pair count and not the pairs:
-                    # it is drawn beside the assembly, the load's last seconds
+                    # it is drawn beside the assembly, which checks every block
+                    # and raises before it hands over an array
                     order = drawing.enter_context(FitOrder(M.PH_MLP, walk.num_pairs, cfg))
-                    pairs = walk.assemble()
+                    pairs = walk.assemble(span_timer=M.PH_MLP.load_span.observe)
                     del walk
                 else:
                     # bounded at the round boundary exactly like the binary and

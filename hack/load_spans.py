@@ -1,0 +1,172 @@
+#!/usr/bin/env python3
+"""What the resident load's assembly costs on a host, by the width of its
+pool and the length of its spans (ISSUE 35's sweep; PERF.md §5).
+
+    chiprun -- env JAX_PLATFORMS=cpu python3 hack/load_spans.py [--chunks 60]
+
+No chip is used: the load is host work, and the host that counts is the
+chip's. The upload is ``train-round-resident``'s (``rounds-60chunk``: a
+body of 2,048 records in 256-record ``train`` blocks, 112 bodies a chunk),
+written once and read from the page cache as a round reads it. Each
+reading is ``wire.walk_train_pairs`` and then ``assemble()`` into arrays
+no reading before it touched, with ``wire.ASSEMBLY_THREADS`` and
+``wire.ASSEMBLY_SPAN_BLOCKS`` set for the reading (they are constants of
+the module: this sweep is where their values come from), alone or beside
+the two draws a fit's order makes in those seconds. One JSON line a
+reading: the walk's seconds, the assembly's, the spans' seconds summed
+over the workers and how many workers that kept busy.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+import threading
+import time
+
+ROOT = os.path.normpath(os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+sys.path.insert(0, ROOT)
+
+import numpy as np
+
+from benchmarks.harness import synth
+from dragonfly2_tpu.schema import wire
+
+
+def stage(path: str, chunks: int, bodies: int, body_records: int, seed: int) -> None:
+    records = synth.download_records(body_records, seed)
+    rpb = wire.BLOCK_RECORDS
+    chunk = bodies * b"".join(
+        wire.encode_train_block(records[i : i + rpb]) for i in range(0, len(records), rpb)
+    )
+    with open(path, "wb") as f:
+        for _ in range(chunks):
+            f.write(chunk)
+
+
+def reading(path: str, threads: int, span_blocks: int, draws: int) -> dict:
+    wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS = threads, span_blocks
+    spans: list = []
+    t0 = time.perf_counter()
+    walk = wire.walk_train_pairs(path)
+    t1 = time.perf_counter()
+    # what FitOrder draws beside the assembly: a permutation of every pair, twice
+    drawing = [
+        threading.Thread(target=np.random.default_rng(i).permutation, args=(walk.num_pairs,))
+        for i in range(draws)
+    ]
+    for t in drawing:
+        t.start()
+    pairs = walk.assemble(span_timer=spans.append)
+    t2 = time.perf_counter()
+    for t in drawing:
+        t.join()
+    t3 = time.perf_counter()
+    out = {
+        "threads": threads, "span_blocks": span_blocks, "draws": draws,
+        "walk_s": round(t1 - t0, 3), "assemble_s": round(t2 - t1, 3), "draws_after_s": round(t3 - t2, 3),
+        "spans": len(spans), "span_s_sum": round(sum(spans), 3), "span_s_max": round(max(spans), 4),
+        "busy_workers": round(sum(spans) / (t2 - t1), 2),
+        "pairs": int(pairs.labels.shape[0]), "blocks": len(walk.blocks),
+    }
+    del walk, pairs
+    return out
+
+
+def parts(path: str, threads: int) -> dict:
+    """The assembly's pieces, each alone on ``threads`` threads: the
+    CRCs, the first touch of an array of the features' size (a write a
+    page), the features' copy into pages touched before, a whole fill
+    of fresh pages and the copy into fresh pages."""
+    import zlib
+    from concurrent.futures import ThreadPoolExecutor
+
+    walk = wire.walk_train_pairs(path)
+    n, per = len(walk.features), 128
+    rows = [0]
+    for f in walk.features:
+        rows.append(rows[-1] + len(f))
+    edges = list(range(0, n, per))
+
+    def timed(work) -> float:
+        t0 = time.perf_counter()
+        with ThreadPoolExecutor(threads) as pool:
+            list(pool.map(work, edges))
+        return round(time.perf_counter() - t0, 3)
+
+    def crc(lo):
+        for _, start, nbytes, _ in walk.blocks[lo : lo + per]:
+            zlib.crc32(walk.mapped[start : start + nbytes])
+
+    out = {"threads": threads, "crc_s": timed(crc)}
+    a = np.empty((walk.num_pairs, 19), np.float32)
+    flat = a.reshape(-1)
+
+    def touch(lo):
+        flat[rows[lo] * 19 : rows[min(lo + per, n)] * 19 : 1024] = 0
+
+    out["touch_s"] = timed(touch)
+
+    def copy(lo):
+        np.concatenate(walk.features[lo : lo + per], out=a[rows[lo] : rows[min(lo + per, n)]])
+
+    out["copy_touched_s"] = timed(copy)
+    out["copy_touched_again_s"] = timed(copy)
+    del a, flat
+    c = np.empty((walk.num_pairs, 19), np.float32)
+
+    def fill(lo):
+        c[rows[lo] : rows[min(lo + per, n)]] = 0
+
+    out["fill_fresh_s"] = timed(fill)
+    del c
+    d = np.empty((walk.num_pairs, 19), np.float32)
+
+    def copy_d(lo):
+        np.concatenate(walk.features[lo : lo + per], out=d[rows[lo] : rows[min(lo + per, n)]])
+
+    out["copy_fresh_s"] = timed(copy_d)
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--chunks", type=int, default=60)
+    ap.add_argument("--bodies", type=int, default=112)
+    ap.add_argument("--body-records", type=int, default=2048)
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--threads", type=int, nargs="+", default=[1, 2, 3, 4, 6, 8])
+    ap.add_argument("--span-blocks", type=int, nargs="+", default=[32, 128, 448, 896])
+    ap.add_argument("--repeats", type=int, default=2)
+    ap.add_argument("--parts", action="store_true", help="the assembly's pieces alone, by thread count, in place of the sweep")
+    args = ap.parse_args()
+    width, span_blocks = wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS  # the module's own, before a reading sets others
+    thp = "/sys/kernel/mm/transparent_hugepage/enabled"
+    print(json.dumps({
+        "cpu_count": os.cpu_count(), "affinity": len(os.sched_getaffinity(0)),
+        "thp": open(thp).read().strip() if os.path.exists(thp) else None,
+    }), flush=True)
+    with tempfile.TemporaryDirectory(dir=os.environ.get("TMPDIR")) as tmp:
+        path = os.path.join(tmp, "upload.dfb")
+        t0 = time.perf_counter()
+        stage(path, args.chunks, args.bodies, args.body_records, args.seed)
+        print(json.dumps({"staged_bytes": os.path.getsize(path), "stage_s": round(time.perf_counter() - t0, 2)}), flush=True)
+        reading(path, 1, span_blocks, 0)  # the mapping's pages, once
+        if args.parts:
+            for threads in args.threads:
+                print(json.dumps(parts(path, threads)), flush=True)
+            return 0
+        for _ in range(args.repeats):
+            for threads in args.threads:
+                print(json.dumps(reading(path, threads, span_blocks, 2)), flush=True)
+            for blocks in args.span_blocks:
+                print(json.dumps(reading(path, width, blocks, 2)), flush=True)
+            print(json.dumps(reading(path, width, span_blocks, 0)), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -3,6 +3,8 @@ announcer → trainer service → ingest over real gRPC, bit-identical
 tensors vs the CSV path, and the CSV-fallback negotiation for old
 trainers (ISSUE round 6 tentpole)."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -584,8 +586,11 @@ _UPLOADS = {
 
 def _write_upload(tmp_path, name):
     """→ (path, [(start, end) per whole block])."""
-    spec, torn = _UPLOADS[name]
-    rng = np.random.default_rng(sorted(_UPLOADS).index(name))
+    return _write_blocks(tmp_path, name, *_UPLOADS[name], seed=sorted(_UPLOADS).index(name))
+
+
+def _write_blocks(tmp_path, name, spec, torn=False, seed=0):
+    rng = np.random.default_rng(seed)
     blocks = [
         _topology_block(i) if b == "topology" else _block(rng, *b) for i, b in enumerate(spec)
     ]
@@ -830,6 +835,129 @@ class TestPairsWrittenOnce:
             assert set(some) == (set(full) if columns is None else set(full) & set(columns))
             for name, arr in some.items():
                 _assert_same_array(arr, full[name])
+
+
+# uploads by how they fall into spans of 4 blocks (``span_of_4``): name → blocks
+_T, _TOPO = (6, 2, 3), "topology"
+_SPANNED = {
+    "one-block": [_T],
+    "exactly-one-span": [(5, 1, 2), (9, 0, 4), (0, 3, 1), (7, 2, 3)],
+    "spans-and-a-remainder": [(3 + i % 5, i % 3, 1 + i % 4) for i in range(11)],
+    # a span that ends in other blocks, one that holds no ``train`` block, one that begins with others
+    "others-between": [_T, (4, 1, 2), _TOPO, _TOPO, _TOPO, _TOPO, _TOPO, _TOPO, _TOPO, (8, 0, 5), _T, _TOPO, (2, 2, 1)],
+    "no-train-block": [_TOPO] * 6,
+}
+
+
+@pytest.fixture
+def span_of_4(monkeypatch):
+    monkeypatch.setattr(wire, "ASSEMBLY_SPAN_BLOCKS", 4)
+    return 4
+
+
+def _flip(path, extent):
+    """One payload byte of the block at ``extent`` flipped."""
+    buf = bytearray(path.read_bytes())
+    buf[extent[1] - 3] ^= 0xFF
+    path.write_bytes(bytes(buf))
+
+
+class TestCheckedAndCopiedASpanAtATime:
+    """The resident read checks every block's CRC in its assembly, a
+    span of blocks at a time, beside the copy (ISSUE 35)."""
+
+    @pytest.mark.parametrize("which", ["whole", "inner"])
+    @pytest.mark.parametrize("name", sorted(_SPANNED))
+    def test_spans_equal_the_concatenation_reference(self, tmp_path, span_of_4, name, which):
+        from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
+
+        path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=7)
+        offset, end = _bounds(extents, which)
+        in_range = len(wire.scan_block_extents(path, offset, end))
+        tally, ran_on = wire.BlockTally(), []
+        walk = wire.walk_train_pairs(path, offset=offset, end=end, tally=tally)
+        got = walk.assemble(span_timer=lambda s: ran_on.append((threading.current_thread().name, s)))
+        assert (tally.decoded, tally.hopped) == (in_range, 0)
+        # once a span, by the thread that ran it: the caller's for one span, the pool's for more
+        assert len(ran_on) == -(-in_range // span_of_4) and all(s >= 0 for _, s in ran_on)
+        pooled = [name.startswith("wire.assemble") for name, _ in ran_on]
+        assert all(pooled) if len(ran_on) > 1 else not any(pooled)
+        assert not [t for t in threading.enumerate() if t.name.startswith("wire.assemble")]
+        want, records = _reference_pairs(path, offset, end)
+        assert got.num_downloads == records
+        if want is None:
+            want = (
+                np.zeros((0, MLP_FEATURE_DIM), np.float32),
+                np.zeros((0,), np.float32),
+                np.zeros((0,), np.int32),
+            )
+        whole = wire.read_train_pairs(path, offset=offset, end=end)
+        for g, w, again in zip((got.features, got.labels, got.download_index), want, (whole.features, whole.labels, whole.download_index)):
+            _assert_same_array(g, w)
+            _assert_same_array(again, w)
+            assert g.flags.c_contiguous and g.flags.writeable and g.flags.owndata
+
+    @pytest.mark.parametrize(
+        "name, corrupt",
+        [
+            ("one-block", [0]),
+            ("spans-and-a-remainder", [0]),  # the first block
+            ("spans-and-a-remainder", [5]),  # inside a span
+            ("spans-and-a-remainder", [10]),  # the last block, in the remainder
+            ("spans-and-a-remainder", [9, 2, 6]),  # three spans fail: the first in file order is named
+            ("others-between", [5]),  # a non-``train`` block, in a span with no ``train`` block
+            ("others-between", [11]),
+            ("no-train-block", [3]),  # no pair to copy: every block is checked all the same
+        ],
+    )
+    def test_a_corrupt_block_anywhere_hands_back_no_array(self, tmp_path, span_of_4, name, corrupt):
+        path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=8)
+        for i in corrupt:
+            _flip(path, extents[i])
+        walk = wire.walk_train_pairs(path)  # the walk reads no payload: it ends with its counts
+        sound = wire.read_train_pairs(_write_blocks(tmp_path, "sound", _SPANNED[name], seed=8)[0])
+        assert (walk.num_pairs, walk.num_downloads) == (len(sound.labels), sound.num_downloads)
+        handed = []
+        for read in (walk.assemble, lambda: wire.read_train_pairs(path)):
+            with pytest.raises(wire.WireError, match=f"block crc mismatch at byte {extents[min(corrupt)][0]}$"):
+                handed.append(read())
+        assert not handed
+        assert not [t for t in threading.enumerate() if t.name.startswith("wire.assemble")]
+        assert len(wire.read_train_pairs(path, verify_crc=False).labels) == walk.num_pairs
+
+    @pytest.mark.parametrize("name, offers", [("one-block", 0), ("exactly-one-span", 1), ("spans-and-a-remainder", 3), ("no-train-block", 2)])
+    def test_the_walk_offers_the_interpreter_once_a_few_blocks(self, tmp_path, monkeypatch, name, offers):
+        """The walk calls nothing that gives the interpreter lock up:
+        once ``WALK_OFFER_BLOCKS`` blocks, of whatever kind, it does."""
+        monkeypatch.setattr(wire, "WALK_OFFER_BLOCKS", 3)
+        slept = []
+        monkeypatch.setattr(wire.time, "sleep", slept.append)
+        path, _ = _write_blocks(tmp_path, name, _SPANNED[name], seed=10)
+        wire.walk_train_pairs(path)
+        assert slept == [0] * offers
+
+    @pytest.mark.parametrize("verify_crc", [True, False])
+    @pytest.mark.parametrize("which", ["whole", "from-second-block", "inner"])
+    @pytest.mark.parametrize("name", ["spans-and-a-remainder", "others-between", "no-train-block"])
+    def test_every_block_of_the_range_is_checked_exactly_once(
+        self, tmp_path, monkeypatch, span_of_4, name, which, verify_crc
+    ):
+        path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=9)
+        offset, end = _bounds(extents, which)
+        stated = sorted(h["crc32"] for h, _ in wire.iter_blocks(path, offset, end, verify_crc=False, columns=()))
+        checked, real = [], wire.zlib.crc32
+
+        def counting(data, *value):
+            crc = real(data, *value)
+            checked.append(crc)  # list.append is atomic: the pool's threads share the list
+            return crc
+
+        monkeypatch.setattr(wire.zlib, "crc32", counting)
+        walk = wire.walk_train_pairs(path, offset=offset, end=end, verify_crc=verify_crc)
+        assert not checked  # the walk checks none
+        walk.assemble()
+        assert sorted(checked) == (stated if verify_crc else [])
+        assert len(set(stated)) == len(stated) > 1
 
 
 def test_round_reports_blocks_decoded_and_hopped(tmp_path):

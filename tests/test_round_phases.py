@@ -199,9 +199,9 @@ def test_a_round_below_its_minimum_leaves_no_drawing_thread(tmp_path, monkeypatc
         assert gate.wait(timeout=60)
         return real(rng, n)
 
-    def assemble_beside_both_draws(walk):
+    def assemble_beside_both_draws(walk, **timer):
         assert begun.acquire(timeout=30) and begun.acquire(timeout=30)
-        return assemble(walk)
+        return assemble(walk, **timer)
 
     monkeypatch.setattr(train_mod, "_permutation", held)
     monkeypatch.setattr(wire.TrainPairsWalk, "assemble", assemble_beside_both_draws)
@@ -220,6 +220,99 @@ def test_a_round_below_its_minimum_leaves_no_drawing_thread(tmp_path, monkeypatc
         t.join(timeout=30)
     assert not order_threads()
     assert M.PH_MLP.order.snapshot()["count"] - before == 2  # of 1 + 2 epochs: the second epoch's was never begun
+
+
+def _mlp_leg(training, host_id):
+    """The resident MLP leg alone, as a round runs it → its split."""
+    splits: dict = {}
+    training._timed_fit("mlp", None, splits, training._train_mlp, host_id, IP, HOSTNAME)
+    return splits["mlp"]
+
+
+@pytest.mark.parametrize("span_blocks, spans", [(128, 1), (2, 2), (1, 3)])
+def test_the_load_books_a_span_once_a_span_inside_load(tmp_path, monkeypatch, span_blocks, spans):
+    """The upload's three blocks are checked and copied in spans
+    (``wire.TrainPairsWalk.assemble``): ``load_span`` moves by one a
+    span, observed while ``load`` is open by the thread that ran the
+    span (the leg's for one span, a worker's for more), and a leg's
+    split, which holds ``load`` once, does not hold it as well."""
+    monkeypatch.setattr(wire, "ASSEMBLY_SPAN_BLOCKS", span_blocks)
+    seen, observe = [], profiling.Phase.observe
+
+    def watched(phase, seconds):
+        if phase is M.PH_MLP.load_span:
+            seen.append((M.PH_MLP.load.active, threading.current_thread() is leg_thread, seconds))
+        observe(phase, seconds)
+
+    monkeypatch.setattr(profiling.Phase, "observe", watched)
+    training = _training(tmp_path, False)
+    host_id = host_id_v2(IP, HOSTNAME)
+    _stage(training.storage, host_id)
+    leg_thread = threading.current_thread()
+    before = M.PH_MLP.load_span.snapshot()
+    split = _mlp_leg(training, host_id)
+    after = M.PH_MLP.load_span.snapshot()
+    assert after["count"] - before["count"] == len(seen) == spans
+    assert all(load_open == 1 and on_leg == (spans == 1) for load_open, on_leg, _ in seen)
+    assert after["total_s"] - before["total_s"] == pytest.approx(sum(s for _, _, s in seen), abs=1e-4)
+    assert split.phase_n[M.PH_MLP.load.name] == 1 and M.PH_MLP.load_span.name not in split.phase_n
+    # a span lies inside load: the spans of one worker cannot outlast it
+    assert sum(s for _, _, s in seen) <= split.phase_s[M.PH_MLP.load.name] * min(spans, wire.ASSEMBLY_THREADS)
+
+
+def test_a_round_books_its_load_spans(round_):
+    moved = round_["ledger"][M.PH_MLP.load_span.name][0]
+    assert moved == (0 if round_["streaming"] else 1)  # three blocks: one span; the streamed fit assembles nothing
+    assert all(round_["ledger"][ph.load_span.name][0] == 0 for ph in (M.PH_GNN, M.PH_GRU))
+
+
+def test_a_corrupt_payload_fails_the_load_in_the_assembly_and_ends_the_order(tmp_path, monkeypatch):
+    """The walk reads headers alone: over an upload whose last payload
+    is corrupt it ends with its counts, and the fit's order is begun
+    from them. The assembly then raises, before ``train_mlp`` is
+    called with anything, and the order made from the walk's count is
+    closed: no draw is left running, none is begun."""
+    monkeypatch.setattr(wire, "ASSEMBLY_SPAN_BLOCKS", 2)
+    import dragonfly2_tpu.trainer.training as training_mod
+
+    made, fits, walks = [], [], []
+
+    class Order(train_mod.FitOrder):
+        def __init__(self, phases, n, cfg, **kw):
+            made.append((self, n))
+            super().__init__(phases, n, cfg, **kw)
+
+    walk_train_pairs = wire.walk_train_pairs
+
+    def walked(*a, **kw):
+        walks.append(walk_train_pairs(*a, **kw))
+        return walks[-1]
+
+    monkeypatch.setattr(training_mod, "FitOrder", Order)
+    monkeypatch.setattr(training_mod, "train_mlp", lambda *a, **kw: fits.append(a))
+    monkeypatch.setattr(wire, "walk_train_pairs", walked)
+    training = _training(tmp_path, False)
+    host_id = host_id_v2(IP, HOSTNAME)
+    _stage(training.storage, host_id)
+    path = training.storage.download_blocks_path(host_id)
+    sound = wire.read_train_pairs(path)
+    buf = bytearray(path.read_bytes())
+    buf[-3] ^= 0xFF
+    path.write_bytes(bytes(buf))
+    spans_before = M.PH_MLP.load_span.snapshot()["count"]
+    with pytest.raises(wire.WireError, match="block crc mismatch at byte"):
+        _mlp_leg(training, host_id)
+    walk, ((order, n),) = walks[-1], made  # the first walk was ``sound``'s
+    assert (walk.num_pairs, walk.num_downloads) == (len(sound.labels), sound.num_downloads) and n == walk.num_pairs
+    assert not fits
+    assert M.PH_MLP.load_span.snapshot()["count"] - spans_before == 1  # the sound span of the two
+    with pytest.raises(RuntimeError, match="after shutdown"):
+        order._threads.submit(int)
+    assert order._split is None and not order._ahead
+    for t in threading.enumerate():
+        if t.name.startswith((M.PH_MLP.order.name, "wire.assemble")):
+            t.join(timeout=30)
+            assert not t.is_alive()
 
 
 @pytest.mark.parametrize("leg", LEGS)
