@@ -141,9 +141,18 @@ GNN_INSTALL_TOTAL = _r.counter(
     "GraphSAGE swaps into the batched serving slot, by how each ended",
     ("result",),  # ok | failed | skipped (no probe-graph source, or a graph too small to embed)
 )
+GNN_REEMBED_TOTAL = _r.counter(
+    "scheduler_gnn_reembed_total",
+    "The loaded GraphSAGE version embedded again on a live graph that had moved, by how each ended",
+    ("result",),  # ok | failed | skipped (a graph too small to embed)
+)
+GNN_UNKNOWN_HOST_TOTAL = _r.counter(
+    "scheduler_gnn_unknown_host_total",
+    "Decisions the served GraphSAGE could not rank because a pair named a host outside its graph",
+)
 GNN_ROWS_TOTAL = _r.counter(
     "scheduler_gnn_rows_total",
-    "Learned node rows of installed GraphSAGE versions, by what became of each on the live graph",
+    "Learned node rows of installed and re-embedded GraphSAGE versions, by what became of each on the live graph",
     ("row",),  # placed (by host id) | default (a host the version never saw) | dropped (a host that left)
 )
 

@@ -41,6 +41,11 @@ PH_GNN_INSTALL = profiling.phase_type("scheduler.gnn_install")
 PH_GNN_EXPORT = profiling.phase_type("scheduler.gnn_export")
 PH_GNN_GRAPH_BUILD = profiling.phase_type("scheduler.gnn_graph_build")
 PH_GNN_EMBED = profiling.phase_type("scheduler.gnn_embed")
+# the loaded version embedded again on a live graph that has moved (the
+# three steps above run inside it, as inside an install), and the next
+# capacity rung's embed and edge heads compiled ahead of the fleet
+PH_GNN_REEMBED = profiling.phase_type("scheduler.gnn_reembed")
+PH_GNN_RUNG_PREPARE = profiling.phase_type("scheduler.gnn_rung_prepare")
 
 
 def _serving_rungs(serving) -> list[int]:
@@ -85,6 +90,12 @@ class ModelRefresher:
         self.loaded_version: tuple[str, int] | None = None  # (model_id, version)
         self.loaded_gru_version: tuple[str, int] | None = None
         self.loaded_gnn_version: tuple[str, int] | None = None
+        # the loaded GraphSAGE version's weights (a re-embed fetches
+        # nothing), the graph version its embed read, and the node
+        # capacities whose embed and edge heads are compiled
+        self._gnn_params = None
+        self._embedded_graph_version: int | None = None
+        self._gnn_capacities: set[int] = set()
         # the installed per-call scorer, kept so a GNN withdrawal can
         # re-occupy the serving slot through the one install path
         self._mlp_scorer = None
@@ -189,6 +200,7 @@ class ModelRefresher:
             if self.loaded_gnn_version is not None:
                 logger.info("active gnn withdrawn; serving falls back to mlp")
                 self.loaded_gnn_version = None
+                self._gnn_params = None
                 if self.serving.model_kind() == "gnn":
                     self.serving.clear()
                     # re-occupy the slot with the loaded MLP, if any —
@@ -199,37 +211,89 @@ class ModelRefresher:
         m = max(active, key=lambda m: (m.updated_at_ns, m.created_at_ns))
         key = (m.model_id, m.version)
         if key == self.loaded_gnn_version:
-            return False
-        try:
-            with PH_GNN_INSTALL:
-                w = self.manager.GetModelWeights(
-                    manager_pb2.GetModelRequest(model_id=m.model_id, version=m.version)
-                )
-                scorer = self._build_gnn_scorer(deserialize_params_auto(w.weights))
-                if scorer is None:
-                    M.GNN_INSTALL_TOTAL.labels("skipped").inc()
-                    return False
-                from dragonfly2_tpu.scheduler.serving import GNNServed
+            return self._reembed_gnn(key)
 
+        def fetch():
+            w = self.manager.GetModelWeights(
+                manager_pb2.GetModelRequest(model_id=m.model_id, version=m.version)
+            )
+            return deserialize_params_auto(w.weights)
+
+        params = self._swap_in_gnn(key, fetch, PH_GNN_INSTALL, M.GNN_INSTALL_TOTAL, "installing")
+        if params is None:
+            return False
+        self.loaded_gnn_version = key
+        self._gnn_params = params
+        return True
+
+    def _reembed_gnn(self, key) -> bool:
+        """The loaded version on the live graph as it is now, when that
+        is no longer the graph its embed read: the same weights, placed
+        by id and embedded again, every rung warmed, swapped in through
+        the serving slot's one install path. Nothing begins when the
+        graph has not moved, or when something else holds the slot."""
+        moved = getattr(self.networktopology, "graph_version", None)
+        if moved is None or self._gnn_params is None or self.serving.model_kind() != "gnn":
+            return False
+        now = moved()
+        if now is None or now == self._embedded_graph_version:
+            return False
+        held = self._gnn_params
+        return self._swap_in_gnn(key, lambda: held, PH_GNN_REEMBED, M.GNN_REEMBED_TOTAL, "embedding again") is not None
+
+    def _swap_in_gnn(self, key, get_params, phase, counter, doing: str):
+        """The one way a GraphSAGE scorer reaches the serving slot, for
+        an install and a re-embed alike: inside ``phase``, the weights
+        ``get_params()`` gives are built into a scorer on the live graph
+        (rows by id, every rung warmed) and swapped in; ``counter``
+        counts how it ended, the rows are counted, and the rung above is
+        compiled ahead if it is due. Returns the weights, or None when
+        nothing was swapped (the model in force stays)."""
+        from dragonfly2_tpu.scheduler.serving import GNNServed
+
+        try:
+            with phase:
+                params = get_params()
+                scorer = self._build_gnn_scorer(params)
+                if scorer is None:
+                    counter.labels("skipped").inc()
+                    return None
                 self.serving.install(GNNServed(scorer), version=f"{key[0]}/v{key[1]}")
         except Exception as e:
-            M.GNN_INSTALL_TOTAL.labels("failed").inc()
+            counter.labels("failed").inc()
             logger.warning(
-                "loading gnn %s v%d failed (%s); keeping previous serving model",
-                m.model_id,
-                m.version,
-                e,
+                "%s gnn %s v%d failed (%s); keeping the serving model in force", doing, key[0], key[1], e
             )
-            return False
-        M.GNN_INSTALL_TOTAL.labels("ok").inc()
+            return None
+        counter.labels("ok").inc()
         for row, count in scorer.rows.items():
             M.GNN_ROWS_TOTAL.labels(row).inc(count)
-        self.loaded_gnn_version = key
         logger.info(
-            "installed gnn %s v%d as the batched serving model (rows %s)",
-            m.model_id, m.version, scorer.rows,
+            "%s gnn %s v%d as the batched serving model: done (rows %s)", doing, key[0], key[1], scorer.rows
         )
-        return True
+        self._prepare_next_rung(scorer)
+        return params
+
+    def _prepare_next_rung(self, scorer) -> None:
+        """Once a scorer's graph is past the share of its node capacity
+        at which the next is due, compile the embed and every rung's
+        edge head for the doubled capacity: here, on the refresher's
+        thread, after the swap and before the one that will need them,
+        so a crossing compiles nothing anywhere."""
+        from dragonfly2_tpu.trainer.serving import past_prepare_share
+
+        cap = scorer.capacity
+        if cap is None:
+            return
+        self._gnn_capacities.add(cap)
+        if 2 * cap in self._gnn_capacities or not past_prepare_share(len(scorer.node_index()), cap):
+            return
+        try:
+            with PH_GNN_RUNG_PREPARE:
+                scorer.prepare_capacity(2 * cap, _serving_rungs(self.serving))
+            self._gnn_capacities.add(2 * cap)
+        except Exception as e:
+            logger.warning("compiling the gnn for node capacity %d ahead failed (%s)", 2 * cap, e)
 
     def _build_gnn_scorer(self, params):
         """Probe graph → swap-time-embedded GNNScorer (None when the
@@ -245,6 +309,10 @@ class ModelRefresher:
 
         with PH_GNN_EXPORT:
             records = self.networktopology.export_records()
+            # the version of the graph this export read: what a later
+            # poll compares to learn that the fleet has moved on
+            read = getattr(self.networktopology, "exported_version", None)
+            self._embedded_graph_version = read() if read is not None else None
         with PH_GNN_GRAPH_BUILD:
             graph = build_probe_graph(records_to_columns(records)) if records else None
         if graph is None or graph.num_nodes < 2:

@@ -217,6 +217,16 @@ class NetworkTopology:
                     adopted += 1
         return adopted
 
+    # -- what a reader that embedded the graph compares ---------------------
+    def graph_version(self) -> "int | None":
+        """A version of the live graph's host and edge sets (the
+        engine's); None without an engine: the KV walk has none."""
+        return self.engine.graph_version() if self.engine is not None else None
+
+    def exported_version(self) -> "int | None":
+        """``graph_version`` as the newest ``export_records`` read it."""
+        return self.engine.exported_version() if self.engine is not None else None
+
     # -- snapshot (training-data export) ----------------------------------
     def export_records(self, dest_limit: int = R.MAX_DEST_HOSTS) -> list:
         """Live probe graph → NetworkTopologyRecord rows (one per source

@@ -570,6 +570,13 @@ class SchedulerService:
         return scheduler_pb2.Empty()
 
     def LeaveHost(self, request, context):
+        """A host leaves: when this returns, no decision that begins
+        names it. Its peers go to Leave first (the filter's bad-node
+        rule drops a peer in that state, so they are out of every
+        candidate set before the host is out of the manager), then the
+        host manager forgets it, then the probe graph: the engine purges
+        it at once for every reader and leaves its arrays to the next
+        flush (docs/topology-engine.md, "A leave is a delta")."""
         M.LEAVE_HOST_TOTAL.inc()
         host = self.resource.host_manager.load(request.host_id)
         if host is None:

@@ -306,6 +306,7 @@ class ScoringService:
             # per-request support check BEFORE queueing: an unknown host
             # can't be embedded, so this decision takes the MLP rung
             # without burning a batch slot
+            M.GNN_UNKNOWN_HOST_TOTAL.inc()
             raise ServingUnsupported("gnn cannot embed this candidate set")
         cfg = self.cfg
         if budget_s is not None and budget_s <= cfg.window_s + cfg.immediate_floor_s:
@@ -378,6 +379,10 @@ class ScoringService:
                 else:
                     dropped.append(j)
                 off += c
+            # the one drop that is no fault: counted apart from every
+            # other (a host that joined since the embed in force)
+            if dropped:
+                M.GNN_UNKNOWN_HOST_TOTAL.inc(len(dropped))
             if not kept:
                 raise ServingUnsupported(
                     "gnn cannot embed any decision in this wave"
@@ -525,6 +530,9 @@ class ScoringService:
                         )
                         req.done.set()
                         M.SERVING_ERRORS_TOTAL.inc()
+                        # a swap landed while it was queued, onto a
+                        # graph without one of its hosts
+                        M.GNN_UNKNOWN_HOST_TOTAL.inc(len(req.counts) if req.counts else 1)
                 batch = scorable
                 if not batch:
                     return

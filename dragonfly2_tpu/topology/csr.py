@@ -4,7 +4,9 @@ The store is the exact mutable truth (EWMA fold per probe, host purge);
 the CSR build turns it into fixed-capacity arrays the jitted kernels
 consume. Capacities only grow, by doubling — static shapes are what let
 the kernels stay compiled (TPU tiling wants fixed array extents; a
-per-flush shape change would recompile every flush).
+per-flush shape change would recompile every flush). A departed host's
+slot goes to the next host that joins, so under turnover the capacities
+follow the live count and not the count of hosts ever seen.
 
 Padding convention: unused edge slots carry ``src = dst = 0`` with
 ``weight = 0`` — in-bounds for gathers (the pallas/XLA static-bound
@@ -12,6 +14,8 @@ masking idiom), zeroed out of every reduction by the weight.
 """
 
 from __future__ import annotations
+
+import heapq
 
 import numpy as np
 
@@ -35,22 +39,45 @@ class AdjacencyStore:
 
     def __init__(self):
         self.index: dict[str, int] = {}
-        self.ids: list[str] = []
+        self.ids: list[str] = []  # slot -> host id; "" for a slot nobody holds
         # (src_idx, dst_idx) -> [avg_rtt_ns, updated_at_s]
         self.edges: dict[tuple[int, int], list[float]] = {}
+        # slot -> the keys of the edges that touch it: a purge walks a
+        # host's own edges, not the whole dict
+        self._touching: dict[int, set[tuple[int, int]]] = {}
+        # slots of departed hosts: ``_leaving`` until a build without
+        # them has been installed (``release``: until then arrays on the
+        # device still hold the departed host's row under that slot),
+        # then ``_free``, lowest first, for the next host that joins
+        self._leaving: list[int] = []
+        self._free: list[int] = []
+        # bumped whenever the host set or the edge set changes (a probe
+        # that only moves an edge's average does not): what a reader
+        # that embedded this graph compares to learn that it has moved
+        self.version = 0
 
     # -- interning --------------------------------------------------------
     def intern(self, host_id: str) -> int:
         idx = self.index.get(host_id)
         if idx is None:
-            idx = len(self.ids)
+            if self._free:
+                idx = heapq.heappop(self._free)
+                self.ids[idx] = host_id
+            else:
+                idx = len(self.ids)
+                self.ids.append(host_id)
             self.index[host_id] = idx
-            self.ids.append(host_id)
+            self.version += 1
         return idx
 
     @property
     def num_hosts(self) -> int:
+        """Slots in use or not yet reusable: the node arrays' extent."""
         return len(self.ids)
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free) + len(self._leaving)
 
     @property
     def num_edges(self) -> int:
@@ -60,8 +87,10 @@ class AdjacencyStore:
     def apply_probe(self, src: str, dest: str, rtt_ns: float, at: float) -> None:
         s, d = self.intern(src), self.intern(dest)
         e = self.edges.get((s, d))
-        if e is None or e[0] <= 0:
-            self.edges[(s, d)] = [float(rtt_ns), at]
+        if e is None:
+            self._add_edge(s, d, [float(rtt_ns), at])
+        elif e[0] <= 0:
+            e[0], e[1] = float(rtt_ns), at
         else:
             e[0] = float(
                 int(EWMA_OLD_WEIGHT * e[0] + (1 - EWMA_OLD_WEIGHT) * rtt_ns)
@@ -78,21 +107,54 @@ class AdjacencyStore:
         e = self.edges.get((s, d))
         if e is not None and e[1] >= updated_at:
             return False
-        self.edges[(s, d)] = [float(avg_rtt_ns), updated_at]
+        if e is None:
+            self._add_edge(s, d, [float(avg_rtt_ns), updated_at])
+        else:
+            e[0], e[1] = float(avg_rtt_ns), updated_at
         return True
 
+    def _add_edge(self, s: int, d: int, value: list) -> None:
+        self.edges[(s, d)] = value
+        self._touching.setdefault(s, set()).add((s, d))
+        self._touching.setdefault(d, set()).add((s, d))
+        self.version += 1
+
+    def _drop_edge(self, key: tuple[int, int]) -> None:
+        del self.edges[key]
+        for end in key:
+            self._touching[end].discard(key)
+        self.version += 1
+
     def purge_host(self, host_id: str) -> bool:
-        """Remove a host's node and every incident edge. The node index
-        is NOT recycled (ids keep their dense slot; the id string is
-        tombstoned) so edge keys of other hosts stay valid."""
+        """Remove a host's node and every incident edge, by the host's
+        own edges alone. Its slot is tombstoned (``ids[slot] = ""``) and
+        held back until :meth:`release`; edge keys of other hosts stay
+        valid throughout."""
         idx = self.index.pop(host_id, None)
         if idx is None:
             return False
         self.ids[idx] = ""
-        self.edges = {
-            (s, d): v for (s, d), v in self.edges.items() if s != idx and d != idx
-        }
+        for key in list(self._touching.get(idx, ())):
+            self._drop_edge(key)
+        self._touching.pop(idx, None)
+        self._leaving.append(idx)
+        self.version += 1
         return True
+
+    def leaving(self) -> list[int]:
+        """Slots purged and not yet released, as of now: a build made
+        now holds none of them."""
+        return list(self._leaving)
+
+    def release(self, slots: list[int]) -> None:
+        """``slots`` (a former :meth:`leaving`) may be interned again:
+        the arrays in force were built after their hosts were purged."""
+        if not slots:
+            return
+        gone = set(slots)
+        self._leaving = [i for i in self._leaving if i not in gone]
+        for i in slots:
+            heapq.heappush(self._free, i)
 
     def purge_stale(self, now: float, max_age_s: float) -> int:
         """Drop edges whose last update is older than ``max_age_s`` —
@@ -100,7 +162,7 @@ class AdjacencyStore:
         aggregation weight (kernels.decay_weights), then disappear."""
         stale = [k for k, v in self.edges.items() if now - v[1] > max_age_s]
         for k in stale:
-            del self.edges[k]
+            self._drop_edge(k)
         return len(stale)
 
     # -- CSR build --------------------------------------------------------

@@ -34,7 +34,11 @@ from dragonfly2_tpu.topology import metrics as TM
 from dragonfly2_tpu.topology.csr import NS_PER_MS, AdjacencyStore
 from dragonfly2_tpu.topology.delta import DeltaQueue, EdgeDelta
 from dragonfly2_tpu.topology.kernels import INF_MS, make_kernels
-from dragonfly2_tpu.trainer.serving import bucket_rows, pad_batch
+from dragonfly2_tpu.trainer.serving import (
+    bucket_rows,
+    pad_batch,
+    past_prepare_share,
+)
 from dragonfly2_tpu.utils import dflog, flight, profiling
 
 logger = dflog.get("topology.engine")
@@ -49,6 +53,9 @@ EV_INFERENCE = flight.event_type("topology.inference")
 # dfprof phase: the wave join's round trip to the backend — the puts of
 # the padded index vectors, the gather kernel, the blocking read
 PH_RTT_GATHER = profiling.phase_type("topology.rtt_gather")
+# one flush whole (drain, build, kernels, swap), and one host's purge
+PH_FLUSH = profiling.phase_type("topology.flush")
+PH_DELETE_HOST = profiling.phase_type("topology.delete_host")
 
 
 @dataclass
@@ -91,9 +98,19 @@ class TopologyEngine:
         self._dropped_seen = 0
         self._last_flush_at = 0.0
         # bumped on every out-of-flush store mutation (adopt,
-        # delete_host): a flush whose build predates the bump must
-        # rebuild instead of installing pre-mutation arrays
+        # delete_host): a flush whose build predates the bump builds
+        # again instead of installing pre-mutation arrays
         self._store_version = 0
+        # the store's version as the newest export copied it
+        self._exported_version: int | None = None
+        # (node capacity, edge capacity) pairs compiled ahead, each with
+        # the gather's row rungs compiled for it; the rungs the wave join
+        # has met; the pairs still to compile, and the thread that does
+        self._compiled_caps: dict[tuple[int, int], set[int]] = {}
+        self._gather_rungs: set[int] = set()
+        self._prepare_lock = threading.Lock()
+        self._prepare_due: list[tuple[int, int]] = []
+        self._prepare_thread: threading.Thread | None = None
         # (src, dest) → (rtt_ns | None, provenance)
         self._cache: dict[tuple[str, str], tuple[float | None, str]] = {}
         self._cache_hits = 0
@@ -129,12 +146,17 @@ class TopologyEngine:
 
     def delete_host(self, host_id: str) -> None:
         """Purge parity with NetworkTopology.delete_host: edges, pending
-        deltas and cached inferences touching the host all go."""
-        with self._lock:
+        deltas and cached inferences touching the host all go, at once
+        for every reader (they resolve a host through the store's index
+        and an edge through its dict, and the host is in neither when
+        this returns). The device arrays are left to the next flush,
+        like a join's deltas: the departed host's row stays in them,
+        unreachable, and its slot is not given out again until a build
+        without it is in force."""
+        with PH_DELETE_HOST, self._lock:
             self.deltas.discard_host(host_id)
             if self.store.purge_host(host_id):
                 self._store_version += 1
-                self._refresh(time.time())
             self._cache.clear()
 
     # ------------------------------------------------------------------
@@ -148,25 +170,30 @@ class TopologyEngine:
         query lock (``_flush_lock`` serializes flushes): est_rtt callers
         keep reading the previous arrays until the swap."""
         now = time.time() if now is None else now
-        with self._flush_lock:
+        with self._flush_lock, PH_FLUSH:
             t0 = time.perf_counter()
             batch = self.deltas.drain()
             with self._lock:
                 for d in batch:
                     self.store.apply_probe(d.src, d.dest, d.rtt_ns, d.created_at)
                 purged = self.store.purge_stale(now, self.cfg.max_age_s)
-                arr = self._build_arrays(now)
-                built_version = self._store_version
-            computed = self._run_kernels(arr)
-            with self._lock:
-                if self._store_version == built_version:
-                    self._swap(arr, computed)
-                else:
-                    # an adopt/delete_host landed mid-kernel: the built
-                    # arrays are stale — rebuild from the current store
-                    self._refresh(now)
-                self._flush_count += 1
-                self._last_flush_at = now
+            # an adopt or a delete_host that lands mid-kernel makes the
+            # build stale: build again, outside the lock as before. The
+            # last try is installed whatever happened meanwhile (readers
+            # resolve hosts and direct edges through the store, so arrays
+            # a mutation behind are safe, and the next flush catches up)
+            for last in (False, False, True):
+                with self._lock:
+                    arr = self._build_arrays(now)
+                    built_version = self._store_version
+                computed = self._run_kernels(arr)
+                with self._lock:
+                    if last or self._store_version == built_version:
+                        self._swap(arr, computed)
+                        self._flush_count += 1
+                        self._last_flush_at = now
+                        break
+            self._prepare_ahead(arr)
             if purged:
                 TM.STALE_PURGED_TOTAL.inc(purged)
             TM.FLUSH_TOTAL.inc()
@@ -185,11 +212,89 @@ class TopologyEngine:
                 self._dropped_seen = dropped
             return len(batch)
 
-    def _refresh(self, now: float) -> None:
-        """Build + kernels + swap in one step — for callers already
-        holding ``_lock`` (delete_host, first-touch builds)."""
-        arr = self._build_arrays(now)
-        self._swap(arr, self._run_kernels(arr))
+    # ------------------------------------------------------------------
+    # the next capacity, compiled before the fleet reaches it
+    # ------------------------------------------------------------------
+    def _prepare_ahead(self, arr: dict) -> None:
+        """Once the live hosts or edges of a build are past the share
+        of their capacity at which the next is due (``past_prepare_share``,
+        the served GraphSAGE's rule), the decay, k-hop, landmark and
+        gather kernels for the doubled capacity are compiled on a thread
+        of their own, on blank arrays of that size: the flush that first
+        needs them, and the rtt joins after it, find them compiled. A
+        row rung the wave join meets later is added at the next flush."""
+        if self.kernels.backend != "jax":
+            return
+        ncap, ecap = len(arr["row_ptr"]) - 1, len(arr["edge_src"])
+        grow_n = past_prepare_share(len(self.store.index), ncap)
+        grow_e = past_prepare_share(arr["num_edges"], ecap)
+        with self._prepare_lock:
+            self._prepare_due = [
+                caps
+                for caps, wanted in (
+                    ((2 * ncap, ecap), grow_n),
+                    ((ncap, 2 * ecap), grow_e),
+                    ((2 * ncap, 2 * ecap), grow_n and grow_e),
+                )
+                if wanted and (caps not in self._compiled_caps or not self._gather_rungs <= self._compiled_caps[caps])
+            ]
+            if self._prepare_due and self._prepare_thread is None:
+                self._prepare_thread = threading.Thread(
+                    target=self._compile_due, name="topology.prepare", daemon=True
+                )
+                self._prepare_thread.start()
+
+    def _compile_due(self) -> None:
+        L = self.cfg.num_landmarks
+        while True:
+            with self._prepare_lock:
+                if not self._prepare_due:
+                    self._prepare_thread = None
+                    return
+                ncap, ecap = self._prepare_due.pop(0)
+            rungs = set(self._gather_rungs)
+            try:
+                blank = {
+                    "row_ptr": np.zeros(ncap + 1, np.int32),
+                    "edge_src": np.zeros(ecap, np.int32),
+                    "edge_dst": np.zeros(ecap, np.int32),
+                    "rtt_log_ms": np.zeros(ecap, np.float32),
+                    "age_s": np.zeros(ecap, np.float32),
+                    "valid": np.zeros(ecap, np.float32),
+                    "landmark_idx": np.zeros(L, np.int32),
+                    "landmark_valid": np.zeros(L, np.float32),
+                }
+                D = self._run_kernels(blank)["D"]
+                self.kernels.est_from_landmarks(D, *self._to_backend_idx(0, 0))
+                for rows in sorted(rungs):
+                    self._gather(D, *(np.zeros(rows, t) for t in (np.int32, np.int32, np.float32, np.float32, np.float32)))
+            except Exception:
+                logger.warning("compiling the kernels for capacity %s ahead failed", (ncap, ecap), exc_info=True)
+            finally:
+                self._compiled_caps[(ncap, ecap)] = rungs  # compiled, or failed: not again at every flush
+
+    def wait_prepared(self, timeout: float | None = None) -> bool:
+        """Wait for the capacities being compiled ahead, if any; False
+        if they are still compiling after ``timeout``."""
+        t = self._prepare_thread
+        if t is not None:
+            t.join(timeout)
+            return not t.is_alive()
+        return True
+
+    def graph_version(self) -> int:
+        """A version of the host and edge sets: moves when a host joins
+        or leaves or an edge appears or goes, and not when a probe only
+        moves an edge's average. Pending deltas are applied first, so
+        two equal readings mean the same graph."""
+        if len(self.deltas):
+            self.flush()
+        with self._lock:
+            return self.store.version
+
+    def exported_version(self) -> int | None:
+        """:meth:`graph_version` as the newest export read it."""
+        return self._exported_version
 
     def _build_arrays(self, now: float) -> dict:
         """Padded CSR + landmark selection from the host store (caller
@@ -197,6 +302,7 @@ class TopologyEngine:
         prev_ncap = len(self._arrays["row_ptr"]) - 1 if self._arrays else 0
         prev_ecap = len(self._arrays["edge_src"]) if self._arrays else 0
         arr = self.store.build_arrays(now, prev_ncap, prev_ecap)
+        arr["leaving"] = self.store.leaving()
         ncap = len(arr["row_ptr"]) - 1
 
         # landmarks: highest fresh-degree hosts (deterministic: degree
@@ -270,8 +376,13 @@ class TopologyEngine:
         self._D = computed["D"]
         self._landmark_idx = arr["landmark_idx"][: arr["num_landmarks"]].copy()
         self._cache.clear()
+        # the slots purged before this build are in no array any more
+        self.store.release(arr["leaving"])
         TM.EDGE_GAUGE.set(self.store.num_edges)
-        TM.HOST_GAUGE.set(len(self.store.index))
+        TM.HOST_GAUGE.labels("live").set(len(self.store.index))
+        TM.HOST_GAUGE.labels("free_slots").set(self.store.free_slots)
+        TM.CAPACITY_GAUGE.labels("nodes").set(len(arr["row_ptr"]) - 1)
+        TM.CAPACITY_GAUGE.labels("edges").set(len(arr["edge_src"]))
 
     def _to_backend(self, arrays: dict) -> dict:
         """numpy → device arrays on the jax backend (HBM when an
@@ -338,7 +449,9 @@ class TopologyEngine:
         if edge is not None:
             TM.QUERY_TOTAL.labels("direct").inc()
             return float(edge[0]), "direct"
-        if self._D is None:
+        if self._D is None or max(s, d) >= self._D.shape[0]:
+            # no landmark row yet (a host interned past the arrays in
+            # force; the gather would clamp to another host's row)
             return None, "none"
         est_ms = float(
             np.asarray(
@@ -431,6 +544,7 @@ class TopologyEngine:
             index = self.store.index
             edges = self.store.edges
             D = self._D  # immutable snapshot: _swap installs new arrays
+            rows_in_force = 0 if D is None else D.shape[0]
             for i in range(n):
                 src, dst = src_ids[i], dst_ids[i]
                 if src == dst:
@@ -446,6 +560,10 @@ class TopologyEngine:
                 if edge is not None:
                     has_direct[i] = True
                     direct_ms[i] = edge[0] / NS_PER_MS
+                elif max(s, d) >= rows_in_force:
+                    # interned past the arrays in force: no landmark
+                    # row to infer from until the next flush
+                    known[i] = False
                 else:
                     need_src[i] = s
                     need_dst[i] = d
@@ -455,28 +573,29 @@ class TopologyEngine:
             out[m] = np.log1p(direct_ms[m]) / np.float32(10.0)
             return out
         rows = bucket_rows(n)
+        self._gather_rungs.add(rows)  # what a capacity compiled ahead is compiled for
         with PH_RTT_GATHER:
-            dev = self._to_backend(
-                {
-                    "src": pad_batch(need_src, rows),
-                    "dst": pad_batch(need_dst, rows),
-                    "direct_ms": pad_batch(direct_ms, rows),
-                    "has_direct": pad_batch(has_direct.astype(np.float32), rows),
-                    "known": pad_batch(known.astype(np.float32), rows),
-                }
-            )
-            padded = self.kernels.gather_rtt_affinity(
+            padded = self._gather(
                 D,
-                dev["src"],
-                dev["dst"],
-                dev["direct_ms"],
-                dev["has_direct"],
-                dev["known"],
+                pad_batch(need_src, rows),
+                pad_batch(need_dst, rows),
+                pad_batch(direct_ms, rows),
+                pad_batch(has_direct.astype(np.float32), rows),
+                pad_batch(known.astype(np.float32), rows),
             )
             # whole-rung D2H then host slice (allowlisted host-pull): a
             # device [:n] would retrace a dynamic_slice per distinct n
             aff = np.asarray(padded)[:n]
         return aff.astype(np.float32, copy=False)
+
+    def _gather(self, D, src, dst, direct_ms, has_direct, known):
+        """The puts of one rung-padded wave and the gather kernel."""
+        dev = self._to_backend(
+            {"src": src, "dst": dst, "direct_ms": direct_ms, "has_direct": has_direct, "known": known}
+        )
+        return self.kernels.gather_rtt_affinity(
+            D, dev["src"], dev["dst"], dev["direct_ms"], dev["has_direct"], dev["known"]
+        )
 
     def rtt_affinity_batch(
         self, child_ids: np.ndarray, parent_ids: np.ndarray
@@ -598,6 +717,7 @@ class TopologyEngine:
         with self._lock:
             edges = list(self.store.edges.items())
             ids = list(self.store.ids)
+            self._exported_version = self.store.version
         by_src: dict[int, list[tuple[int, list[float]]]] = {}
         for (s, d), v in edges:
             by_src.setdefault(s, []).append((d, [v[0], v[1]]))

@@ -8,7 +8,14 @@ EDGE_GAUGE = _r.gauge(
     "topology_edges", "Edges resident in the device adjacency"
 )
 HOST_GAUGE = _r.gauge(
-    "topology_hosts", "Hosts interned in the device adjacency"
+    "topology_hosts",
+    "Slots of the device adjacency by state: held by a live host, or free for the next to join",
+    ("state",),  # live | free_slots
+)
+CAPACITY_GAUGE = _r.gauge(
+    "topology_capacity",
+    "Extent the device arrays are padded to (what the kernels are compiled for)",
+    ("kind",),  # nodes | edges
 )
 DELTA_QUEUE_GAUGE = _r.gauge(
     "topology_delta_queue_depth", "Probe deltas waiting for the next flush"

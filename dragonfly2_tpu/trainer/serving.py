@@ -98,6 +98,23 @@ def node_capacity(n: int) -> int:
     return max(64, 1 << max(n - 1, 0).bit_length())
 
 
+# The share of a capacity rung past which the next rung is compiled
+# ahead. Three quarters: a fleet that doubles takes a quarter of its
+# size to get from there to the rung's end, which is minutes to days of
+# joins against the seconds a compile takes; and right after a crossing
+# a fleet stands at half of its new rung, so nothing is compiled for a
+# rung it may never reach (a lower share would compile two rungs ahead
+# of a fleet that has just crossed one).
+PREPARE_AHEAD_SHARE = 0.75
+
+
+def past_prepare_share(n: int, capacity: int) -> bool:
+    """Whether ``n`` live entries of ``capacity`` are far enough up the
+    rung for the next one to be compiled now (the served GraphSAGE's
+    node tables and the topology engine's arrays share the rule)."""
+    return n > PREPARE_AHEAD_SHARE * capacity
+
+
 def place_node_rows(params: Any, node_ids: list) -> "tuple[dict, dict]":
     """A GraphSAGE version's learned rows on another graph than the one
     it was fitted on: ``params`` without its ``node_ids`` and with
@@ -423,8 +440,9 @@ class GNNScorer:
 
             # the padding of the sharded embed, to the capacity rung in
             # place of a shard multiple (the graph never outgrows its rung)
-            cap = node_capacity(graph.num_nodes)
+            cap = self.capacity = node_capacity(graph.num_nodes)
             feats, neighbors, mask = pad_node_arrays(graph, cap)
+            self._widths = (feats.shape[1], neighbors.shape[1])
             if "node_embed" in params:
                 params["node_embed"] = pad_batch(params["node_embed"], cap)
             self._params = _device_params(params)
@@ -434,6 +452,31 @@ class GNNScorer:
                 )
             )
         self._predict = _jit_once(_served_gnn_edge)
+
+    # the node capacity the one-device embed was padded to; None under a mesh
+    capacity: "int | None" = None
+
+    def prepare_capacity(self, capacity: int, rungs) -> None:
+        """Compile the embed and the edge head of every row rung of
+        ``rungs`` for node tables of ``capacity`` rows, on blank tables
+        of this scorer's widths: the scorer built when the fleet has
+        outgrown this one's rung then finds them compiled."""
+        import jax
+        import jax.numpy as jnp
+
+        params = dict(self._params)
+        feats, degree = self._widths
+        if "node_embed" in params:
+            params["node_embed"] = jnp.zeros((capacity, params["node_embed"].shape[1]), jnp.float32)
+        emb = _jit_once(_served_gnn_embed)(
+            params,
+            jnp.zeros((capacity, feats), jnp.float32),
+            jnp.zeros((capacity, degree), jnp.int32),
+            jnp.zeros((capacity, degree), jnp.float32),
+        )
+        for rows in rungs:
+            idx = jnp.zeros((rows,), jnp.int32)
+            jax.block_until_ready(self._predict(params, emb, idx, idx))
 
     def _sharded_embed(self, graph, mesh, axis: str):
         """Graph-parallel embed at swap time: pad node tables to the
@@ -457,6 +500,10 @@ class GNNScorer:
 
     def has_host(self, host_id: str) -> bool:
         return host_id in self._node_index
+
+    def node_index(self) -> "dict[str, int]":
+        """host id → node of the served graph."""
+        return self._node_index
 
     def node_rows(self) -> "dict[str, np.ndarray]":
         """host id → the learned row this scorer holds for it (a host

@@ -12,6 +12,7 @@ counted; the engine's export holds its lock for a copy, not for the walk.
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 
@@ -303,6 +304,12 @@ def test_export_answers_a_concurrent_rtt_join_within_a_slice(fleet):
     # the flush inside an export rebuilds the arrays under the lock; what is
     # measured here is the export's own hold, so the flush's are told apart
     real_flush, engine.flush = engine.flush, lambda *a, **k: 0
+    # ... and so is the collector's: the copy allocates a tuple an edge, and a
+    # full collection that falls inside the hold walks everything this test
+    # process has imported (60-100 ms here). The serving process freezes what
+    # is alive at start-up (colocated.settle), and so does this
+    gc.collect()
+    gc.freeze()
     t.start()
     waits = []
     deadline = time.perf_counter() + 1.5
@@ -313,6 +320,7 @@ def test_export_answers_a_concurrent_rtt_join_within_a_slice(fleet):
         time.sleep(0.001)
     stop.set()
     t.join(timeout=10.0)
+    gc.unfreeze()
     engine.flush = real_flush
     assert exports and exports[0] == 400
     held = [took for _, took in holds]
