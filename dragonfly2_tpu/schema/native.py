@@ -144,6 +144,11 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.df_topo_node_ids_size.restype = c_long
     lib.df_topo_export_nodes.argtypes = [c_void_p, c_char_p, f32_p, f32_p, f32_p]
     lib.df_topo_export_edges.argtypes = [c_void_p, i32_p, i32_p, f64_p]
+    i64_p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    lib.df_crc32_blocks.argtypes = [c_void_p, i64_p, c_long]
+    lib.df_crc32_blocks.restype = c_long
+    lib.df_gather.argtypes = [c_void_p, i64_p, c_long]
+    lib.df_gather.restype = None
     return lib
 
 
@@ -151,9 +156,11 @@ def load() -> ctypes.CDLL | None:
     """The native library, building it on first use; None when
     unavailable (callers fall back to the numpy path)."""
     global _lib, _load_failed
+    if os.environ.get("DF_NO_NATIVE"):
+        return None  # read at every call: a process can set it after a load
     if _lib is not None:
         return _lib
-    if _load_failed or os.environ.get("DF_NO_NATIVE"):
+    if _load_failed:
         return None
     with _lock:
         if _lib is not None or _load_failed:

@@ -580,9 +580,13 @@ def stream_train_pairs(
 # this many consecutive blocks of the range, of whatever kind, checked and
 # then copied by one worker; a range of more than one span is assembled by
 # this many workers at a time. Span-sized units, because a worker takes
-# the interpreter lock again after every ``crc32`` and every copy: a few
-# microseconds a block, and between them the tens of milliseconds of a
-# span in which it holds no lock. Both chosen on the chip's host (13
+# the interpreter lock again after every call that gave it up, and beside
+# a scheduler's 16 decision workers waits 0.2 ms each time to have it
+# back: with the native library a span is one call for its check and one
+# a column for its copies (``df_crc32_blocks``, ``df_gather``), a few
+# requests a span; without it ``zlib.crc32`` and numpy's copy give the
+# lock up once a block and once an array, four requests a block, 215,040
+# a week's upload. Both chosen on the chip's host (13
 # cores, no huge pages: a first touch is 3.9 us a 4 KiB page) by
 # ``hack/load_spans.py`` over a week's upload (PERF.md §5): the assembly
 # takes 7.8 s on one worker, 4.4-4.7 on two, 3.9-4.0 on three, 4.5-4.7 on
@@ -599,6 +603,24 @@ ASSEMBLY_THREADS = 3
 # offer costs the walk 0.2 ms of waiting to have it back (once a block
 # was measured: 9 s a round, PERF.md §6, PR 35).
 WALK_OFFER_BLOCKS = 32
+
+
+def _gather(lib, parts: list, out: np.ndarray) -> None:
+    """``np.concatenate(parts, out=out)`` for a span's column, ``out``
+    contiguous: by one call of the native library ``lib`` where that is
+    a copy of bytes end to end (every part of ``out``'s type and row
+    shape, contiguous, and together of its length), so the lock is asked
+    for once a column and not once a part; by numpy itself wherever it
+    has more to do (a cast, a stride) or to refuse, and without the
+    library."""
+    if lib is not None and all(
+        p.dtype == out.dtype and p.shape[1:] == out.shape[1:] and p.flags.c_contiguous for p in parts
+    ):
+        pieces = np.array([(p.ctypes.data, p.nbytes) for p in parts], np.int64).reshape(-1, 2)
+        if int(pieces[:, 1].sum()) == out.nbytes:
+            lib.df_gather(out.ctypes.data, pieces, len(parts))
+            return
+    np.concatenate(parts, out=out)
 
 
 @dataclass
@@ -625,7 +647,7 @@ class TrainPairsWalk:
     blocks: list = field(default_factory=list)
     trains_before: list = field(default_factory=list)  # the ``train`` blocks before each of ``blocks``
 
-    def assemble(self, span_timer=None):
+    def assemble(self, span_timer=None, check_timer=None):
         """The blocks' pairs concatenated → ``PairExamples``, and with
         ``verify_crc`` every block of the range checked against the
         ``crc32`` its header states: exactly once, here, and all of them
@@ -643,10 +665,27 @@ class TrainPairsWalk:
         bytes no other span touches, so ``ASSEMBLY_THREADS`` spans run
         side by side; a range of one span runs on the caller's thread:
         one path, narrower or wider by the number of blocks.
+
+        Where the native library loaded (schema/native.py), a span's
+        blocks are checked by one call of it, which holds no lock from
+        the span's first byte to its last and says which block failed
+        first, and each of the span's three columns is copied by one
+        call: a worker asks for the lock a few times a span. Where it
+        did not, ``zlib.crc32`` is called once a block and
+        ``np.concatenate`` copies an array at a time, and either gives
+        the lock up and asks for it again every time: four times a
+        block. The same CRC-32 over the same bytes and the same arrays
+        either way: the per-block path is what the library's is held to.
         ``span_timer``, when given, is called as ``span_timer(seconds)``
-        once a span by the thread that ran it."""
+        once a span by the thread that ran it, and ``check_timer`` the
+        same way with the seconds of the library's check: not at all
+        where the per-block loop ran."""
+        from dragonfly2_tpu.schema import native
         from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
 
+        lib = native.load() if self.blocks else None
+        # the mapping's first byte: a payload's place counts from it
+        base = np.frombuffer(self.mapped, np.uint8).ctypes.data if lib is not None else 0
         features = np.empty((self.num_pairs, MLP_FEATURE_DIM), np.float32)
         labels = np.empty((self.num_pairs,), np.float32)
         download_index = np.empty(
@@ -656,19 +695,31 @@ class TrainPairsWalk:
         pairs_before = [0, *itertools.accumulate(lengths)]  # each ``train`` block's offset in the arrays
         trains_before = [*self.trains_before, len(lengths)]
 
+        def check_span(span: list) -> None:
+            # a header is JSON: where one states what no crc32 is, the per-block check says where
+            if lib is None or not all(type(crc) is int and 0 <= crc <= 0xFFFFFFFF for _, _, _, crc in span):
+                for pos, start, nbytes, crc in span:
+                    if zlib.crc32(self.mapped[start : start + nbytes]) & 0xFFFFFFFF != crc:
+                        raise WireError(f"block crc mismatch at byte {pos}")
+                return
+            t0 = time.perf_counter()
+            bad = lib.df_crc32_blocks(base, np.array(span, np.int64), len(span))
+            if check_timer is not None:
+                check_timer(time.perf_counter() - t0)
+            if bad >= 0:
+                raise WireError(f"block crc mismatch at byte {span[bad][0]}")
+
         def assemble_span(lo: int) -> None:
             t0 = time.perf_counter()
             span = self.blocks[lo : lo + ASSEMBLY_SPAN_BLOCKS]
             if self.verify_crc:
-                for pos, start, nbytes, crc in span:
-                    if zlib.crc32(self.mapped[start : start + nbytes]) & 0xFFFFFFFF != crc:
-                        raise WireError(f"block crc mismatch at byte {pos}")
+                check_span(span)
             # the span's ``train`` blocks, and where their pairs go
             t_lo, t_hi = trains_before[lo], trains_before[lo + len(span)]
             at, end = pairs_before[t_lo], pairs_before[t_hi]
             if end > at:
-                np.concatenate(self.features[t_lo:t_hi], out=features[at:end])
-                np.concatenate(self.labels[t_lo:t_hi], out=labels[at:end])
+                _gather(lib, self.features[t_lo:t_hi], features[at:end])
+                _gather(lib, self.labels[t_lo:t_hi], labels[at:end])
                 # per-block indices are 0-based within their block's record batch —
                 # rebase onto the running record count so the concatenated result
                 # keeps the documented "row in the source batch" invariant instead
@@ -678,7 +729,7 @@ class TrainPairsWalk:
                 # the whole upload (``np.repeat`` of every block's base held it
                 # 0.2 s at 55M pairs) and not a lock handed over once a block
                 index = download_index[at:end]
-                np.concatenate(self.download_index[t_lo:t_hi], out=index)
+                _gather(lib, self.download_index[t_lo:t_hi], index)
                 bases = np.asarray(self.bases[t_lo:t_hi], index.dtype)
                 np.add(index, np.repeat(bases, lengths[t_lo:t_hi]), out=index)
             if span_timer is not None:

@@ -113,6 +113,14 @@ FIT_STAGES = (
     # says the spans engaged, the total is seconds summed over the workers, and
     # total / (load - the walk's seconds) is how many of them were busy
     "load_span",
+    # a span's blocks checked by one call of the native library that holds no
+    # interpreter lock (schema/native.py df_crc32_blocks), inside load_span:
+    # observed once a span by the same worker with the call's seconds, so the
+    # count says the one-call check engaged (a span a count; 0 where the
+    # library did not load and zlib.crc32 ran once a block), the total is
+    # seconds checking, and load_span - load_check is the copy and the first
+    # touch of its pages
+    "load_check",
     # a permutation drawn (the holdout's, an epoch's): entered on the drawing
     # thread, once a permutation, 1 + epochs a fit; its seconds are drawn beside
     # the leg's, so the ledger and a trace's host plane hold them and no leg's

@@ -3,10 +3,12 @@ client + storage + training core + gRPC server, Serve/Stop lifecycle."""
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from pathlib import Path
 
 from dragonfly2_tpu.rpc import glue
+from dragonfly2_tpu.schema import native
 from dragonfly2_tpu.trainer.service import SERVICE_NAME, TrainerService
 from dragonfly2_tpu.trainer.storage import TrainerStorage
 from dragonfly2_tpu.trainer.train import FitConfig, GNNFitConfig
@@ -74,6 +76,12 @@ class TrainerServerConfig:
 class TrainerServer:
     def __init__(self, config: TrainerServerConfig):
         self.cfg = config
+        # the native library, loaded (and on a machine's first start built:
+        # make, some 4 s) while the server comes up and not inside the first
+        # round's load, whose check of a span's blocks is a call of it
+        # (schema/wire.py); a round that comes sooner waits in load() for the
+        # build, and where no toolchain is found the rounds run without it
+        threading.Thread(target=native.load, name="trainer.native_load", daemon=True).start()
         Path(config.data_dir).mkdir(parents=True, exist_ok=True)
         self.storage = TrainerStorage(config.data_dir)
 

@@ -456,7 +456,9 @@ class Training:
                     # it is drawn beside the assembly, which checks every block
                     # and raises before it hands over an array
                     order = drawing.enter_context(FitOrder(M.PH_MLP, walk.num_pairs, cfg))
-                    pairs = walk.assemble(span_timer=M.PH_MLP.load_span.observe)
+                    pairs = walk.assemble(
+                        span_timer=M.PH_MLP.load_span.observe, check_timer=M.PH_MLP.load_check.observe
+                    )
                     del walk
                 else:
                     # bounded at the round boundary exactly like the binary and

@@ -15,6 +15,17 @@ the module: this sweep is where their values come from), alone or beside
 the two draws a fit's order makes in those seconds. One JSON line a
 reading: the walk's seconds, the assembly's, the spans' seconds summed
 over the workers and how many workers that kept busy.
+
+``--beside N [N ...]`` (ISSUE 39) reads the assembly, at the module's own
+width and span, beside N Python threads that want the interpreter as a
+scheduler's decision workers do in the colocated service (each works
+``--duty`` of its time in pure Python, 1 ms at a stretch, and sleeps the
+rest; the switch interval is the service's 0.5 ms), once on each of the
+paths a span takes: ``zlib.crc32`` once a block and ``np.concatenate`` an
+array at a time (``DF_NO_NATIVE``), and the native library's one call for
+the check and one a column for the copies; between them, to size each
+piece, the library's check with numpy's copies. The lines also carry the
+library's checks and their seconds.
 """
 
 from __future__ import annotations
@@ -33,7 +44,7 @@ sys.path.insert(0, ROOT)
 import numpy as np
 
 from benchmarks.harness import synth
-from dragonfly2_tpu.schema import wire
+from dragonfly2_tpu.schema import native, wire
 
 
 def stage(path: str, chunks: int, bodies: int, body_records: int, seed: int) -> None:
@@ -47,9 +58,35 @@ def stage(path: str, chunks: int, bodies: int, body_records: int, seed: int) -> 
             f.write(chunk)
 
 
-def reading(path: str, threads: int, span_blocks: int, draws: int) -> dict:
+def busy(stop: threading.Event, duty: float, stretch: float = 0.001) -> None:
+    """A thread that wants the interpreter ``duty`` of the time."""
+    while not stop.is_set():
+        until = time.perf_counter() + stretch
+        while time.perf_counter() < until:
+            pass
+        if duty < 1.0:
+            time.sleep(stretch * (1.0 - duty) / duty)
+
+
+_GATHER = wire._gather
+
+
+def reading(path: str, threads: int, span_blocks: int, draws: int, beside: int = 0, duty: float = 1.0, library: str = "both") -> dict:
+    """``library``: ``"both"`` (the module as it is: the span's check and
+    its copies a call each), ``"check"`` (the check alone: the copies left
+    to numpy, an array at a time) or ``"none"`` (``DF_NO_NATIVE``)."""
     wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS = threads, span_blocks
+    if library == "none":
+        os.environ["DF_NO_NATIVE"] = "1"
+    else:
+        os.environ.pop("DF_NO_NATIVE", None)
+    wire._gather = (lambda lib, parts, out: _GATHER(None, parts, out)) if library == "check" else _GATHER
     spans: list = []
+    checks: list = []
+    stop = threading.Event()
+    others = [threading.Thread(target=busy, args=(stop, duty), daemon=True) for _ in range(beside)]
+    for t in others:
+        t.start()
     t0 = time.perf_counter()
     walk = wire.walk_train_pairs(path)
     t1 = time.perf_counter()
@@ -60,13 +97,16 @@ def reading(path: str, threads: int, span_blocks: int, draws: int) -> dict:
     ]
     for t in drawing:
         t.start()
-    pairs = walk.assemble(span_timer=spans.append)
+    pairs = walk.assemble(span_timer=spans.append, check_timer=checks.append)
     t2 = time.perf_counter()
-    for t in drawing:
+    stop.set()
+    for t in drawing + others:
         t.join()
     t3 = time.perf_counter()
     out = {
         "threads": threads, "span_blocks": span_blocks, "draws": draws,
+        "beside": beside, "duty": duty, "library": library if native.load() is not None else "none",
+        "checks": len(checks), "check_s_sum": round(sum(checks), 3),
         "walk_s": round(t1 - t0, 3), "assemble_s": round(t2 - t1, 3), "draws_after_s": round(t3 - t2, 3),
         "spans": len(spans), "span_s_sum": round(sum(spans), 3), "span_s_max": round(max(spans), 4),
         "busy_workers": round(sum(spans) / (t2 - t1), 2),
@@ -142,6 +182,8 @@ def main() -> int:
     ap.add_argument("--span-blocks", type=int, nargs="+", default=[32, 128, 448, 896])
     ap.add_argument("--repeats", type=int, default=2)
     ap.add_argument("--parts", action="store_true", help="the assembly's pieces alone, by thread count, in place of the sweep")
+    ap.add_argument("--beside", type=int, nargs="+", help="the assembly on both check paths beside this many threads that want the interpreter, in place of the sweep")
+    ap.add_argument("--duty", type=float, nargs="+", default=[0.03], help="the share of its time such a thread works in Python")
     args = ap.parse_args()
     width, span_blocks = wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS  # the module's own, before a reading sets others
     thp = "/sys/kernel/mm/transparent_hugepage/enabled"
@@ -155,6 +197,16 @@ def main() -> int:
         stage(path, args.chunks, args.bodies, args.body_records, args.seed)
         print(json.dumps({"staged_bytes": os.path.getsize(path), "stage_s": round(time.perf_counter() - t0, 2)}), flush=True)
         reading(path, 1, span_blocks, 0)  # the mapping's pages, once
+        if args.beside:
+            from dragonfly2_tpu.colocated.server import SWITCH_INTERVAL_S
+
+            sys.setswitchinterval(SWITCH_INTERVAL_S)
+            print(json.dumps({"switch_interval_s": SWITCH_INTERVAL_S, "library": native.available()}), flush=True)
+            for _ in range(args.repeats):
+                for beside, duty in ((b, d) for b in args.beside for d in (args.duty if b else args.duty[:1])):
+                    for library in ("none", "check", "both"):
+                        print(json.dumps(reading(path, width, span_blocks, 2, beside, duty, library)), flush=True)
+            return 0
         if args.parts:
             for threads in args.threads:
                 print(json.dumps(parts(path, threads)), flush=True)

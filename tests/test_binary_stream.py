@@ -14,7 +14,7 @@ import trainer_pb2  # noqa: E402
 import grpc
 
 from dragonfly2_tpu.rpc.glue import TRAINER_SERVICE, ServiceClient, dial, serve
-from dragonfly2_tpu.schema import synth, wire
+from dragonfly2_tpu.schema import native, synth, wire
 from dragonfly2_tpu.schema.columnar import records_to_columns, write_csv
 from dragonfly2_tpu.schema.features import extract_pair_features, extract_piece_sequences
 from dragonfly2_tpu.scheduler.announcer import Announcer
@@ -855,6 +855,22 @@ def span_of_4(monkeypatch):
     return 4
 
 
+@pytest.fixture(params=["library", "per-block"])
+def check_path(request, monkeypatch):
+    """The two ways the assembly checks a span: one call of the native
+    library (schema/native.py), or ``zlib.crc32`` once a block where the
+    library did not load, as under ``DF_NO_NATIVE``. → the path's name
+    and the library, loaded or None."""
+    monkeypatch.delenv("DF_NO_NATIVE", raising=False)
+    lib = native.load()
+    if request.param == "per-block":
+        monkeypatch.setenv("DF_NO_NATIVE", "1")
+        assert native.load() is None and not native.available()
+    elif lib is None:
+        pytest.skip("native library unavailable (no toolchain)")
+    return request.param, lib
+
+
 def _flip(path, extent):
     """One payload byte of the block at ``extent`` flipped."""
     buf = bytearray(path.read_bytes())
@@ -868,20 +884,26 @@ class TestCheckedAndCopiedASpanAtATime:
 
     @pytest.mark.parametrize("which", ["whole", "inner"])
     @pytest.mark.parametrize("name", sorted(_SPANNED))
-    def test_spans_equal_the_concatenation_reference(self, tmp_path, span_of_4, name, which):
+    def test_spans_equal_the_concatenation_reference(self, tmp_path, span_of_4, check_path, name, which):
         from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
 
         path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=7)
         offset, end = _bounds(extents, which)
         in_range = len(wire.scan_block_extents(path, offset, end))
-        tally, ran_on = wire.BlockTally(), []
+        tally, ran_on, checked_on = wire.BlockTally(), [], []
         walk = wire.walk_train_pairs(path, offset=offset, end=end, tally=tally)
-        got = walk.assemble(span_timer=lambda s: ran_on.append((threading.current_thread().name, s)))
+        got = walk.assemble(
+            span_timer=lambda s: ran_on.append((threading.current_thread().name, s)),
+            check_timer=lambda s: checked_on.append((threading.current_thread().name, s)),
+        )
         assert (tally.decoded, tally.hopped) == (in_range, 0)
         # once a span, by the thread that ran it: the caller's for one span, the pool's for more
         assert len(ran_on) == -(-in_range // span_of_4) and all(s >= 0 for _, s in ran_on)
         pooled = [name.startswith("wire.assemble") for name, _ in ran_on]
         assert all(pooled) if len(ran_on) > 1 else not any(pooled)
+        # the library's call is timed once a span by that same thread, inside the span's seconds; the per-block loop never
+        assert sorted(t for t, _ in checked_on) == (sorted(t for t, _ in ran_on) if check_path[0] == "library" else [])
+        assert sum(s for _, s in checked_on) <= sum(s for _, s in ran_on)
         assert not [t for t in threading.enumerate() if t.name.startswith("wire.assemble")]
         want, records = _reference_pairs(path, offset, end)
         assert got.num_downloads == records
@@ -903,14 +925,18 @@ class TestCheckedAndCopiedASpanAtATime:
             ("one-block", [0]),
             ("spans-and-a-remainder", [0]),  # the first block
             ("spans-and-a-remainder", [5]),  # inside a span
+            ("spans-and-a-remainder", [4]),  # a span's first block
+            ("spans-and-a-remainder", [7]),  # a span's last block
+            ("spans-and-a-remainder", [7, 6]),  # two of one span: the library says which came first
             ("spans-and-a-remainder", [10]),  # the last block, in the remainder
             ("spans-and-a-remainder", [9, 2, 6]),  # three spans fail: the first in file order is named
+            ("exactly-one-span", [3, 1]),  # one span, on the caller's thread
             ("others-between", [5]),  # a non-``train`` block, in a span with no ``train`` block
             ("others-between", [11]),
             ("no-train-block", [3]),  # no pair to copy: every block is checked all the same
         ],
     )
-    def test_a_corrupt_block_anywhere_hands_back_no_array(self, tmp_path, span_of_4, name, corrupt):
+    def test_a_corrupt_block_anywhere_hands_back_no_array(self, tmp_path, span_of_4, check_path, name, corrupt):
         path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=8)
         for i in corrupt:
             _flip(path, extents[i])
@@ -924,6 +950,19 @@ class TestCheckedAndCopiedASpanAtATime:
         assert not handed
         assert not [t for t in threading.enumerate() if t.name.startswith("wire.assemble")]
         assert len(wire.read_train_pairs(path, verify_crc=False).labels) == walk.num_pairs
+
+    @pytest.mark.parametrize("states", [1 << 32, -1, 1.5, "7", None, True])
+    def test_a_header_that_states_no_crc32_is_a_mismatch_at_its_block(self, tmp_path, span_of_4, check_path, states):
+        """A header is JSON and may state anything for its ``crc32``:
+        what no 32 bits hold matches no payload, on either path, and the
+        block named is that one (a corrupt block after it in the same
+        span is not)."""
+        path, extents = _write_blocks(tmp_path, "spans-and-a-remainder", _SPANNED["spans-and-a-remainder"], seed=8)
+        _flip(path, extents[7])
+        walk = wire.walk_train_pairs(path)
+        walk.blocks[5] = (*walk.blocks[5][:3], states)
+        with pytest.raises(wire.WireError, match=f"block crc mismatch at byte {extents[5][0]}$"):
+            walk.assemble()
 
     @pytest.mark.parametrize("name, offers", [("one-block", 0), ("exactly-one-span", 1), ("spans-and-a-remainder", 3), ("no-train-block", 2)])
     def test_the_walk_offers_the_interpreter_once_a_few_blocks(self, tmp_path, monkeypatch, name, offers):
@@ -940,7 +979,7 @@ class TestCheckedAndCopiedASpanAtATime:
     @pytest.mark.parametrize("which", ["whole", "from-second-block", "inner"])
     @pytest.mark.parametrize("name", ["spans-and-a-remainder", "others-between", "no-train-block"])
     def test_every_block_of_the_range_is_checked_exactly_once(
-        self, tmp_path, monkeypatch, span_of_4, name, which, verify_crc
+        self, tmp_path, monkeypatch, span_of_4, check_path, name, which, verify_crc
     ):
         path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=9)
         offset, end = _bounds(extents, which)
@@ -953,11 +992,130 @@ class TestCheckedAndCopiedASpanAtATime:
             return crc
 
         monkeypatch.setattr(wire.zlib, "crc32", counting)
+        # the library's call: the payloads it was told to check and what their headers state, a list a call
+        (path_name, lib), calls = check_path, []
+        if lib is not None:
+            in_one_call = lib.df_crc32_blocks
+
+            def counting_calls(base, blocks, n):
+                assert blocks.shape == (n, 4)
+                calls.append([(int(nbytes), int(crc)) for _, _, nbytes, crc in blocks])
+                return in_one_call(base, blocks, n)
+
+            monkeypatch.setattr(lib, "df_crc32_blocks", counting_calls)
         walk = wire.walk_train_pairs(path, offset=offset, end=end, verify_crc=verify_crc)
-        assert not checked  # the walk checks none
+        assert not checked and not calls  # the walk checks none
         walk.assemble()
-        assert sorted(checked) == (stated if verify_crc else [])
+        by_library = path_name == "library" and verify_crc
+        assert sorted(checked) == (stated if verify_crc and not by_library else [])
+        assert sorted(crc for call in calls for _, crc in call) == (stated if by_library else [])
         assert len(set(stated)) == len(stated) > 1
+        # a call a span, the span's blocks in file order, each with its own payload's length
+        lengths = [(nbytes, crc) for _, _, nbytes, crc in walk.blocks]
+        assert sorted(calls) == sorted(lengths[lo : lo + span_of_4] for lo in range(0, len(lengths) if by_library else 0, span_of_4))
+
+
+    @pytest.mark.parametrize(
+        "case",
+        ["as-the-walk-builds-them", "one-part", "empty-parts-between", "another-type", "a-stride", "flat-labels"],
+    )
+    def test_a_columns_copy_is_numpys_concatenation(self, check_path, case):
+        """``wire._gather`` against ``np.concatenate(parts, out=out)``:
+        parts as the walk builds them (read-only views, of one type,
+        contiguous) copied by the library in one call; a part of
+        another type or with a stride left to numpy, which casts or
+        steps; the same array either way and on either path."""
+        lib = check_path[1] if check_path[0] == "library" else None
+        rng = np.random.default_rng(3)
+        rows = {"one-part": [7], "empty-parts-between": [0, 5, 0, 0, 3, 0]}.get(case, [4, 1, 9, 2])
+        shape = () if case == "flat-labels" else (19,)
+        parts = [rng.random((n, *shape), np.float32) for n in rows]
+        if case == "another-type":
+            parts[2] = parts[2].astype(np.float64)
+        if case == "a-stride":
+            parts[1] = rng.random((2, *shape), np.float32)[::2]
+        for p in parts:
+            p.flags.writeable = False
+        want = np.concatenate(parts).astype(np.float32)
+        whole = np.full((sum(rows) + 2, *shape), -1.0, np.float32)
+        wire._gather(lib, parts, whole[1:-1])
+        _assert_same_array(whole[1:-1], want)
+        assert (whole[0] == -1).all() and (whole[-1] == -1).all()  # nothing written past its place
+
+    @pytest.mark.parametrize("fault", ["too-short", "too-long", "other-row-shape", "no-parts"])
+    def test_a_copy_numpy_refuses_is_refused_by_numpy(self, check_path, fault):
+        """Parts that do not fill ``out``, or of another row shape, are
+        not the library's to copy: numpy raises as it did, and the
+        library writes nothing."""
+        lib = check_path[1] if check_path[0] == "library" else None
+        parts = [np.ones((3, 19), np.float32), np.ones((2, 19), np.float32)]
+        out = np.zeros((5, 19), np.float32)
+        if fault == "too-short":
+            out = np.zeros((6, 19), np.float32)
+        elif fault == "too-long":
+            out = np.zeros((4, 19), np.float32)
+        elif fault == "other-row-shape":
+            parts[1] = np.ones((2, 18), np.float32)
+        else:
+            parts = []
+        with pytest.raises(ValueError):
+            wire._gather(lib, parts, out)
+        assert not out.any()
+
+    def test_a_span_asks_for_the_lock_a_few_times_and_not_four_times_a_block(self, tmp_path, check_path):
+        """What the one-call check and copies are for. The interpreter's
+        forced hand-over is set far beyond the test, so a second thread
+        is handed the lock only where the assembling thread gives it up.
+        That thread counts one and then waits until the assembling
+        thread is back in Python (its profile hook says so at every
+        return from C), so a count is a hand-over: on the library's
+        path a few a span (the check, a column's copy, the rebasing
+        add) whatever the number of blocks, on the per-block path one
+        at a ``crc32`` or an array copied. No clock is read."""
+        import os
+        import sys
+
+        blocks = 127  # one span, on the caller's thread; a block's payload 250 KB
+        path, _ = _write_blocks(tmp_path, "many", [(3000, 0, 64)] * blocks, seed=12)
+        walk = wire.walk_train_pairs(path)
+        handed, stop, counting, back = [0], threading.Event(), threading.Event(), threading.Event()
+
+        def count():
+            while not stop.is_set():
+                handed[0] += 1
+                counting.set()
+                back.clear()
+                back.wait()
+
+        def back_in_python(frame, event, arg):
+            if event == "c_return":
+                back.set()
+
+        interval = sys.getswitchinterval()
+        counter = threading.Thread(target=count, name="test.counter")
+        sys.setswitchinterval(3600.0)
+        try:
+            counter.start()
+            counting.wait()
+            before = handed[0]
+            sys.setprofile(back_in_python)
+            try:
+                pairs = walk.assemble()
+            finally:
+                sys.setprofile(None)
+            during = handed[0] - before
+        finally:
+            stop.set()
+            back.set()
+            sys.setswitchinterval(interval)
+            counter.join()
+        assert len(pairs.labels) == 3000 * blocks
+        # the library's bound holds on any machine; the per-block path's
+        # count needs a second core for the counting thread to run on
+        if check_path[0] == "library":
+            assert during <= 12, during
+        elif len(os.sched_getaffinity(0)) > 1:
+            assert during > 12, during
 
 
 def test_round_reports_blocks_decoded_and_hopped(tmp_path):

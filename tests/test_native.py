@@ -8,6 +8,7 @@ fields.
 """
 
 import os
+import time
 
 import numpy as np
 import pytest
@@ -223,3 +224,119 @@ def test_f16_nan_preserved():
         assert feats is not None
         # the NaN flows into at least one f16 feature as NaN, not inf
         assert np.isnan(feats).any() or not np.isinf(feats).any()
+
+
+# ---------------------------------------------------------------------------
+# df_crc32_blocks: a span of blocks checked in one call that holds no
+# interpreter lock (schema/wire.py TrainPairsWalk.assemble)
+# ---------------------------------------------------------------------------
+
+_MIB = 1 << 20
+
+
+def _stated(buf, pieces):
+    """``pieces`` of ``buf`` as the walk notes blocks: first byte (here
+    the piece's number), payload's first byte, its length, and zlib's
+    CRC-32 of it."""
+    import zlib
+
+    return np.array(
+        [(i, start, n, zlib.crc32(buf[start : start + n])) for i, (start, n) in enumerate(pieces)], np.int64
+    ).reshape(-1, 4)
+
+
+@pytest.mark.parametrize(
+    "length",
+    [0, 1, 2, 7, 8, 9, 15, 16, 17, 63, 64, 65, 79, 80, 127, 128, 129, 255, 1023, 4096, 4097, 65_535, _MIB + 13, 5 * _MIB + 1],
+)
+def test_crc32_blocks_is_zlibs_crc32_at_every_length_and_alignment(length):
+    """The routine against ``zlib.crc32`` over the same bytes: lengths
+    around every stride it takes (8 bytes a table step, 16 a fold, 64 a
+    round of four lanes) up to several MiB, at every alignment of the
+    first byte within 16; a stated CRC one bit off is a mismatch."""
+    lib = native.load()
+    buf = np.random.default_rng(length).integers(0, 256, length + 16, dtype=np.uint8)
+    table = _stated(buf, [(shift, length) for shift in range(16)])
+    assert lib.df_crc32_blocks(buf.ctypes.data, table, len(table)) == -1
+    for shift in (0, 5, 15):
+        off = table[shift : shift + 1].copy()
+        off[0, 3] ^= 1 << (shift * 2)
+        assert lib.df_crc32_blocks(buf.ctypes.data, off, 1) == 0
+
+
+def test_crc32_blocks_random_pieces_and_the_first_mismatch():
+    """Pieces of random lengths at random places, overlapping or not,
+    in one call: -1 when every one is what is stated, else the index of
+    the first that is not, whatever comes after it."""
+    lib = native.load()
+    rng = np.random.default_rng(39)
+    buf = rng.integers(0, 256, 3 * _MIB, dtype=np.uint8)
+    lengths = rng.integers(0, 200_000, 64)
+    pieces = [(int(rng.integers(0, len(buf) - n + 1)), int(n)) for n in lengths]
+    table = _stated(buf, pieces)
+    assert lib.df_crc32_blocks(buf.ctypes.data, table, len(table)) == -1
+    assert lib.df_crc32_blocks(buf.ctypes.data, table, 0) == -1
+    for wrong in ([0], [63], [17, 40], [40, 17, 63]):
+        bad = table.copy()
+        bad[wrong, 3] ^= 0x8000_0001
+        assert lib.df_crc32_blocks(buf.ctypes.data, bad, len(bad)) == min(wrong)
+    # a stated CRC that no 32 bits hold matches nothing, as in the per-block comparison
+    wide = table.copy()
+    wide[5, 3] += 1 << 32
+    assert lib.df_crc32_blocks(buf.ctypes.data, wide, len(wide)) == 5
+
+
+def test_crc32_blocks_holds_no_interpreter_lock():
+    """A Python thread makes progress while another is inside one call
+    of the check. The interpreter's forced hand-over is set far beyond
+    the test (a thread that holds the lock keeps it until it gives it
+    up), so the counting thread can only count while the checking
+    thread is inside a call that has given the lock up; it offers the
+    lock back after every count. No reading of a clock: a call that
+    held the lock would leave the count where it was, however long."""
+    import sys
+    import threading
+
+    lib = native.load()
+    buf = np.random.default_rng(1).integers(0, 256, 16 * _MIB, dtype=np.uint8)
+    table = np.repeat(_stated(buf, [(0, len(buf))]), 8, axis=0)  # 128 MiB a call
+    counted, stop, counting = [0], threading.Event(), threading.Event()
+
+    def count():
+        while not stop.is_set():
+            counted[0] += 1
+            counting.set()
+            time.sleep(0)  # the lock, offered
+
+    interval = sys.getswitchinterval()
+    counter = threading.Thread(target=count, name="test.counter")
+    sys.setswitchinterval(3600.0)
+    try:
+        counter.start()
+        counting.wait()  # a wait gives the lock up: the counter runs its first turns
+        moved = []
+        for _ in range(20):
+            before = counted[0]
+            assert lib.df_crc32_blocks(buf.ctypes.data, table, len(table)) == -1
+            moved.append(counted[0] - before)
+        # and between the calls nobody is handed the lock: a pure-Python stretch moves nothing
+        before = counted[0]
+        sum(range(200_000))
+        held = counted[0] - before
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        counter.join()
+    # in some call, at the least: on a machine of one busy core a call can end before the counter is given a turn
+    assert sum(moved) > 0 and held == 0, (moved, held)
+
+
+def test_no_native_is_read_at_every_call(monkeypatch):
+    """``DF_NO_NATIVE`` set after the library loaded turns its callers
+    to their fallbacks from the next call on, and taking it away turns
+    them back: the loaded library is kept."""
+    lib = native.load()
+    monkeypatch.setenv("DF_NO_NATIVE", "1")
+    assert native.load() is None and not native.available()
+    monkeypatch.delenv("DF_NO_NATIVE")
+    assert native.load() is lib

@@ -14,7 +14,7 @@ import pytest
 
 import jax
 
-from dragonfly2_tpu.schema import synth, wire
+from dragonfly2_tpu.schema import native, synth, wire
 from dragonfly2_tpu.trainer import metrics as M
 from dragonfly2_tpu.trainer import train as train_mod
 from dragonfly2_tpu.trainer.storage import TrainerStorage
@@ -264,6 +264,76 @@ def test_a_round_books_its_load_spans(round_):
     moved = round_["ledger"][M.PH_MLP.load_span.name][0]
     assert moved == (0 if round_["streaming"] else 1)  # three blocks: one span; the streamed fit assembles nothing
     assert all(round_["ledger"][ph.load_span.name][0] == 0 for ph in (M.PH_GNN, M.PH_GRU))
+
+
+def test_a_round_books_its_load_checks(round_):
+    """A span checked by the library's one call is a ``load_check``
+    beside its ``load_span`` (the round's process has the library or
+    has not: the count says which path ran), inside the span's seconds;
+    the MLP leg alone has entries, and no leg's split holds them."""
+    spans, span_s = round_["ledger"][M.PH_MLP.load_span.name]
+    checks, check_s = round_["ledger"][M.PH_MLP.load_check.name]
+    assert checks == (spans if native.available() else 0) and 0 <= check_s <= span_s + 1e-9
+    assert all(round_["ledger"][ph.load_check.name][0] == 0 for ph in (M.PH_GNN, M.PH_GRU))
+    assert M.PH_MLP.load_check.name not in round_["outcome"].splits["mlp"].phase_n
+
+
+def test_the_trainer_server_loads_the_library_as_it_starts(tmp_path, monkeypatch):
+    """The library is loaded (on a machine's first start: built) on a
+    thread of its own while the server comes up, not inside the first
+    round's ``load``."""
+    from dragonfly2_tpu.trainer.server import TrainerServer, TrainerServerConfig
+
+    loaded = []
+    monkeypatch.setattr(native, "load", lambda: loaded.append(threading.current_thread().name))
+    TrainerServer(TrainerServerConfig(data_dir=str(tmp_path / "trainer")))
+    for t in threading.enumerate():
+        if t.name == "trainer.native_load":
+            t.join()
+    assert loaded == ["trainer.native_load"]
+
+
+@pytest.mark.parametrize("path", ["library", "per-block"])
+@pytest.mark.parametrize("span_blocks, spans", [(128, 1), (2, 2), (1, 3)])
+def test_the_load_books_a_check_once_a_span_where_the_library_checks(tmp_path, monkeypatch, span_blocks, spans, path):
+    """``load_check`` moves by one a span on the library's path (420 a
+    fit for a week's upload), observed by the thread that ran the span
+    while ``load`` is open, before that span's ``load_span`` and inside
+    its seconds; where the library did not load (``DF_NO_NATIVE``) the
+    per-block check runs, ``load_check`` stays where it was and
+    ``load_span`` counts the spans all the same."""
+    monkeypatch.setattr(wire, "ASSEMBLY_SPAN_BLOCKS", span_blocks)
+    if path == "per-block":
+        monkeypatch.setenv("DF_NO_NATIVE", "1")
+    elif not native.available():
+        pytest.skip("native library unavailable (no toolchain)")
+    seen, observe = [], profiling.Phase.observe
+
+    def watched(phase, seconds):
+        if phase in (M.PH_MLP.load_check, M.PH_MLP.load_span):
+            seen.append((phase.name, threading.get_ident(), M.PH_MLP.load.active, seconds))
+        observe(phase, seconds)
+
+    monkeypatch.setattr(profiling.Phase, "observe", watched)
+    training = _training(tmp_path, False)
+    host_id = host_id_v2(IP, HOSTNAME)
+    _stage(training.storage, host_id)
+    before = {ph.name: ph.snapshot() for ph in (M.PH_MLP.load_check, M.PH_MLP.load_span)}
+    split = _mlp_leg(training, host_id)
+    moved = {name: profiling.phase_type(name).snapshot()["count"] - b["count"] for name, b in before.items()}
+    assert moved == {M.PH_MLP.load_span.name: spans, M.PH_MLP.load_check.name: spans if path == "library" else 0}
+    assert all(load_open == 1 for _, _, load_open, _ in seen)
+    assert M.PH_MLP.load_check.name not in split.phase_n and M.PH_MLP.load_span.name not in split.phase_n
+    # a thread's entries alternate: a span's check, then that span, which holds the check's seconds
+    by_thread: dict = {}
+    for name, thread, _, seconds in seen:
+        by_thread.setdefault(thread, []).append((name, seconds))
+    for entries in by_thread.values():
+        if path == "library":
+            assert [n for n, _ in entries] == [M.PH_MLP.load_check.name, M.PH_MLP.load_span.name] * (len(entries) // 2)
+            assert all(check <= span for (_, check), (_, span) in zip(entries[::2], entries[1::2]))
+        else:
+            assert {n for n, _ in entries} == {M.PH_MLP.load_span.name}
 
 
 def test_a_corrupt_payload_fails_the_load_in_the_assembly_and_ends_the_order(tmp_path, monkeypatch):
