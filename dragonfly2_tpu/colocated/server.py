@@ -22,9 +22,11 @@ import sys
 from dataclasses import dataclass, field
 from pathlib import Path
 
+from dragonfly2_tpu.scheduler import scheduling
 from dragonfly2_tpu.scheduler.server import SchedulerServer, SchedulerServerConfig
+from dragonfly2_tpu.trainer import metrics as trainer_metrics
 from dragonfly2_tpu.trainer.server import TrainerServer, TrainerServerConfig
-from dragonfly2_tpu.utils import dflog
+from dragonfly2_tpu.utils import dflog, profiling
 
 logger = dflog.get("colocated.server")
 
@@ -87,6 +89,30 @@ def scheduler_config(cfg: ColocatedConfig, trainer_address: str) -> SchedulerSer
 SWITCH_INTERVAL_S = 0.0005
 
 
+_ROUND = trainer_metrics.PH_ROUND
+_WALK, _ASSEMBLE = trainer_metrics.PH_MLP.load_walk, trainer_metrics.PH_MLP.load_assemble
+_MLP_FIT, _GNN_FIT, _GRU_FIT = (leg.fit for leg in trainer_metrics.LEG_PHASES.values())
+
+
+def round_stretch() -> str:
+    """What the trainer's round is doing now, for a decision that begins
+    now (``scheduling.stretch_provider``): what a decision waits for
+    follows it. ``walk``: the resident load walks the upload's headers,
+    the interpreter's work alone. ``assemble``: the load's workers check
+    and copy, off the interpreter lock. ``fit_shared``: past the load, at
+    least two legs' fits still running: their epochs' slices share the
+    chip's queue. ``fit_alone``: one leg left, or none. ``idle``: no round.
+    A few reads of open-phase counts, no lock."""
+    if not _ROUND.active:
+        return "idle"
+    if _WALK.active:
+        return "walk"
+    if _ASSEMBLE.active:
+        return "assemble"
+    legs = (_MLP_FIT.active > 0) + (_GNN_FIT.active > 0) + (_GRU_FIT.active > 0)
+    return "fit_shared" if legs >= 2 else "fit_alone"
+
+
 def settle() -> float:
     """What the process takes on once both servers are built; returns the
     switch interval that was there. The interpreter's switch interval
@@ -95,11 +121,19 @@ def settle() -> float:
     is put out of the collector's reach: a full collection stops every
     thread for as long as it takes to walk what it tracks (0.2 s at
     460,000 objects beside a round, twice a round), and a decision
-    waits with them; after this it walks what came since."""
+    waits with them; after this it walks what came since, and each such
+    walk is a ``process.gc_full`` span. The scheduler's decisions are
+    booked by the stretch of the trainer's round they begin in: this is
+    the one process in which the scheduler can know it. All of it is the
+    process's, not a server's (the benchmark's generators build the two
+    servers themselves and call this); ``ColocatedServer.stop()`` puts
+    back what a process that goes on would feel."""
     was = sys.getswitchinterval()
     sys.setswitchinterval(SWITCH_INTERVAL_S)
     gc.collect()
     gc.freeze()
+    profiling.watch_collections()
+    scheduling.stretch_provider = round_stretch
     return was
 
 
@@ -135,6 +169,7 @@ class ColocatedServer:
         self.trainer.stop()
         # a process that goes on after the service runs and collects as before
         gc.unfreeze()
+        scheduling.stretch_provider = None
         if self._switch_interval_was is not None:
             sys.setswitchinterval(self._switch_interval_was)
             self._switch_interval_was = None

@@ -158,21 +158,12 @@ GNN_ROWS_TOTAL = _r.counter(
 
 # -- wave scheduling (scheduler/wave.py, docs/serving.md "wave
 # scheduling"): W decisions × C candidates packed into one scoring
-# dispatch; occupancy is rows = Σ wave sizes ------------------------------
+# dispatch. A wave batch's rows are scheduler_serving_batch_occupancy's (a
+# wave is a batch), its unpack the scheduler.score_unpack phase's ---------
 WAVE_DECISIONS_TOTAL = _r.counter(
     "scheduler_wave_decisions_total",
     "Scheduling decisions submitted via wave packing, by path",
     ("path",),  # batched | immediate | overflow
-)
-WAVE_OCCUPANCY_ROWS = _r.histogram(
-    "scheduler_wave_occupancy_rows",
-    "Candidate rows (Σ wave sizes) per scored wave batch",
-    buckets=(8, 16, 32, 64, 128, 256, 512, 1024),
-)
-WAVE_UNPACK_SECONDS = _r.histogram(
-    "scheduler_wave_unpack_seconds",
-    "Segment-rank unpack wall per wave request",
-    buckets=(1e-5, 5e-5, 1e-4, 5e-4, 1e-3, 5e-3, 2e-2),
 )
 # -- predictive preheat plane (dragonfly2_tpu/preheat/, docs/preheat.md):
 # demand folding, forecast sweeps, planned tasks and the jobs they ride --

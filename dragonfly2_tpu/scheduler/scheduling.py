@@ -40,7 +40,30 @@ logger = dflog.get("scheduling")
 # the evaluator leg; the topology and storage legs are declared at
 # their own sites) — live counters on /debug/prof, always on
 PH_SCHEDULE = profiling.phase_type("scheduler.schedule_op")
-PH_EVALUATE = profiling.phase_type("scheduler.evaluate")
+# a decision's service from inside: find_candidate_parents whole, and
+# within it the six rules and the evaluator's ranking (the rtt join and
+# the scoring service's two round trips lie in evaluate, under phases of
+# their own); find_parents - filter_parents - evaluate is the decision's
+# self time
+PH_FIND_PARENTS = profiling.phase_type("scheduler.find_parents")
+PH_FILTER_PARENTS = profiling.phase_type("scheduler.filter_parents", inner=True)
+PH_EVALUATE = profiling.phase_type("scheduler.evaluate", inner=True)
+
+# What the process around the scheduler was doing when a decision began:
+# a callable that names a stretch, given by whoever assembles a process in
+# which that can be known (colocated/server.py: the stretches of the
+# trainer's round on the same interpreter and chip) and imported by no
+# one here. A scheduler alone has none and books nothing by stretch. The
+# decision's seconds (find_parents' own, no second clock) go to the phase
+# of the stretch it began in, so the counts of these sum to find_parents'
+# and each mean is the service beside that stretch. One for the process,
+# like the rest of what colocated.settle() takes on (the switch interval,
+# the frozen collector): its stop() puts it back
+STRETCHES = ("walk", "assemble", "fit_shared", "fit_alone", "idle")
+PH_FIND_PARENTS_BESIDE = {
+    stretch: profiling.phase_type(f"scheduler.find_parents_beside_{stretch}") for stretch in STRETCHES
+}
+stretch_provider = None  # () -> one of STRETCHES
 
 # flight-recorder emitters: one event per scheduling decision, always on
 # (the per-decision record the sampled trace usually misses)
@@ -259,28 +282,38 @@ class Scheduling:
     def find_candidate_parents(
         self, peer: Peer, blocklist: set[str] | None = None
     ) -> tuple[list[Peer], bool]:
-        blocklist = blocklist or set()
-        # only ReceivedNormal/Running peers reschedule; other states
-        # (incl. BackToSource) are already placed
-        if not peer.fsm.is_state(PEER_STATE_RECEIVED_NORMAL, PEER_STATE_RUNNING):
-            return [], False
+        # the stretch is read once, as the decision begins
+        provider = stretch_provider
+        stretch = provider() if provider is not None else None
+        try:
+            with PH_FIND_PARENTS:
+                # only ReceivedNormal/Running peers reschedule; other states
+                # (incl. BackToSource) are already placed
+                if not peer.fsm.is_state(PEER_STATE_RECEIVED_NORMAL, PEER_STATE_RUNNING):
+                    return [], False
 
-        candidates = self._filter_candidate_parents(peer, blocklist)
-        if not candidates:
-            return [], False
+                with PH_FILTER_PARENTS:
+                    candidates = self._filter_candidate_parents(peer, blocklist or set())
+                if not candidates:
+                    return [], False
 
-        total = peer.task.total_piece_count
-        # duplicated call instead of maybe_span: the unsampled branch
-        # then pays ONE predicate — not even the attrs dict build
-        _e0 = time.perf_counter()
-        if tracing.is_sampling():
-            with tracing.get("scheduler").span("evaluate", candidates=len(candidates)):
-                candidates = self.evaluator.evaluate_parents(candidates, peer, total)
-        else:
-            candidates = self.evaluator.evaluate_parents(candidates, peer, total)
-        PH_EVALUATE.observe(time.perf_counter() - _e0)
-        limit = self._candidate_parent_limit()
-        return candidates[:limit], True
+                total = peer.task.total_piece_count
+                # duplicated call instead of maybe_span: the unsampled branch
+                # then pays ONE predicate — not even the attrs dict build
+                with PH_EVALUATE:
+                    if tracing.is_sampling():
+                        with tracing.get("scheduler").span("evaluate", candidates=len(candidates)):
+                            candidates = self.evaluator.evaluate_parents(candidates, peer, total)
+                    else:
+                        candidates = self.evaluator.evaluate_parents(candidates, peer, total)
+                limit = self._candidate_parent_limit()
+                return candidates[:limit], True
+        finally:
+            # the same seconds, whatever way the decision ended; a name
+            # outside STRETCHES books nothing
+            beside = PH_FIND_PARENTS_BESIDE.get(stretch)
+            if beside is not None:
+                beside.observe(PH_FIND_PARENTS.last_s)
 
     def find_success_parent(
         self, peer: Peer, blocklist: set[str] | None = None

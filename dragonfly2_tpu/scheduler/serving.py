@@ -225,9 +225,6 @@ class ScoringService:
         self.rows_scored = 0
         self.waves = 0
         self.wave_rows = 0
-        # recent per-wave unpack walls (µs) for bench percentiles;
-        # bounded so a long soak never grows it past two pages
-        self.wave_unpack_us: "list[float]" = []
 
     # -- lifecycle -----------------------------------------------------
     def start(self) -> None:
@@ -436,22 +433,13 @@ class ScoringService:
         """Immediate/overflow escape for a wave: one bucketed forward,
         host segment rank — same orders as the fused path."""
         scores = self._score_now(model, features, pairs)
-        t0 = time.perf_counter()
         rankings = wavelib.rank_segments(scores, counts)
-        self._note_unpack(time.perf_counter() - t0)
         out = []
         off = 0
         for c, rk in zip(counts, rankings):
             out.append((scores[off : off + c], rk))
             off += c
         return out
-
-    def _note_unpack(self, dt_s: float) -> None:
-        M.WAVE_UNPACK_SECONDS.observe(dt_s)
-        us = self.wave_unpack_us
-        us.append(dt_s * 1e6)
-        if len(us) > 4096:
-            del us[:2048]
 
     def _score_now(self, model, features, pairs) -> np.ndarray:
         FP_SCORE()
@@ -595,7 +583,6 @@ class ScoringService:
             self.batches += 1
             self.rows_scored += rows
             if has_wave:
-                M.WAVE_OCCUPANCY_ROWS.observe(rows)
                 self.waves += 1
                 self.wave_rows += rows
             with PH_SCORE_UNPACK:
@@ -607,10 +594,8 @@ class ScoringService:
                         # segments, so its slice of the global
                         # permutation is already its local
                         # segment-grouped order
-                        t0 = time.perf_counter()
                         local = order[off : off + req.rows] - off
                         req.rankings = wavelib.split_order(local, req.counts)
-                        self._note_unpack(time.perf_counter() - t0)
                     off += req.rows
                     req.done.set()
 

@@ -647,7 +647,7 @@ class TrainPairsWalk:
     blocks: list = field(default_factory=list)
     trains_before: list = field(default_factory=list)  # the ``train`` blocks before each of ``blocks``
 
-    def assemble(self, span_timer=None, check_timer=None):
+    def assemble(self, span_phase=None, check_phase=None):
         """The blocks' pairs concatenated → ``PairExamples``, and with
         ``verify_crc`` every block of the range checked against the
         ``crc32`` its header states: exactly once, here, and all of them
@@ -676,13 +676,16 @@ class TrainPairsWalk:
         the lock up and asks for it again every time: four times a
         block. The same CRC-32 over the same bytes and the same arrays
         either way: the per-block path is what the library's is held to.
-        ``span_timer``, when given, is called as ``span_timer(seconds)``
-        once a span by the thread that ran it, and ``check_timer`` the
-        same way with the seconds of the library's check: not at all
-        where the per-block loop ran."""
+        ``span_phase``, when given, is a context manager that can be
+        entered on several threads at once (a profiling phase): the
+        thread that runs a span enters it around the span, once a span,
+        and ``check_phase`` the same way around the library's check: not
+        at all where the per-block loop ran."""
         from dragonfly2_tpu.schema import native
         from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
 
+        span_phase = span_phase or contextlib.nullcontext()
+        check_phase = check_phase or contextlib.nullcontext()
         lib = native.load() if self.blocks else None
         # the mapping's first byte: a payload's place counts from it
         base = np.frombuffer(self.mapped, np.uint8).ctypes.data if lib is not None else 0
@@ -702,38 +705,34 @@ class TrainPairsWalk:
                     if zlib.crc32(self.mapped[start : start + nbytes]) & 0xFFFFFFFF != crc:
                         raise WireError(f"block crc mismatch at byte {pos}")
                 return
-            t0 = time.perf_counter()
-            bad = lib.df_crc32_blocks(base, np.array(span, np.int64), len(span))
-            if check_timer is not None:
-                check_timer(time.perf_counter() - t0)
+            with check_phase:
+                bad = lib.df_crc32_blocks(base, np.array(span, np.int64), len(span))
             if bad >= 0:
                 raise WireError(f"block crc mismatch at byte {span[bad][0]}")
 
         def assemble_span(lo: int) -> None:
-            t0 = time.perf_counter()
-            span = self.blocks[lo : lo + ASSEMBLY_SPAN_BLOCKS]
-            if self.verify_crc:
-                check_span(span)
-            # the span's ``train`` blocks, and where their pairs go
-            t_lo, t_hi = trains_before[lo], trains_before[lo + len(span)]
-            at, end = pairs_before[t_lo], pairs_before[t_hi]
-            if end > at:
-                _gather(lib, self.features[t_lo:t_hi], features[at:end])
-                _gather(lib, self.labels[t_lo:t_hi], labels[at:end])
-                # per-block indices are 0-based within their block's record batch —
-                # rebase onto the running record count so the concatenated result
-                # keeps the documented "row in the source batch" invariant instead
-                # of aliasing records across blocks. A span's indices are rebased
-                # in the array the caller is handed, by one add over the span: a
-                # few calls a span under the interpreter lock, not a pass over
-                # the whole upload (``np.repeat`` of every block's base held it
-                # 0.2 s at 55M pairs) and not a lock handed over once a block
-                index = download_index[at:end]
-                _gather(lib, self.download_index[t_lo:t_hi], index)
-                bases = np.asarray(self.bases[t_lo:t_hi], index.dtype)
-                np.add(index, np.repeat(bases, lengths[t_lo:t_hi]), out=index)
-            if span_timer is not None:
-                span_timer(time.perf_counter() - t0)
+            with span_phase:
+                span = self.blocks[lo : lo + ASSEMBLY_SPAN_BLOCKS]
+                if self.verify_crc:
+                    check_span(span)
+                # the span's ``train`` blocks, and where their pairs go
+                t_lo, t_hi = trains_before[lo], trains_before[lo + len(span)]
+                at, end = pairs_before[t_lo], pairs_before[t_hi]
+                if end > at:
+                    _gather(lib, self.features[t_lo:t_hi], features[at:end])
+                    _gather(lib, self.labels[t_lo:t_hi], labels[at:end])
+                    # per-block indices are 0-based within their block's record batch —
+                    # rebase onto the running record count so the concatenated result
+                    # keeps the documented "row in the source batch" invariant instead
+                    # of aliasing records across blocks. A span's indices are rebased
+                    # in the array the caller is handed, by one add over the span: a
+                    # few calls a span under the interpreter lock, not a pass over
+                    # the whole upload (``np.repeat`` of every block's base held it
+                    # 0.2 s at 55M pairs) and not a lock handed over once a block
+                    index = download_index[at:end]
+                    _gather(lib, self.download_index[t_lo:t_hi], index)
+                    bases = np.asarray(self.bases[t_lo:t_hi], index.dtype)
+                    np.add(index, np.repeat(bases, lengths[t_lo:t_hi]), out=index)
 
         edges = range(0, len(self.blocks), ASSEMBLY_SPAN_BLOCKS)
         if len(edges) <= 1:

@@ -204,7 +204,6 @@ class TopologyEngine:
                 edges=self.store.num_edges,
                 wall_ms=round((time.perf_counter() - t0) * 1e3, 3),
             )
-            TM.FLUSH_LATENCY.observe(time.perf_counter() - t0)
             TM.DELTA_QUEUE_GAUGE.set(len(self.deltas))
             dropped = self.deltas.dropped
             if dropped > self._dropped_seen:

@@ -26,11 +26,6 @@ DELTA_DROPPED_TOTAL = _r.counter(
 FLUSH_TOTAL = _r.counter(
     "topology_flush_total", "Delta flushes applied to the device adjacency"
 )
-FLUSH_LATENCY = _r.histogram(
-    "topology_flush_seconds",
-    "Delta flush latency (drain + CSR build + device refresh)",
-    buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, float("inf")),
-)
 QUERY_TOTAL = _r.counter(
     "topology_query_total", "est_rtt queries", ("source",)
 )

@@ -78,11 +78,19 @@ JIT_RECOMPILES_TOTAL = _r.counter(
 )
 PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
 
+# A round: Training.train() from its first line to its outcome, entered
+# on the caller's thread. Open = a round is running; which of its legs
+# still are, each leg's ``fit`` phase below says.
+PH_ROUND = profiling.phase_type("trainer.round")
+
 # The resident fits' phases, one vocabulary for the three legs
-# (trainer/training.py wraps load and register, trainer/train.py the
-# rest). load/split/holdout/register are entered once a fit, the next
-# four once an epoch, never twice for one piece of work: total / count
-# reads as seconds a fit or seconds an epoch. A phase times the call as
+# (trainer/training.py wraps fit, load and register, trainer/train.py the
+# rest). load/split/table_put/holdout/register are entered once a fit, the
+# next four once an epoch, never twice for one piece of work: total / count
+# reads as seconds a fit or seconds an epoch. A stage entered inside
+# another (INNER_STAGES: feed_slice in gather, load_span in load) is
+# the ledger's and the trace's; the leg's split holds the outer one
+# (utils/profiling.py split). A phase times the call as
 # the code makes it; epoch_dispatch waits for each of its slices
 # (trainer/train.py), so the device's time lies in it. A fit's order is
 # drawn ahead of it on threads of its own (trainer/train.py FitOrder):
@@ -90,6 +98,9 @@ PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
 # the drawing threads took, and 1 - (split + gather's wait) / order is
 # the share of the draws that the leg did not sit out.
 FIT_STAGES = (
+    # the leg's whole fit as the round runs it (Training._timed_fit), around
+    # every stage below and the leg's own bookkeeping, and outside its split
+    "fit",
     "load",  # bytes on disk -> host arrays
     "split",  # the wait for the permutation that sets the holdout apart
     "gather",  # the wait for the epoch's permutation, then its row numbers index[perm], each slice of them handed to the device as it is composed
@@ -98,28 +109,28 @@ FIT_STAGES = (
     "epoch_wait",  # the read of the epoch's mean loss (on the host once the last slice is in)
     "holdout",  # holdout gather, forward and read-back
     "register",  # params to the host and create_model
-    # the bounded slices (trainer/train.py): one put and the wait for the put
-    # before it (a slice of the fit's table, 64 MiB, once a fit and under no
-    # other phase: booked, so a leg's split holds the table's put under this
-    # name; a slice of an epoch's row numbers, inside gather: observed), one
-    # dispatch and its wait (inside epoch_dispatch: observed). A leg's split
-    # counts no second twice; the ledger's entries say the slices engaged,
-    # total / count how long one holds its leg
+    "table_put",  # the fit's table on the chip (trainer/train.py _put_table), once a fit, a slice at a time
+    # the bounded slices (trainer/train.py), each entered where it is run: one
+    # put and the wait for the put before it (a slice of the fit's table, 64
+    # MiB, inside table_put; a slice of an epoch's row numbers, inside gather),
+    # one dispatch and its wait (inside epoch_dispatch). The ledger's entries
+    # say the slices engaged, total / count how long one holds its leg
     "feed_slice",
     "epoch_slice",
     # a span of the upload's blocks checked against their CRCs and copied into
     # the arrays the fit is handed (schema/wire.py TrainPairsWalk.assemble),
-    # inside load: observed once a span by the worker that ran it, so the count
-    # says the spans engaged, the total is seconds summed over the workers, and
-    # total / (load - the walk's seconds) is how many of them were busy
+    # inside load_assemble: entered once a span by the worker that runs it, on
+    # the worker's thread, so the count says the spans engaged, the total is
+    # seconds summed over the workers, and total / load_assemble is how many
+    # of them were busy
     "load_span",
     # a span's blocks checked by one call of the native library that holds no
     # interpreter lock (schema/native.py df_crc32_blocks), inside load_span:
-    # observed once a span by the same worker with the call's seconds, so the
-    # count says the one-call check engaged (a span a count; 0 where the
-    # library did not load and zlib.crc32 ran once a block), the total is
-    # seconds checking, and load_span - load_check is the copy and the first
-    # touch of its pages
+    # entered once a span by the same worker around the call, so the count
+    # says the one-call check engaged (a span a count; 0 where the library did
+    # not load and zlib.crc32 ran once a block), the total is seconds
+    # checking, and load_span - load_check is the copy and the first touch of
+    # its pages
     "load_check",
     # a permutation drawn (the holdout's, an epoch's): entered on the drawing
     # thread, once a permutation, 1 + epochs a fit; its seconds are drawn beside
@@ -127,6 +138,14 @@ FIT_STAGES = (
     # split does
     "order",
 )
+# The two stretches of the resident MLP leg's load (schema/wire.py), inside
+# load, once a fit each, and no other leg's: the walk over the upload's
+# headers, the interpreter's work alone, and the assembly, which waits for
+# its workers' spans; load = load_walk + load_assemble but for the order's
+# start
+MLP_LOAD_STAGES = ("load_walk", "load_assemble")
+# entered inside another stage, on the leg's thread or on a worker's: in no split
+INNER_STAGES = frozenset({*MLP_LOAD_STAGES, "feed_slice", "epoch_slice", "load_span", "load_check"})
 
 
 # What a resident fit puts on the chip: its table (every column, once a
@@ -145,13 +164,20 @@ class _LegPhases(SimpleNamespace):
     __slots__ = ("put_bytes",)
 
 
-def _fit_phases(leg: str) -> _LegPhases:
-    phases = _LegPhases(**{stage: profiling.phase_type(f"trainer.{leg}_{stage}") for stage in FIT_STAGES})
+def _fit_phases(leg: str, stages: tuple = FIT_STAGES) -> _LegPhases:
+    phases = _LegPhases(
+        **{stage: profiling.phase_type(f"trainer.{leg}_{stage}", inner=stage in INNER_STAGES) for stage in stages}
+    )
     phases.put_bytes = FIT_PUT_BYTES_TOTAL.labels(leg)
     return phases
 
 
-PH_MLP, PH_GNN, PH_GRU = (_fit_phases(leg) for leg in ("mlp", "gnn", "gru"))
+LEG_PHASES = {
+    "mlp": _fit_phases("mlp", FIT_STAGES + MLP_LOAD_STAGES),
+    "gnn": _fit_phases("gnn"),
+    "gru": _fit_phases("gru"),
+}
+PH_MLP, PH_GNN, PH_GRU = LEG_PHASES.values()
 # unix timestamp of the last SUCCESSFUL fit per model: the telemetry
 # plane's fit-freshness source (freshness = now - value; 0 = never) —
 # a gauge, so the manager can compute staleness without rate math

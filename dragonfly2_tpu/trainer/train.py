@@ -23,6 +23,7 @@ Throughput design (north star: 1B records in <10 min on v5e-8):
 
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -41,7 +42,7 @@ from jax.sharding import PartitionSpec as P
 from dragonfly2_tpu.models import gnn as gnn_mod
 from dragonfly2_tpu.models import gru as gru_mod
 from dragonfly2_tpu.models import mlp as mlp_mod
-from dragonfly2_tpu.trainer.metrics import PH_GNN, PH_GRU, PH_MLP
+from dragonfly2_tpu.trainer.metrics import LEG_PHASES, PH_GNN, PH_GRU, PH_MLP
 from dragonfly2_tpu.utils import faults
 from dragonfly2_tpu.utils.jitcache import jit_once
 
@@ -332,11 +333,10 @@ def _put_table(mesh, phases, *columns: np.ndarray) -> _Table:
     packs it. A put is asynchronous: it lands while the next is
     handed over, and is waited for before the one after that is, so the
     transfer queue never holds more than two (a served batch's put
-    waits behind 128 MiB at most). ``phases.feed_slice`` is booked what
-    each put and wait took: no other phase is open here, so the leg's
-    split counts the table's put under that name. Under a mesh every
-    chip holds the table whole (the row numbers carry the batch's
-    sharding)."""
+    waits behind 128 MiB at most). ``phases.table_put`` is open from the
+    first slice to the packed table's arrival, ``phases.feed_slice``
+    inside it around each put and wait. Under a mesh every chip holds
+    the table whole (the row numbers carry the batch's sharding)."""
     spec = tuple((c.shape[1:], jax.dtypes.canonicalize_dtype(c.dtype)) for c in columns)
     if any(dtype.itemsize != 4 for _, dtype in spec):
         raise TypeError(f"a fit's columns hold 32-bit values, not {[str(d) for _, d in spec]}")
@@ -348,22 +348,22 @@ def _put_table(mesh, phases, *columns: np.ndarray) -> _Table:
     slices = max(-(-n // (max(FEED_SLICE_BYTES // (4 * words) // per_row, 1) * per_row)), 1)
     rows = -(-max(n, 1) // (slices * per_row)) * per_row
     everywhere = None if mesh is None else NamedSharding(mesh, P())
-    packed = jnp.zeros((slices * rows // per_row, lanes), jnp.uint32, device=everywhere)
-    last = None
-    for lo in range(0, n, rows):
-        t0 = time.perf_counter()
-        parts = []
-        for c, (_, dtype) in zip(columns, spec):
-            part = c[lo : lo + rows]
-            if len(part) < rows:
-                part = np.concatenate([part, np.zeros((rows - len(part), *c.shape[1:]), c.dtype)])
-            parts.append(_on_mesh(mesh, part.astype(dtype, copy=False)))
-            phases.put_bytes.inc(parts[-1].nbytes)
-        packed = _pack_slice(packed, lo // per_row, *parts)
-        jax.block_until_ready(last)
-        last = parts
-        phases.feed_slice.book(time.perf_counter() - t0)
-    return _Table(jax.block_until_ready(packed), spec)
+    with phases.table_put:
+        packed = jnp.zeros((slices * rows // per_row, lanes), jnp.uint32, device=everywhere)
+        last = None
+        for lo in range(0, n, rows):
+            with phases.feed_slice:
+                parts = []
+                for c, (_, dtype) in zip(columns, spec):
+                    part = c[lo : lo + rows]
+                    if len(part) < rows:
+                        part = np.concatenate([part, np.zeros((rows - len(part), *c.shape[1:]), c.dtype)])
+                    parts.append(_on_mesh(mesh, part.astype(dtype, copy=False)))
+                    phases.put_bytes.inc(parts[-1].nbytes)
+                packed = _pack_slice(packed, lo // per_row, *parts)
+                jax.block_until_ready(last)
+                last = parts
+        return _Table(jax.block_until_ready(packed), spec)
 
 
 class _Epoch(tuple):
@@ -422,18 +422,17 @@ def _feed_slices(mesh, host, table: _Table, steps: int, phases, axis: str = "dp"
     parallelism). Returns the epoch of ``steps`` steps over ``table``
     as ``make_epoch_fn`` takes it. ``phases`` is the leg's: ``gather``
     times the loop (the wait for the permutation, the row numbers, their puts),
-    ``feed_slice`` is fed what each slice's put and wait took of it,
+    ``feed_slice`` inside it each slice's put and wait,
     ``feed`` times the wait for what had not landed when it ended."""
     fed: list = []
     with phases.gather:
         for rows in host:
-            t0 = time.perf_counter()
-            sharded = mesh is not None and rows.shape[1] % mesh.shape[axis] == 0
-            fed.append(_on_mesh(mesh, rows, P(None, axis) if sharded else P()))
-            phases.put_bytes.inc(rows.nbytes)
-            del rows  # the transfer holds it until it is through
-            jax.block_until_ready(fed[-2:-1])
-            phases.feed_slice.observe(time.perf_counter() - t0)
+            with phases.feed_slice:
+                sharded = mesh is not None and rows.shape[1] % mesh.shape[axis] == 0
+                fed.append(_on_mesh(mesh, rows, P(None, axis) if sharded else P()))
+                phases.put_bytes.inc(rows.nbytes)
+                del rows  # the transfer holds it until it is through
+                jax.block_until_ready(fed[-2:-1])
     with phases.feed:
         jax.block_until_ready(fed)
     epoch = _Epoch(table.columns)
@@ -509,10 +508,10 @@ def make_epoch_fn(
     takes its name from the loss's (``mlp_loss`` -> ``mlp_epoch``), so
     that a trace's host events (``PjitFunction(mlp_epoch)``), the XLA
     module and the scope of the step's ops say which leg they belong to;
-    a leg's ``epoch_slice`` phase is fed each slice's wall."""
+    a leg's ``epoch_slice`` phase is open around each slice."""
     leg = getattr(loss_fn, "__name__", "loss").removesuffix("_loss")
     name = leg + "_epoch"
-    slice_phase = {"mlp": PH_MLP, "gnn": PH_GNN, "gru": PH_GRU}.get(leg)
+    slice_phase = LEG_PHASES[leg].epoch_slice if leg in LEG_PHASES else contextlib.nullcontext()
 
     def epoch_slice(params, opt_state, table, rows, steps):
         def body(i, carry):
@@ -531,13 +530,11 @@ def make_epoch_fn(
     def epoch(params, opt_state, batches):
         left, loss_sum = batches.steps, 0.0
         for rows in batches.rows:
-            t0 = time.perf_counter()
-            steps = min(rows.shape[0], left)
-            params, opt_state, part_sum = run_slice(params, opt_state, batches.table, rows, steps)
-            loss_sum += float(part_sum)  # the wait: nothing is queued behind a running slice
-            left -= steps
-            if slice_phase is not None:
-                slice_phase.epoch_slice.observe(time.perf_counter() - t0)
+            with slice_phase:
+                steps = min(rows.shape[0], left)
+                params, opt_state, part_sum = run_slice(params, opt_state, batches.table, rows, steps)
+                loss_sum += float(part_sum)  # the wait: nothing is queued behind a running slice
+                left -= steps
         return params, opt_state, loss_sum / batches.steps
 
     def lower(params, opt_state, batches):

@@ -228,7 +228,7 @@ class Training:
         mlp_info: dict = {}
         splits = outcome.splits
         t0 = time.perf_counter()
-        with self._round_profile(), concurrent.futures.ThreadPoolExecutor(
+        with self._round_profile(), M.PH_ROUND, concurrent.futures.ThreadPoolExecutor(
             max_workers=3
         ) as pool:
             f_mlp = pool.submit(
@@ -315,9 +315,11 @@ class Training:
 
         # the fit span is active while fn runs so the ingest pipeline can
         # stamp its exemplars with the owning trace_id; the split is this
-        # thread's, so the phases fn enters are credited to this leg
+        # thread's, so the phases fn enters are credited to this leg; the
+        # leg's own ``fit`` phase is open around the split, not in it
         with (
             M.FIT_DURATION.labels(model).time(),
+            M.LEG_PHASES[model].fit,
             tracing.use_span(span),
             profiling.split() as mine,
         ):
@@ -451,14 +453,14 @@ class Training:
             order = None
             with M.PH_MLP.load:
                 if binary:
-                    walk = wire.walk_train_pairs(path, offset=offset, end=boundary, tally=blocks)
+                    with M.PH_MLP.load_walk:
+                        walk = wire.walk_train_pairs(path, offset=offset, end=boundary, tally=blocks)
                     # the fit's order needs the pair count and not the pairs:
                     # it is drawn beside the assembly, which checks every block
                     # and raises before it hands over an array
                     order = drawing.enter_context(FitOrder(M.PH_MLP, walk.num_pairs, cfg))
-                    pairs = walk.assemble(
-                        span_timer=M.PH_MLP.load_span.observe, check_timer=M.PH_MLP.load_check.observe
-                    )
+                    with M.PH_MLP.load_assemble:
+                        pairs = walk.assemble(span_phase=M.PH_MLP.load_span, check_phase=M.PH_MLP.load_check)
                     del walk
                 else:
                     # bounded at the round boundary exactly like the binary and

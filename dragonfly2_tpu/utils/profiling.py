@@ -22,7 +22,11 @@ Two instruments, both cheap enough to leave on in production:
   phases on the host plane beside the device's ops; and it is
   credited to the thread's own
   :func:`split`, when one is open, so a caller can read what ITS work
-  took apart from the process-wide totals. The ledger generalizes the
+  took apart from the process-wide totals (a phase declared ``inner``,
+  one entered inside another on the same thread, is the ledger's and
+  the trace's alone: a split counts no second twice). The interpreter's
+  full collections are a phase too, ``process.gc_full``
+  (:func:`watch_collections`). The ledger generalizes the
   trainer's per-fit StreamStats split into live, cross-service
   counters: the same buffer_wait/decode_wait/h2d/step attribution,
   scrapeable mid-fit via ``/metrics`` (``prof_phase_seconds``) and
@@ -58,6 +62,7 @@ from __future__ import annotations
 import bisect
 import collections
 import contextlib
+import gc
 import os
 import sys
 import threading
@@ -426,7 +431,9 @@ def split():
     ``{phase: [entries, seconds]}`` for every phase THIS thread leaves
     (the ``with`` form) or books (``Phase.book``) inside the block. The
     ledger sums a process; two fits of the same model running at once
-    share its totals, and each still reads its own work here."""
+    share its totals, and each still reads its own work here. A phase
+    declared ``inner`` is credited to none: the phase it is entered
+    inside holds its seconds."""
     prev, mine = _thread.split, {}
     _thread.split = mine
     try:
@@ -439,7 +446,11 @@ class Phase:
     """One named wall-clock phase. Declared once per module via
     :func:`phase_type`; usable as a (re-entrant, thread-safe) context
     manager or fed pre-measured durations with ``observe`` (ledger
-    only) or ``book`` (ledger and the calling thread's open split).
+    only) or ``book`` (ledger and the calling thread's open split). The
+    ``with`` form books, or observes where the phase is declared
+    ``inner``: one entered inside another ``with`` phase on the same
+    thread, whose seconds that one holds already. ``last_s`` is what the
+    ``with`` block the calling thread left last took.
 
     The hot path is ledger-only — one bisect + one short lock per
     ``observe``, plain GIL int adds for the active counter (the flight
@@ -450,11 +461,12 @@ class Phase:
 
     __slots__ = (
         "name", "count", "total_s", "max_s", "bucket_counts", "active_n",
-        "_lock", "_tls", "_synced_count", "_synced_total_s",
+        "inner", "_lock", "_tls", "_synced_count", "_synced_total_s",
     )
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, inner: bool = False):
         self.name = name
+        self.inner = inner
         self.count = 0
         self.total_s = 0.0
         self.max_s = 0.0
@@ -507,17 +519,28 @@ class Phase:
         return self
 
     def __exit__(self, *exc):
-        ann, t0 = self._tls.starts.pop()
-        dt = time.perf_counter() - t0
+        tls = self._tls
+        ann, t0 = tls.starts.pop()
+        tls.last_s = dt = time.perf_counter() - t0
         if ann is not None:
             ann.__exit__(None, None, None)
         self.active_n -= 1
-        self.book(dt)
+        if self.inner:
+            self.observe(dt)
+        else:
+            self.book(dt)
         return False
 
     @property
     def active(self) -> int:
         return self.active_n
+
+    @property
+    def last_s(self) -> float:
+        """The seconds of the ``with`` block the calling thread left
+        last: for a caller that files the same seconds elsewhere too,
+        without a clock of its own."""
+        return self._tls.last_s
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -554,11 +577,13 @@ _phases_lock = threading.Lock()
 _sync_lock = threading.Lock()
 
 
-def phase_type(name: str) -> Phase:
+def phase_type(name: str, inner: bool = False) -> Phase:
     """Declare (or fetch) a named phase. Names are ``<service>.<what>``
     like flight event types and are censused by the dfanalyze metrics
     pass (duplicates, convention). Idempotent: re-declaring a name
-    returns the same ledger entry."""
+    returns the same ledger entry. ``inner``: the phase is entered
+    inside another ``with`` phase on the same thread, and no
+    :func:`split` is credited it."""
     service, _, what = name.partition(".")
     if not service or not what or not all(
         c.islower() or c.isdigit() or c in "._" for c in name
@@ -569,8 +594,10 @@ def phase_type(name: str) -> Phase:
         with _phases_lock:
             ph = _phases.get(name)
             if ph is None:
-                ph = Phase(name)
+                ph = Phase(name, inner)
                 _phases[name] = ph
+    if inner:  # whoever fetched the name first (a tool reading the ledger) did not know
+        ph.inner = True
     return ph
 
 
@@ -579,6 +606,32 @@ def phase(name: str) -> Phase:
     Prefer a module-level ``phase_type`` declaration on hot paths (the
     dict lookup here is the only difference)."""
     return _phases.get(name) or phase_type(name)
+
+
+# A full collection stops every thread for as long as it takes to walk
+# what the collector tracks (0.04-0.16 s beside a round, PERF.md): a span
+# on the collecting thread, so a trace shows it between the device's ops
+# and the ledger counts it. The young generations, thousands a second,
+# return at one comparison. A collection is no work of the leg whose
+# thread it fell on, so no split holds it.
+PH_GC_FULL = phase_type("process.gc_full", inner=True)
+
+
+def _on_collection(event: str, info: dict) -> None:
+    if info["generation"] != 2:
+        return
+    if event == "start":
+        PH_GC_FULL.__enter__()
+    elif getattr(PH_GC_FULL._tls, "starts", None):  # not one under way when the hook went in
+        PH_GC_FULL.__exit__(None, None, None)
+
+
+def watch_collections() -> None:
+    """Enter ``process.gc_full`` around every full collection from now
+    on (idempotent): called where a service's process is assembled,
+    :func:`install` and ``colocated.settle``."""
+    if _on_collection not in gc.callbacks:
+        gc.callbacks.append(_on_collection)
 
 
 def ledger_snapshot() -> dict:
@@ -618,13 +671,15 @@ def profiler() -> SamplingProfiler:
 
 def install(service: str) -> None:
     """Start the process-wide sampler (idempotent), next to
-    ``flight.install`` in every server assembly. ``DF_PROF=0`` or
-    ``DF_PROF_HZ=0`` leaves the phase ledger live but samples nothing."""
+    ``flight.install`` in every server assembly, and watch the
+    interpreter's full collections. ``DF_PROF=0`` or ``DF_PROF_HZ=0``
+    leaves the phase ledger live but samples nothing."""
     if service:
         if not _profiler.service:
             _profiler.service = service
         elif service not in _profiler.service.split("+"):
             _profiler.service += f"+{service}"
+    watch_collections()
     if enabled():
         _profiler.start()
 

@@ -23,6 +23,16 @@ were still running when each began (``legs_3``: all of them; ``legs_1``:
 the MLP leg alone), and the ``assemble`` and ``fit`` stretches by the same:
 two trees read alike where the same stretch beside the same legs waits the
 same, and differ in how many seconds a round are of each kind.
+
+Since ISSUE 40 the program books the same cut itself (a decision's seconds
+from inside, ``scheduler.find_parents_beside_<stretch>``, and the stretches
+as ``trainer.mlp_load_walk`` / ``_load_assemble``): a third line gives, for
+the same window, the in-program count and mean a stretch and the two
+stretches' seconds a round, so one run shows whether inside and outside
+agree (``fit`` there is ``fit_shared`` and ``fit_alone`` together, ``idle``
+is ``between``). The outside cut's ``service_mean_ms`` is the mean from a
+decision's own start, the queue wait left out: what the inside means are
+to be held against. A tree without those phases prints zeros.
 """
 
 from __future__ import annotations
@@ -42,6 +52,16 @@ def main() -> int:
     from benchmarks.generators import decide_under_round as gen
     from dragonfly2_tpu.schema import wire
     from dragonfly2_tpu.trainer import training as training_mod
+    from dragonfly2_tpu.utils import profiling
+
+    inside_names = ("walk", "assemble", "fit_shared", "fit_alone", "idle")
+    inside = {
+        **{n: profiling.phase_type(f"scheduler.find_parents_beside_{n}") for n in inside_names},
+        "walk_s": profiling.phase_type("trainer.mlp_load_walk"),
+        "assemble_s": profiling.phase_type("trainer.mlp_load_assemble"),
+        "find_parents": profiling.phase_type("scheduler.find_parents"),
+    }
+    inside_at: list = []  # the phases' (count, seconds) as each window opened and closed, the timed window's last
 
     marks: list = []  # (what, perf_counter)
     windows: list = []  # (t0, due, start, end) of every Beside closed, the timed window's last
@@ -62,14 +82,22 @@ def main() -> int:
     training_mod.Training.train = marked(training_mod.Training.train, "round", "round_end")
     training_mod.Training._train_gnn = marked(training_mod.Training._train_gnn, None, "gnn_end")
     training_mod.Training._train_gru = marked(training_mod.Training._train_gru, None, "gru_end")
-    close = gen.Beside.close
+    close, open_ = gen.Beside.close, gen.Beside.open
+
+    def ledger() -> dict:
+        return {k: (ph.count, ph.total_s) for k, ph in inside.items()}
+
+    def opening(self, *a, **kw):
+        inside_at.append([ledger(), None])
+        return open_(self, *a, **kw)
 
     def closing(self, *a, **kw):
         out = close(self, *a, **kw)
+        inside_at[-1][1] = ledger()
         windows.append((self.t0, np.array(self.due), np.array(self.start), np.array(self.end)))
         return out
 
-    gen.Beside.close = closing
+    gen.Beside.open, gen.Beside.close = opening, closing
     rc = bench_run.main()
     t0, due, start, end = windows[-1]
     answered = end > 0
@@ -82,6 +110,7 @@ def main() -> int:
             others.append(sorted(at.get(leg, t) for leg in ("gnn_end", "gru_end")))
     names = ("walk", "assemble", "fit", "between")
     lat: dict = {n: [] for n in names}
+    service: dict = {n: [] for n in names}  # from a decision's own start: no queue wait in it
     seconds = {n: 0.0 for n in names}
     for i, r in enumerate(rounds):
         until = rounds[i + 1][0] if i + 1 < len(rounds) else r[3]
@@ -90,14 +119,32 @@ def main() -> int:
             seconds[n] += hi - lo
             took = answered & (start >= lo) & (start < hi)
             lat[n].extend(((end - (t0 + due))[took] * 1e3).tolist())
+            service[n].extend(((end - start)[took] * 1e3).tolist())
     out = {
         n: {
             "s_a_round": round(seconds[n] / max(len(rounds), 1), 3), "decisions": len(lat[n]),
             **({f"p{q}_ms": round(float(np.percentile(lat[n], q)), 2) for q in (50, 90, 99)} if lat[n] else {}),
+            **({"mean_ms": round(float(np.mean(lat[n])), 2), "service_mean_ms": round(float(np.mean(service[n])), 2)} if lat[n] else {}),
         }
         for n in names
     }
     print("decisions by the round's stretch: " + json.dumps({"rounds": len(rounds), **out}), flush=True)
+    # the program's own record of the same window
+    was, now = inside_at[-1]
+    moved = {k: (now[k][0] - was[k][0], now[k][1] - was[k][1]) for k in inside}
+    fit = tuple(moved["fit_shared"][i] + moved["fit_alone"][i] for i in (0, 1))
+    print(
+        "decisions by the round's stretch, from inside: "
+        + json.dumps({
+            "find_parents": moved["find_parents"][0],
+            **{
+                n: {"decisions": c, "mean_ms": round(s / c * 1e3, 2) if c else None}
+                for n, (c, s) in (*((n, moved[n]) for n in inside_names), ("fit", fit))
+            },
+            **{n: round(moved[n][1] / moved[n][0], 3) if moved[n][0] else None for n in ("walk_s", "assemble_s")},
+        }),
+        flush=True,
+    )
     try:
         # the same decisions by the legs still running: three until the first of the other two ends, one after the second
         cut: dict = {}

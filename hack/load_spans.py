@@ -45,6 +45,7 @@ import numpy as np
 
 from benchmarks.harness import synth
 from dragonfly2_tpu.schema import native, wire
+from dragonfly2_tpu.utils import profiling
 
 
 def stage(path: str, chunks: int, bodies: int, body_records: int, seed: int) -> None:
@@ -81,8 +82,8 @@ def reading(path: str, threads: int, span_blocks: int, draws: int, beside: int =
     else:
         os.environ.pop("DF_NO_NATIVE", None)
     wire._gather = (lambda lib, parts, out: _GATHER(None, parts, out)) if library == "check" else _GATHER
-    spans: list = []
-    checks: list = []
+    # phases of this reading's own, as the trainer hands the assembly its leg's
+    spans, checks = profiling.Phase("hack.load_span"), profiling.Phase("hack.load_check")
     stop = threading.Event()
     others = [threading.Thread(target=busy, args=(stop, duty), daemon=True) for _ in range(beside)]
     for t in others:
@@ -97,7 +98,7 @@ def reading(path: str, threads: int, span_blocks: int, draws: int, beside: int =
     ]
     for t in drawing:
         t.start()
-    pairs = walk.assemble(span_timer=spans.append, check_timer=checks.append)
+    pairs = walk.assemble(span_phase=spans, check_phase=checks)
     t2 = time.perf_counter()
     stop.set()
     for t in drawing + others:
@@ -106,10 +107,10 @@ def reading(path: str, threads: int, span_blocks: int, draws: int, beside: int =
     out = {
         "threads": threads, "span_blocks": span_blocks, "draws": draws,
         "beside": beside, "duty": duty, "library": library if native.load() is not None else "none",
-        "checks": len(checks), "check_s_sum": round(sum(checks), 3),
+        "checks": checks.count, "check_s_sum": round(checks.total_s, 3),
         "walk_s": round(t1 - t0, 3), "assemble_s": round(t2 - t1, 3), "draws_after_s": round(t3 - t2, 3),
-        "spans": len(spans), "span_s_sum": round(sum(spans), 3), "span_s_max": round(max(spans), 4),
-        "busy_workers": round(sum(spans) / (t2 - t1), 2),
+        "spans": spans.count, "span_s_sum": round(spans.total_s, 3), "span_s_max": round(spans.max_s, 4),
+        "busy_workers": round(spans.total_s / (t2 - t1), 2),
         "pairs": int(pairs.labels.shape[0]), "blocks": len(walk.blocks),
     }
     del walk, pairs

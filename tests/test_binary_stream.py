@@ -4,6 +4,7 @@ tensors vs the CSV path, and the CSV-fallback negotiation for old
 trainers (ISSUE round 6 tentpole)."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -878,6 +879,20 @@ def _flip(path, extent):
     path.write_bytes(bytes(buf))
 
 
+class _Entered:
+    """What ``assemble`` takes for a phase: a context manager entered on
+    whatever thread runs a span; ``left`` is (thread, seconds) an entry."""
+
+    def __init__(self):
+        self.left, self._mine = [], threading.local()
+
+    def __enter__(self):
+        self._mine.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.left.append((threading.current_thread().name, time.perf_counter() - self._mine.t0))
+
+
 class TestCheckedAndCopiedASpanAtATime:
     """The resident read checks every block's CRC in its assembly, a
     span of blocks at a time, beside the copy (ISSUE 35)."""
@@ -890,18 +905,16 @@ class TestCheckedAndCopiedASpanAtATime:
         path, extents = _write_blocks(tmp_path, name, _SPANNED[name], seed=7)
         offset, end = _bounds(extents, which)
         in_range = len(wire.scan_block_extents(path, offset, end))
-        tally, ran_on, checked_on = wire.BlockTally(), [], []
+        tally, spans, checks = wire.BlockTally(), _Entered(), _Entered()
         walk = wire.walk_train_pairs(path, offset=offset, end=end, tally=tally)
-        got = walk.assemble(
-            span_timer=lambda s: ran_on.append((threading.current_thread().name, s)),
-            check_timer=lambda s: checked_on.append((threading.current_thread().name, s)),
-        )
+        got = walk.assemble(span_phase=spans, check_phase=checks)
+        ran_on, checked_on = spans.left, checks.left
         assert (tally.decoded, tally.hopped) == (in_range, 0)
         # once a span, by the thread that ran it: the caller's for one span, the pool's for more
         assert len(ran_on) == -(-in_range // span_of_4) and all(s >= 0 for _, s in ran_on)
         pooled = [name.startswith("wire.assemble") for name, _ in ran_on]
         assert all(pooled) if len(ran_on) > 1 else not any(pooled)
-        # the library's call is timed once a span by that same thread, inside the span's seconds; the per-block loop never
+        # the library's call is entered once a span by that same thread, inside the span's seconds; the per-block loop never
         assert sorted(t for t, _ in checked_on) == (sorted(t for t, _ in ran_on) if check_path[0] == "library" else [])
         assert sum(s for _, s in checked_on) <= sum(s for _, s in ran_on)
         assert not [t for t in threading.enumerate() if t.name.startswith("wire.assemble")]

@@ -22,8 +22,10 @@ from dragonfly2_tpu.rpc import gen  # noqa: F401
 import manager_pb2  # noqa: E402
 
 from dragonfly2_tpu.colocated import ColocatedConfig, ColocatedServer
+from dragonfly2_tpu.colocated import server as assembly
 from dragonfly2_tpu.rpc import resilience
 from dragonfly2_tpu.scheduler import resource as res
+from dragonfly2_tpu.scheduler import scheduling as scheduling_mod
 from dragonfly2_tpu.scheduler.evaluator import MLEvaluator
 from dragonfly2_tpu.scheduler.serving import MLPServed, ScoringService, ServingConfig
 from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
@@ -217,10 +219,111 @@ def test_the_service_settles_when_it_is_up_and_puts_it_all_back(cluster, tmp_pat
     )
     second.serve()
     assert sys.getswitchinterval() == pytest.approx(SWITCH_INTERVAL_S)
+    # and what only this process can say: the round's stretch for the scheduler's decisions, full collections as spans
+    assert scheduling_mod.stretch_provider is assembly.round_stretch
+    assert gc.callbacks.count(profiling._on_collection) == 1
     second.stop()
     assert gc.get_freeze_count() == 0 and sys.getswitchinterval() == pytest.approx(0.005)
+    assert scheduling_mod.stretch_provider is None
     sys.setswitchinterval(SWITCH_INTERVAL_S)
     gc.freeze()  # the fixture's service goes on as it was
+    scheduling_mod.stretch_provider = assembly.round_stretch
+
+
+# what has to be open for each of the provider's cases: the round's phase, the
+# MLP leg's two load stretches, how many legs' fits
+_STRETCH_CASES = {
+    "walk": ("round", "mlp_fit", "gnn_fit", "gru_fit", "mlp_load_walk"),
+    "assemble": ("round", "mlp_fit", "gru_fit", "mlp_load_assemble"),
+    "fit_shared": ("round", "mlp_fit", "gru_fit"),
+    "fit_alone": ("round", "gru_fit"),
+    "idle": (),
+}
+
+
+@pytest.mark.parametrize("stretch", sorted(_STRETCH_CASES))
+def test_the_provider_names_the_rounds_stretch_from_phases_other_threads_hold_open(stretch):
+    """``round_stretch`` reads open-phase counts and nothing else: with
+    the matching phases held open on threads of their own it names each
+    of its five cases, and ``idle`` again once they close."""
+    from dragonfly2_tpu.trainer import metrics as TM
+
+    phases = {
+        "round": TM.PH_ROUND, "mlp_fit": TM.PH_MLP.fit, "gnn_fit": TM.PH_GNN.fit, "gru_fit": TM.PH_GRU.fit,
+        "mlp_load_walk": TM.PH_MLP.load_walk, "mlp_load_assemble": TM.PH_MLP.load_assemble,
+    }
+    assert assembly.round_stretch() == "idle"
+    opened, release = threading.Barrier(len(_STRETCH_CASES[stretch]) + 1, timeout=30), threading.Event()
+
+    def hold(ph):
+        with ph:
+            opened.wait()
+            assert release.wait(timeout=30)
+
+    holders = [threading.Thread(target=hold, args=(phases[name],)) for name in _STRETCH_CASES[stretch]]
+    for t in holders:
+        t.start()
+    try:
+        opened.wait()
+        assert assembly.round_stretch() == stretch
+    finally:
+        release.set()
+        for t in holders:
+            t.join(timeout=30)
+    assert assembly.round_stretch() == "idle"
+    # a round with no leg's fit open (the pool's threads not yet begun, or all three done) is no shared fit
+    with TM.PH_ROUND:
+        assert assembly.round_stretch() == "fit_alone"
+
+
+def test_decisions_beside_a_round_are_each_booked_under_one_stretch(cluster):
+    """A toy window beside a toy round in the service's own process: the
+    five ``find_parents_beside_*`` counts sum to ``find_parents``' own,
+    decisions that began while the round ran are under its stretches,
+    and those asked after it under ``idle``."""
+    from dragonfly2_tpu.schema import synth, wire
+    from dragonfly2_tpu.utils.idgen import host_id_v2
+
+    srv = cluster["srv"]
+    assert scheduling_mod.stretch_provider is assembly.round_stretch
+    find = srv.scheduler.scheduling.find_candidate_parents
+    parents, child, task = _swarm(6)
+    for p in (*parents, child):
+        task.store_peer(p)
+    names = ("scheduler.find_parents", *(ph.name for ph in scheduling_mod.PH_FIND_PARENTS_BESIDE.values()))
+    host_id = host_id_v2(IP, HOSTNAME)
+    srv.trainer.storage.append_download_blocks(
+        host_id, wire.encode_train_block(synth.make_download_records(256, seed=4))
+    )
+    srv.trainer.storage.mark_download_round(host_id)
+    before = {n: _phase(n)["count"] for n in names}
+    stop, asked = threading.Event(), [0, 0]
+
+    def ask(k):
+        while not stop.is_set():
+            got, found = find(child)
+            assert found and len(got) > 0
+            asked[k] += 1
+            time.sleep(0.002)
+
+    workers = [threading.Thread(target=ask, args=(k,)) for k in range(2)]
+    for t in workers:
+        t.start()
+    try:
+        outcome = srv.trainer.training.train(IP, HOSTNAME)
+    finally:
+        stop.set()
+        for t in workers:
+            t.join(timeout=60)
+    assert outcome.mlp_error is None, outcome  # the fixture stages no topology: the MLP leg's load is the round's here
+    during = {n: _phase(n)["count"] - before[n] for n in names}
+    for _ in range(3):
+        assert find(child)[1]
+    moved = {n: _phase(n)["count"] - before[n] for n in names}
+    beside = {n.rsplit("beside_", 1)[1]: c for n, c in moved.items() if "beside_" in n}
+    assert sum(beside.values()) == moved["scheduler.find_parents"] == sum(asked) + 3
+    assert sum(during.values()) - during["scheduler.find_parents"] == during["scheduler.find_parents"]
+    assert beside["idle"] >= 3 and sum(beside.values()) - beside["idle"] > 0, beside
 
 
 @pytest.mark.parametrize("key, part", [("streaming", "trainer"), ("algorithm", "scheduler"), ("no_such_key", "scheduler")])

@@ -183,6 +183,46 @@ class TestPhaseLedger:
         assert ph.snapshot()["count"] == base + 2
         assert ph.active == 0
 
+    def test_a_split_holds_no_phase_declared_inner(self):
+        """A phase declared ``inner`` is entered inside another ``with``
+        phase on its thread: the ledger's and the trace's, and no
+        split's, which holds the outer one and so counts no second
+        twice. ``last_s`` is what the block this thread left last took."""
+        outer = profiling.phase_type("trainer.test_outer")
+        inner = profiling.phase_type("trainer.test_inner", inner=True)
+        # declared once; a reader that fetched the name first does not undo it
+        assert profiling.phase_type("trainer.test_inner") is inner and inner.inner
+        assert profiling.phase_type("trainer.test_read_first").inner is False
+        assert profiling.phase_type("trainer.test_read_first", inner=True).inner is True
+        base = {ph.name: ph.snapshot() for ph in (outer, inner)}
+        with profiling.split() as mine:
+            with outer:
+                with inner:
+                    pass
+                with inner:
+                    time.sleep(0.002)
+        assert {name: n for name, (n, _) in mine.items()} == {outer.name: 1}
+        moved = {ph.name: ph.snapshot()["count"] - base[ph.name]["count"] for ph in (outer, inner)}
+        assert moved == {outer.name: 1, inner.name: 2}
+        assert mine[outer.name][1] == outer.last_s >= inner.last_s >= 0.002
+        assert outer.snapshot()["total_s"] - base[outer.name]["total_s"] == pytest.approx(outer.last_s, abs=1e-6)
+
+    @pytest.mark.parametrize("generation, entries", [(2, 1), (0, 0), (1, 0)])
+    def test_a_full_collection_is_a_phase_and_a_young_one_is_not(self, generation, entries):
+        import gc
+
+        profiling.watch_collections()
+        profiling.watch_collections()  # idempotent: one hook
+        assert gc.callbacks.count(profiling._on_collection) == 1
+        ph = profiling.PH_GC_FULL
+        base = ph.snapshot()
+        with profiling.split() as mine:
+            gc.collect(generation)
+        snap = ph.snapshot()
+        assert snap["count"] - base["count"] == entries and snap["active"] == 0
+        assert snap["total_s"] >= base["total_s"]
+        assert not mine  # a collection is no work of the thread it fell on
+
     def test_snapshot_shares_sum_within_group(self):
         a = profiling.phase_type("manager.test_share_a")
         b = profiling.phase_type("manager.test_share_b")

@@ -26,8 +26,10 @@ from dragonfly2_tpu.utils.idgen import host_id_v2
 IP, HOSTNAME = "10.0.0.7", "sched-phases"
 LEGS = ("mlp", "gnn", "gru")
 EPOCHS = {"mlp": 2, "gnn": 4, "gru": 3}
-ONCE_A_FIT = ("load", "split", "holdout", "register")
+ONCE_A_FIT = ("load", "split", "table_put", "holdout", "register")
 ONCE_AN_EPOCH = ("gather", "feed", "epoch_dispatch", "epoch_wait")
+# entered inside one of those on the leg's thread: the ledger's and the trace's, in no split
+INSIDE_ANOTHER = ("fit", "load_walk", "load_assemble", "feed_slice", "epoch_slice", "load_span", "load_check")
 STREAM_PHASES = ("trainer.decode_wait", "trainer.buffer_wait", "trainer.h2d", "trainer.step")
 
 
@@ -141,7 +143,22 @@ def _expected_counts(leg: str, streaming: bool) -> dict:
         return {"trainer.mlp_register": 1}
     want = {f"trainer.{leg}_{stage}": 1 for stage in ONCE_A_FIT}
     want.update({f"trainer.{leg}_{stage}": EPOCHS[leg] for stage in ONCE_AN_EPOCH})
-    want[f"trainer.{leg}_feed_slice"] = 1  # the table's put, one slice at toy size, under no other phase
+    return want
+
+
+def _expected_inside(leg: str, streaming: bool) -> dict:
+    """The ledger's entries a round that no split holds: a phase open
+    inside another on the leg's thread, and the leg's ``fit`` around its
+    split."""
+    if leg == "mlp" and streaming:
+        return {"trainer.mlp_fit": 1}
+    want = {
+        f"trainer.{leg}_fit": 1,
+        f"trainer.{leg}_feed_slice": 1 + EPOCHS[leg],  # the table's one slice at toy size, and one an epoch of row numbers
+        f"trainer.{leg}_epoch_slice": EPOCHS[leg],
+    }
+    if leg == "mlp":
+        want.update({"trainer.mlp_load_walk": 1, "trainer.mlp_load_assemble": 1, "trainer.mlp_load_span": 1})
     return want
 
 
@@ -153,11 +170,10 @@ def test_every_phase_once_a_fit_or_once_an_epoch(round_, leg):
     want = _expected_counts(leg, round_["streaming"])
     assert split.phase_n == want
     assert all(split.phase_s[name] > 0 for name in want)
-    # the process-wide ledger moved by the same entries
-    for name, n in want.items():
-        if name.endswith("_feed_slice"):  # the ledger holds the epochs' row-number slices too
-            n += EPOCHS[leg]
+    # the process-wide ledger moved by the same entries, and by those inside them
+    for name, n in {**want, **_expected_inside(leg, round_["streaming"])}.items():
         assert round_["ledger"][name][0] == n, name
+    assert not {f"trainer.{leg}_{stage}" for stage in INSIDE_ANOTHER} & set(split.phase_n)
     if leg == "mlp" and round_["streaming"]:
         assert split.stream is not None and split.stream.steps > 0
         assert all(round_["ledger"][name][0] > 0 for name in STREAM_PHASES)
@@ -336,6 +352,69 @@ def test_the_load_books_a_check_once_a_span_where_the_library_checks(tmp_path, m
             assert {n for n, _ in entries} == {M.PH_MLP.load_span.name}
 
 
+@pytest.mark.parametrize("path", ["library", "per-block"])
+def test_the_load_is_its_walk_and_its_assembly(tmp_path, monkeypatch, path):
+    """``load_walk`` and ``load_assemble`` are entered once a fit inside
+    ``load``, on the leg's thread: the ledger's, not the split's, and
+    together the load to within 5% (between them the fit's order is
+    begun: here that is held to nothing, a toy upload's load being
+    milliseconds and a thread's start as long). The spans count as
+    before on either path, entered by the workers inside the assembly."""
+    import contextlib
+
+    import dragonfly2_tpu.trainer.training as training_mod
+
+    if path == "per-block":
+        monkeypatch.setenv("DF_NO_NATIVE", "1")
+    elif not native.available():
+        pytest.skip("native library unavailable (no toolchain)")
+    monkeypatch.setattr(wire, "ASSEMBLY_SPAN_BLOCKS", 1)
+    monkeypatch.setattr(training_mod, "FitOrder", lambda *a, **kw: contextlib.nullcontext())
+
+    class LoadedAndNoFurther(Exception):
+        pass
+
+    def no_fit(*a, **kw):  # the load is what is read here: the leg ends where its fit would begin
+        raise LoadedAndNoFurther
+
+    monkeypatch.setattr(training_mod, "train_mlp", no_fit)
+    # an upload long enough that what lies between the two stretches (a few calls) is under a twentieth: 120 blocks
+    training = _training(tmp_path, False)
+    host_id = host_id_v2(IP, HOSTNAME)
+    block = wire.encode_train_block(synth.make_download_records(wire.BLOCK_RECORDS, seed=5))
+    for _ in range(120):
+        training.storage.append_download_blocks(host_id, block)
+    training.storage.mark_download_round(host_id)
+    blocks = len(wire.scan_block_extents(training.storage.download_blocks_path(host_id)))
+    stages = ("load", "load_walk", "load_assemble", "load_span", "load_check")
+    phases = {stage: getattr(M.PH_MLP, stage) for stage in stages}
+    open_when_entered = []
+    real_enter = profiling.Phase.__enter__
+
+    def watched(ph):
+        if ph in (phases["load_walk"], phases["load_assemble"]):
+            open_when_entered.append((ph.name, phases["load"].active, phases["load_walk"].active))
+        elif ph is phases["load_span"]:
+            open_when_entered.append((ph.name, phases["load_assemble"].active, threading.current_thread().name.startswith("wire.assemble")))
+        return real_enter(ph)
+
+    monkeypatch.setattr(profiling.Phase, "__enter__", watched)
+    before = {stage: ph.snapshot() for stage, ph in phases.items()}
+    splits: dict = {}
+    with pytest.raises(LoadedAndNoFurther):
+        training._timed_fit("mlp", None, splits, training._train_mlp, host_id, IP, HOSTNAME)
+    split = splits["mlp"]
+    moved = {stage: (ph.snapshot()["count"] - before[stage]["count"], ph.snapshot()["total_s"] - before[stage]["total_s"]) for stage, ph in phases.items()}
+    assert [moved[stage][0] for stage in stages] == [1, 1, 1, blocks, blocks if path == "library" else 0]
+    assert open_when_entered[:2] == [(phases["load_walk"].name, 1, 0), (phases["load_assemble"].name, 1, 0)]
+    assert open_when_entered[2:] == [(phases["load_span"].name, 1, True)] * blocks  # on the workers, inside the assembly
+    load, walk, assemble = (moved[stage][1] for stage in stages[:3])
+    assert walk > 0 and assemble > 0 and 0.95 * load <= walk + assemble <= load
+    assert split.phase_n[phases["load"].name] == 1
+    assert not {phases[stage].name for stage in stages[1:]} & set(split.phase_n)
+    assert split.phase_s[phases["load"].name] == pytest.approx(load, abs=1e-5)
+
+
 def test_a_corrupt_payload_fails_the_load_in_the_assembly_and_ends_the_order(tmp_path, monkeypatch):
     """The walk reads headers alone: over an upload whose last payload
     is corrupt it ends with its counts, and the fit's order is begun
@@ -375,7 +454,7 @@ def test_a_corrupt_payload_fails_the_load_in_the_assembly_and_ends_the_order(tmp
     walk, ((order, n),) = walks[-1], made  # the first walk was ``sound``'s
     assert (walk.num_pairs, walk.num_downloads) == (len(sound.labels), sound.num_downloads) and n == walk.num_pairs
     assert not fits
-    assert M.PH_MLP.load_span.snapshot()["count"] - spans_before == 1  # the sound span of the two
+    assert M.PH_MLP.load_span.snapshot()["count"] - spans_before == 2  # both were entered: the sound one, and the one whose check raised
     with pytest.raises(RuntimeError, match="after shutdown"):
         order._threads.submit(int)
     assert order._split is None and not order._ahead
@@ -521,6 +600,34 @@ def test_a_with_phase_is_on_the_profilers_clock(tmp_path):
     assert fed.name not in names  # observe(dt) feeds the ledger alone
 
 
+def test_a_with_phase_entered_on_a_worker_thread_is_in_the_trace(tmp_path):
+    """The assembly's workers enter ``load_span`` on their own threads
+    while the profiler's session was opened by another: the annotation
+    is the thread's that entered it, and the trace holds it by name."""
+    span = profiling.phase_type("trainer.test_on_a_worker")
+    done, together = [], threading.Barrier(3, timeout=30)
+
+    def work():
+        with span:
+            together.wait()  # three threads alive at once: three of the system's, not one reused
+            done.append(threading.current_thread().name)
+
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=False):
+        workers = [threading.Thread(target=work, name=f"wire.assemble_{k}") for k in range(3)]
+        for t in workers:
+            t.start()
+        for t in workers:
+            t.join(timeout=30)
+    assert len(done) == 3
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    entries = [
+        sum(ev.name == span.name for ev in line.events)
+        for plane in data.planes if plane.name.startswith("/host:") for line in plane.lines
+    ]
+    assert [n for n in entries if n] == [1, 1, 1]  # a line a thread, an entry each
+
+
 def test_a_phase_does_not_import_jax():
     code = (
         "import sys\n"
@@ -582,7 +689,9 @@ def test_a_round_with_profile_dir_is_one_trace(tmp_path):
     assert sorted(training.manager_client.registered) == ["gnn", "gru", "mlp"]
     assert os.listdir(prof) == ["round"]
     names = _host_event_names(str(prof / "round"))
+    assert {"trainer.round", "trainer.mlp_load_walk", "trainer.mlp_load_assemble", "trainer.mlp_load_span"} <= names
     for leg in LEGS:
+        assert {f"trainer.{leg}_fit", f"trainer.{leg}_table_put", f"trainer.{leg}_feed_slice", f"trainer.{leg}_epoch_slice"} <= names
         assert f"trainer.{leg}_gather" in names
         assert f"trainer.{leg}_order" in names  # from the drawing threads
         assert f"trainer.{leg}_epoch_wait" in names

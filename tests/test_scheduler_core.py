@@ -216,6 +216,80 @@ class TestEvaluator:
         assert np.isfinite(f).all()
 
 
+class TestDecisionPhases:
+    """A decision's service from inside (scheduler.find_parents around
+    the rules and the ranking), and by the stretch a provider names."""
+
+    NAMES = ("scheduler.find_parents", "scheduler.filter_parents", "scheduler.evaluate")
+
+    def _decide(self, n: int):
+        from dragonfly2_tpu.scheduler import scheduling as mod
+        from dragonfly2_tpu.utils import profiling
+
+        t = res.Task("t")
+        t.total_piece_count = 10
+        child = make_peer(0, t, make_host(0))
+        child.fsm.event(res.PEER_EVENT_REGISTER_NORMAL)
+        parent = running_parent(1, t)
+        sched = Scheduling(BaseEvaluator(), SchedulingConfig(retry_interval=0.0))
+        names = (*self.NAMES, *(ph.name for ph in mod.PH_FIND_PARENTS_BESIDE.values()))
+        before = self.before = {name: profiling.phase_type(name).snapshot() for name in names}
+        for _ in range(n):
+            got, found = sched.find_candidate_parents(child)
+            assert found and got == [parent]
+        after = {name: profiling.phase_type(name).snapshot() for name in names}
+        return {name: (after[name]["count"] - before[name]["count"], after[name]["total_s"] - before[name]["total_s"]) for name in names}
+
+    def test_a_scheduler_alone_books_nothing_by_stretch_and_loses_nothing_else(self, monkeypatch):
+        from dragonfly2_tpu.scheduler import scheduling as mod
+
+        monkeypatch.setattr(mod, "stretch_provider", None)
+        moved = self._decide(5)
+        assert [moved[name][0] for name in self.NAMES] == [5, 5, 5]
+        assert all(moved[ph.name] == (0, 0.0) for ph in mod.PH_FIND_PARENTS_BESIDE.values())
+        find, rules, rank = (moved[name][1] for name in self.NAMES)
+        assert rules > 0 and rank > 0 and rules + rank <= find  # the rest is the decision's own
+
+    @pytest.mark.parametrize("stretch", ["walk", "assemble", "fit_shared", "fit_alone", "idle"])
+    def test_a_decision_is_booked_under_the_stretch_it_began_in(self, monkeypatch, stretch):
+        from dragonfly2_tpu.scheduler import scheduling as mod
+
+        assert tuple(mod.PH_FIND_PARENTS_BESIDE) == mod.STRETCHES
+        asked = []
+        monkeypatch.setattr(mod, "stretch_provider", lambda: asked.append(stretch) or stretch)
+        moved = self._decide(3)
+        assert asked == [stretch] * 3  # read once a decision
+        by_stretch = {s: moved[ph.name][0] for s, ph in mod.PH_FIND_PARENTS_BESIDE.items()}
+        assert by_stretch == {s: 3 * (s == stretch) for s in mod.STRETCHES}
+        assert sum(by_stretch.values()) == moved["scheduler.find_parents"][0]
+        # the whole decision's seconds themselves: one clock
+        assert moved[mod.PH_FIND_PARENTS_BESIDE[stretch].name][1] == pytest.approx(moved["scheduler.find_parents"][1], abs=1e-5)
+
+    def test_a_stretch_no_one_declared_books_nothing_and_fails_no_decision(self, monkeypatch):
+        from dragonfly2_tpu.scheduler import scheduling as mod
+
+        monkeypatch.setattr(mod, "stretch_provider", lambda: "lunch")
+        moved = self._decide(2)
+        assert moved["scheduler.find_parents"][0] == 2
+        assert all(moved[ph.name][0] == 0 for ph in mod.PH_FIND_PARENTS_BESIDE.values())
+
+    def test_a_decision_that_raises_is_booked_whole_and_by_stretch(self, monkeypatch):
+        """The counts by stretch sum to ``find_parents``' whatever way a
+        decision ends."""
+        from dragonfly2_tpu.scheduler import scheduling as mod
+
+        def raising(self, peer, blocklist):
+            raise RuntimeError("a rule gone wrong")
+
+        monkeypatch.setattr(mod, "stretch_provider", lambda: "walk")
+        monkeypatch.setattr(Scheduling, "_filter_candidate_parents", raising)
+        with pytest.raises(RuntimeError, match="a rule gone wrong"):
+            self._decide(1)
+        walk, whole = mod.PH_FIND_PARENTS_BESIDE["walk"], mod.PH_FIND_PARENTS
+        assert walk.snapshot()["count"] - self.before[walk.name]["count"] == 1
+        assert whole.snapshot()["count"] - self.before[whole.name]["count"] == 1
+
+
 class TestFilterRules:
     def _setup(self):
         t = res.Task("t")
