@@ -149,6 +149,9 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.df_crc32_blocks.restype = c_long
     lib.df_gather.argtypes = [c_void_p, i64_p, c_long]
     lib.df_gather.restype = None
+    # the rows' address and not an array: none is handed over for the count
+    lib.df_walk_blocks.argtypes = [c_void_p, ctypes.c_int64, ctypes.c_int64, c_void_p, c_long, i64_p]
+    lib.df_walk_blocks.restype = c_long
     return lib
 
 

@@ -54,7 +54,7 @@ CSV instead of mis-decoding.
 from __future__ import annotations
 
 import contextlib
-import itertools
+import functools
 import json
 import mmap
 import os
@@ -595,14 +595,35 @@ def stream_train_pairs(
 # alike and spans of 32 a tenth slower.
 ASSEMBLY_SPAN_BLOCKS = 128
 ASSEMBLY_THREADS = 3
-# The walk in front of it is the interpreter's work alone (25 us a block)
+# The interpreter's walk in front of it (the walk where the native library
+# did not load, and from the first block the library's walk is not sure
+# of) is the interpreter's work alone (25-35 us a block: 1.4-1.9 s for a
+# week's 53,760 blocks alone on the chip's host, 2.9-5.7 s inside a round)
 # and would hold the interpreter lock until another thread's switch
 # interval took it, 5 ms at a time in a trainer of its own: the other
-# legs' loads stood for its whole 1.9 s. Once this many blocks (0.8 ms)
-# it offers the lock. Not once a block: beside 16 decision workers every
+# legs' loads stood for all of it. Once this many blocks (0.8 ms) it
+# offers the lock. Not once a block: beside 16 decision workers every
 # offer costs the walk 0.2 ms of waiting to have it back (once a block
-# was measured: 9 s a round, PERF.md §6, PR 35).
+# was measured: 9 s a round, PERF.md §6, PR 35). The library's walk
+# (``df_walk_blocks``) holds no lock to offer.
 WALK_OFFER_BLOCKS = 32
+
+# A block's row of ``TrainPairsWalk.table``: ten numbers, as
+# native/dfnative.cc df_walk_blocks writes them (its WalkColumn, name for
+# name) and as the interpreter's walk fills them from the header it parsed
+(
+    _POS,  # the block's first byte
+    _PAYLOAD,  # its payload's first byte, both counted from the mapping's
+    _NBYTES,  # the payload's length
+    _CRC32,  # what the header states; -1 where that is no 32 bits: it matches no payload
+    _TRAIN,  # 1 for a ``train`` block: the rest is 0 and -1 for any other
+    _PAIRS,
+    _RECORDS,  # the source records the pairs came from: their indices count from 0 in each block
+    _FEATURES,  # where each pair column lies in the payload, in a row the
+    _LABELS,  # library's walk wrote; -1 in one the interpreter's did, which
+    _INDEX,  # built the block's views instead (``TrainPairsWalk.views``)
+    WALK_COLUMNS,
+) = range(11)
 
 
 def _gather(lib, parts: list, out: np.ndarray) -> None:
@@ -626,26 +647,73 @@ def _gather(lib, parts: list, out: np.ndarray) -> None:
 @dataclass
 class TrainPairsWalk:
     """What ``walk_train_pairs`` found, before a payload byte is read: a
-    view into the mapping a pair column a ``train`` block (they keep it
-    open), the record count and the pair count, and of every block of
-    the range, whatever its kind, where its payload lies and the
-    ``crc32`` its header states. ``assemble`` checks those and makes the
-    arrays a fit is handed; whoever needs only the counts (a fit's order
-    is a function of ``num_pairs``: trainer/train.py ``FitOrder``) has
-    them before that."""
+    row of numbers for every block of the range, whatever its kind, in
+    file order (``table``: where the block and its payload lie, the
+    ``crc32`` its header states, and of a ``train`` block its counts and
+    where its three pair columns lie), and from them by array arithmetic
+    the record count, the pair count and each ``train`` block's place in
+    the arrays. ``assemble`` checks the blocks and makes the arrays a fit
+    is handed; whoever needs only the counts (a fit's order is a function
+    of ``num_pairs``: trainer/train.py ``FitOrder``) has them before that.
 
-    features: list  # a block's [m, F] float32
-    labels: list  # a block's [m] float32
-    download_index: list  # a block's [m] int32, 0-based within its block's records
-    bases: list  # records before each block: its indices' base
-    num_downloads: int = 0
-    num_pairs: int = 0
+    A pair column is a view into the mapping (``mapped``; a view keeps
+    it open). The library's walk builds none: the assembly copies from
+    the addresses the table gives. The interpreter's walk builds a
+    block's three as it parses the header (``views``: of the range's last
+    ``train`` blocks, from the first block it walked; of all of them
+    where the library did not load). ``features``, ``labels``,
+    ``download_index`` and ``blocks`` are lists an entry a block for
+    whoever reads them, made at the first read: a fit reads none."""
+
+    table: np.ndarray  # [blocks, WALK_COLUMNS] int64
+    views: list = field(default_factory=list)  # (features [m, F] float32, labels [m] float32, index [m] int32) a ``train`` block the interpreter walked
     verify_crc: bool = True
     mapped: memoryview | None = None  # the whole mapping: a payload is a slice of it
-    # every block of the range, whatever its kind, in file order: its first
-    # byte, its payload's, the payload's length, the crc32 its header states
-    blocks: list = field(default_factory=list)
-    trains_before: list = field(default_factory=list)  # the ``train`` blocks before each of ``blocks``
+
+    def __post_init__(self):
+        train = self.table[:, _TRAIN] != 0
+        self.trains = np.flatnonzero(train)  # the ``train`` blocks' rows of the table
+        # the ``train`` blocks before each block, and before the range's end
+        self.trains_before = np.concatenate(([0], np.cumsum(train)))
+        records, self.lengths = self.table[self.trains, _RECORDS], self.table[self.trains, _PAIRS]
+        self.bases = np.cumsum(records) - records  # records before each ``train`` block: its indices' base
+        # pairs before each ``train`` block and before the end: their place in the arrays
+        self.pairs_before = np.concatenate(([0], np.cumsum(self.lengths)))
+        self.num_downloads, self.num_pairs = int(records.sum()), int(self.pairs_before[-1])
+
+    def train_views(self, lo: int, hi: int) -> list:
+        """The three pair columns of the range's ``train`` blocks ``lo``
+        to ``hi``: the interpreter's walk's where it held them, else the
+        same views made from the block's row."""
+        from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
+
+        first_held = len(self.trains) - len(self.views)
+        rows = self.table[self.trains[lo : min(hi, first_held)]]
+        made = [
+            (
+                np.frombuffer(self.mapped, np.float32, m * MLP_FEATURE_DIM, at + f).reshape(m, MLP_FEATURE_DIM),
+                np.frombuffer(self.mapped, np.float32, m, at + l),
+                np.frombuffer(self.mapped, np.int32, m, at + i),
+            )
+            for at, m, f, l, i in rows[:, (_PAYLOAD, _PAIRS, _FEATURES, _LABELS, _INDEX)].tolist()
+        ]
+        return made + self.views[max(lo - first_held, 0) : max(hi - first_held, 0)]
+
+    @functools.cached_property
+    def _columns(self) -> tuple:
+        return tuple(map(list, zip(*self.train_views(0, len(self.trains))))) or ([], [], [])
+
+    features = property(lambda self: self._columns[0])  # a ``train`` block's [m, F] float32
+    labels = property(lambda self: self._columns[1])  # a ``train`` block's [m] float32
+    download_index = property(lambda self: self._columns[2])  # a ``train`` block's [m] int32, 0-based within its block's records
+
+    @functools.cached_property
+    def blocks(self) -> list:
+        """Every block of the range, whatever its kind, in file order:
+        its first byte, its payload's, the payload's length, the crc32
+        its header states. Once read the list is what ``assemble`` checks
+        by, so whoever rewrites an entry is checked by what they wrote."""
+        return [tuple(row) for row in self.table[:, : _CRC32 + 1].tolist()]
 
     def assemble(self, span_phase=None, check_phase=None):
         """The blocks' pairs concatenated → ``PairExamples``, and with
@@ -670,71 +738,90 @@ class TrainPairsWalk:
         blocks are checked by one call of it, which holds no lock from
         the span's first byte to its last and says which block failed
         first, and each of the span's three columns is copied by one
-        call: a worker asks for the lock a few times a span. Where it
-        did not, ``zlib.crc32`` is called once a block and
-        ``np.concatenate`` copies an array at a time, and either gives
-        the lock up and asks for it again every time: four times a
-        block. The same CRC-32 over the same bytes and the same arrays
-        either way: the per-block path is what the library's is held to.
-        ``span_phase``, when given, is a context manager that can be
-        entered on several threads at once (a profiling phase): the
-        thread that runs a span enters it around the span, once a span,
-        and ``check_phase`` the same way around the library's check: not
-        at all where the per-block loop ran."""
+        call, from the addresses the table gives: a worker asks for the
+        lock a few times a span and touches no object of a block's. A
+        span that holds a block the interpreter walked copies its blocks'
+        views, as ``_gather`` does. Where the library did not load,
+        ``zlib.crc32`` is called once a block and ``np.concatenate``
+        copies an array at a time, and either gives the lock up and asks
+        for it again every time: four times a block. The same CRC-32 over
+        the same bytes and the same arrays either way: the per-block path
+        is what the library's is held to. ``span_phase``, when given, is
+        a context manager that can be entered on several threads at once
+        (a profiling phase): the thread that runs a span enters it around
+        the span, once a span, and ``check_phase`` the same way around
+        the library's check: not at all where the per-block loop ran."""
         from dragonfly2_tpu.schema import native
         from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM, PairExamples
 
         span_phase = span_phase or contextlib.nullcontext()
         check_phase = check_phase or contextlib.nullcontext()
-        lib = native.load() if self.blocks else None
-        # the mapping's first byte: a payload's place counts from it
-        base = np.frombuffer(self.mapped, np.uint8).ctypes.data if lib is not None else 0
+        n = len(self.table)
+        lib = native.load() if n else None
+        # the ``train`` blocks the library's walk read: their columns' places are in the table
+        placed = len(self.trains) - len(self.views)
         features = np.empty((self.num_pairs, MLP_FEATURE_DIM), np.float32)
         labels = np.empty((self.num_pairs,), np.float32)
-        download_index = np.empty(
-            (self.num_pairs,), self.download_index[0].dtype if self.download_index else np.int32
-        )
-        lengths = [len(l) for l in self.labels]
-        pairs_before = [0, *itertools.accumulate(lengths)]  # each ``train`` block's offset in the arrays
-        trains_before = [*self.trains_before, len(lengths)]
+        download_index = np.empty((self.num_pairs,), self.views[0][2].dtype if self.views and not placed else np.int32)
+        columns = (features, labels, download_index)
+        bases, lengths = self.bases.astype(download_index.dtype), self.lengths
+        held = self.__dict__.get("blocks")
+        if held is None:
+            blocks = np.ascontiguousarray(self.table[:, : _CRC32 + 1])
+        else:  # a header is JSON and a list holds anything: what no crc32 is matches no payload
+            blocks = np.array(
+                [(pos, start, nbytes, crc if type(crc) is int and 0 <= crc <= 0xFFFFFFFF else -1) for pos, start, nbytes, crc in held],
+                np.int64,
+            ).reshape(-1, 4)
+        if lib is not None:
+            # the mapping's first byte: a payload's place counts from it
+            base = np.frombuffer(self.mapped, np.uint8).ctypes.data
+            rows = self.table[self.trains[:placed]]
+            # a ``train`` block's piece of each column: its first byte's address and its length
+            pieces = [
+                np.stack([base + rows[:, _PAYLOAD] + rows[:, c], rows[:, _PAIRS] * out.strides[0]], axis=1)
+                for c, out in zip((_FEATURES, _LABELS, _INDEX), columns)
+            ]
 
-        def check_span(span: list) -> None:
-            # a header is JSON: where one states what no crc32 is, the per-block check says where
-            if lib is None or not all(type(crc) is int and 0 <= crc <= 0xFFFFFFFF for _, _, _, crc in span):
-                for pos, start, nbytes, crc in span:
+        def check_span(lo: int, hi: int) -> None:
+            if lib is None:
+                for pos, start, nbytes, crc in blocks[lo:hi].tolist():
                     if zlib.crc32(self.mapped[start : start + nbytes]) & 0xFFFFFFFF != crc:
                         raise WireError(f"block crc mismatch at byte {pos}")
                 return
             with check_phase:
-                bad = lib.df_crc32_blocks(base, np.array(span, np.int64), len(span))
+                bad = lib.df_crc32_blocks(base, blocks[lo:hi], hi - lo)
             if bad >= 0:
-                raise WireError(f"block crc mismatch at byte {span[bad][0]}")
+                raise WireError(f"block crc mismatch at byte {blocks[lo + bad, 0]}")
 
         def assemble_span(lo: int) -> None:
             with span_phase:
-                span = self.blocks[lo : lo + ASSEMBLY_SPAN_BLOCKS]
+                hi = min(lo + ASSEMBLY_SPAN_BLOCKS, n)
                 if self.verify_crc:
-                    check_span(span)
+                    check_span(lo, hi)
                 # the span's ``train`` blocks, and where their pairs go
-                t_lo, t_hi = trains_before[lo], trains_before[lo + len(span)]
-                at, end = pairs_before[t_lo], pairs_before[t_hi]
-                if end > at:
-                    _gather(lib, self.features[t_lo:t_hi], features[at:end])
-                    _gather(lib, self.labels[t_lo:t_hi], labels[at:end])
-                    # per-block indices are 0-based within their block's record batch —
-                    # rebase onto the running record count so the concatenated result
-                    # keeps the documented "row in the source batch" invariant instead
-                    # of aliasing records across blocks. A span's indices are rebased
-                    # in the array the caller is handed, by one add over the span: a
-                    # few calls a span under the interpreter lock, not a pass over
-                    # the whole upload (``np.repeat`` of every block's base held it
-                    # 0.2 s at 55M pairs) and not a lock handed over once a block
-                    index = download_index[at:end]
-                    _gather(lib, self.download_index[t_lo:t_hi], index)
-                    bases = np.asarray(self.bases[t_lo:t_hi], index.dtype)
-                    np.add(index, np.repeat(bases, lengths[t_lo:t_hi]), out=index)
+                t_lo, t_hi = int(self.trains_before[lo]), int(self.trains_before[hi])
+                at, end = self.pairs_before[t_lo], self.pairs_before[t_hi]
+                if end == at:
+                    return
+                if lib is not None and t_hi <= placed:
+                    for out, piece in zip(columns, pieces):
+                        lib.df_gather(out[at:end].ctypes.data, piece[t_lo:t_hi], t_hi - t_lo)
+                else:
+                    for out, parts in zip(columns, zip(*self.train_views(t_lo, t_hi))):
+                        _gather(lib, list(parts), out[at:end])
+                # per-block indices are 0-based within their block's record batch —
+                # rebase onto the running record count so the concatenated result
+                # keeps the documented "row in the source batch" invariant instead
+                # of aliasing records across blocks. A span's indices are rebased
+                # in the array the caller is handed, by one add over the span: a
+                # few calls a span under the interpreter lock, not a pass over
+                # the whole upload (``np.repeat`` of every block's base held it
+                # 0.2 s at 55M pairs) and not a lock handed over once a block
+                index = download_index[at:end]
+                np.add(index, np.repeat(bases[t_lo:t_hi], lengths[t_lo:t_hi]), out=index)
 
-        edges = range(0, len(self.blocks), ASSEMBLY_SPAN_BLOCKS)
+        edges = range(0, n, ASSEMBLY_SPAN_BLOCKS)
         if len(edges) <= 1:
             for lo in edges:
                 assemble_span(lo)
@@ -757,16 +844,40 @@ class TrainPairsWalk:
         )
 
 
+def _walk_interpreted(mm, start: int, end: int, views: list, tally: BlockTally | None) -> list:
+    """The interpreter's walk of ``[start, end)``: every header parsed
+    and of each ``train`` block the three pair columns built, as views
+    into the mapping, appended to ``views`` → the blocks' rows."""
+    rows = []
+    for pos, header_len, payload_len in _hop_mapped(mm, start, end):
+        if len(rows) % WALK_OFFER_BLOCKS == WALK_OFFER_BLOCKS - 1:
+            time.sleep(0)  # the interpreter lock, offered to whoever waits for it
+        header, cols = _decode_body(mm, pos, header_len, payload_len, False, _PAIR_COLUMNS)
+        if tally is not None:
+            tally.decoded += 1
+        crc = header["crc32"]
+        if not (type(crc) is int and 0 <= crc <= 0xFFFFFFFF):
+            crc = -1
+        payload = pos + _PREAMBLE.size + header_len
+        if header["kind"] != KIND_TRAIN:
+            rows.append((pos, payload, payload_len, crc, 0, 0, 0, -1, -1, -1))
+            continue
+        f, l = _train_tensors(header, cols)
+        views.append((f, l, cols["pairs.download_index"]))
+        rows.append((pos, payload, payload_len, crc, 1, len(l), int(header.get("records", header["rows"])), -1, -1, -1))
+    return rows
+
+
 def walk_train_pairs(
     path: str | os.PathLike,
     offset: int = 0,
     end: int | None = None,
     verify_crc: bool = True,
     tally: BlockTally | None = None,
+    native_phase=None,
 ) -> TrainPairsWalk:
-    """One pass over the headers of the blocks of ``[offset, end)``:
-    every header parsed, and of each ``train`` block the three pair
-    columns built, as views into the mapping. No payload page is
+    """One pass over the headers of the blocks of ``[offset, end)`` → a
+    row of numbers a block (``TrainPairsWalk.table``). No payload page is
     touched and nothing is copied: when it returns, the counts are known,
     the pairs are still the file's, and no block has been checked yet.
     The walk notes where each block's payload lies and the ``crc32``
@@ -775,34 +886,52 @@ def walk_train_pairs(
     before it hands over an array: that is the round's check of the
     blocks a newest-first reader, ``read_gru_tail``, hops over.
 
-    The walk is the interpreter's work from end to end (25 us a block,
-    1.4 s for a week's 53,760 blocks alone), where a walk that also
-    checked gave the interpreter lock up at every ``crc32``: it offers
-    the lock once ``WALK_OFFER_BLOCKS`` blocks."""
-    walk = TrainPairsWalk([], [], [], [], verify_crc=verify_crc)
+    Where the native library loaded, the range is the library's to walk
+    (``df_walk_blocks``, called to count the blocks and then to fill
+    their rows), and it holds no interpreter lock from the first header
+    to the last: the other legs' loads and a scheduler's decisions have
+    the interpreter meanwhile, and no object is made for a block.
+    ``native_phase``, when given, is a context manager entered around
+    that (a profiling phase). The library reads a header only where it
+    is sure of it. At the first block it is not sure of (a pair column
+    not ``raw``, as ``encode_block`` writes one that is all zeros;
+    another type or shape; a ``crc32`` that is no 32 bits; a key missing
+    or stated twice; an escape in a string it would compare; a header it
+    cannot finish; no magic at the block's edge) it stops, and the
+    interpreter walks the range from that block on, to the same rows or
+    the same error.
+
+    Where it did not load, the interpreter walks the whole range: every
+    header parsed and of each ``train`` block the three pair columns
+    built, as views into the mapping, its work from end to end (25-35 us
+    a block), where a walk that also checked gave the interpreter lock up
+    at every ``crc32``: it offers the lock once ``WALK_OFFER_BLOCKS``
+    blocks. The same table either way but for where a column lies, which
+    only the library's rows say: the interpreter's walk is what the
+    library's is held to."""
+    from dragonfly2_tpu.schema import native
+
     end = _clamped_end(path, end)
     if offset >= end:
-        return walk
+        return TrainPairsWalk(np.zeros((0, WALK_COLUMNS), np.int64), verify_crc=verify_crc)
+    lib, views = native.load(), []
+    table = np.zeros((0, WALK_COLUMNS), np.int64)
     with _mapped(path) as mm:
-        walk.mapped = memoryview(mm)
-        for pos, header_len, payload_len in _hop_mapped(mm, offset, end):
-            if len(walk.blocks) % WALK_OFFER_BLOCKS == WALK_OFFER_BLOCKS - 1:
-                time.sleep(0)  # the interpreter lock, offered to whoever waits for it
-            header, cols = _decode_body(mm, pos, header_len, payload_len, False, _PAIR_COLUMNS)
+        mapped = memoryview(mm)
+        if lib is not None:
+            base = np.frombuffer(mapped, np.uint8).ctypes.data
+            stopped_at = np.empty(1, np.int64)
+            with native_phase or contextlib.nullcontext():
+                n = lib.df_walk_blocks(base, offset, end, None, 0, stopped_at)  # the range's blocks, by their preambles
+                table = np.empty((n, WALK_COLUMNS), np.int64)
+                table = table[: lib.df_walk_blocks(base, offset, end, table.ctypes.data, n, stopped_at)]
             if tally is not None:
-                tally.decoded += 1
-            walk.blocks.append((pos, pos + _PREAMBLE.size + header_len, payload_len, header["crc32"]))
-            walk.trains_before.append(len(walk.bases))
-            if header["kind"] != KIND_TRAIN:
-                continue
-            f, l = _train_tensors(header, cols)
-            walk.features.append(f)
-            walk.labels.append(l)
-            walk.download_index.append(cols["pairs.download_index"])
-            walk.bases.append(walk.num_downloads)
-            walk.num_downloads += int(header.get("records", header["rows"]))
-            walk.num_pairs += len(l)
-    return walk
+                tally.decoded += len(table)
+            offset = int(stopped_at[0]) if stopped_at[0] >= 0 else end
+        if offset < end:
+            rows = _walk_interpreted(mm, offset, end, views, tally)
+            table = np.concatenate([table, np.array(rows, np.int64).reshape(-1, WALK_COLUMNS)])
+    return TrainPairsWalk(table, views, verify_crc, mapped)
 
 
 def read_train_pairs(

@@ -140,10 +140,16 @@ FIT_STAGES = (
 )
 # The two stretches of the resident MLP leg's load (schema/wire.py), inside
 # load, once a fit each, and no other leg's: the walk over the upload's
-# headers, the interpreter's work alone, and the assembly, which waits for
-# its workers' spans; load = load_walk + load_assemble but for the order's
-# start
-MLP_LOAD_STAGES = ("load_walk", "load_assemble")
+# headers and the assembly, which waits for its workers' spans; load =
+# load_walk + load_assemble but for the order's start. And inside the walk,
+# load_walk_native: the headers read by the native library, which holds no
+# interpreter lock (schema/native.py df_walk_blocks), entered around its
+# calls, so the count says the library's walk engaged (1 a fit; 0 where the
+# library did not load and the interpreter parsed every header), the total
+# is the library's seconds, and load_walk - load_walk_native is what the
+# interpreter still does: the table's arithmetic and, from a block the
+# library was not sure of, the rest of the walk
+MLP_LOAD_STAGES = ("load_walk", "load_assemble", "load_walk_native")
 # entered inside another stage, on the leg's thread or on a worker's: in no split
 INNER_STAGES = frozenset({*MLP_LOAD_STAGES, "feed_slice", "epoch_slice", "load_span", "load_check"})
 

@@ -454,7 +454,9 @@ class Training:
             with M.PH_MLP.load:
                 if binary:
                     with M.PH_MLP.load_walk:
-                        walk = wire.walk_train_pairs(path, offset=offset, end=boundary, tally=blocks)
+                        walk = wire.walk_train_pairs(
+                            path, offset=offset, end=boundary, tally=blocks, native_phase=M.PH_MLP.load_walk_native
+                        )
                     # the fit's order needs the pair count and not the pairs:
                     # it is drawn beside the assembly, which checks every block
                     # and raises before it hands over an array

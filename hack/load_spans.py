@@ -16,21 +16,29 @@ the two draws a fit's order makes in those seconds. One JSON line a
 reading: the walk's seconds, the assembly's, the spans' seconds summed
 over the workers and how many workers that kept busy.
 
-``--beside N [N ...]`` (ISSUE 39) reads the assembly, at the module's own
-width and span, beside N Python threads that want the interpreter as a
-scheduler's decision workers do in the colocated service (each works
-``--duty`` of its time in pure Python, 1 ms at a stretch, and sleeps the
-rest; the switch interval is the service's 0.5 ms), once on each of the
-paths a span takes: ``zlib.crc32`` once a block and ``np.concatenate`` an
-array at a time (``DF_NO_NATIVE``), and the native library's one call for
-the check and one a column for the copies; between them, to size each
-piece, the library's check with numpy's copies. The lines also carry the
-library's checks and their seconds.
+``--beside N [N ...]`` (ISSUE 39) reads the walk and the assembly, at the
+module's own width and span, beside N Python threads that want the
+interpreter as a scheduler's decision workers do in the colocated service
+(each works ``--duty`` of its time in pure Python, 1 ms at a stretch, and
+sleeps the rest; the switch interval is the service's 0.5 ms), once on
+each of the paths a load takes: every header parsed by the interpreter,
+``zlib.crc32`` once a block and ``np.concatenate`` an array at a time
+(``DF_NO_NATIVE``), and the native library's walk, its one call for the
+check and one a column for the copies. The lines also carry the
+library's checks and their seconds, and the seconds of the walk that
+were the library's (``walk_native_s``; 0 where the interpreter walked).
+
+``--walk`` (ISSUE 43) reads the walk alone, with no assembly behind it,
+beside each of ``--beside``'s thread counts (``--walk --beside 0 16``: alone
+and beside 16), on both paths in turn: a line a reading with the walk's
+seconds, the library's seconds inside them, and how many of the blocks'
+headers the interpreter parsed (all of them, or none).
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -69,52 +77,79 @@ def busy(stop: threading.Event, duty: float, stretch: float = 0.001) -> None:
             time.sleep(stretch * (1.0 - duty) / duty)
 
 
-_GATHER = wire._gather
-
-
-def reading(path: str, threads: int, span_blocks: int, draws: int, beside: int = 0, duty: float = 1.0, library: str = "both") -> dict:
-    """``library``: ``"both"`` (the module as it is: the span's check and
-    its copies a call each), ``"check"`` (the check alone: the copies left
-    to numpy, an array at a time) or ``"none"`` (``DF_NO_NATIVE``)."""
-    wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS = threads, span_blocks
-    if library == "none":
-        os.environ["DF_NO_NATIVE"] = "1"
-    else:
-        os.environ.pop("DF_NO_NATIVE", None)
-    wire._gather = (lambda lib, parts, out: _GATHER(None, parts, out)) if library == "check" else _GATHER
-    # phases of this reading's own, as the trainer hands the assembly its leg's
-    spans, checks = profiling.Phase("hack.load_span"), profiling.Phase("hack.load_check")
+@contextlib.contextmanager
+def others_wanting_the_interpreter(beside: int, duty: float):
+    """``beside`` threads of ``busy`` for as long as the block runs."""
     stop = threading.Event()
     others = [threading.Thread(target=busy, args=(stop, duty), daemon=True) for _ in range(beside)]
     for t in others:
         t.start()
-    t0 = time.perf_counter()
-    walk = wire.walk_train_pairs(path)
-    t1 = time.perf_counter()
-    # what FitOrder draws beside the assembly: a permutation of every pair, twice
-    drawing = [
-        threading.Thread(target=np.random.default_rng(i).permutation, args=(walk.num_pairs,))
-        for i in range(draws)
-    ]
+    try:
+        yield
+    finally:
+        stop.set()
+        for t in others:
+            t.join()
+
+
+def use_library(library: bool) -> None:
+    """The path the next reading takes: ``DF_NO_NATIVE`` is read at every call."""
+    if library:
+        os.environ.pop("DF_NO_NATIVE", None)
+    else:
+        os.environ["DF_NO_NATIVE"] = "1"
+
+
+def reading(path: str, threads: int, span_blocks: int, draws: int, beside: int = 0, duty: float = 1.0, library: bool = True) -> dict:
+    """``library``: the module as it is, or (False) ``DF_NO_NATIVE``."""
+    wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS = threads, span_blocks
+    use_library(library)
+    # phases of this reading's own, as the trainer hands the assembly its leg's
+    spans, checks, walked = profiling.Phase("hack.load_span"), profiling.Phase("hack.load_check"), profiling.Phase("hack.load_walk_native")
+    with others_wanting_the_interpreter(beside, duty):
+        t0 = time.perf_counter()
+        walk = wire.walk_train_pairs(path, native_phase=walked)
+        t1 = time.perf_counter()
+        # what FitOrder draws beside the assembly: a permutation of every pair, twice
+        drawing = [
+            threading.Thread(target=np.random.default_rng(i).permutation, args=(walk.num_pairs,))
+            for i in range(draws)
+        ]
+        for t in drawing:
+            t.start()
+        pairs = walk.assemble(span_phase=spans, check_phase=checks)
+        t2 = time.perf_counter()
     for t in drawing:
-        t.start()
-    pairs = walk.assemble(span_phase=spans, check_phase=checks)
-    t2 = time.perf_counter()
-    stop.set()
-    for t in drawing + others:
         t.join()
     t3 = time.perf_counter()
     out = {
         "threads": threads, "span_blocks": span_blocks, "draws": draws,
-        "beside": beside, "duty": duty, "library": library if native.load() is not None else "none",
+        "beside": beside, "duty": duty, "library": native.load() is not None,
         "checks": checks.count, "check_s_sum": round(checks.total_s, 3),
-        "walk_s": round(t1 - t0, 3), "assemble_s": round(t2 - t1, 3), "draws_after_s": round(t3 - t2, 3),
+        "walk_s": round(t1 - t0, 4), "walk_native_s": round(walked.total_s, 4), "assemble_s": round(t2 - t1, 3), "draws_after_s": round(t3 - t2, 3),
         "spans": spans.count, "span_s_sum": round(spans.total_s, 3), "span_s_max": round(spans.max_s, 4),
         "busy_workers": round(spans.total_s / (t2 - t1), 2),
-        "pairs": int(pairs.labels.shape[0]), "blocks": len(walk.blocks),
+        "pairs": int(pairs.labels.shape[0]), "blocks": len(walk.table),
     }
     del walk, pairs
     return out
+
+
+def walking(path: str, beside: int, duty: float, library: bool) -> dict:
+    """The walk alone: ``library`` False is ``DF_NO_NATIVE``."""
+    use_library(library)
+    walked = profiling.Phase("hack.load_walk_native")
+    with others_wanting_the_interpreter(beside, duty):
+        time.sleep(0.05)  # the others under way
+        t0 = time.perf_counter()
+        walk = wire.walk_train_pairs(path, native_phase=walked)
+        t1 = time.perf_counter()
+    return {
+        "walk_alone": True, "beside": beside, "duty": duty, "library": library and native.load() is not None,
+        "walk_s": round(t1 - t0, 4), "walk_native_s": round(walked.total_s, 4), "walks_by_the_library": walked.count,
+        "blocks": len(walk.table), "parsed_by_the_interpreter": int((walk.table[:, wire._FEATURES] < 0).sum()),
+        "pairs": walk.num_pairs, "records": walk.num_downloads,
+    }
 
 
 def parts(path: str, threads: int) -> dict:
@@ -183,7 +218,8 @@ def main() -> int:
     ap.add_argument("--span-blocks", type=int, nargs="+", default=[32, 128, 448, 896])
     ap.add_argument("--repeats", type=int, default=2)
     ap.add_argument("--parts", action="store_true", help="the assembly's pieces alone, by thread count, in place of the sweep")
-    ap.add_argument("--beside", type=int, nargs="+", help="the assembly on both check paths beside this many threads that want the interpreter, in place of the sweep")
+    ap.add_argument("--beside", type=int, nargs="+", help="the walk and the assembly on each path beside this many threads that want the interpreter, in place of the sweep")
+    ap.add_argument("--walk", action="store_true", help="the walk alone on both paths, beside each of --beside's thread counts (default 0 16), in place of the sweep")
     ap.add_argument("--duty", type=float, nargs="+", default=[0.03], help="the share of its time such a thread works in Python")
     args = ap.parse_args()
     width, span_blocks = wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS  # the module's own, before a reading sets others
@@ -198,14 +234,21 @@ def main() -> int:
         stage(path, args.chunks, args.bodies, args.body_records, args.seed)
         print(json.dumps({"staged_bytes": os.path.getsize(path), "stage_s": round(time.perf_counter() - t0, 2)}), flush=True)
         reading(path, 1, span_blocks, 0)  # the mapping's pages, once
-        if args.beside:
+        if args.beside or args.walk:
             from dragonfly2_tpu.colocated.server import SWITCH_INTERVAL_S
 
             sys.setswitchinterval(SWITCH_INTERVAL_S)
             print(json.dumps({"switch_interval_s": SWITCH_INTERVAL_S, "library": native.available()}), flush=True)
+        if args.walk:
+            for _ in range(args.repeats):
+                for beside, duty in ((b, d) for b in args.beside or [0, 16] for d in (args.duty if b else args.duty[:1])):
+                    for library in (False, True):
+                        print(json.dumps(walking(path, beside, duty, library)), flush=True)
+            return 0
+        if args.beside:
             for _ in range(args.repeats):
                 for beside, duty in ((b, d) for b in args.beside for d in (args.duty if b else args.duty[:1])):
-                    for library in ("none", "check", "both"):
+                    for library in (False, True):
                         print(json.dumps(reading(path, width, span_blocks, 2, beside, duty, library)), flush=True)
             return 0
         if args.parts:

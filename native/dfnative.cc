@@ -19,6 +19,9 @@
 //    columnar-v1 blocks (schema/wire.py TrainPairsWalk.assemble) in one
 //    call, zlib's CRC-32 bit for bit, and a column of the span's pairs
 //    copied to its place in one call.
+//  - df_walk_blocks: the walk in front of them (schema/wire.py
+//    walk_train_pairs): the headers of a range's blocks read in one call
+//    into a row of numbers a block.
 //
 // CSV dialect: RFC4180 quotes (python csv.writer). Embedded header lines
 // (every upload round re-sends one, trainer service demux) are detected by
@@ -27,6 +30,7 @@
 //
 // C ABI only — bound from Python via ctypes (schema/native.py).
 
+#include <cctype>
 #include <cmath>
 #include <cstddef>  // offsetof — do not rely on <immintrin.h> pulling it in
 #include <cstdint>
@@ -1087,6 +1091,254 @@ uint32_t crc32_of(const unsigned char* p, size_t n) {
   return ~crc32_tables(c, p, n);
 }
 
+// ---------------------------------------------------------------------------
+// A columnar-v1 block's header read without an interpreter: what the
+// resident load's walk (schema/wire.py walk_train_pairs) needs of a block
+// is a row of numbers, and df_walk_blocks writes one a block from the
+// range's first header on in one call, where the interpreter's walk
+// parses 53,760 headers a week's upload with the interpreter lock held.
+//
+// The scanner reads a header only where it is sure that json.loads and
+// the interpreter's walk would read the same numbers from it: strict
+// JSON in ASCII, the keys it reads stated once and with no escape, the
+// three pair columns ``raw`` of the types and shapes a train block is
+// written with (schema/wire.py encode_train_block), inside the payload.
+// Whatever else a header holds (keys in another order, keys and columns
+// it does not read) it steps over, checking the syntax as it goes. It
+// never guesses: at the first header it is not sure of the walk stops,
+// and the interpreter's walk takes the range from that block on, to the
+// same rows or the same error.
+// ---------------------------------------------------------------------------
+
+enum WalkColumn {  // schema/wire.py WALK_COLUMNS, name for name
+  kWalkPos, kWalkPayload, kWalkNbytes, kWalkCrc32, kWalkTrain, kWalkPairs,
+  kWalkRecords, kWalkFeatures, kWalkLabels, kWalkIndex, kWalkColumns
+};
+
+struct JsonCursor {
+  const unsigned char* p;
+  const unsigned char* end;
+
+  void ws() {
+    while (p < end && (*p == ' ' || *p == '\t' || *p == '\n' || *p == '\r')) ++p;
+  }
+  bool eat(char c) {
+    ws();
+    if (p < end && *p == c) return ++p, true;
+    return false;
+  }
+  // a string's bytes between its quotes; false where json.loads would
+  // refuse it or would have to decode it (a byte past ASCII)
+  bool string(FieldRef* s, bool* escaped) {
+    if (!eat('"')) return false;
+    const unsigned char* start = p;
+    *escaped = false;
+    for (; p < end; ++p) {
+      const unsigned char c = *p;
+      if (c == '"') {
+        *s = {reinterpret_cast<const char*>(start), size_t(p - start)};
+        return ++p, true;
+      }
+      if (c < 0x20 || c >= 0x80) return false;
+      if (c != '\\') continue;
+      *escaped = true;
+      if (++p == end) return false;
+      if (*p == 'u') {
+        if (end - p < 5) return false;
+        for (int i = 1; i <= 4; ++i)
+          if (!isxdigit(p[i])) return false;
+        p += 4;
+      } else if (*p == 0 || !strchr("\"\\/bfnrt", *p)) {
+        return false;
+      }
+    }
+    return false;
+  }
+  // a string with no escape in it: one whose bytes can be compared
+  bool plain(FieldRef* s) {
+    bool escaped;
+    return string(s, &escaped) && !escaped;
+  }
+  bool digit() const { return p < end && *p >= '0' && *p <= '9'; }
+  // 0|[1-9]\d* of at most 18 digits -> its value
+  bool whole(int64_t* v) {
+    const unsigned char* start = p;
+    for (*v = 0; digit(); ++p)
+      if (p - start < 18) *v = *v * 10 + (*p - '0');
+    const ptrdiff_t n = p - start;
+    return n >= 1 && n <= 18 && (n == 1 || *start != '0');
+  }
+  // 0 or a positive integer, written as one
+  bool integer(int64_t* v) {
+    ws();
+    return whole(v) && !(p < end && (*p == '.' || *p == 'e' || *p == 'E'));
+  }
+  bool digits() {  // one or more
+    if (!digit()) return false;
+    while (digit()) ++p;
+    return true;
+  }
+  bool number() {  // -?(0|[1-9]\d*)(\.\d+)?([eE][+-]?\d+)?
+    int64_t v;
+    if (p < end && *p == '-') ++p;
+    if (!whole(&v)) return false;
+    if (p < end && *p == '.' && (++p, !digits())) return false;
+    if (p < end && (*p == 'e' || *p == 'E')) {
+      if (++p < end && (*p == '+' || *p == '-')) ++p;
+      if (!digits()) return false;
+    }
+    return true;
+  }
+  bool literal(const char* word) {
+    const size_t n = strlen(word);
+    if (size_t(end - p) < n || memcmp(p, word, n) != 0) return false;
+    return p += n, true;
+  }
+  // any value, its syntax checked and nothing kept
+  bool skip(int depth = 0) {
+    ws();
+    if (p == end || depth > 32) return false;
+    FieldRef s;
+    bool escaped;
+    switch (*p) {
+      case '"': return string(&s, &escaped);
+      case 't': return literal("true");
+      case 'f': return literal("false");
+      case 'n': return literal("null");
+      case '[':
+        ++p;
+        if (eat(']')) return true;
+        do {
+          if (!skip(depth + 1)) return false;
+        } while (eat(','));
+        return eat(']');
+      case '{':
+        ++p;
+        if (eat('}')) return true;
+        do {
+          if (!string(&s, &escaped) || !eat(':') || !skip(depth + 1)) return false;
+        } while (eat(','));
+        return eat('}');
+      default: return number();
+    }
+  }
+};
+
+struct PairColumn {
+  const char* name;
+  const char* dtype;
+  int64_t row_bytes;  // of one pair
+  int dims;
+};
+const PairColumn kPairColumns[3] = {  // schema/wire.py _PAIR_COLUMNS
+    {"pairs.features", "<f4", 4 * kFeatureDim, 2},
+    {"pairs.labels", "<f4", 4, 1},
+    {"pairs.download_index", "<i4", 4, 1},
+};
+
+// The header ``[h, h + header_len)`` of a block whose payload holds
+// ``payload_len`` bytes -> the row's crc32 and what follows it. False:
+// not a header this scanner is sure of, and nothing of the row is to be
+// believed.
+bool scan_header(const unsigned char* h, size_t header_len, int64_t payload_len,
+                 int64_t* row) {
+  JsonCursor j{h, h + header_len};
+  enum { kKind = 1, kRows = 2, kRecords = 4, kCrc = 8, kCols = 16, kMeta = 32 };
+  unsigned seen = 0, columns = 0;
+  bool train = false, dim_stated = false;
+  int64_t rows = 0, records = 0, crc = 0, feature_dim = 0;
+  int64_t pairs[3] = {0, 0, 0}, at[3] = {0, 0, 0};
+  FieldRef key, s;
+  if (!j.eat('{')) return false;
+  do {
+    if (!j.plain(&key) || !j.eat(':')) return false;
+    const unsigned bit = key.eq("kind") ? kKind : key.eq("rows") ? kRows
+                       : key.eq("records") ? kRecords : key.eq("crc32") ? kCrc
+                       : key.eq("cols") ? kCols : key.eq("meta") ? kMeta : 0;
+    if (seen & bit) return false;  // stated twice: json.loads keeps the last
+    seen |= bit;
+    switch (bit) {
+      case kKind:
+        if (!j.plain(&s)) return false;
+        train = s.eq("train");
+        break;
+      case kRows:
+        if (!j.integer(&rows)) return false;
+        break;
+      case kRecords:
+        if (!j.integer(&records)) return false;
+        break;
+      case kCrc:
+        if (!j.integer(&crc) || crc > 0xFFFFFFFFLL) return false;
+        break;
+      case kMeta:
+        if (!j.eat('{')) return false;
+        if (j.eat('}')) break;
+        do {
+          if (!j.plain(&key) || !j.eat(':')) return false;
+          if (!key.eq("feature_dim")) {
+            if (!j.skip()) return false;
+          } else if (dim_stated || !j.integer(&feature_dim)) {
+            return false;
+          } else {
+            dim_stated = true;
+          }
+        } while (j.eat(','));
+        if (!j.eat('}')) return false;
+        break;
+      case kCols:
+        if (!j.eat('[')) return false;
+        if (j.eat(']')) break;
+        do {  // an entry: [name, dtype, shape, encoding, offset, nbytes, ...]
+          if (!j.eat('[') || !j.plain(&s)) return false;
+          int c = 0;
+          while (c < 3 && !s.eq(kPairColumns[c].name)) ++c;
+          if (c == 3) {
+            while (j.eat(','))
+              if (!j.skip()) return false;
+          } else {
+            const PairColumn& col = kPairColumns[c];
+            int64_t nbytes, width = kFeatureDim;
+            if (columns & (1u << c)) return false;
+            columns |= 1u << c;
+            if (!j.eat(',') || !j.plain(&s) || !s.eq(col.dtype)) return false;
+            if (!j.eat(',') || !j.eat('[') || !j.integer(&pairs[c])) return false;
+            if (col.dims == 2 && (!j.eat(',') || !j.integer(&width))) return false;
+            if (!j.eat(']') || width != kFeatureDim) return false;
+            if (!j.eat(',') || !j.plain(&s) || !s.eq("raw")) return false;
+            if (!j.eat(',') || !j.integer(&at[c])) return false;
+            if (!j.eat(',') || !j.integer(&nbytes)) return false;
+            // a pair is at least a byte of the payload, so no product below overflows
+            if (pairs[c] < 1 || pairs[c] > payload_len) return false;
+            if (nbytes != pairs[c] * col.row_bytes) return false;
+            if (at[c] > payload_len || nbytes > payload_len - at[c]) return false;
+          }
+          if (!j.eat(']')) return false;
+        } while (j.eat(','));
+        if (!j.eat(']')) return false;
+        break;
+      default:
+        if (!j.skip()) return false;
+    }
+  } while (j.eat(','));
+  if (!j.eat('}')) return false;
+  j.ws();
+  if (j.p != j.end) return false;
+  if ((seen & (kKind | kCrc | kCols)) != (kKind | kCrc | kCols)) return false;
+  row[kWalkCrc32] = crc;
+  row[kWalkTrain] = train;
+  row[kWalkPairs] = row[kWalkRecords] = 0;
+  row[kWalkFeatures] = row[kWalkLabels] = row[kWalkIndex] = -1;
+  // a pair column in a block of another kind is the interpreter's to build
+  if (!train) return columns == 0;
+  if (!(seen & kRows) || !dim_stated || feature_dim != kFeatureDim) return false;
+  if (columns != 7 || pairs[0] != pairs[1] || pairs[0] != pairs[2]) return false;
+  row[kWalkPairs] = pairs[0];
+  row[kWalkRecords] = (seen & kRecords) ? records : rows;
+  for (int c = 0; c < 3; ++c) row[kWalkFeatures + c] = at[c];
+  return true;
+}
+
 }  // namespace
 
 // ---------------------------------------------------------------------------
@@ -1265,6 +1517,50 @@ void df_gather(unsigned char* dst, const int64_t* pieces, long n) {
     memcpy(dst, reinterpret_cast<const void*>(piece[0]), size_t(piece[1]));
     dst += piece[1];
   }
+}
+
+// The blocks of ``[start, end)`` of the mapping at ``base`` hopped by
+// their preambles under the rules of schema/wire.py _hop_mapped (a torn
+// tail ends the walk; so does anything but the magic at a block's edge)
+// -> how many whole blocks were walked. With ``rows`` null nothing else
+// is read: the count a caller makes room by. Else a row of kWalkColumns
+// numbers a block is written (schema/wire.py WALK_COLUMNS): where the
+// block and its payload lie and what scan_header read of its header. The
+// walk stops at the first block whose header scan_header is not sure of,
+// and at the ``cap``-th. ``*stopped_at`` is the byte of the block's edge
+// it stopped at short of the range's end or its torn tail (no magic
+// there, no header it reads, no room for the row), where the interpreter's
+// walk takes over, to raise or to read; else -1.
+long df_walk_blocks(const unsigned char* base, int64_t start, int64_t end,
+                    int64_t* rows, long cap, int64_t* stopped_at) {
+  constexpr int64_t kPreamble = 16;  // magic, header_len u32, payload_len u64
+  long n = 0;
+  *stopped_at = -1;
+  for (int64_t pos = start; pos < end && end - pos >= kPreamble; ++n) {
+    const unsigned char* b = base + pos;
+    uint32_t header_len;
+    uint64_t payload_len;
+    memcpy(&header_len, b + 4, 4);
+    memcpy(&payload_len, b + 8, 8);
+    const uint64_t left = uint64_t(end - pos - kPreamble);
+    if (memcmp(b, "DFB1", 4) != 0) {
+      *stopped_at = pos;
+      break;
+    }
+    if (header_len > left || payload_len > left - header_len) break;  // torn tail
+    if (rows != nullptr) {
+      int64_t* row = rows + n * kWalkColumns;
+      if (n == cap || !scan_header(b + kPreamble, header_len, int64_t(payload_len), row)) {
+        *stopped_at = pos;
+        break;
+      }
+      row[kWalkPos] = pos;
+      row[kWalkPayload] = pos + kPreamble + header_len;
+      row[kWalkNbytes] = int64_t(payload_len);
+    }
+    pos += kPreamble + int64_t(header_len) + int64_t(payload_len);
+  }
+  return n;
 }
 
 }  // extern "C"

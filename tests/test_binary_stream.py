@@ -3,8 +3,10 @@ announcer → trainer service → ingest over real gRPC, bit-identical
 tensors vs the CSV path, and the CSV-fallback negotiation for old
 trainers (ISSUE round 6 tentpole)."""
 
+import json
 import threading
 import time
+import zlib
 
 import numpy as np
 import pytest
@@ -978,14 +980,22 @@ class TestCheckedAndCopiedASpanAtATime:
             walk.assemble()
 
     @pytest.mark.parametrize("name, offers", [("one-block", 0), ("exactly-one-span", 1), ("spans-and-a-remainder", 3), ("no-train-block", 2)])
-    def test_the_walk_offers_the_interpreter_once_a_few_blocks(self, tmp_path, monkeypatch, name, offers):
-        """The walk calls nothing that gives the interpreter lock up:
-        once ``WALK_OFFER_BLOCKS`` blocks, of whatever kind, it does."""
+    def test_the_walk_offers_the_interpreter_once_a_few_blocks(self, tmp_path, monkeypatch, check_path, name, offers):
+        """The interpreter's walk calls nothing that gives the
+        interpreter lock up: once ``WALK_OFFER_BLOCKS`` blocks, of
+        whatever kind, it does. The library's walk holds no lock to
+        offer: what is offered there is offered by the interpreter's
+        walk of the blocks from the first the library was not sure of
+        (``exactly-one-span`` has a block of no pairs, third of four)."""
         monkeypatch.setattr(wire, "WALK_OFFER_BLOCKS", 3)
         slept = []
         monkeypatch.setattr(wire.time, "sleep", slept.append)
         path, _ = _write_blocks(tmp_path, name, _SPANNED[name], seed=10)
-        wire.walk_train_pairs(path)
+        walk = wire.walk_train_pairs(path)
+        if check_path[0] == "library":
+            sure = _read_by_the_library(path)
+            offers = (len(sure) - sure.index(False)) // 3 if False in sure else 0
+            assert sure[:3] == [True, True, False] if name == "exactly-one-span" else len(sure) == len(walk.table)
         assert slept == [0] * offers
 
     @pytest.mark.parametrize("verify_crc", [True, False])
@@ -1129,6 +1139,304 @@ class TestCheckedAndCopiedASpanAtATime:
             assert during <= 12, during
         elif len(os.sched_getaffinity(0)) > 1:
             assert during > 12, during
+
+
+def _raw_block(rng, pairs: int, seqs: int, records: int) -> bytes:
+    """``_block`` with every pair column ``raw``: one pair at the least
+    and two records, so no column is all zeros by the draw."""
+    while True:
+        block = _block(rng, pairs, seqs, records)
+        header, _, _ = wire.decode_block(block, columns=())
+        if all(e[3] == "raw" for e in header["cols"] if e[0] in wire._PAIR_COLUMNS):
+            return block
+
+
+def _read_by_the_library(path, offset=0, end=None) -> list:
+    """Of each block of the range, whether its header is one the
+    library's walk reads: a block of another kind, or a ``train`` block
+    whose three pair columns are ``raw``."""
+    return [
+        h["kind"] != wire.KIND_TRAIN or all(e[3] == "raw" for e in h["cols"] if e[0] in wire._PAIR_COLUMNS)
+        for h, _ in wire.iter_blocks(path, offset, end, verify_crc=False, columns=())
+    ]
+
+
+def _reheaded(block: bytes, edit) -> bytes:
+    """``block`` with its header rewritten: ``edit(header)`` → the
+    header to state (a dict, dumped as ``encode_block`` dumps it, or the
+    bytes themselves). The payload is left as it is."""
+    _, header_len, payload_len = wire._PREAMBLE.unpack_from(block)
+    header = edit(json.loads(block[16 : 16 + header_len]))
+    if not isinstance(header, bytes):
+        header = json.dumps(header, separators=(",", ":")).encode()
+    return wire._PREAMBLE.pack(wire.MAGIC, len(header), payload_len) + header + block[16 + header_len :]
+
+
+def _col(header: dict, name: str) -> list:
+    return next(e for e in header["cols"] if e[0] == name)
+
+
+def _set(header: dict, **keys) -> dict:
+    return {**header, **keys}
+
+
+def _repacked(block: bytes, name: str, lay_out) -> bytes:
+    """``block`` with its column ``name`` laid out anew at the payload's
+    end: ``lay_out(entry, column bytes, where)`` rewrites the column's
+    entry of the header and returns the bytes to append at ``where``."""
+    _, header_len, payload_len = wire._PREAMBLE.unpack_from(block)
+    header = json.loads(block[16 : 16 + header_len])
+    entry = _col(header, name)
+    held = block[16 + header_len + entry[4] :][: entry[5]]
+    at = payload_len + (-payload_len % 8)
+    payload = block[16 + header_len :] + b"\0" * (at - payload_len) + lay_out(entry, held, at)
+    header["crc32"] = zlib.crc32(payload) & 0xFFFFFFFF
+    head = json.dumps(header, separators=(",", ":")).encode()
+    return wire._PREAMBLE.pack(wire.MAGIC, len(head), len(payload)) + head + payload
+
+
+def _wider_labels(block: bytes) -> bytes:
+    """The block's labels stated and laid out as float64: a column of
+    another type than the schema's."""
+
+    def lay_out(entry, held, at):
+        wide = np.frombuffer(held, np.float32).astype(np.float64).tobytes()
+        entry[1], entry[4], entry[5] = "<f8", at, len(wide)
+        return wide
+
+    return _repacked(block, "pairs.labels", lay_out)
+
+
+def _dict_index(block: bytes) -> bytes:
+    """The block's ``pairs.download_index`` dictionary-encoded, as
+    ``encode_block`` encodes a string column: codes and a table of the
+    values as text."""
+
+    def lay_out(entry, held, at):
+        uniques, codes = np.unique(np.frombuffer(held, np.int32), return_inverse=True)
+        table, codes = "\n".join(str(u) for u in uniques).encode(), codes.astype(np.uint32).tobytes()
+        entry[3:] = ["dict", at, len(codes), at + len(codes), len(table)]
+        return codes + table
+
+    return _repacked(block, "pairs.download_index", lay_out)
+
+
+# a header's rewriting → (the block's bytes from the block's, does the library read the header itself?)
+_HEADERS = {
+    # as ``encode_block`` writes them, and what json.loads reads alike
+    "as-written": (lambda b: b, True),
+    "keys-in-another-order": (lambda b: _reheaded(b, lambda h: dict(reversed(list(h.items())))), True),
+    "cols-in-another-order": (lambda b: _reheaded(b, lambda h: _set(h, cols=h["cols"][::-1])), True),
+    "spaces-between": (lambda b: _reheaded(b, lambda h: json.dumps(h, indent=1).encode()), True),
+    "a-key-it-does-not-read": (lambda b: _reheaded(b, lambda h: _set(h, note={"by": ["x", 1.5e3, None, True, "\\u00e9"]}, meta={**h["meta"], "more": [-0.5]})), True),
+    "no-records": (lambda b: _reheaded(b, lambda h: {k: v for k, v in h.items() if k != "records"}), True),
+    # what the library is not sure of: the interpreter's from that block on
+    "labels-all-zero": (None, False),  # ``encode_block`` writes the column ``zero``
+    "index-dict-encoded": (_dict_index, False),
+    "labels-of-another-type": (_wider_labels, False),
+    "crc32-too-wide": (lambda b: _reheaded(b, lambda h: _set(h, crc32=1 << 32)), False),
+    "crc32-negative": (lambda b: _reheaded(b, lambda h: _set(h, crc32=-1)), False),
+    "crc32-a-fraction": (lambda b: _reheaded(b, lambda h: _set(h, crc32=1.5)), False),
+    "crc32-a-string": (lambda b: _reheaded(b, lambda h: _set(h, crc32="7")), False),
+    "crc32-null": (lambda b: _reheaded(b, lambda h: _set(h, crc32=None)), False),
+    "crc32-true": (lambda b: _reheaded(b, lambda h: _set(h, crc32=True)), False),
+    "no-crc32": (lambda b: _reheaded(b, lambda h: {k: v for k, v in h.items() if k != "crc32"}), False),
+    "no-rows": (lambda b: _reheaded(b, lambda h: {k: v for k, v in h.items() if k != "rows"}), False),
+    "no-meta": (lambda b: _reheaded(b, lambda h: {k: v for k, v in h.items() if k != "meta"}), False),
+    "a-foreign-feature-width": (lambda b: _reheaded(b, lambda h: _set(h, meta={"feature_dim": h["meta"]["feature_dim"] - 1})), False),
+    "no-labels-column": (lambda b: _reheaded(b, lambda h: _set(h, cols=[e for e in h["cols"] if e[0] != "pairs.labels"])), False),
+    "an-escape-in-a-key": (lambda b: _reheaded(b, lambda h: json.dumps(h, separators=(",", ":")).replace('"kind"', '"k\\u0069nd"').encode()), False),
+    "an-escape-in-a-column-name": (lambda b: _reheaded(b, lambda h: json.dumps(h, separators=(",", ":")).replace('"pairs.labels"', '"pairs\\u002elabels"').encode()), False),
+    "a-key-stated-twice": (lambda b: _reheaded(b, lambda h: json.dumps(h, separators=(",", ":")).replace('{"kind"', '{"rows":1,"kind"').encode()), False),
+    "a-byte-past-ascii": (lambda b: _reheaded(b, lambda h: json.dumps(_set(h, note=chr(233)), separators=(",", ":"), ensure_ascii=False).encode()), False),
+    "records-a-fraction": (lambda b: _reheaded(b, lambda h: _set(h, records=float(h["records"]))), False),
+    "an-unknown-encoding": (lambda b: _reheaded(b, lambda h: (_col(h, "pairs.features").__setitem__(3, "packed"), h)[1]), False),
+    "a-column-past-the-payload": (lambda b: _reheaded(b, lambda h: (_col(h, "pairs.download_index").__setitem__(4, 1 << 40), h)[1]), False),
+    "no-json": (lambda b: _reheaded(b, lambda h: json.dumps(h, separators=(",", ":"))[:-1].encode()), False),
+    "garbage-at-the-edge": (lambda b: b"DFB2" + b[4:], False),
+}
+
+
+def _outcome(read):
+    """What ``read()`` returned, or the error it raised by its type and
+    its words: the two walks are held to both."""
+    try:
+        return read()
+    except Exception as e:  # noqa: BLE001 - whatever it is, the other path raises the same
+        return (type(e).__name__, str(e))
+
+
+def _told(walk, pairs) -> dict:
+    """Everything a walk says, and the arrays its assembly hands over or
+    the error it raises, in forms that compare."""
+    if not isinstance(walk, wire.TrainPairsWalk):
+        return {"walk": walk}
+    base = np.frombuffer(walk.mapped, np.uint8).ctypes.data if walk.mapped is not None else 0
+    views = walk.train_views(0, len(walk.trains))
+    return {
+        "blocks": walk.blocks,
+        "rows": walk.table[:, : wire._FEATURES].tolist(),
+        "trains_before": walk.trains_before.tolist(),
+        "bases": walk.bases.tolist(),
+        "counts": (walk.num_pairs, walk.num_downloads),
+        # where each pair column lies and how long it is; one that is no view of the mapping, by what it holds
+        "columns": [
+            [(v.ctypes.data - base, v.nbytes, v.dtype.str, v.shape) if not v.flags.owndata and v.base is not None and 0 <= v.ctypes.data - base < max(len(walk.mapped), 1) else (v.tobytes(), v.dtype.str, v.shape) for v in block]
+            for block in views
+        ],
+        "pairs": pairs if isinstance(pairs, tuple) else tuple((a.tobytes(), a.dtype.str, a.shape) for a in (pairs.features, pairs.labels, pairs.download_index)) + (pairs.num_downloads,),
+    }
+
+
+def _both_walks(monkeypatch, path, offset=0, end=None, verify_crc=True):
+    """``[offset, end)`` walked and assembled with the library and
+    without it, and walked with it and assembled without → what each
+    told, the tallies, and the library's walk itself."""
+    told, tallies, walks = [], [], []
+    for walk_by, assemble_by in (("library", "library"), ("interpreter", "interpreter"), ("library", "interpreter"), ("interpreter", "library")):
+        tally = wire.BlockTally()
+        with monkeypatch.context() as m:
+            if walk_by == "interpreter":
+                m.setenv("DF_NO_NATIVE", "1")
+            walk = _outcome(lambda: wire.walk_train_pairs(path, offset=offset, end=end, verify_crc=verify_crc, tally=tally))
+        with monkeypatch.context() as m:
+            if assemble_by == "interpreter":
+                m.setenv("DF_NO_NATIVE", "1")
+            pairs = _outcome(walk.assemble) if isinstance(walk, wire.TrainPairsWalk) else None
+        told.append(_told(walk, pairs))
+        tallies.append((tally.decoded, tally.hopped))
+        walks.append(walk)
+    return told, tallies, walks[0]
+
+
+@pytest.fixture
+def with_the_library(monkeypatch):
+    monkeypatch.delenv("DF_NO_NATIVE", raising=False)
+    if native.load() is None:
+        pytest.skip("native library unavailable (no toolchain)")
+
+
+class TestTheLibrarysWalkIsTheInterpreters:
+    """The resident read's walk is one call of the native library that
+    holds no interpreter lock, held to the interpreter's walk table for
+    table and error for error (ISSUE 43)."""
+
+    @pytest.mark.parametrize("which", ["whole", "inner", "from-second-block", "to-last-block", "empty-range"])
+    @pytest.mark.parametrize("name", sorted(_UPLOADS) + sorted(_SPANNED))
+    def test_the_two_walks_tell_the_same(self, tmp_path, monkeypatch, with_the_library, span_of_4, name, which):
+        """Uploads of mixed kinds, a torn tail, blocks of no pairs (their
+        columns ``zero``: the library stops at the first), ranges cut by
+        ``offset`` and ``end`` and an empty one: ``blocks``, the rows,
+        the columns' places and lengths, ``bases``, ``trains_before``,
+        the counts and ``tally.decoded`` equal, and ``assemble()``'s
+        arrays bit for bit, whichever path walked and whichever
+        assembled."""
+        path, extents = _write_upload(tmp_path, name) if name in _UPLOADS else _write_blocks(tmp_path, name, _SPANNED[name], seed=21)
+        offset, end = (extents[-1][0], extents[-1][0]) if which == "empty-range" else _bounds(extents, which)
+        told, tallies, walk = _both_walks(monkeypatch, path, offset, end)
+        assert told[0] == told[1] == told[2] == told[3] and len(set(tallies)) == 1
+        in_range = wire.scan_block_extents(path, offset, end)
+        assert tallies[0] == (len(in_range), 0) and [b[0] for b in walk.blocks] == [s for s, _ in in_range]
+        want, records = _reference_pairs(path, offset, end)
+        assert told[0]["counts"] == (0 if want is None else len(want[1]), records)
+        if want is not None:
+            assert told[0]["pairs"][:3] == tuple((a.tobytes(), a.dtype.str, a.shape) for a in want)
+        # the library walked up to the first block with a pair column that is not ``raw``, and the interpreter from it on
+        sure, train = _read_by_the_library(path, offset, end), (walk.table[:, wire._TRAIN] != 0).tolist()
+        placed = sure.index(False) if False in sure else len(sure)
+        assert (walk.table[:, wire._FEATURES] >= 0).tolist() == [i < placed and t for i, t in enumerate(train)]
+        assert len(walk.views) == sum(train[placed:])
+
+    @pytest.mark.parametrize("at", [0, 2, 5])
+    @pytest.mark.parametrize("fault", sorted(_HEADERS))
+    def test_a_header_the_library_is_not_sure_of_is_the_interpreters(self, tmp_path, monkeypatch, with_the_library, span_of_4, fault, at):
+        """One block of six with its header rewritten, first, third or
+        last. Where the header says the same to ``json.loads`` in other
+        bytes (keys or columns in another order, spaces, keys the walk
+        does not read) the library reads it. Where it does not (a pair
+        column ``zero``- or ``dict``-encoded or of another type, a
+        ``crc32`` that is no integer of 32 bits, a key missing, twice or
+        escaped, another feature width, no JSON, no magic) the library
+        stops at that block, the interpreter walks from it on, and the
+        result is the interpreter's: the same tables and arrays, or the
+        same error word for word, from the walk or from the assembly."""
+        rng = np.random.default_rng(22)
+        blocks = [_raw_block(rng, 5 + i, i % 3, 2 + i % 2) for i in range(6)]
+        rewrite, read_by_the_library = _HEADERS[fault]
+        if fault == "labels-all-zero":
+            header, cols, _ = wire.decode_block(blocks[at])
+            cols = {k: np.zeros_like(v) if k == "pairs.labels" else v for k, v in cols.items()}
+            blocks[at] = wire.encode_block(cols, wire.KIND_TRAIN, records=header["records"], meta=header["meta"])
+        else:
+            blocks[at] = rewrite(blocks[at])
+        path = tmp_path / "rewritten.dfb"
+        path.write_bytes(b"".join(blocks))
+        told, tallies, walk = _both_walks(monkeypatch, path)
+        assert told[0] == told[1] == told[2] == told[3] and len(set(tallies)) == 1
+        if read_by_the_library:
+            assert not walk.views and (walk.table[:, wire._FEATURES] >= 0).all() and isinstance(told[0]["pairs"][0], tuple)
+            assert told[0]["counts"] == (sum(5 + i for i in range(6)), sum(2 + i % 2 for i in range(6)) + (3 + at - at % 2 if fault == "no-records" else 0))  # ``rows`` where no ``records`` is stated
+        elif isinstance(walk, wire.TrainPairsWalk):
+            assert len(walk.views) == 6 - at and (walk.table[:, wire._FEATURES] >= 0).tolist() == [i < at for i in range(6)]
+        # what each fault comes to, on both paths
+        edge = sum(len(b) for b in blocks[:at])
+        want = {
+            "a-foreign-feature-width": ("WireError", "train block feature dim 18 != schema 19 — incompatible peer (negotiation token should have gated this)"),
+            "garbage-at-the-edge": ("WireError", f"bad block magic at byte {edge}: b'DFB2'"),
+            "an-unknown-encoding": ("WireError", "unknown column encoding 'packed' for 'pairs.features'"),
+            "no-crc32": ("KeyError", "'crc32'"), "no-rows": ("KeyError", "'rows'"), "no-labels-column": ("KeyError", "'pairs.labels'"),
+            "no-meta": ("WireError", "train block feature dim None != schema 19 — incompatible peer (negotiation token should have gated this)"),
+        }
+        if fault in want:
+            assert told[0] == {"walk": want[fault]}
+        elif fault.startswith("crc32-"):
+            assert told[0]["pairs"] == ("WireError", f"block crc mismatch at byte {edge}") and told[0]["blocks"][at][3] == -1
+        elif fault in ("no-json", "a-column-past-the-payload"):
+            assert set(told[0]) == {"walk"} and told[0]["walk"][0] in ("JSONDecodeError", "ValueError")
+        elif fault == "an-escape-in-a-key":
+            assert told[0] == {"walk": ("KeyError", "'kind'")} or isinstance(told[0]["pairs"][0], tuple)
+        else:
+            assert isinstance(told[0]["pairs"][0], tuple), told[0]["pairs"]
+
+    @pytest.mark.parametrize("verify_crc", [True, False])
+    def test_a_fits_path_builds_no_view_and_no_list(self, tmp_path, monkeypatch, with_the_library, span_of_4, verify_crc):
+        """With the library, the walk and the assembly make no object a
+        block: no view is built (``np.frombuffer`` is called for the
+        mapping's first byte alone), no list is made, no header is
+        parsed. The lists are made at their first read, for whoever
+        reads them."""
+        rng = np.random.default_rng(23)
+        path = tmp_path / "spans.dfb"
+        path.write_bytes(b"".join(_raw_block(rng, 3 + i % 5, i % 3, 2 + i % 4) for i in range(11)))
+        made, parsed = [], []
+        frombuffer, loads = np.frombuffer, json.loads
+        monkeypatch.setattr(wire.np, "frombuffer", lambda *a, **kw: (made.append(a[1:]), frombuffer(*a, **kw))[1])
+        monkeypatch.setattr(wire.json, "loads", lambda *a, **kw: (parsed.append(1), loads(*a, **kw))[1])
+        walk = wire.walk_train_pairs(path, verify_crc=verify_crc)
+        pairs = walk.assemble()
+        assert made == [(np.uint8,)] * 2 and not parsed and not walk.views  # the mapping's address: once in the walk, once in the assembly
+        assert not {"blocks", "_columns"} & set(vars(walk))
+        assert len(walk.features) == len(walk.labels) == len(walk.download_index) == 11 and len(made) == 2 + 3 * 11
+        assert walk.features is walk.features and len(made) == 2 + 3 * 11  # made once
+        assert [len(l) for l in walk.labels] == walk.lengths.tolist() and len(walk.blocks) == 11
+        want, _ = _reference_pairs(path)
+        for got, w in zip((pairs.features, pairs.labels, pairs.download_index), want):
+            _assert_same_array(got, w)
+
+    def test_the_librarys_walk_is_entered_once_and_the_interpreters_never(self, tmp_path, monkeypatch, check_path):
+        """``native_phase`` is entered around the library's calls, once
+        a walk, by the walking thread; on the interpreter's path not at
+        all. An empty range enters nothing on either."""
+        rng = np.random.default_rng(24)
+        path = tmp_path / "spans.dfb"
+        path.write_bytes(b"".join(_raw_block(rng, 3 + i % 5, i % 3, 2 + i % 4) for i in range(11)))
+        extents = wire.scan_block_extents(path)
+        entered = _Entered()
+        walk = wire.walk_train_pairs(path, native_phase=entered)
+        assert [t for t, _ in entered.left] == ([threading.current_thread().name] if check_path[0] == "library" else [])
+        assert len(walk.table) == 11 and len(walk.views) == (0 if check_path[0] == "library" else 11)
+        wire.walk_train_pairs(path, offset=extents[3][0], end=extents[3][0], native_phase=entered)
+        assert len(entered.left) == (1 if check_path[0] == "library" else 0)
 
 
 def test_round_reports_blocks_decoded_and_hopped(tmp_path):

@@ -331,6 +331,144 @@ def test_crc32_blocks_holds_no_interpreter_lock():
     assert sum(moved) > 0 and held == 0, (moved, held)
 
 
+# ---------------------------------------------------------------------------
+# df_walk_blocks: the headers of a range of blocks read in one call that
+# holds no interpreter lock (schema/wire.py walk_train_pairs)
+# ---------------------------------------------------------------------------
+
+
+def _train_block(rng, pairs: int, records: int) -> bytes:
+    from dragonfly2_tpu.schema import wire
+    from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
+
+    cols = {
+        "pairs.features": rng.random((pairs, MLP_FEATURE_DIM), np.float32) + 1,
+        "pairs.labels": rng.random(pairs, np.float32) + 1,
+        "pairs.download_index": np.arange(1, pairs + 1, dtype=np.int32) % records,
+    }
+    return wire.encode_block(cols, wire.KIND_TRAIN, records=records, meta={"feature_dim": MLP_FEATURE_DIM})
+
+
+def _walked(buf: bytes, start: int, end: int, cap=None):
+    """``df_walk_blocks`` over ``buf`` as the walk calls it: once for the
+    count, once for the rows (``cap`` of them; None: the count) → the
+    count, the rows it filled and where it stopped."""
+    from dragonfly2_tpu.schema import wire
+
+    lib, stopped = native.load(), np.empty(1, np.int64)
+    held = np.frombuffer(buf, np.uint8)
+    n = lib.df_walk_blocks(held.ctypes.data, start, end, None, 0, stopped)
+    rows = np.full((n if cap is None else cap, wire.WALK_COLUMNS), -7, np.int64)
+    filled = lib.df_walk_blocks(held.ctypes.data, start, end, rows.ctypes.data, len(rows), stopped)
+    assert (rows[filled:] == -7).all()  # nothing written past the rows it says it filled
+    return n, rows[:filled], int(stopped[0])
+
+
+def test_walk_blocks_rows_are_the_headers_numbers():
+    """Ten numbers a block, in the order ``wire.WALK_COLUMNS`` counts:
+    against the header ``json.loads`` reads. The walk stops at the
+    ``cap``-th row and says where; a count reads no header."""
+    import json
+
+    from dragonfly2_tpu.schema import wire
+
+    rng = np.random.default_rng(41)
+    blocks = [_train_block(rng, 3 + i, 2 + i % 3) for i in range(6)]
+    blocks.insert(2, wire.encode_topology_block(make_topology_records(5, num_hosts=4, seed=1)))
+    buf = b"".join(blocks)
+    edges = np.cumsum([0] + [len(b) for b in blocks]).tolist()
+    n, rows, stopped = _walked(buf, 0, len(buf))
+    assert (n, stopped) == (7, -1) and rows.shape == (7, 10)
+    for row, block, pos in zip(rows.tolist(), blocks, edges):
+        _, header_len, payload_len = wire._PREAMBLE.unpack_from(block)
+        header = json.loads(block[16 : 16 + header_len])
+        at = {e[0]: e[4] for e in header["cols"]}
+        train = header["kind"] == "train"
+        assert tuple(row) == (
+            pos, pos + 16 + header_len, payload_len, header["crc32"], int(train),
+            *((header["rows"], header["records"], *(at[c] for c in wire._PAIR_COLUMNS)) if train else (0, 0, -1, -1, -1)),
+        )
+    count, some, stopped = _walked(buf, 0, len(buf), cap=3)
+    assert (count, stopped) == (7, edges[3]) and some.tobytes() == rows[:3].tobytes()
+    # a range from a block's edge to another's; a torn tail ends it; so do fewer bytes than a preamble
+    inner = _walked(buf, edges[1], edges[-2])
+    assert inner[0] == 5 and inner[1].tobytes() == rows[1:6].tobytes() and inner[2] == -1
+    for short in (1, 15, 16, 17, len(blocks[-1]) - 1):
+        assert _walked(buf, 0, edges[-2] + short)[::2] == (6, -1)
+    assert _walked(buf, 0, 0)[::2] == (0, -1)
+
+
+def test_walk_blocks_stops_where_there_is_no_magic_or_no_header_it_is_sure_of():
+    """The count and the rows end at the same block, and ``stopped_at``
+    is that block's first byte: the interpreter's walk goes on from
+    there, to raise or to read."""
+    rng = np.random.default_rng(42)
+    blocks = [_train_block(rng, 4, 3) for _ in range(5)]
+    edges = np.cumsum([0] + [len(b) for b in blocks]).tolist()
+    buf = bytearray(b"".join(blocks))
+    buf[edges[3] + 3] = ord("2")
+    n, rows, stopped = _walked(bytes(buf), 0, len(buf))
+    assert (n, stopped) == (3, edges[3]) and rows[:, 0].tolist() == edges[:3]
+    # with only a preamble's bytes left it is still a bad magic, as in the interpreter's walk; with fewer, a torn tail
+    assert _walked(bytes(buf), 0, edges[3] + 16)[::2] == (3, edges[3])
+    assert _walked(bytes(buf), 0, edges[3] + 15)[::2] == (3, -1)
+    # a header that is no JSON: the count hops it by its preamble, the rows stop at it
+    buf = bytearray(b"".join(blocks))
+    buf[edges[2] + 16] = ord("[")
+    n, rows, stopped = _walked(bytes(buf), 0, len(buf))
+    assert (n, len(rows), stopped) == (5, 2, edges[2])
+
+
+def test_walk_blocks_holds_no_interpreter_lock(tmp_path):
+    """A Python thread makes progress while another is inside the
+    library's walk of some thousands of blocks: the shape of the check's
+    test above. The interpreter's forced hand-over is set far beyond
+    the test, so the counting thread counts only while the walking
+    thread is inside a call that gave the lock up. Every wait has a
+    limit of its own: nothing here can hang the run."""
+    import sys
+    import threading
+
+    from dragonfly2_tpu.schema import wire
+
+    block = _train_block(np.random.default_rng(43), 4, 3)
+    path = tmp_path / "many.dfb"
+    path.write_bytes(block * 20_000)
+    counted, stop, counting = [0], threading.Event(), threading.Event()
+
+    def count():
+        while not stop.is_set():
+            counted[0] += 1
+            counting.set()
+            time.sleep(0)  # the lock, offered
+
+    interval = sys.getswitchinterval()
+    counter = threading.Thread(target=count, name="test.counter", daemon=True)
+    sys.setswitchinterval(3600.0)
+    try:
+        counter.start()
+        assert counting.wait(timeout=60)
+        moved, walked = [], []
+        deadline = time.monotonic() + 120
+        for _ in range(20):
+            before = counted[0]
+            walk = wire.walk_train_pairs(path)
+            moved.append(counted[0] - before)
+            walked.append((len(walk.table), len(walk.views), walk.num_pairs))
+            assert time.monotonic() < deadline
+        before = counted[0]
+        sum(range(200_000))
+        held = counted[0] - before
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        counter.join(timeout=60)
+    assert not counter.is_alive()
+    assert set(walked) == {(20_000, 0, 80_000)}  # the library's, every block of it
+    # in some call, at the least: on a machine of one busy core a call can end before the counter is given a turn
+    assert sum(moved) > 0 and held == 0, (moved, held)
+
+
 def test_no_native_is_read_at_every_call(monkeypatch):
     """``DF_NO_NATIVE`` set after the library loaded turns its callers
     to their fallbacks from the next call on, and taking it away turns

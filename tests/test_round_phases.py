@@ -29,7 +29,7 @@ EPOCHS = {"mlp": 2, "gnn": 4, "gru": 3}
 ONCE_A_FIT = ("load", "split", "table_put", "holdout", "register")
 ONCE_AN_EPOCH = ("gather", "feed", "epoch_dispatch", "epoch_wait")
 # entered inside one of those on the leg's thread: the ledger's and the trace's, in no split
-INSIDE_ANOTHER = ("fit", "load_walk", "load_assemble", "feed_slice", "epoch_slice", "load_span", "load_check")
+INSIDE_ANOTHER = ("fit", "load_walk", "load_assemble", "load_walk_native", "feed_slice", "epoch_slice", "load_span", "load_check")
 STREAM_PHASES = ("trainer.decode_wait", "trainer.buffer_wait", "trainer.h2d", "trainer.step")
 
 
@@ -159,6 +159,7 @@ def _expected_inside(leg: str, streaming: bool) -> dict:
     }
     if leg == "mlp":
         want.update({"trainer.mlp_load_walk": 1, "trainer.mlp_load_assemble": 1, "trainer.mlp_load_span": 1})
+        want["trainer.mlp_load_walk_native"] = int(native.available())  # the library's walk, where it loaded
     return want
 
 
@@ -413,6 +414,64 @@ def test_the_load_is_its_walk_and_its_assembly(tmp_path, monkeypatch, path):
     assert split.phase_n[phases["load"].name] == 1
     assert not {phases[stage].name for stage in stages[1:]} & set(split.phase_n)
     assert split.phase_s[phases["load"].name] == pytest.approx(load, abs=1e-5)
+
+
+@pytest.mark.parametrize("path", ["library", "interpreter", "handed-over"])
+def test_the_librarys_walk_counts_once_a_fit_inside_the_walk(tmp_path, monkeypatch, path):
+    """``load_walk_native`` is entered around the library's walk, inside
+    ``load_walk`` on the leg's thread: 1 a fit where the library loaded,
+    its seconds held by ``load_walk``'s, in no split; 0 and no seconds
+    where the interpreter parsed every header (``DF_NO_NATIVE``). A walk
+    the library hands over at a block it is not sure of (here the
+    upload's second, its labels all zeros and so ``zero``-encoded) still
+    counts its call, and the fit is handed the same arrays."""
+    import dragonfly2_tpu.trainer.training as training_mod
+
+    if path == "interpreter":
+        monkeypatch.setenv("DF_NO_NATIVE", "1")
+    elif not native.available():
+        pytest.skip("native library unavailable (no toolchain)")
+    handed = []
+    monkeypatch.setattr(training_mod, "train_mlp", lambda features, labels, **kw: handed.append((features, labels)) or 1 / 0)
+    training = _training(tmp_path, False)
+    host_id = host_id_v2(IP, HOSTNAME)
+    for i in range(6):
+        block = wire.encode_train_block(synth.make_download_records(wire.BLOCK_RECORDS, seed=40 + i))
+        if path == "handed-over" and i == 1:
+            header, cols, _ = wire.decode_block(block)
+            cols = {k: np.zeros_like(v) if k == "pairs.labels" else v for k, v in cols.items()}
+            block = wire.encode_block(cols, wire.KIND_TRAIN, records=header["records"], meta=header["meta"])
+        training.storage.append_download_blocks(host_id, block)
+    training.storage.mark_download_round(host_id)
+    phases = {stage: getattr(M.PH_MLP, stage) for stage in ("load", "load_walk", "load_walk_native")}
+    open_when_entered = []
+    real_enter = profiling.Phase.__enter__
+
+    def watched(ph):
+        if ph is phases["load_walk_native"]:
+            open_when_entered.append((phases["load_walk"].active, threading.current_thread().name))
+        return real_enter(ph)
+
+    monkeypatch.setattr(profiling.Phase, "__enter__", watched)
+    before = {stage: ph.snapshot() for stage, ph in phases.items()}
+    splits: dict = {}
+    leg = threading.current_thread().name
+    with pytest.raises(ZeroDivisionError):
+        training._timed_fit("mlp", None, splits, training._train_mlp, host_id, IP, HOSTNAME)
+    moved = {stage: (ph.snapshot()["count"] - before[stage]["count"], ph.snapshot()["total_s"] - before[stage]["total_s"]) for stage, ph in phases.items()}
+    by_library = path != "interpreter"
+    assert [moved[stage][0] for stage in phases] == [1, 1, int(by_library)]
+    assert open_when_entered == ([(1, leg)] if by_library else [])
+    assert (0 < moved["load_walk_native"][1] <= moved["load_walk"][1]) if by_library else moved["load_walk_native"][1] == 0
+    assert phases["load_walk_native"].name not in splits["mlp"].phase_n and phases["load_walk_native"].inner
+    blocks = training.storage.download_blocks_path(host_id)
+    with monkeypatch.context() as m:
+        m.setenv("DF_NO_NATIVE", "1")
+        want = wire.read_train_pairs(blocks)
+    ((features, labels),) = handed
+    np.testing.assert_array_equal(np.asarray(features), want.features)
+    np.testing.assert_array_equal(np.asarray(labels), want.labels)
+    assert splits["mlp"].blocks_decoded == 6
 
 
 def test_a_corrupt_payload_fails_the_load_in_the_assembly_and_ends_the_order(tmp_path, monkeypatch):
