@@ -83,6 +83,29 @@ PH_JIT_COMPILE = profiling.phase_type("trainer.jit_compile")
 # still are, each leg's ``fit`` phase below says.
 PH_ROUND = profiling.phase_type("trainer.round")
 
+# Rounds of several schedulers on one trainer (trainer/training.py
+# RoundAdmission). round_wait: entered by a round's thread from its
+# arrival until it is admitted, before ``trainer.round`` and on no leg's
+# thread, so it lies outside the round and in no leg's split: count =
+# rounds, total = seconds waited (a round that finds the trainer empty
+# leaves it in microseconds; one that finds another running walks its
+# upload's headers in it, its reckoning, before it is admitted or
+# waits). merge: the average of a cadence's MLP versions and its
+# create_model, on the thread of the cadence's last round, after that
+# round's ``trainer.round`` has closed.
+PH_ROUND_WAIT = profiling.phase_type("trainer.round_wait")
+PH_MERGE = profiling.phase_type("trainer.merge")
+ROUND_ADMISSION_TOTAL = _r.counter(
+    "trainer_round_admission_total",
+    "Rounds admitted to the chip, by whether they waited for another round's return",
+    ("result",),
+)
+ROUNDS_RUNNING = _r.gauge("trainer_rounds_running", "Rounds admitted and not yet returned")
+ROUNDS_RESERVED_BYTES = _r.gauge(
+    "trainer_rounds_reserved_bytes",
+    "Device bytes the running rounds were reckoned to hold at their fullest",
+)
+
 # The resident fits' phases, one vocabulary for the three legs
 # (trainer/training.py wraps fit, load and register, trainer/train.py the
 # rest). load/split/table_put/holdout/register are entered once a fit, the

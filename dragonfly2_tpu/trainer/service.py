@@ -90,24 +90,34 @@ class TrainerService:
             raise
 
         if host_id is not None:
-            # stream complete: everything appended so far is whole rounds —
-            # mark the byte boundary incremental offsets may commit up to
-            self.storage.mark_download_round(host_id)
-            if self.synchronous:
-                self.training.train(ip, hostname)
-            else:
-                from dragonfly2_tpu.utils import tracing
-
-                # the async fit must stay in the uploader's trace: hand
-                # the rpc.Train span to the worker thread (contextvars
-                # don't cross threads on their own)
-                threading.Thread(
-                    target=self._train_safely,
-                    args=(ip, hostname, tracing.current_span()),
-                    name="trainer.fit",
-                    daemon=True,
-                ).start()
+            self.fit_after_stream(ip, hostname)
         return trainer_pb2.TrainResponse()
+
+    def fit_after_stream(self, ip: str, hostname: str) -> "threading.Thread | None":
+        """A host's stream is complete: everything appended so far is
+        whole rounds, so mark the byte boundary incremental offsets may
+        commit up to, and fork the host's round (reference
+        service_v1.go:155-159) so the stream's ack is not held for
+        minutes of training. ``synchronous`` runs it inline. -> the
+        round's thread, for a caller that staged the upload itself and
+        waits for the round (the Train handler does not)."""
+        self.storage.mark_download_round(host_id_v2(ip, hostname))
+        if self.synchronous:
+            self.training.train(ip, hostname)
+            return None
+        from dragonfly2_tpu.utils import tracing
+
+        # the async fit must stay in the uploader's trace: hand
+        # the rpc.Train span to the worker thread (contextvars
+        # don't cross threads on their own)
+        thread = threading.Thread(
+            target=self._train_safely,
+            args=(ip, hostname, tracing.current_span()),
+            name="trainer.fit",
+            daemon=True,
+        )
+        thread.start()
+        return thread
 
     def _train_safely(self, ip: str, hostname: str, parent_span=None) -> None:
         from dragonfly2_tpu.utils import tracing

@@ -325,6 +325,36 @@ def _on_mesh(mesh, a, spec=P()):
     return jnp.asarray(a) if mesh is None else jax.device_put(a, NamedSharding(mesh, spec))
 
 
+def _table_slices(n: int, words: int, per_row: int) -> tuple[int, int]:
+    """-> (puts, rows a put) for a table of ``n`` rows: the fewest slices
+    under ``FEED_SLICE_BYTES``, the rows spread evenly over them in whole
+    table rows."""
+    slices = max(-(-n // (max(FEED_SLICE_BYTES // (4 * words) // per_row, 1) * per_row)), 1)
+    return slices, -(-max(n, 1) // (slices * per_row)) * per_row
+
+
+def resident_fit_bytes(n: int, *row_shapes: tuple) -> int:
+    """What a resident fit of ``n`` rows holds on the chip at its
+    fullest, reckoned from the row count alone (``row_shapes``: each
+    column's row shape, 32 bits a value), before a byte of it is read:
+    the table as ``_put_table`` lays it out (85.3 B a pair for the
+    MLP's 19 features and a label, six pairs a 512 B row), an epoch's
+    row numbers (4 B a row; the holdout's come a slice at a time and
+    the epoch's are gone by then), and four slices of
+    ``FEED_SLICE_BYTES`` for what passes through: two puts in flight
+    and their packing while the table is laid, or the holdout's gathered
+    slice and its forward. A week's 55,050,240 pairs reckon
+    4,697,628,672 + 220,200,960 + 268,435,456 = 5,186,265,088 B
+    (measured peak of such a round alone: 5.05 GB, PERF.md). The
+    trainer admits rounds by this number (trainer/training.py
+    ``RoundAdmission``), so it errs above what is measured, never
+    under."""
+    columns = tuple((tuple(shape), np.dtype(np.float32)) for shape in row_shapes)
+    words, per_row, lanes = _Table.geometry(columns)
+    slices, rows = _table_slices(n, words, per_row)
+    return slices * rows // per_row * lanes * 4 + 4 * n + 4 * FEED_SLICE_BYTES
+
+
 def _put_table(mesh, phases, *columns: np.ndarray) -> _Table:
     """The fit's table from its host columns: a put a slice of
     ``FEED_SLICE_BYTES`` (each column's rows ``[lo:hi]``, a view: nothing
@@ -344,9 +374,7 @@ def _put_table(mesh, phases, *columns: np.ndarray) -> _Table:
     n = len(columns[0])
     if n >= 1 << 31:
         raise ValueError(f"{n} rows: an epoch's row numbers are 32-bit")
-    # the fewest slices under the bound, the rows spread evenly over them in whole table rows
-    slices = max(-(-n // (max(FEED_SLICE_BYTES // (4 * words) // per_row, 1) * per_row)), 1)
-    rows = -(-max(n, 1) // (slices * per_row)) * per_row
+    slices, rows = _table_slices(n, words, per_row)
     everywhere = None if mesh is None else NamedSharding(mesh, P())
     with phases.table_put:
         packed = jnp.zeros((slices * rows // per_row, lanes), jnp.uint32, device=everywhere)

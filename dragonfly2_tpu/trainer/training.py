@@ -18,6 +18,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import contextlib
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Protocol
@@ -27,18 +28,25 @@ import numpy as np
 from dragonfly2_tpu.schema import native, wire
 from dragonfly2_tpu.schema.columnar import records_to_columns
 from dragonfly2_tpu.schema.features import build_probe_graph, extract_pair_features
+from dragonfly2_tpu.trainer.federation import FittedVersion, holdout_sample, merge_versions
 from dragonfly2_tpu.trainer.storage import TrainerStorage
 from dragonfly2_tpu.trainer.train import (
     FitConfig,
     FitOrder,
     GNNFitConfig,
     release_in_pieces,
+    resident_fit_bytes,
     train_gnn,
     train_mlp,
 )
 from dragonfly2_tpu.trainer import metrics as M
 from dragonfly2_tpu.utils import dflog, flight, profiling
-from dragonfly2_tpu.utils.idgen import gnn_model_id_v1, host_id_v2, mlp_model_id_v1
+from dragonfly2_tpu.utils.idgen import (
+    federated_model_id_v1,
+    gnn_model_id_v1,
+    host_id_v2,
+    mlp_model_id_v1,
+)
 
 logger = dflog.get("trainer")
 
@@ -175,6 +183,132 @@ class LegSplit:
         }
 
 
+# What admission keeps free of the device's ``bytes_limit``: the
+# runtime's own and the executables with their scratch, a scheduler's
+# served models and landmark table where one shares the process
+# (dragonfly2_tpu.colocated), the transfer queue's staging, and room for
+# a table of gigabytes to be laid in one piece after others have come
+# and gone. A constant: the rule has to admit the same cadence the same
+# way in every run, on every machine of one kind.
+ROUND_RESERVE_BYTES = 2 << 30
+# a round's GraphSAGE and GRU fits on the chip (the GRU's table of a
+# million sequences is 70-89 MB, the graph's kilobytes a host)
+ROUND_SMALL_FITS_BYTES = 128 << 20
+# no pair takes less of an upload, in either payload form: its 19
+# features, its label and its download's index in a train block, many
+# times that as CSV text. The reckoning's bound where the headers cannot
+# be walked
+_MIN_PAIR_BYTES = 84
+
+
+@dataclass
+class Admission:
+    """A round's own account of how it came onto the chip."""
+
+    host_id: str = ""
+    arrival: int = 0  # its place among the trainer's arrivals
+    order: int = 0  # its place among the trainer's admissions
+    result: str = "at_once"  # or "waited": refused for room, admitted on another round's return
+    waited_s: float = 0.0
+    reserved_bytes: "int | None" = None  # what it was reckoned to hold on the chip at its fullest
+    admitted: bool = False
+
+
+class RoundAdmission:
+    """Which rounds run side by side on the trainer's chip. A round holds
+    its table there for the whole fit; every scheduler's Train stream
+    forks a round at its end, and a cluster's schedulers reach their
+    upload together. A round is admitted when what it was reckoned to
+    hold (``Training._reckon_round_bytes``: from its upload's headers, before
+    a pair is read) fits the budget beside the reckoned bytes of the
+    rounds running; otherwise it waits, in arrival order, for a running
+    round's return. The decision is a function of the uploads' sizes,
+    the arrival order and the device's limit: never of what the device
+    holds at the moment. A round alone is always admitted (an upload
+    that alone does not fit is ROADMAP M2's), and no waiting round is
+    dropped, cut or sent another way."""
+
+    def __init__(self, limit: "int | None"):
+        self.limit = limit  # the device's bytes_limit; None: unbounded (a backend that states none)
+        self._cond = threading.Condition()
+        self._waiting: collections.deque = collections.deque()  # Admission, in arrival order
+        self._running: list = []
+        self._arrivals = self._admissions = 0
+
+    @property
+    def budget(self) -> "int | None":
+        return None if self.limit is None else self.limit - ROUND_RESERVE_BYTES
+
+    def arrive(self, host_id: str) -> Admission:
+        """Take a place in the arrival order; a round that finds none
+        running and none waiting is admitted on the spot."""
+        with self._cond:
+            a = Admission(host_id=host_id, arrival=self._arrivals)
+            self._arrivals += 1
+            if self._running or self._waiting:
+                self._waiting.append(a)
+            else:
+                self._admit(a)
+            return a
+
+    def reckoned(self, a: Admission, nbytes: int) -> None:
+        with self._cond:
+            a.reserved_bytes = int(nbytes)
+            self._sync_gauges()
+            self._cond.notify_all()
+
+    def wait(self, a: Admission) -> None:
+        """Until ``a`` is admitted: at the head of the arrivals, with
+        its own reckoning and that of every running round known, and
+        room for it beside them."""
+        t0 = time.perf_counter()
+        with self._cond:
+            while not a.admitted:
+                known = a.reserved_bytes is not None and all(r.reserved_bytes is not None for r in self._running)
+                if self._waiting[0] is a and known:
+                    beside = sum(r.reserved_bytes for r in self._running)
+                    if not self._running or self.budget is None or beside + a.reserved_bytes <= self.budget:
+                        self._waiting.popleft()
+                        self._admit(a)
+                        self._cond.notify_all()  # the next in line may fit too
+                        break
+                    for behind in self._waiting:  # in arrival order: whoever stands behind waits for room too
+                        behind.result = "waited"
+                self._cond.wait()
+        a.waited_s = round(time.perf_counter() - t0, 6) if a.result == "waited" else 0.0
+        M.ROUND_ADMISSION_TOTAL.labels(a.result).inc()
+
+    def leave(self, a: Admission) -> bool:
+        """``a``'s round has returned. -> whether that ends a cadence:
+        no round is running and none waits."""
+        with self._cond:
+            (self._running if a.admitted else self._waiting).remove(a)
+            self._sync_gauges()
+            self._cond.notify_all()
+            return not self._running and not self._waiting
+
+    def _admit(self, a: Admission) -> None:
+        self._running.append(a)
+        a.admitted, a.order = True, self._admissions
+        self._admissions += 1
+        self._sync_gauges()
+
+    def _sync_gauges(self) -> None:
+        M.ROUNDS_RUNNING.set(len(self._running))
+        M.ROUNDS_RESERVED_BYTES.set(sum(r.reserved_bytes or 0 for r in self._running))
+
+
+def _device_bytes_limit(mesh) -> "int | None":
+    """The smallest ``bytes_limit`` of the devices a fit lays its table
+    on (under a mesh every chip holds it whole); None where the backend
+    states none (the CPU's)."""
+    import jax
+
+    devices = list(mesh.devices.flat) if mesh is not None else jax.local_devices()[:1]
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    return min(limits) if limits and all(limits) else None
+
+
 @dataclass
 class TrainingOutcome:
     mlp_metrics: dict[str, float] | None = None
@@ -185,6 +319,7 @@ class TrainingOutcome:
     gru_error: str | None = None  # GRU is optional; never gates .ok
     wall_s: float = 0.0  # the round: the three fits, side by side
     splits: dict[str, LegSplit] = field(default_factory=dict)  # by leg
+    admission: Admission = field(default_factory=Admission)
 
     @property
     def ok(self) -> bool:
@@ -210,14 +345,38 @@ class Training:
 
             mesh = auto_dp_mesh()
         self.mesh = mesh
+        self.admission = RoundAdmission(_device_bytes_limit(mesh))
+        # the hosts' newest fitted MLP versions since the last merge, and
+        # the hosts whose MLP fit failed since the last cadence's end
+        self._fitted: dict[str, FittedVersion] = {}
+        self._unfitted: set[str] = set()
+        self._fitted_lock = threading.Lock()
 
     def train(self, ip: str, hostname: str) -> TrainingOutcome:
+        """One scheduler host's round: admitted to the chip by what its
+        upload will hold there (``RoundAdmission``), fitted, and, where
+        its return ends a cadence of several hosts' rounds, followed by
+        their merge."""
+        host_id = host_id_v2(ip, hostname)
+        admission = self.admission.arrive(host_id)
+        try:
+            with M.PH_ROUND_WAIT:  # from its arrival until it is admitted: a round alone passes through
+                if not admission.admitted:
+                    self.admission.reckoned(admission, self._reckon_round_bytes(host_id))
+                self.admission.wait(admission)
+            outcome = self._round(host_id, ip, hostname, admission)
+        finally:
+            cadence_over = self.admission.leave(admission)
+        if cadence_over:
+            self._merge()
+        return outcome
+
+    def _round(self, host_id: str, ip: str, hostname: str, admission: Admission) -> TrainingOutcome:
         """Fit MLP + GNN for one uploading scheduler host, concurrently
         (reference training.go:60-78 errgroup)."""
         from dragonfly2_tpu.utils import tracing
 
-        host_id = host_id_v2(ip, hostname)
-        outcome = TrainingOutcome()
+        outcome = TrainingOutcome(admission=admission)
         # the caller's span (rpc.Train when driven by the Train stream):
         # fit spans in the pool threads parent under it explicitly —
         # contextvars don't cross ThreadPoolExecutor boundaries
@@ -233,7 +392,7 @@ class Training:
         ) as pool:
             f_mlp = pool.submit(
                 self._timed_fit, "mlp", parent_span, splits, self._train_mlp,
-                host_id, ip, hostname, mlp_info,
+                host_id, ip, hostname, mlp_info, admission,
             )
             f_gnn = pool.submit(
                 self._timed_fit, "gnn", parent_span, splits, self._train_gnn,
@@ -252,6 +411,10 @@ class Training:
             except Exception as e:
                 logger.exception("trainMLP failed for %s", host_id)
                 outcome.mlp_error = str(e)
+                with self._fitted_lock:
+                    self._fitted.pop(host_id, None)
+                    self._unfitted.add(host_id)
+            self._reckon_alone(admission, 0)  # a leg that ended before it knew its pairs
             try:
                 outcome.gnn_metrics = f_gnn.result()
             except Exception as e:
@@ -274,6 +437,9 @@ class Training:
             mlp_error=outcome.mlp_error or "",
             gnn_error=outcome.gnn_error or "",
             gru_error=outcome.gru_error or "",
+            admission=admission.result,
+            waited_s=admission.waited_s,
+            reserved_bytes=admission.reserved_bytes or 0,
         )
         if self.config.clear_after_train and not self.config.incremental:
             # the reference retrains from scratch each round and drops
@@ -287,10 +453,10 @@ class Training:
                 self.storage.clear_network_topology(host_id)
         return outcome
 
-    def _timed_fit(self, model: str, parent_span, splits: dict, fn, *args):
+    def _timed_fit(self, model: str, parent_span, splits: dict, fn, host_id: str, *args):
         from dragonfly2_tpu.utils import tracing
 
-        span = tracing.get("trainer").start_span("fit", parent=parent_span, model=model)
+        span = tracing.get("trainer").start_span("fit", parent=parent_span, model=model, host_id=host_id)
         t0 = time.perf_counter()
         blocks = wire.BlockTally()  # filled by the leg's block readers
 
@@ -324,13 +490,13 @@ class Training:
             profiling.split() as mine,
         ):
             try:
-                result = fn(*args, blocks=blocks)
+                result = fn(host_id, *args, blocks=blocks)
             except Exception as e:
-                EV_FIT(model=model, outcome="failure", error=str(e), **close(mine))
+                EV_FIT(model=model, host_id=host_id, outcome="failure", error=str(e), **close(mine))
                 span.end("error")
                 M.FIT_TOTAL.labels(model, "failure").inc()
                 raise
-            EV_FIT(model=model, outcome="success", **close(mine))
+            EV_FIT(model=model, host_id=host_id, outcome="success", **close(mine))
         span.end("ok")
         M.FIT_TOTAL.labels(model, "success").inc()
         # fit-freshness source for the cluster telemetry plane: the SLO
@@ -375,6 +541,7 @@ class Training:
         ip: str,
         hostname: str,
         info: dict | None = None,
+        admission: "Admission | None" = None,
         blocks: wire.BlockTally | None = None,
     ) -> dict[str, float]:
         # payload selection: binary columnar stream (zero-parse ingest)
@@ -392,7 +559,7 @@ class Training:
         if has_csv and has_bin:
             try:
                 return self._train_mlp_from(
-                    host_id, ip, hostname, binary=False, info=info, blocks=blocks
+                    host_id, ip, hostname, binary=False, info=info, admission=admission, blocks=blocks
                 )
             except BelowMinRecords as e:
                 # the CSV-era leftover alone can't train (below the
@@ -409,13 +576,13 @@ class Training:
                     e,
                 )
                 metrics = self._train_mlp_from(
-                    host_id, ip, hostname, binary=True, info=info, blocks=blocks
+                    host_id, ip, hostname, binary=True, info=info, admission=admission, blocks=blocks
                 )
                 if info is not None:
                     info["binary"] = None
                 return metrics
         return self._train_mlp_from(
-            host_id, ip, hostname, binary=has_bin, info=info, blocks=blocks
+            host_id, ip, hostname, binary=has_bin, info=info, admission=admission, blocks=blocks
         )
 
     def _train_mlp_from(
@@ -425,25 +592,18 @@ class Training:
         hostname: str,
         binary: bool,
         info: dict | None = None,
+        admission: "Admission | None" = None,
         blocks: wire.BlockTally | None = None,
     ) -> dict[str, float]:
         if info is not None:
             info["binary"] = binary
-        path = (
-            self.storage.download_blocks_path(host_id)
-            if binary
-            else self.storage.download_path(host_id)
-        )
-        offset = (
-            self.storage.download_offset(host_id, binary=binary)
-            if self.config.incremental
-            else 0
-        )
+        path, offset = self._download_range(host_id, binary)
         # the boundary is marked by the Train service at stream EOF (locked
         # against appends), so the committed offset never lands mid-record
         # (mid-block for the binary file)
         boundary = self.storage.download_round_boundary(host_id, binary=binary)
         if self._use_streaming(path, offset, binary):
+            self._reckon_alone(admission, 0)
             return self._train_mlp_streaming(
                 host_id, ip, hostname, path, offset, boundary, binary, info
             )
@@ -457,6 +617,7 @@ class Training:
                         walk = wire.walk_train_pairs(
                             path, offset=offset, end=boundary, tally=blocks, native_phase=M.PH_MLP.load_walk_native
                         )
+                    self._reckon_alone(admission, walk.num_pairs)
                     # the fit's order needs the pair count and not the pairs:
                     # it is drawn beside the assembly, which checks every block
                     # and raises before it hands over an array
@@ -469,6 +630,7 @@ class Training:
                     # streaming paths: the in-flight tail past it may be
                     # truncated by a failed stream, and the offset commit below
                     # wouldn't cover it anyway
+                    self._reckon_alone(admission, (boundary - offset) // _MIN_PAIR_BYTES)
                     pairs = native.decode_pairs_file(path, offset=offset, end=boundary)
                     if pairs is None:
                         recs = [
@@ -486,26 +648,112 @@ class Training:
                 )
             if pairs.features.shape[0] == 0:
                 raise BelowMinRecords("no trainable (download, parent) pairs")
+            if order is None:
+                order = drawing.enter_context(FitOrder(M.PH_MLP, pairs.features.shape[0], cfg))
+            # what a merge with other hosts' versions will be scored on, once the upload is gone
+            # (taken before the fit, which ends the order)
+            holdout = holdout_sample(pairs.features, pairs.labels, order.split()[1])
             result = train_mlp(pairs.features, pairs.labels, mesh=self.mesh, config=cfg, order=order)
         # the upload's pairs go a slice at a time, not in one free on return
+        fitted_on = pairs.features.shape[0]
         owned = [pairs.features, pairs.labels, pairs.download_index]
         del pairs
         release_in_pieces(owned)
-        if self.manager_client is not None:
-            with M.PH_MLP.register:
-                self.manager_client.create_model(
-                    model_id=mlp_model_id_v1(ip, hostname),
-                    model_type="mlp",
-                    ip=ip,
-                    hostname=hostname,
-                    params=_to_host(result.params),
-                    evaluation=result.metrics,
-                )
+        self._register_mlp(host_id, ip, hostname, result.params, result.metrics, fitted_on, holdout)
         if self.config.incremental:
             # commit only after a fully successful round (incl. upload) —
             # a crashed round re-decodes from the previous offset
             self.storage.commit_download_offset(host_id, boundary, binary=binary)
         return result.metrics
+
+    def _register_mlp(
+        self, host_id: str, ip: str, hostname: str, params, metrics: dict, pairs: int, holdout: "tuple | None" = None
+    ) -> None:
+        """The fitted version to the manager under the host's id, and kept
+        as the host's newest for the cadence's merge."""
+        with M.PH_MLP.register:
+            on_host = _to_host(params)
+            if self.manager_client is not None:
+                self.manager_client.create_model(
+                    model_id=mlp_model_id_v1(ip, hostname),
+                    model_type="mlp",
+                    ip=ip,
+                    hostname=hostname,
+                    params=on_host,
+                    evaluation=metrics,
+                )
+        with self._fitted_lock:
+            self._fitted[host_id] = FittedVersion(on_host, pairs, holdout)
+
+    @staticmethod
+    def _round_bytes(pairs: int) -> int:
+        """What a round whose resident MLP fit has ``pairs`` pairs holds
+        on the chip at its fullest: the fit's table, row numbers and
+        slices (``train.resident_fit_bytes``), and a flat allowance for
+        the two small fits (a streamed fit's two superbatches too)."""
+        from dragonfly2_tpu.schema.features import MLP_FEATURE_DIM
+
+        return ROUND_SMALL_FITS_BYTES + (resident_fit_bytes(pairs, (MLP_FEATURE_DIM,), ()) if pairs else 0)
+
+    def _reckon_alone(self, admission: "Admission | None", pairs: int) -> None:
+        """A round admitted alone, on the spot, is reckoned by its MLP
+        leg, from the walk the load makes anyway, for whoever arrives
+        while it runs (a round that waited was reckoned before it did:
+        ``_reckon_round_bytes``)."""
+        if admission is not None and admission.reserved_bytes is None:
+            self.admission.reckoned(admission, self._round_bytes(pairs))
+
+    def _reckon_round_bytes(self, host_id: str) -> int:
+        """What this host's round will hold on the chip at its fullest
+        (``_round_bytes``), from what is pending for it in storage and
+        before a pair of it is read: the pairs its upload's headers
+        count. A streamed fit counts none. Where the headers cannot be
+        walked (CSV text; a header the MLP leg will refuse too) the
+        pairs are bounded by the bytes, from above."""
+        pairs = 0
+        for binary in (False, True):
+            path, offset = self._download_range(host_id, binary)
+            if self._pending_bytes(host_id, binary) <= 0 or self._use_streaming(path, offset, binary):
+                continue
+            # a round fits one payload form (the other waits for the next): the larger sets the bound
+            boundary = self.storage.download_round_boundary(host_id, binary=binary)
+            bound = (boundary - offset) // _MIN_PAIR_BYTES
+            if binary:
+                try:
+                    bound = wire.walk_train_pairs(path, offset=offset, end=boundary).num_pairs
+                except Exception:
+                    logger.warning("upload of %s reckoned by its bytes: its headers do not walk", host_id)
+            pairs = max(pairs, bound)
+        return self._round_bytes(pairs)
+
+    def _merge(self) -> "dict[str, float] | None":
+        """A cadence has ended (a round returned and none runs or waits):
+        where more than one host has had an MLP version fitted since the
+        last merge, register their pair-weighted mean as ONE model under
+        the federated id (trainer/federation.py). It waits for no host
+        that uploaded nothing; a host whose fit failed is left out and
+        named. One host alone merges nothing, and its version stays the
+        newest for a later cadence. -> the merged model's evaluation."""
+        with self._fitted_lock:
+            unfitted, self._unfitted = sorted(self._unfitted), set()
+            if len(self._fitted) < 2:
+                return None
+            fitted, self._fitted = self._fitted, {}
+        if unfitted:
+            logger.warning("merge without %s: their MLP fit failed this cadence", ", ".join(unfitted))
+        with M.PH_MERGE:
+            merged, evaluation = merge_versions(fitted)
+            if self.manager_client is not None:
+                self.manager_client.create_model(
+                    model_id=federated_model_id_v1(),
+                    model_type="mlp",
+                    ip="",
+                    hostname="federated",
+                    params=merged,
+                    evaluation=evaluation,
+                )
+        logger.info("merged %d hosts' MLP versions over %d pairs", len(fitted), int(evaluation["pairs"]))
+        return evaluation
 
     def _fit_config(self, cfg, model: str, host_id: str):
         """Stamp the per-(model, host) checkpoint dir onto a fit config
@@ -524,9 +772,10 @@ class Training:
             ),
         )
 
-    def _pending_bytes(self, host_id: str, binary: bool) -> int:
-        import os
-
+    def _download_range(self, host_id: str, binary: bool) -> tuple:
+        """-> (the host's download file of that payload form, the offset
+        a round reads it from: what earlier rounds committed in
+        incremental mode, else its start)."""
         path = (
             self.storage.download_blocks_path(host_id)
             if binary
@@ -537,6 +786,12 @@ class Training:
             if self.config.incremental
             else 0
         )
+        return path, offset
+
+    def _pending_bytes(self, host_id: str, binary: bool) -> int:
+        import os
+
+        path, offset = self._download_range(host_id, binary)
         try:
             return os.path.getsize(path) - offset
         except OSError:
@@ -645,16 +900,9 @@ class Training:
             stats.steps,
             stats.records_per_s,
         )
-        if self.manager_client is not None:
-            with M.PH_MLP.register:
-                self.manager_client.create_model(
-                    model_id=mlp_model_id_v1(ip, hostname),
-                    model_type="mlp",
-                    ip=ip,
-                    hostname=hostname,
-                    params=_to_host(params),
-                    evaluation=stats.metrics,
-                )
+        self._register_mlp(
+            host_id, ip, hostname, params, stats.metrics, stats.pairs // max(self.config.streaming_passes, 1)
+        )
         if self.config.incremental:
             self.storage.commit_download_offset(host_id, boundary, binary=binary)
         return stats.metrics
@@ -805,33 +1053,6 @@ class Training:
                     params=_to_host(result.params),
                     evaluation=result.metrics,
                 )
-        return result.metrics
-
-    # -- federated round over every uploading host's shard ----------------
-    def federated_round(
-        self, config: FitConfig | None = None
-    ) -> "dict[str, float]":
-        """Fit every host shard independently, FedAvg-merge, upload ONE
-        global model (trainer/federation.py). Returns the merged model's
-        cross-shard holdout metrics."""
-        from dragonfly2_tpu.trainer.federation import federated_fit_mlp
-        from dragonfly2_tpu.utils.idgen import federated_model_id_v1
-
-        host_ids = self.storage.host_ids()
-        if not host_ids:
-            raise ValueError("no host shards in trainer storage")
-        result = federated_fit_mlp(
-            self.storage, host_ids, config=config or self.config.mlp, mesh=self.mesh
-        )
-        if self.manager_client is not None:
-            self.manager_client.create_model(
-                model_id=federated_model_id_v1(),
-                model_type="mlp",
-                ip="",
-                hostname="federated",
-                params=_to_host(result.params),
-                evaluation=result.metrics,
-            )
         return result.metrics
 
 
