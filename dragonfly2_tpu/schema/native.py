@@ -152,6 +152,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     # the rows' address and not an array: none is handed over for the count
     lib.df_walk_blocks.argtypes = [c_void_p, ctypes.c_int64, ctypes.c_int64, c_void_p, c_long, i64_p]
     lib.df_walk_blocks.restype = c_long
+    lib.df_hop_blocks.argtypes = lib.df_walk_blocks.argtypes
+    lib.df_hop_blocks.restype = c_long
     return lib
 
 

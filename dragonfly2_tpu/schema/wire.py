@@ -626,6 +626,13 @@ WALK_OFFER_BLOCKS = 32
 ) = range(11)
 
 
+def _stated_crc32(crc) -> int:
+    """The ``crc32`` a header states as ``df_crc32_blocks`` takes it: a
+    header is JSON and holds anything, and what is no integer of 32 bits
+    is -1, which matches no payload."""
+    return crc if type(crc) is int and 0 <= crc <= 0xFFFFFFFF else -1
+
+
 def _gather(lib, parts: list, out: np.ndarray) -> None:
     """``np.concatenate(parts, out=out)`` for a span's column, ``out``
     contiguous: by one call of the native library ``lib`` where that is
@@ -769,10 +776,7 @@ class TrainPairsWalk:
         if held is None:
             blocks = np.ascontiguousarray(self.table[:, : _CRC32 + 1])
         else:  # a header is JSON and a list holds anything: what no crc32 is matches no payload
-            blocks = np.array(
-                [(pos, start, nbytes, crc if type(crc) is int and 0 <= crc <= 0xFFFFFFFF else -1) for pos, start, nbytes, crc in held],
-                np.int64,
-            ).reshape(-1, 4)
+            blocks = np.array([(pos, start, nbytes, _stated_crc32(crc)) for pos, start, nbytes, crc in held], np.int64).reshape(-1, 4)
         if lib is not None:
             # the mapping's first byte: a payload's place counts from it
             base = np.frombuffer(self.mapped, np.uint8).ctypes.data
@@ -855,9 +859,7 @@ def _walk_interpreted(mm, start: int, end: int, views: list, tally: BlockTally |
         header, cols = _decode_body(mm, pos, header_len, payload_len, False, _PAIR_COLUMNS)
         if tally is not None:
             tally.decoded += 1
-        crc = header["crc32"]
-        if not (type(crc) is int and 0 <= crc <= 0xFFFFFFFF):
-            crc = -1
+        crc = _stated_crc32(header["crc32"])
         payload = pos + _PREAMBLE.size + header_len
         if header["kind"] != KIND_TRAIN:
             rows.append((pos, payload, payload_len, crc, 0, 0, 0, -1, -1, -1))
@@ -949,6 +951,102 @@ def read_train_pairs(
     return walk_train_pairs(path, offset, end, verify_crc, tally).assemble()
 
 
+class _Stopwatch:
+    """The seconds of the ``with`` blocks it was entered around, summed:
+    several stretches of one call that a phase is to count as one."""
+
+    total = 0.0
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.total += time.perf_counter() - self._t0
+
+
+def _gru_tail_interpreted(mm, cap: int, offset: int, end: int, verify_crc: bool):
+    """``read_gru_tail`` where the native library did not load, and what
+    its path through the library is held to: a generator hops the range,
+    then ``_decode_body`` once a block from the last one backwards (the
+    header parsed, ``zlib.crc32`` over the payload, the three columns'
+    views) and a ``np.concatenate`` a column → the three columns (None
+    where the range holds no sequence) and how many blocks were decoded
+    and hopped."""
+    parts: list[dict[str, np.ndarray]] = []  # newest block first
+    held = 0
+    blocks = list(_hop_mapped(mm, offset, end))
+    at = len(blocks)
+    while at and held < cap:
+        at -= 1
+        header, cols = _decode_body(mm, *blocks[at], verify_crc, _GRU_COLUMNS)
+        if header["kind"] == KIND_TRAIN and len(cols["gru.sequences"]):
+            parts.append(cols)
+            held += len(cols["gru.sequences"])
+    columns = [np.concatenate([p[c] for p in reversed(parts)])[-cap:] for c in _GRU_COLUMNS] if parts else None
+    return columns, len(blocks) - at, at
+
+
+def _gru_tail_by_the_library(lib, library, mm, cap: int, offset: int, end: int, verify_crc: bool):
+    """``_gru_tail_interpreted`` with the interpreter touching only the
+    blocks it keeps: the range hopped by one call of the library to a
+    row of three numbers a block (``df_hop_blocks``, called to count and
+    then to fill: what ``list(_hop_mapped(...))`` holds; from a byte the
+    library stopped at short of the range's end, no magic there, the
+    generator takes the range, to raise what it raises); the kept
+    blocks decoded from the last one backwards, unchecked
+    (``_decode_body``: the header parsed, the three columns made as
+    views, no payload byte read); the kept blocks checked
+    by one call (``df_crc32_blocks``), newest first; each column laid
+    into an array made once at its final length by one call
+    (``_gather``), the oldest kept block's head left out where more than
+    ``cap`` are held. ``library`` is entered around each of the
+    library's stretches."""
+    base = np.frombuffer(mm, np.uint8).ctypes.data
+    stopped_at = np.empty(1, np.int64)
+    with library:
+        n = lib.df_hop_blocks(base, offset, end, None, 0, stopped_at)
+        blocks = np.empty((n, 3), np.int64)
+        blocks = blocks[: lib.df_hop_blocks(base, offset, end, blocks.ctypes.data, n, stopped_at)]
+    if stopped_at[0] >= 0:
+        rest = list(_hop_mapped(mm, int(stopped_at[0]), end))
+        blocks = np.concatenate([blocks, np.array(rest, np.int64).reshape(-1, 3)])
+    parts: list[dict[str, np.ndarray]] = []  # newest block first
+    kept: list[tuple] = []  # the same blocks as ``df_crc32_blocks`` takes them
+    held, at = 0, len(blocks)
+    try:
+        while at and held < cap:
+            at -= 1
+            pos, header_len, payload_len = blocks[at].tolist()
+            header, cols = _decode_body(mm, pos, header_len, payload_len, False, _GRU_COLUMNS)
+            if verify_crc:
+                kept.append((pos, pos + _PREAMBLE.size + header_len, payload_len, _stated_crc32(header["crc32"])))
+            if header["kind"] == KIND_TRAIN and len(cols["gru.sequences"]):
+                parts.append(cols)
+                held += len(cols["gru.sequences"])
+    finally:
+        # also where a header raised: the interpreter's path has checked
+        # every newer block by then, and a mismatch there is what it raises
+        if kept:
+            checked = np.array(kept, np.int64)
+            with library:
+                bad = lib.df_crc32_blocks(base, checked, len(checked))
+            if bad >= 0:
+                raise WireError(f"block crc mismatch at byte {checked[bad, 0]}")
+    if not parts:
+        return None, len(blocks) - at, at
+    parts.reverse()
+    rows = min(held, cap)
+    columns = []
+    for c in _GRU_COLUMNS:
+        pieces = [p[c] for p in parts]
+        pieces[0] = pieces[0][held - rows :]
+        out = np.empty((rows, *pieces[0].shape[1:]), np.result_type(*{p.dtype for p in pieces}))
+        with library:
+            _gather(lib, pieces, out)
+        columns.append(out)
+    return columns, len(blocks) - at, at
+
+
 def read_gru_tail(
     path: str | os.PathLike,
     cap: int,
@@ -956,6 +1054,7 @@ def read_gru_tail(
     end: int | None = None,
     verify_crc: bool = True,
     tally: BlockTally | None = None,
+    native_phase=None,
 ):
     """The newest ``cap`` piece sequences of the ``train`` blocks in
     ``[offset, end)``, in file order → ``PieceSequences`` (all of them
@@ -969,28 +1068,37 @@ def read_gru_tail(
     Only the blocks decoded here are CRC-checked here. The blocks hopped
     over are checked by the round's MLP read of the same range, which
     decodes every block (``read_train_pairs``, ``stream_train_pairs``):
-    a corrupt block anywhere still fails that fit."""
+    a corrupt block anywhere still fails that fit.
+
+    Where the native library loaded, the hop, the check and the copies
+    are calls of it that hold no interpreter lock, and the interpreter
+    parses the headers of the blocks it keeps and no others
+    (``_gru_tail_by_the_library``): beside a scheduler's decision
+    workers a week's 53,760 short iterations each waited to have the
+    lock back. Where it did not, the interpreter does it all
+    (``_gru_tail_interpreted``): the same arrays, tally and errors
+    either way. ``native_phase``, when given, is a phase
+    (utils/profiling.py) told the library's seconds, summed over its
+    calls, once a read (``observe``): not at all where the library did
+    not load."""
+    from dragonfly2_tpu.schema import native
     from dragonfly2_tpu.schema.features import PieceSequences, extract_piece_sequences
 
     end = _clamped_end(path, end)
     if offset >= end:
         return extract_piece_sequences({})
-    parts: list[dict[str, np.ndarray]] = []  # newest block first
-    held = 0
+    lib = native.load()
     with _mapped(path) as mm:
-        blocks = list(_hop_mapped(mm, offset, end))
-        at = len(blocks)
-        while at and held < cap:
-            at -= 1
-            header, cols = _decode_body(mm, *blocks[at], verify_crc, _GRU_COLUMNS)
-            if header["kind"] == KIND_TRAIN and len(cols["gru.sequences"]):
-                parts.append(cols)
-                held += len(cols["gru.sequences"])
+        if lib is None:
+            columns, decoded, hopped = _gru_tail_interpreted(mm, cap, offset, end, verify_crc)
+        else:
+            library = _Stopwatch()
+            try:
+                columns, decoded, hopped = _gru_tail_by_the_library(lib, library, mm, cap, offset, end, verify_crc)
+            finally:
+                if native_phase is not None:
+                    native_phase.observe(library.total)
     if tally is not None:
-        tally.decoded += len(blocks) - at
-        tally.hopped += at
-    if not parts:
-        return extract_piece_sequences({})
-    return PieceSequences(
-        *(np.concatenate([p[c] for p in reversed(parts)])[-cap:] for c in _GRU_COLUMNS)
-    )
+        tally.decoded += decoded
+        tally.hopped += hopped
+    return PieceSequences(*columns) if columns else extract_piece_sequences({})

@@ -173,8 +173,20 @@ FIT_STAGES = (
 # interpreter still does: the table's arithmetic and, from a block the
 # library was not sure of, the rest of the walk
 MLP_LOAD_STAGES = ("load_walk", "load_assemble", "load_walk_native")
+# The GRU leg's own, inside its load: the tail of the upload read through
+# the native library, which holds no interpreter lock (schema/wire.py
+# read_gru_tail: the range hopped by df_hop_blocks, the kept blocks checked
+# by df_crc32_blocks, their three columns laid by df_gather). The library's
+# calls lie either side of the kept headers' parse, which is the
+# interpreter's, so the read sums their seconds and tells the phase once
+# (``observe``, not ``with``: the ledger's, on no trace): the count says the
+# library read the tail (1 a fit; 0 where it did not load and the
+# interpreter hopped and decoded block by block), the total is the library's
+# seconds, and load - load_native is what the interpreter still does: the
+# file's mapping, a json.loads a kept block, the views
+GRU_LOAD_STAGES = ("load_native",)
 # entered inside another stage, on the leg's thread or on a worker's: in no split
-INNER_STAGES = frozenset({*MLP_LOAD_STAGES, "feed_slice", "epoch_slice", "load_span", "load_check"})
+INNER_STAGES = frozenset({*MLP_LOAD_STAGES, *GRU_LOAD_STAGES, "feed_slice", "epoch_slice", "load_span", "load_check"})
 
 
 # What a resident fit puts on the chip: its table (every column, once a
@@ -204,7 +216,7 @@ def _fit_phases(leg: str, stages: tuple = FIT_STAGES) -> _LegPhases:
 LEG_PHASES = {
     "mlp": _fit_phases("mlp", FIT_STAGES + MLP_LOAD_STAGES),
     "gnn": _fit_phases("gnn"),
-    "gru": _fit_phases("gru"),
+    "gru": _fit_phases("gru", FIT_STAGES + GRU_LOAD_STAGES),
 }
 PH_MLP, PH_GNN, PH_GRU = LEG_PHASES.values()
 # unix timestamp of the last SUCCESSFUL fit per model: the telemetry

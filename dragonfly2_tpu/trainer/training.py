@@ -1016,6 +1016,7 @@ class Training:
                     cap,
                     end=self.storage.download_round_boundary(host_id, binary=True),
                     tally=blocks,
+                    native_phase=M.PH_GRU.load_native,
                 )
             cpath = self.storage.download_path(host_id)
             if seqs.sequences.shape[0] < cap and cpath.exists() and cpath.stat().st_size:

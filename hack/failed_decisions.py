@@ -61,7 +61,7 @@ BEAT_S = 0.005
 PHASES = (
     "trainer.round", "trainer.mlp_fit", "trainer.gru_fit", "trainer.gnn_fit", "trainer.mlp_load", "trainer.mlp_load_walk",
     "trainer.mlp_load_walk_native", "trainer.mlp_load_assemble", "trainer.mlp_load_span", "trainer.mlp_load_check",
-    "trainer.gru_load", "trainer.gnn_load", "trainer.mlp_table_put", "trainer.mlp_feed_slice", "trainer.mlp_epoch_dispatch",
+    "trainer.gru_load", "trainer.gru_load_native", "trainer.gnn_load", "trainer.mlp_table_put", "trainer.mlp_feed_slice", "trainer.mlp_epoch_dispatch",
     "trainer.gru_epoch_dispatch", "trainer.gnn_epoch_dispatch", "process.gc_full", "scheduler.find_parents",
     "scheduler.find_parents_beside_walk", "scheduler.find_parents_beside_assemble", "scheduler.find_parents_beside_fit_shared",
     "scheduler.find_parents_beside_fit_alone", "scheduler.find_parents_beside_idle", "scheduler.gnn_reembed", "topology.flush",

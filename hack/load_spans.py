@@ -33,6 +33,17 @@ beside each of ``--beside``'s thread counts (``--walk --beside 0 16``: alone
 and beside 16), on both paths in turn: a line a reading with the walk's
 seconds, the library's seconds inside them, and how many of the blocks'
 headers the interpreter parsed (all of them, or none).
+
+``--gru-tail`` (ISSUE 46) reads the GRU leg's load alone,
+``wire.read_gru_tail`` of the newest ``--cap`` sequences, beside each of
+``--beside``'s thread counts, on both paths in turn: a line a reading with
+the tail's seconds and its pieces apart, each summed over its calls from
+outside (the module's names replaced by timed ones for the reading): the
+hop (the generator's list, or ``df_hop_blocks``), the kept headers
+(``json.loads``, and how many), the check (``zlib.crc32`` once a block, or
+``df_crc32_blocks``), the copies (``np.concatenate``, or ``df_gather``),
+what the read told ``native_phase`` and how often, and the tally. The first
+line is the first read of the file by this process.
 """
 
 from __future__ import annotations
@@ -152,6 +163,64 @@ def walking(path: str, beside: int, duty: float, library: bool) -> dict:
     }
 
 
+def timed(into: dict, piece: str, call):
+    """``call`` with its seconds and its calls summed into ``into[piece]``."""
+
+    def timed_call(*args, **kw):
+        t0 = time.perf_counter()
+        try:
+            return call(*args, **kw)
+        finally:
+            seconds, calls = into.get(piece, (0.0, 0))
+            into[piece] = (seconds + time.perf_counter() - t0, calls + 1)
+
+    return timed_call
+
+
+class Timed:
+    """``target`` with the calls of its attributes named in ``pieces``
+    timed under the piece's name (``timed``)."""
+
+    def __init__(self, target, pieces: dict, into: dict):
+        self.target, self._pieces, self._into = target, pieces, into
+
+    def __getattr__(self, name):
+        attr = getattr(self.target, name)
+        return timed(self._into, self._pieces[name], attr) if name in self._pieces else attr
+
+
+def tail(path: str, cap: int, beside: int, duty: float, library: bool) -> dict:
+    """The GRU tail alone, its pieces timed from outside: ``library``
+    False is ``DF_NO_NATIVE``."""
+    use_library(library)
+    told, tally, pieces = profiling.Phase("hack.gru_load_native"), wire.BlockTally(), {}
+    lib, load, hop = native.load(), native.load, wire._hop_mapped
+    timed_lib = Timed(lib, {"df_hop_blocks": "hop", "df_crc32_blocks": "check", "df_gather": "copies"}, pieces)
+    if lib is not None:
+        native.load = lambda: timed_lib
+    wire._hop_mapped = timed(pieces, "hop", lambda *a: list(hop(*a)))
+    wire.json, wire.zlib = Timed(json, {"loads": "headers"}, pieces), Timed(wire.zlib, {"crc32": "check"}, pieces)
+    wire.np = Timed(np, {"concatenate": "copies"}, pieces)
+    try:
+        with others_wanting_the_interpreter(beside, duty):
+            time.sleep(0.05)  # the others under way
+            t0 = time.perf_counter()
+            seqs = wire.read_gru_tail(path, cap, tally=tally, native_phase=told)
+            t1 = time.perf_counter()
+    finally:
+        native.load, wire._hop_mapped = load, hop
+        wire.json, wire.zlib, wire.np = wire.json.target, wire.zlib.target, np
+    out = {
+        "gru_tail": True, "beside": beside, "duty": duty, "library": lib is not None, "tail_s": round(t1 - t0, 4),
+        "told_native_s": round(told.total_s, 4), "told": told.count,
+        **{f"{piece}_s": round(pieces.get(piece, (0.0, 0))[0], 4) for piece in ("hop", "headers", "check", "copies")},
+        "calls": {piece: calls for piece, (_, calls) in sorted(pieces.items())},
+        "decoded": tally.decoded, "hopped": tally.hopped, "sequences": int(seqs.labels.shape[0]),
+    }
+    out["rest_s"] = round(out["tail_s"] - sum(out[f"{piece}_s"] for piece in ("hop", "headers", "check", "copies")), 4)
+    return out
+
+
 def parts(path: str, threads: int) -> dict:
     """The assembly's pieces, each alone on ``threads`` threads: the
     CRCs, the first touch of an array of the features' size (a write a
@@ -220,6 +289,8 @@ def main() -> int:
     ap.add_argument("--parts", action="store_true", help="the assembly's pieces alone, by thread count, in place of the sweep")
     ap.add_argument("--beside", type=int, nargs="+", help="the walk and the assembly on each path beside this many threads that want the interpreter, in place of the sweep")
     ap.add_argument("--walk", action="store_true", help="the walk alone on both paths, beside each of --beside's thread counts (default 0 16), in place of the sweep")
+    ap.add_argument("--gru-tail", action="store_true", help="the GRU leg's read of its tail alone on both paths, its pieces apart, beside each of --beside's thread counts (default 0 16), in place of the sweep")
+    ap.add_argument("--cap", type=int, default=1_000_000, help="the sequences --gru-tail keeps (TrainingConfig.gru_max_sequences)")
     ap.add_argument("--duty", type=float, nargs="+", default=[0.03], help="the share of its time such a thread works in Python")
     args = ap.parse_args()
     width, span_blocks = wire.ASSEMBLY_THREADS, wire.ASSEMBLY_SPAN_BLOCKS  # the module's own, before a reading sets others
@@ -233,12 +304,21 @@ def main() -> int:
         t0 = time.perf_counter()
         stage(path, args.chunks, args.bodies, args.body_records, args.seed)
         print(json.dumps({"staged_bytes": os.path.getsize(path), "stage_s": round(time.perf_counter() - t0, 2)}), flush=True)
-        reading(path, 1, span_blocks, 0)  # the mapping's pages, once
-        if args.beside or args.walk:
+        if args.gru_tail:
+            print(json.dumps({**tail(path, args.cap, 0, 1.0, True), "first": True}), flush=True)
+        else:
+            reading(path, 1, span_blocks, 0)  # the mapping's pages, once
+        if args.beside or args.walk or args.gru_tail:
             from dragonfly2_tpu.colocated.server import SWITCH_INTERVAL_S
 
             sys.setswitchinterval(SWITCH_INTERVAL_S)
             print(json.dumps({"switch_interval_s": SWITCH_INTERVAL_S, "library": native.available()}), flush=True)
+        if args.gru_tail:
+            for _ in range(args.repeats):
+                for beside, duty in ((b, d) for b in args.beside or [0, 16] for d in (args.duty if b else args.duty[:1])):
+                    for library in (False, True):
+                        print(json.dumps(tail(path, args.cap, beside, duty, library)), flush=True)
+            return 0
         if args.walk:
             for _ in range(args.repeats):
                 for beside, duty in ((b, d) for b in args.beside or [0, 16] for d in (args.duty if b else args.duty[:1])):

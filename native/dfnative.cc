@@ -1339,6 +1339,42 @@ bool scan_header(const unsigned char* h, size_t header_len, int64_t payload_len,
   return true;
 }
 
+// The hop, once for every export that walks a range: the blocks of
+// ``[start, end)`` of the mapping at ``base`` stepped over by their
+// preambles under the rules of schema/wire.py _hop_mapped (a torn tail
+// ends the walk; so does anything but the magic at a block's edge) ->
+// how many whole blocks were walked. ``block(n, pos, header_len,
+// payload_len)`` is called for the n-th whole block and says whether to
+// go on. ``*stopped_at`` is the byte of the block's edge the walk stopped
+// at short of the range's end or its torn tail (no magic there, or
+// ``block`` said no), where the interpreter's hop takes over; else -1.
+constexpr int64_t kPreamble = 16;  // magic, header_len u32, payload_len u64
+template <typename Block>
+long hop_blocks(const unsigned char* base, int64_t start, int64_t end,
+                int64_t* stopped_at, Block block) {
+  long n = 0;
+  *stopped_at = -1;
+  for (int64_t pos = start; pos < end && end - pos >= kPreamble; ++n) {
+    const unsigned char* b = base + pos;
+    uint32_t header_len;
+    uint64_t payload_len;
+    memcpy(&header_len, b + 4, 4);
+    memcpy(&payload_len, b + 8, 8);
+    const uint64_t left = uint64_t(end - pos - kPreamble);
+    if (memcmp(b, "DFB1", 4) != 0) {
+      *stopped_at = pos;
+      break;
+    }
+    if (header_len > left || payload_len > left - header_len) break;  // torn tail
+    if (!block(n, pos, header_len, payload_len)) {
+      *stopped_at = pos;
+      break;
+    }
+    pos += kPreamble + int64_t(header_len) + int64_t(payload_len);
+  }
+  return n;
+}
+
 }  // namespace
 
 // ---------------------------------------------------------------------------
@@ -1519,48 +1555,48 @@ void df_gather(unsigned char* dst, const int64_t* pieces, long n) {
   }
 }
 
-// The blocks of ``[start, end)`` of the mapping at ``base`` hopped by
-// their preambles under the rules of schema/wire.py _hop_mapped (a torn
-// tail ends the walk; so does anything but the magic at a block's edge)
-// -> how many whole blocks were walked. With ``rows`` null nothing else
-// is read: the count a caller makes room by. Else a row of kWalkColumns
-// numbers a block is written (schema/wire.py WALK_COLUMNS): where the
-// block and its payload lie and what scan_header read of its header. The
-// walk stops at the first block whose header scan_header is not sure of,
-// and at the ``cap``-th. ``*stopped_at`` is the byte of the block's edge
-// it stopped at short of the range's end or its torn tail (no magic
-// there, no header it reads, no room for the row), where the interpreter's
-// walk takes over, to raise or to read; else -1.
+// The blocks of ``[start, end)`` walked (hop_blocks) -> how many whole
+// blocks. With ``rows`` null nothing but the preambles is read: the count
+// a caller makes room by. Else a row of kWalkColumns numbers a block is
+// written (schema/wire.py WALK_COLUMNS): where the block and its payload
+// lie and what scan_header read of its header. The walk stops at the
+// first block whose header scan_header is not sure of, and at the
+// ``cap``-th: ``*stopped_at`` is that block's edge (no header it reads,
+// no room for the row), where the interpreter's walk takes over, to raise
+// or to read.
 long df_walk_blocks(const unsigned char* base, int64_t start, int64_t end,
                     int64_t* rows, long cap, int64_t* stopped_at) {
-  constexpr int64_t kPreamble = 16;  // magic, header_len u32, payload_len u64
-  long n = 0;
-  *stopped_at = -1;
-  for (int64_t pos = start; pos < end && end - pos >= kPreamble; ++n) {
-    const unsigned char* b = base + pos;
-    uint32_t header_len;
-    uint64_t payload_len;
-    memcpy(&header_len, b + 4, 4);
-    memcpy(&payload_len, b + 8, 8);
-    const uint64_t left = uint64_t(end - pos - kPreamble);
-    if (memcmp(b, "DFB1", 4) != 0) {
-      *stopped_at = pos;
-      break;
-    }
-    if (header_len > left || payload_len > left - header_len) break;  // torn tail
-    if (rows != nullptr) {
-      int64_t* row = rows + n * kWalkColumns;
-      if (n == cap || !scan_header(b + kPreamble, header_len, int64_t(payload_len), row)) {
-        *stopped_at = pos;
-        break;
-      }
-      row[kWalkPos] = pos;
-      row[kWalkPayload] = pos + kPreamble + header_len;
-      row[kWalkNbytes] = int64_t(payload_len);
-    }
-    pos += kPreamble + int64_t(header_len) + int64_t(payload_len);
-  }
-  return n;
+  return hop_blocks(base, start, end, stopped_at,
+                    [=](long n, int64_t pos, uint32_t header_len, uint64_t payload_len) {
+    if (rows == nullptr) return true;
+    int64_t* row = rows + n * kWalkColumns;
+    if (n == cap || !scan_header(base + pos + kPreamble, header_len, int64_t(payload_len), row))
+      return false;
+    row[kWalkPos] = pos;
+    row[kWalkPayload] = pos + kPreamble + header_len;
+    row[kWalkNbytes] = int64_t(payload_len);
+    return true;
+  });
+}
+
+// The same hop with no header read: three numbers a block written to
+// ``blocks`` (where it begins, its header's length, its payload's: what
+// schema/wire.py _hop_mapped yields), for a reader that parses the
+// headers of a few blocks itself (schema/wire.py read_gru_tail, from the
+// last block backwards). With ``blocks`` null the count alone; it stops
+// at the ``cap``-th block as df_walk_blocks does.
+long df_hop_blocks(const unsigned char* base, int64_t start, int64_t end,
+                   int64_t* blocks, long cap, int64_t* stopped_at) {
+  return hop_blocks(base, start, end, stopped_at,
+                    [=](long n, int64_t pos, uint32_t header_len, uint64_t payload_len) {
+    if (blocks == nullptr) return true;
+    if (n == cap) return false;
+    int64_t* block = blocks + 3 * n;
+    block[0] = pos;
+    block[1] = header_len;
+    block[2] = int64_t(payload_len);
+    return true;
+  });
 }
 
 }  // extern "C"

@@ -1439,6 +1439,238 @@ class TestTheLibrarysWalkIsTheInterpreters:
         assert len(entered.left) == (1 if check_path[0] == "library" else 0)
 
 
+def _both_tails(monkeypatch, path, cap, offset=0, end=None, verify_crc=True) -> list:
+    """``read_gru_tail`` through the native library and without it →
+    what each told: the three arrays in forms that compare, or the error
+    by its type and its words, and the tally."""
+    told = []
+    for by in ("library", "interpreter"):
+        tally = wire.BlockTally()
+        with monkeypatch.context() as m:
+            if by == "interpreter":
+                m.setenv("DF_NO_NATIVE", "1")
+            got = _outcome(lambda: wire.read_gru_tail(path, cap, offset=offset, end=end, verify_crc=verify_crc, tally=tally))
+        if not isinstance(got, tuple):
+            got = [(a.tobytes(), a.dtype.str, a.shape) for a in (got.sequences, got.labels, got.lengths)]
+        told.append((got, (tally.decoded, tally.hopped)))
+    return told
+
+
+def _gru_block(rng, seqs: int, **columns) -> bytes:
+    """``_block`` with sequences, and any of its columns as given."""
+    header, cols, _ = wire.decode_block(_block(rng, 4, seqs, 2))
+    return wire.encode_block({**cols, **columns}, wire.KIND_TRAIN, records=header["records"], meta=header["meta"])
+
+
+def _without_sequences(block: bytes) -> bytes:
+    return _reheaded(block, lambda h: _set(h, cols=[e for e in h["cols"] if e[0] != "gru.sequences"]))
+
+
+def _packed_lengths(block: bytes) -> bytes:
+    return _reheaded(block, lambda h: (_col(h, "gru.lengths").__setitem__(3, "packed"), h)[1])
+
+
+def _wider_gru_labels(block: bytes) -> bytes:
+    def lay_out(entry, held, at):
+        wide = np.frombuffer(held, np.float32).astype(np.float64).tobytes()
+        entry[1], entry[4], entry[5] = "<f8", at, len(wide)
+        return wide
+
+    return _repacked(block, "gru.labels", lay_out)
+
+
+class _Told:
+    """What ``read_gru_tail`` takes for ``native_phase``: told seconds."""
+
+    def __init__(self):
+        self.seconds = []
+
+    def observe(self, seconds):
+        self.seconds.append((threading.current_thread().name, seconds))
+
+
+class TestTheLibrarysTailIsTheInterpreters:
+    """Where the native library loaded the GRU tail is read through it
+    (the hop, the check and the copies a call each, the kept blocks'
+    headers the interpreter's), held to the interpreter's read array for
+    array, tally for tally and error for error (ISSUE 46)."""
+
+    @pytest.mark.parametrize("cap", [0, 1, 3, 5, 10, 12, 29, 30, 31, 1000])
+    def test_caps_below_at_and_above_the_total(self, tmp_path, monkeypatch, with_the_library, cap):
+        path, extents = _write_upload(tmp_path, "even")  # 6 blocks of 5 sequences
+        by_library, interpreted = _both_tails(monkeypatch, path, cap)
+        assert by_library == interpreted
+        assert by_library[1] == (min(-(-cap // 5), 6), 6 - min(-(-cap // 5), 6))
+        assert by_library[0] == [(a.tobytes(), a.dtype.str, a.shape) for a in _reference_gru(path, cap)]
+
+    @pytest.mark.parametrize("which", ["whole", "inner", "from-second-block", "to-last-block", "empty-range", "end-past-the-file"])
+    @pytest.mark.parametrize("cap", [2, 8, 1000])
+    @pytest.mark.parametrize("name", sorted(_UPLOADS) + sorted(_SPANNED))
+    def test_the_two_tails_tell_the_same(self, tmp_path, monkeypatch, with_the_library, name, cap, which):
+        """Uploads with blocks of other kinds between, ``train`` blocks
+        of no sequences (passed over alike, and counted as decoded), a
+        torn tail, no ``train`` block at all, under ``offset`` and
+        ``end``, an empty range and an ``end`` past the file."""
+        path, extents = _write_upload(tmp_path, name) if name in _UPLOADS else _write_blocks(tmp_path, name, _SPANNED[name], seed=31)
+        offset, end = {"empty-range": (extents[-1][0], extents[-1][0]), "end-past-the-file": (0, 10**12)}.get(which) or _bounds(extents, which)
+        by_library, interpreted = _both_tails(monkeypatch, path, cap, offset, end)
+        assert by_library == interpreted
+        assert by_library[0] == [(a.tobytes(), a.dtype.str, a.shape) for a in _reference_gru(path, cap, offset, end)]
+        assert sum(by_library[1]) == len(wire.scan_block_extents(path, offset, end))
+
+    @pytest.mark.parametrize("tail", [1, 7, 15, 16, 17, 40])
+    def test_a_torn_tail_of_any_length_ends_the_hop(self, tmp_path, monkeypatch, with_the_library, tail):
+        """Less than a preamble, a preamble alone, a preamble and part of
+        the header: the whole blocks before it are the range."""
+        path, extents = _write_upload(tmp_path, "even")
+        whole = path.read_bytes()
+        path.write_bytes(whole + whole[:tail])
+        by_library, interpreted = _both_tails(monkeypatch, path, 8)
+        assert by_library == interpreted and by_library[1] == (2, 4)
+        assert by_library[0] == [(a.tobytes(), a.dtype.str, a.shape) for a in _reference_gru(path, 8)]
+
+    @pytest.mark.parametrize("verify_crc", [True, False])
+    @pytest.mark.parametrize("corrupt", [(5,), (4,), (4, 5), (3,), (0,), (0, 3)])
+    def test_a_corrupt_kept_block_raises_and_a_hopped_one_is_not_the_tails(self, tmp_path, monkeypatch, with_the_library, corrupt, verify_crc):
+        """Cap 8 of "even" keeps the last two blocks. A flipped byte
+        there raises for that block (for the newest of two, the first
+        the interpreter's path checks), before any array is handed back;
+        one in a block hopped over is the MLP leg's to find, and with
+        ``verify_crc`` off nothing is checked."""
+        path, extents = _write_upload(tmp_path, "even")
+        for at in corrupt:
+            _flip(path, extents[at])
+        by_library, interpreted = _both_tails(monkeypatch, path, 8, verify_crc=verify_crc)
+        assert by_library == interpreted
+        if verify_crc and max(corrupt) >= 4:
+            assert by_library == (("WireError", f"block crc mismatch at byte {extents[max(corrupt)][0]}"), (0, 0))
+        else:
+            assert by_library[1] == (2, 4) and by_library[0][1][2] == (8,)
+
+    @pytest.mark.parametrize("at", [5, 4, 1])
+    @pytest.mark.parametrize("fault", sorted(f for f in _HEADERS if "crc32" in f))
+    def test_a_header_with_no_usable_crc32(self, tmp_path, monkeypatch, with_the_library, fault, at):
+        """A ``crc32`` that is no integer of 32 bits matches no payload:
+        a mismatch at its block where the block is kept (and a
+        ``KeyError`` where the header states none), nothing where it is
+        hopped over."""
+        rng = np.random.default_rng(32)
+        blocks = [_block(rng, 7, 5, 3) for _ in range(6)]
+        blocks[at] = _HEADERS[fault][0](blocks[at])
+        path = tmp_path / "reheaded.dfb"
+        path.write_bytes(b"".join(blocks))
+        by_library, interpreted = _both_tails(monkeypatch, path, 8)
+        assert by_library == interpreted
+        edge = sum(len(b) for b in blocks[:at])
+        if at == 1:
+            assert by_library[1] == (2, 4)
+        else:
+            assert by_library == (("KeyError", "'crc32'") if fault == "no-crc32" else ("WireError", f"block crc mismatch at byte {edge}"), (0, 0))
+        with_crc_off = _both_tails(monkeypatch, path, 8, verify_crc=False)
+        assert with_crc_off[0] == with_crc_off[1] and with_crc_off[0][1] == (2, 4)
+
+    @pytest.mark.parametrize("offset_past_it", [False, True])
+    @pytest.mark.parametrize("at", [0, 2, 5])
+    def test_garbage_at_a_block_edge(self, tmp_path, monkeypatch, with_the_library, at, offset_past_it):
+        """Anything but the magic at a block's edge stops the library's
+        hop, and the interpreter's takes the range from that byte: to
+        the ``WireError`` it raises, wherever the block lies; a range
+        that begins past it reads as if it were not there."""
+        path, extents = _write_upload(tmp_path, "even")
+        buf = bytearray(path.read_bytes())
+        buf[extents[at][0] + 3] = ord("2")
+        path.write_bytes(bytes(buf))
+        offset = extents[at][1] if offset_past_it else 0
+        by_library, interpreted = _both_tails(monkeypatch, path, 3, offset=offset)
+        assert by_library == interpreted
+        if offset_past_it:
+            assert by_library[1] == ((1, 4 - at) if at < 5 else (0, 0))
+        else:
+            assert by_library == (("WireError", f"bad block magic at byte {extents[at][0]}: b'DFB2'"), (0, 0))
+
+    @pytest.mark.parametrize("older_too", [False, True])
+    @pytest.mark.parametrize("fault", ["no-json", "no-sequences-column", "an-unknown-encoding", "not-a-train-block-s-kind"])
+    def test_a_kept_header_the_interpreter_cannot_read(self, tmp_path, monkeypatch, with_the_library, fault, older_too):
+        """The kept blocks' headers are the interpreter's on both paths,
+        and so are their errors. Where a newer kept block is corrupt as
+        well (``older_too``: the fault lies in the older of two kept),
+        the mismatch is raised, as by the path that checks a block
+        before it parses the next."""
+        rng = np.random.default_rng(33)
+        blocks = [_block(rng, 7, 5, 3) for _ in range(6)]
+        at = 4 if older_too else 5
+        blocks[at] = {
+            "no-json": _HEADERS["no-json"][0], "no-sequences-column": _without_sequences, "an-unknown-encoding": _packed_lengths,
+            "not-a-train-block-s-kind": lambda b: _reheaded(b, lambda h: _set(h, kind="other")),
+        }[fault](blocks[at])
+        path = tmp_path / "reheaded.dfb"
+        path.write_bytes(b"".join(blocks))
+        edges = np.cumsum([0] + [len(b) for b in blocks]).tolist()
+        if older_too:
+            _flip(path, (edges[5], edges[6]))
+        by_library, interpreted = _both_tails(monkeypatch, path, 8)
+        assert by_library == interpreted
+        if older_too:
+            assert by_library[0] == ("WireError", f"block crc mismatch at byte {edges[5]}")
+        elif fault == "not-a-train-block-s-kind":
+            assert by_library[1] == (3, 3)  # passed over, and an older block kept in its place
+        else:
+            assert by_library[0][0] in {"no-json": ("JSONDecodeError",), "no-sequences-column": ("KeyError",), "an-unknown-encoding": ("WireError",)}[fault]
+
+    @pytest.mark.parametrize("cap", [4, 9, 1000])
+    @pytest.mark.parametrize("column", ["labels-all-zero", "lengths-all-zero", "labels-of-another-type", "as-written"])
+    def test_a_column_that_is_no_view_of_the_mapping(self, tmp_path, monkeypatch, with_the_library, column, cap):
+        """A kept block's column ``zero``-encoded (``encode_block``
+        writes one that is all zeros so) is an array of zeros and no
+        view; one stated in another type makes the whole column that of
+        ``np.concatenate``'s promotion. Laid by the library where the
+        pieces are bytes end to end, by numpy where they are not."""
+        rng = np.random.default_rng(34)
+        blocks = [_gru_block(rng, 3 + i) for i in range(5)]
+        if column == "labels-all-zero":
+            blocks[3] = _gru_block(rng, 6, **{"gru.labels": np.zeros(6, np.float32)})
+        elif column == "lengths-all-zero":
+            blocks[4] = _gru_block(rng, 7, **{"gru.lengths": np.zeros(7, np.int32)})
+        elif column == "labels-of-another-type":
+            blocks[3] = _wider_gru_labels(blocks[3])
+        path = tmp_path / "columns.dfb"
+        path.write_bytes(b"".join(blocks))
+        by_library, interpreted = _both_tails(monkeypatch, path, cap)
+        assert by_library == interpreted
+        assert by_library[0] == [(a.tobytes(), a.dtype.str, a.shape) for a in _reference_gru(path, cap)]
+        assert by_library[0][1][1] == ("<f8" if column == "labels-of-another-type" and cap > 7 else "<f4")
+
+    def test_the_library_parses_the_kept_headers_alone_and_is_counted_once(self, tmp_path, monkeypatch, check_path):
+        """With the library, ``json.loads`` runs once a kept block and
+        for no block hopped over, the generator hops nothing, no
+        ``zlib.crc32`` is called, and ``native_phase`` is told the
+        library's seconds once a read, by the reading thread; without
+        it never. An empty range tells nothing on either."""
+        path, extents = _write_upload(tmp_path, "even")
+        parsed, hopped, checked = [], [], []
+        loads, hop, crc32 = json.loads, wire._hop_mapped, zlib.crc32
+        monkeypatch.setattr(wire.json, "loads", lambda *a, **kw: (parsed.append(1), loads(*a, **kw))[1])
+        monkeypatch.setattr(wire, "_hop_mapped", lambda *a: (hopped.append(1), hop(*a))[1])
+        monkeypatch.setattr(wire.zlib, "crc32", lambda *a: (checked.append(1), crc32(*a))[1])
+        told, tally = _Told(), wire.BlockTally()
+        got = wire.read_gru_tail(path, 8, tally=tally, native_phase=told)
+        assert got.labels.shape == (8,) and (tally.decoded, tally.hopped) == (2, 4) and len(parsed) == 2
+        by_library = check_path[0] == "library"
+        assert (len(hopped), len(checked)) == ((0, 0) if by_library else (1, 2))
+        assert [t for t, _ in told.seconds] == ([threading.current_thread().name] if by_library else [])
+        assert all(0 < s < 5 for _, s in told.seconds)
+        wire.read_gru_tail(path, 8, offset=extents[3][0], end=extents[3][0], native_phase=told)
+        assert len(told.seconds) == int(by_library)
+
+    def test_the_library_is_told_even_where_the_read_raises(self, tmp_path, with_the_library):
+        path, extents = _write_upload(tmp_path, "even")
+        _flip(path, extents[5])
+        told = _Told()
+        with pytest.raises(wire.WireError, match=f"block crc mismatch at byte {extents[5][0]}"):
+            wire.read_gru_tail(path, 8, native_phase=told)
+        assert len(told.seconds) == 1
+
+
 def test_round_reports_blocks_decoded_and_hopped(tmp_path):
     """A round on a multi-block upload with a small GRU cap: the GRU leg
     decodes only the blocks that hold the cap and hops the rest, the

@@ -112,6 +112,9 @@ def test_the_phases_are_the_windows_and_the_librarys_walk_is_among_them(run):
     assert phases.get("mlp_load_walk_native", [0, 0.0])[0] == (rounds if native.available() else 0)
     assert phases.get("mlp_load_walk_native", [0, 0.0])[1] <= phases["mlp_load_walk"][1] <= phases["mlp_load"][1]
     assert phases["find_parents"][0] == run["told"]["decisions"]
+    # the GRU leg's tail read through the library, told once a fit inside its load
+    assert phases["gru_load"][0] == rounds and phases.get("gru_load_native", [0, 0.0])[0] == (rounds if native.available() else 0)
+    assert phases.get("gru_load_native", [0, 0.0])[1] <= phases["gru_load"][1]
 
 
 def test_the_parent_runs_a_process_a_seed_in_the_tree_and_keeps_the_lines(run, tmp_path, monkeypatch, capsys):

@@ -30,7 +30,7 @@ EPOCHS = {"mlp": 2, "gnn": 4, "gru": 3}
 ONCE_A_FIT = ("load", "split", "table_put", "holdout", "register")
 ONCE_AN_EPOCH = ("gather", "feed", "epoch_dispatch", "epoch_wait")
 # entered inside one of those on the leg's thread: the ledger's and the trace's, in no split
-INSIDE_ANOTHER = ("fit", "load_walk", "load_assemble", "load_walk_native", "feed_slice", "epoch_slice", "load_span", "load_check")
+INSIDE_ANOTHER = ("fit", "load_walk", "load_assemble", "load_walk_native", "load_native", "feed_slice", "epoch_slice", "load_span", "load_check")
 STREAM_PHASES = ("trainer.decode_wait", "trainer.buffer_wait", "trainer.h2d", "trainer.step")
 
 
@@ -161,6 +161,8 @@ def _expected_inside(leg: str, streaming: bool) -> dict:
     if leg == "mlp":
         want.update({"trainer.mlp_load_walk": 1, "trainer.mlp_load_assemble": 1, "trainer.mlp_load_span": 1})
         want["trainer.mlp_load_walk_native"] = int(native.available())  # the library's walk, where it loaded
+    if leg == "gru":
+        want["trainer.gru_load_native"] = int(native.available())  # the tail read through the library, streamed round or resident
     return want
 
 
@@ -421,6 +423,58 @@ def test_the_load_is_its_walk_and_its_assembly(tmp_path, monkeypatch, path):
     assert split.phase_n[phases["load"].name] == 1
     assert not {phases[stage].name for stage in stages[1:]} & set(split.phase_n)
     assert split.phase_s[phases["load"].name] == pytest.approx(load, abs=1e-5)
+
+
+@pytest.mark.parametrize("path", ["library", "interpreter"])
+def test_the_gru_tail_read_through_the_library_counts_once_a_fit_inside_the_load(tmp_path, monkeypatch, path):
+    """``gru_load_native`` is told the library's seconds once a fit, by
+    the leg's thread while its ``load`` is open: count 1 where the
+    library read the tail, its seconds held by ``load``'s, in no split;
+    0 and no seconds under ``DF_NO_NATIVE``, where the interpreter
+    hopped and decoded block by block. It is the GRU leg's stage and no
+    other's, and the fit is handed the same arrays either way."""
+    import dragonfly2_tpu.trainer.train as train_mod
+
+    if path == "interpreter":
+        monkeypatch.setenv("DF_NO_NATIVE", "1")
+    elif not native.available():
+        pytest.skip("native library unavailable (no toolchain)")
+    handed = []
+    monkeypatch.setattr(train_mod, "train_gru", lambda sequences, labels, lengths=None, **kw: handed.append((sequences, labels, lengths)) or 1 / 0)
+    training = _training(tmp_path, False, gru_max_sequences=40, gru_min_sequences=1)
+    host_id = host_id_v2(IP, HOSTNAME)
+    for i in range(6):
+        training.storage.append_download_blocks(host_id, wire.encode_train_block(synth.make_download_records(64, seed=50 + i)))
+    training.storage.mark_download_round(host_id)
+    load, told = M.PH_GRU.load, M.PH_GRU.load_native
+    open_when_told = []
+    real_observe = profiling.Phase.observe
+
+    def watched(ph, seconds):
+        if ph is told:
+            open_when_told.append((load.active, threading.current_thread().name))
+        return real_observe(ph, seconds)
+
+    monkeypatch.setattr(profiling.Phase, "observe", watched)
+    before = {ph: ph.snapshot() for ph in (load, told)}
+    splits: dict = {}
+    with pytest.raises(ZeroDivisionError):
+        training._timed_fit("gru", None, splits, training._train_gru, host_id, IP, HOSTNAME)
+    moved = {ph: (ph.snapshot()["count"] - before[ph]["count"], ph.snapshot()["total_s"] - before[ph]["total_s"]) for ph in (load, told)}
+    by_library = path == "library"
+    assert (moved[load][0], moved[told][0]) == (1, int(by_library))
+    assert open_when_told == ([(1, threading.current_thread().name)] if by_library else [])
+    assert (0 < moved[told][1] <= moved[load][1]) if by_library else moved[told][1] == 0
+    assert told.inner and told.name == "trainer.gru_load_native" and told.name not in splits["gru"].phase_n
+    assert not hasattr(M.PH_MLP, "load_native") and not hasattr(M.PH_GNN, "load_native")
+    assert 0 < splits["gru"].blocks_decoded < 6 and splits["gru"].blocks_decoded + splits["gru"].blocks_hopped == 6
+    with monkeypatch.context() as m:
+        m.setenv("DF_NO_NATIVE", "1")
+        want = wire.read_gru_tail(training.storage.download_blocks_path(host_id), 40)
+    ((sequences, labels, lengths),) = handed
+    assert len(want.labels) == 40
+    for got, w in zip((sequences, labels, lengths), (want.sequences, want.labels, want.lengths)):
+        np.testing.assert_array_equal(np.asarray(got), w)
 
 
 @pytest.mark.parametrize("path", ["library", "interpreter", "handed-over"])
